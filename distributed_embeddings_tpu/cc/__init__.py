@@ -5,17 +5,22 @@ nvcc (`/root/reference/Makefile:38-52`); here TPU device code is Pallas
 (``ops/pallas_apply.py``) and the native host code — the data loader — is
 built by the Makefile in this directory into ``_data_loader.so``.
 
-``load_data_loader()`` returns the ctypes library, building it on first use
-if a toolchain is available; callers fall back to the numpy path when it
-returns None.
+``load_data_loader()`` returns the ctypes library, (re)building it on first
+use when ``data_loader.cc`` is newer than the ``.so`` (``make`` decides —
+the ``.so`` is git-ignored, so a checkout may carry a stale one or none);
+callers fall back to the numpy path when it returns None, which is logged
+once.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
+
+logger = logging.getLogger(__name__)
 
 _CC_DIR = os.path.dirname(os.path.abspath(__file__))
 _SO_PATH = os.path.join(_CC_DIR, "_data_loader.so")
@@ -52,14 +57,17 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def build(force: bool = False) -> bool:
-  """Compile ``_data_loader.so``; returns success."""
-  if os.path.exists(_SO_PATH) and not force:
-    return True
+  """Bring ``_data_loader.so`` up to date with ``data_loader.cc`` (``make``
+  compares their timestamps; ``force`` rebuilds regardless); returns
+  success."""
   try:
     subprocess.run(["make", "-C", _CC_DIR, "-s"] + (["-B"] if force else []),
                    check=True, capture_output=True, timeout=120)
     return os.path.exists(_SO_PATH)
-  except (subprocess.SubprocessError, OSError):
+  except (subprocess.SubprocessError, OSError) as e:
+    detail = getattr(e, "stderr", b"") or b""
+    logger.warning("native data loader build failed: %s %s", e,
+                   detail.decode(errors="replace")[-500:])
     return False
 
 
@@ -70,10 +78,12 @@ def load_data_loader():
     if _lib is not None or _load_attempted:
       return _lib
     _load_attempted = True
-    if not build():
-      return None
-    try:
-      _lib = _configure(ctypes.CDLL(_SO_PATH))
-    except OSError:
-      _lib = None
+    if build():
+      try:
+        _lib = _configure(ctypes.CDLL(_SO_PATH))
+      except OSError as e:
+        logger.warning("native data loader failed to load: %s", e)
+    if _lib is None:
+      logger.warning("native data loader unavailable: the numpy reader "
+                     "path is used instead")
     return _lib

@@ -1,65 +1,24 @@
-"""Version-portability shims for JAX APIs whose home has moved.
+"""The JAX names the library and its tests import from one place.
 
-Every site in the library (and the tests) imports these names from here
-instead of guessing which jax version is installed:
+One installation is supported (the jax/jaxlib pinned in pyproject.toml),
+so these are plain aliases — kept so call sites share one import:
 
-- :func:`shard_map`: promoted to ``jax.shard_map`` in newer releases;
-  jax 0.4.x only ships ``jax.experimental.shard_map.shard_map``. Prefer
-  the top-level name when present (the experimental module is slated for
-  removal once the promotion lands everywhere).
-- :func:`enable_x64`: ``jax.enable_x64`` was removed (jax 0.4.31+ raises
-  AttributeError); the supported context manager is
-  ``jax.experimental.enable_x64``. Newer releases expose the same thing
-  under ``jax.experimental`` too, so one import order serves all.
+- ``shard_map = jax.shard_map``. Its autodiff tracks varying vs
+  replicated values, so differentiating a body that mixes a replicated
+  (``P()``) param into device-varying math sums that param's grad across
+  the axis itself, exactly once. Callers never psum replicated-param
+  grads again (summing twice would double-count); model-parallel shards'
+  grads are rank-local by construction and are never summed.
+- ``enable_x64 = jax.enable_x64``.
+- ``axis_size = jax.lax.axis_size``: a static Python int at trace time.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-  shard_map = jax.shard_map
-else:  # jax <= 0.4.x: experimental home
-  from jax.experimental.shard_map import shard_map  # noqa: F401
-
-# True when shard_map's autodiff inserts the replicated-param grad psum
-# itself: the promoted ``jax.shard_map`` tracks varying-vs-replicated
-# values (the VMA machinery), so differentiating a body that mixes a
-# replicated param into device-varying math transposes the implicit
-# broadcast into a psum. The 0.4.x experimental shard_map has no such
-# rewrite for in-body autodiff — grads of replicated params come back
-# DEVICE-LOCAL, and callers must psum explicitly (see
-# ``finalize_hybrid_grads`` / ``training.make_sparse_train_step``).
-SHARD_MAP_PSUMS_REPLICATED_GRADS = hasattr(jax, "shard_map")
-
-
-def psum_replicated_grads(tree, axis_name):
-  """Cross-device sum of replicated-param grads, exactly once per step.
-
-  No-op on jax versions whose shard_map already summed them (summing
-  twice would double-count); an explicit ``lax.psum`` on 0.4.x. Call on
-  grads of REPLICATED (``P()``) params only — model-parallel shards'
-  grads are rank-local by construction and must never be summed."""
-  if SHARD_MAP_PSUMS_REPLICATED_GRADS:
-    return tree
-  return jax.tree_util.tree_map(
-      lambda g: jax.lax.psum(g, axis_name), tree)
-
-try:
-  from jax.experimental import enable_x64  # noqa: F401
-except ImportError:  # pragma: no cover - releases that finished the move
-  enable_x64 = jax.enable_x64
-
-
-def axis_size(axis_name):
-  """Static size of a mapped mesh axis.
-
-  ``jax.lax.axis_size`` landed after 0.4.37; on older releases
-  ``lax.psum`` of a Python constant constant-folds to the axis size (an
-  int at trace time — no collective is emitted)."""
-  if hasattr(jax.lax, "axis_size"):
-    return jax.lax.axis_size(axis_name)
-  return jax.lax.psum(1, axis_name)
-
+shard_map = jax.shard_map
+enable_x64 = jax.enable_x64
+axis_size = jax.lax.axis_size
 
 __all__ = ["shard_map", "enable_x64", "axis_size"]

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import SHARD_MAP_PSUMS_REPLICATED_GRADS, axis_size
+from ..compat import axis_size
 from ..parallel.lookup_engine import (
     DistributedLookup,
     class_param_name,
@@ -435,22 +435,8 @@ def finalize_hybrid_grads(grads, axis_name: str = "mp"):
   to non-distributed training — which is what the reference achieves with
   ``register_local_var`` + averaging Horovod allreduce
   (`dist_model_parallel.py:715-773`).
-
-  On jax 0.4.x, whose experimental shard_map does NOT insert the
-  replicated-grad psum during in-body autodiff
-  (``compat.SHARD_MAP_PSUMS_REPLICATED_GRADS``), the psum is applied here
-  explicitly — to replicated leaves only; ``mp_table_*`` shard grads are
-  rank-local by construction and summing them would mix different tables'
-  row windows.
   """
   scale = 1.0 / axis_size(axis_name)
-  if not SHARD_MAP_PSUMS_REPLICATED_GRADS:
-    def fin(path, g):
-      names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
-      if is_model_parallel_param(names):
-        return g * scale
-      return jax.lax.psum(g, axis_name) * scale
-    return jax.tree_util.tree_map_with_path(fin, grads)
   return jax.tree_util.tree_map(lambda g: g * scale, grads)
 
 

@@ -343,10 +343,7 @@ def mxu_operand_dtype(dtype):
   of trace-time backend detection."""
   if dtype != jnp.float32:
     return dtype
-  try:
-    if jax.default_backend() != "tpu":
-      return dtype
-  except RuntimeError:
+  if jax.default_backend() != "tpu":
     return dtype
   prec = jax.config.jax_default_matmul_precision
   if prec not in (None, "default", "bfloat16", "fastest"):
@@ -356,10 +353,7 @@ def mxu_operand_dtype(dtype):
 
 def _use_pallas_apply() -> bool:
   """True when the Pallas RMW apply kernel can run (real TPU backend)."""
-  try:
-    return jax.default_backend() == "tpu"
-  except RuntimeError:
-    return False
+  return jax.default_backend() == "tpu"
 
 
 def scatter_add_fused(layout: PackedLayout, buf: jax.Array, ids: jax.Array,

@@ -47,6 +47,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_util import out_struct
+
+# the kernel's name in HLO (the Mosaic custom call) and in device traces
+KERNEL_NAME = "de_apply_rows_cached"
+
 
 def _apply_kernel(slots, chunk, scaled, warm, unroll,
                   *refs):
@@ -284,7 +289,7 @@ def apply_rows_cached(buf: jax.Array, ids: jax.Array, delta: jax.Array,
                              unroll)
   in_specs = [
       pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.SMEM),
-      pl.BlockSpec(memory_space=pltpu.ANY),  # buf (aliased)
+      pl.BlockSpec(memory_space=pl.ANY),  # buf (aliased)
       pl.BlockSpec((chunk, w), lambda i: (i, 0)),
   ]
   operands = [ids, buf, delta]
@@ -295,8 +300,8 @@ def apply_rows_cached(buf: jax.Array, ids: jax.Array, delta: jax.Array,
       kernel,
       grid=((n + pad) // chunk,),
       in_specs=in_specs,
-      out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-      out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+      out_specs=pl.BlockSpec(memory_space=pl.ANY),
+      out_shape=out_struct(buf.shape, buf.dtype, *operands),
       scratch_shapes=[
           pltpu.SMEM((slots,), jnp.int32),
           pltpu.SMEM((slots,), jnp.int32),
@@ -309,4 +314,5 @@ def apply_rows_cached(buf: jax.Array, ids: jax.Array, delta: jax.Array,
       input_output_aliases={1: 0},
       compiler_params=pltpu.CompilerParams(has_side_effects=True),
       interpret=interpret,
+      name=KERNEL_NAME,
   )(*operands)

@@ -26,7 +26,7 @@ to one row are totally ordered exactly as this loop orders them, and
 in-flight DMA only ever touches distinct rows. Any divergence between
 this simulator and ``np.add.at`` is therefore a real state-machine bug,
 not a timing artifact (the semaphore/pipelining layer is validated on
-hardware by ``make tpu-smoke``).
+hardware by ``make chip-smoke``).
 
 The eviction in the kernel writes ``ebuf`` to ``buf_out`` ABSOLUTELY (not
 add) — correct because rbuf captured the row's pre-accumulation value and

@@ -25,10 +25,11 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_util import out_struct
 
 PHYS = 128
 _MAX_KB = 256
@@ -106,7 +107,8 @@ def build_delta_rows(layout, rule, dz, sub, aux, h: int, step,
           pl.BlockSpec((kb, a_last), lambda i: (i, 0)),
       ],
       out_specs=pl.BlockSpec((kb, h * PHYS), lambda i: (i, 0)),
-      out_shape=jax.ShapeDtypeStruct((k, h * PHYS), jnp.float32),
+      out_shape=out_struct((k, h * PHYS), jnp.float32, dz, sub2, aux2),
       interpret=interpret,
+      name="de_delta_build",
   )(step_arr, dz, sub2, aux2)
   return out.reshape(n, PHYS)

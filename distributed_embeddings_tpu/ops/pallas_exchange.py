@@ -55,19 +55,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_util import out_struct
+
 LANES = 128
 
-# renamed TPUCompilerParams -> CompilerParams across JAX releases, and the
-# field set differs (0.4.x has no has_side_effects — not needed here: the
-# kernel writes a real output, there is no aliased in-place buffer)
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
-def _compiler_params(**want):
-  import dataclasses
-  fields = {f.name for f in dataclasses.fields(_CompilerParams)}
-  return _CompilerParams(**{k: v for k, v in want.items() if k in fields})
+# the kernel's name in HLO (the Mosaic custom call) and in device traces
+KERNEL_NAME = "de_exchange_gather_send"
 
 
 def _use_pallas_exchange() -> bool:
@@ -77,10 +70,7 @@ def _use_pallas_exchange() -> bool:
   default) AND a real TPU backend."""
   if os.environ.get("DE_TPU_PALLAS_EXCHANGE", "0") != "1":
     return False
-  try:
-    return jax.default_backend() == "tpu"
-  except RuntimeError:
-    return False
+  return jax.default_backend() == "tpu"
 
 
 def _exchange_kernel(chunk, nchunks, remote, *refs):
@@ -169,27 +159,25 @@ def _call_exchange(buf: jax.Array, flat_ids: jax.Array, nbr: jax.Array,
         [flat_ids, jnp.full((pad,), -1, flat_ids.dtype)])
   nchunks = (n + pad) // chunk
   kernel = functools.partial(_exchange_kernel, chunk, nchunks, remote)
-  params = dict(has_side_effects=True)
-  if collective_id is not None:
-    params["collective_id"] = collective_id
-  params = _compiler_params(**params)
   return pl.pallas_call(
       kernel,
       in_specs=[
           pl.BlockSpec(memory_space=pltpu.SMEM),   # ids
           pl.BlockSpec(memory_space=pltpu.SMEM),   # (send_to, recv_from)
-          pl.BlockSpec(memory_space=pltpu.ANY),    # buf
+          pl.BlockSpec(memory_space=pl.ANY),       # buf
       ],
-      out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-      out_shape=jax.ShapeDtypeStruct((n + pad, LANES), buf.dtype),
+      out_specs=pl.BlockSpec(memory_space=pl.ANY),
+      out_shape=out_struct((n + pad, LANES), buf.dtype, flat_ids, nbr, buf),
       scratch_shapes=[
           pltpu.VMEM((2, chunk, LANES), jnp.float32),
           pltpu.SemaphoreType.DMA((2,)),
           pltpu.SemaphoreType.DMA((2,)),
           pltpu.SemaphoreType.DMA((2,)),
       ],
-      compiler_params=params,
+      compiler_params=pltpu.CompilerParams(has_side_effects=True,
+                                           collective_id=collective_id),
       interpret=interpret,
+      name=KERNEL_NAME,
   )(flat_ids, nbr, buf)
 
 

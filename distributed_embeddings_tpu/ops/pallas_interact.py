@@ -45,8 +45,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_util import out_struct
+
 FWD_BLOCK = 256
 BWD_BLOCK = 128
+
+# the kernels' names in HLO (the Mosaic custom calls) and in device traces
+PARTS_FWD_NAME = "de_interact_parts_fwd"
+PARTS_BWD_NAME = "de_interact_parts_bwd"
+FWD_NAME = "de_interact_fwd"
+BWD_NAME = "de_interact_bwd"
 
 
 def use_pallas_interact(b: int, f: int, d: int, dtype) -> bool:
@@ -59,10 +67,7 @@ def use_pallas_interact(b: int, f: int, d: int, dtype) -> bool:
     return False  # f=1 with k=-1 has zero pairs: XLA handles the empty einsum
   if b % FWD_BLOCK != 0 or b % BWD_BLOCK != 0:
     return False
-  try:
-    return jax.default_backend() == "tpu"
-  except RuntimeError:
-    return False
+  return jax.default_backend() == "tpu"
 
 
 def xla_reference(flat: jax.Array, m_np, f: int) -> jax.Array:
@@ -154,8 +159,9 @@ def interact_parts_fwd(parts, m3: jax.Array,
           pl.BlockSpec((FWD_BLOCK, d), lambda i: (i, 0)) for _ in range(f)
       ],
       out_specs=pl.BlockSpec((FWD_BLOCK, npair), lambda i: (i, 0)),
-      out_shape=jax.ShapeDtypeStruct((b, npair), jnp.float32),
+      out_shape=out_struct((b, npair), jnp.float32, parts),
       interpret=interpret,
+      name=PARTS_FWD_NAME,
   )(m3, *parts)
 
 
@@ -177,10 +183,11 @@ def interact_parts_bwd(d_acts: jax.Array, parts, m3t: jax.Array,
       out_specs=[
           pl.BlockSpec((BWD_BLOCK, d), lambda i: (i, 0)) for _ in range(f)
       ],
-      out_shape=[jax.ShapeDtypeStruct((b, d), jnp.bfloat16)
+      out_shape=[out_struct((b, d), jnp.bfloat16, d_acts, parts)
                  for _ in range(f)],
       scratch_shapes=[pltpu.VMEM((BWD_BLOCK, f, f), jnp.float32)],
       interpret=interpret,
+      name=PARTS_BWD_NAME,
   )(m3t, d_acts, *parts)
   return tuple(outs)
 
@@ -198,8 +205,9 @@ def interact_fwd(feats: jax.Array, m3: jax.Array,
           pl.BlockSpec((FWD_BLOCK, f, d), lambda i: (i, 0, 0)),
       ],
       out_specs=pl.BlockSpec((FWD_BLOCK, npair), lambda i: (i, 0)),
-      out_shape=jax.ShapeDtypeStruct((b, npair), jnp.float32),
+      out_shape=out_struct((b, npair), jnp.float32, feats),
       interpret=interpret,
+      name=FWD_NAME,
   )(m3, feats)
 
 
@@ -217,7 +225,8 @@ def interact_bwd(d_acts: jax.Array, feats: jax.Array,
           pl.BlockSpec((BWD_BLOCK, f, d), lambda i: (i, 0, 0)),
       ],
       out_specs=pl.BlockSpec((BWD_BLOCK, f, d), lambda i: (i, 0, 0)),
-      out_shape=jax.ShapeDtypeStruct((b, f, d), jnp.bfloat16),
+      out_shape=out_struct((b, f, d), jnp.bfloat16, d_acts, feats),
       scratch_shapes=[pltpu.VMEM((BWD_BLOCK, f, f), jnp.float32)],
       interpret=interpret,
+      name=BWD_NAME,
   )(m3t, d_acts, feats)

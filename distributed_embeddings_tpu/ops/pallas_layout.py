@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .pallas_util import out_struct
+
 _MAX_BLOCK_ELEMS = 1 << 19  # ~2 MiB f32 per block INCLUDING tile padding
 
 
@@ -37,10 +39,7 @@ def row_major(x: jax.Array) -> jax.Array:
   a [1, S, 8] f32 block is S x 128 x 4 bytes in VMEM, not S x 8 x 4).
   No-op off-TPU or when no even blocking fits the budget (the pin is an
   optimization, never a semantic requirement)."""
-  try:
-    if jax.default_backend() != "tpu":
-      return x
-  except RuntimeError:
+  if jax.default_backend() != "tpu":
     return x
   if x.ndim < 2 or x.size == 0:
     return x
@@ -67,5 +66,6 @@ def row_major(x: jax.Array) -> jax.Array:
       grid=grid,
       in_specs=[pl.BlockSpec(block, imap)],
       out_specs=pl.BlockSpec(block, imap),
-      out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+      out_shape=out_struct(x.shape, x.dtype, x),
+      name="de_row_major_pin",
   )(x)

@@ -15,8 +15,10 @@ from .mesh import (
     DEFAULT_AXIS,
     batch_sharding,
     create_mesh,
+    device_summary,
     initialize_multihost,
     replicated,
+    require_tpu,
     table_sharding,
 )
 
@@ -33,7 +35,9 @@ __all__ = [
     "DEFAULT_AXIS",
     "batch_sharding",
     "create_mesh",
+    "device_summary",
     "initialize_multihost",
     "replicated",
+    "require_tpu",
     "table_sharding",
 ]

@@ -118,10 +118,7 @@ def _use_pallas_delta() -> bool:
   twins (docs/BENCHMARKS.md round-5 staging study)."""
   if os.environ.get("DE_TPU_PALLAS_DELTA", "0") != "1":
     return False
-  try:
-    return jax.default_backend() == "tpu"
-  except RuntimeError:
-    return False
+  return jax.default_backend() == "tpu"
 
 
 def class_param_name(width: int, combiner: Optional[str],

@@ -20,6 +20,28 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DEFAULT_AXIS = "mp"
 
 
+def device_summary() -> dict:
+  """The devices as JAX reports them; every entry point prints this next
+  to its results so a number can never be read without its device."""
+  devices = jax.devices()
+  return {"platform": devices[0].platform,
+          "kind": devices[0].device_kind,
+          "count": len(devices)}
+
+
+def require_tpu(who: str) -> dict:
+  """Exit non-zero unless the default backend is a TPU.
+
+  For programs whose result only means something on the chip (the
+  benchmark, the kernel smokes): they have no CPU mode, and a run that
+  found no accelerator must say so rather than continue."""
+  dev = device_summary()
+  if dev["platform"] != "tpu":
+    raise SystemExit(f"{who}: needs a TPU backend, found {dev}; "
+                     "there is no CPU mode")
+  return dev
+
+
 def create_mesh(world_size: Optional[int] = None,
                 axis_name: str = DEFAULT_AXIS,
                 devices: Optional[Sequence[jax.Device]] = None) -> Mesh:

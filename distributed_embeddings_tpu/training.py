@@ -34,7 +34,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import axis_size, psum_replicated_grads, shard_map
+from .compat import axis_size, shard_map
 
 from .layers.dist_model_parallel import (
     DistributedOptimizer,
@@ -391,25 +391,52 @@ def init_scale_spans(plan: DistEmbeddingStrategy, key, rank: int):
 
 def draw_packed_class(plan: DistEmbeddingStrategy, key, layout,
                       rule: SparseRule, sub: jax.Array,
-                      dtype=jnp.float32) -> jax.Array:
+                      dtype=jnp.float32, mesh: Optional[Mesh] = None,
+                      axis_name: str = "mp") -> jax.Array:
   """Draw one sparse class's fused buffer (all ranks stacked) directly in
-  packed physical layout — device-side, deterministic in ``sub``."""
+  packed physical layout — device-side, deterministic in ``sub``.
+
+  With ``mesh``, every rank's block is drawn ON ITS OWN DEVICE by one
+  SPMD program and the buffer is born sharded ``P(axis_name)``: no device
+  ever holds more than its block, which is what lets a model whose
+  tables exceed one chip's memory initialise at all. Without a mesh there
+  is one device to draw on, and it gets the whole stack (abstract
+  evaluation, single-device runs, small tests). Both forms draw the same
+  values."""
   from .ops.packed_table import init_packed_uniform
-  blocks = []
-  for r in range(plan.world_size):
-    spans = init_scale_spans(plan, key, r)
+  world = plan.world_size
+  spans = [init_scale_spans(plan, key, r) for r in range(world)]
+  # one span table for all ranks (short ranks padded with empty spans), so
+  # a single program — indexed by the rank it runs as — serves every rank
+  n_spans = max(len(sp) for sp in spans)
+  offs = np.zeros((world, n_spans), np.int32)
+  lens = np.zeros((world, n_spans), np.int32)
+  scales = np.zeros((world, n_spans), np.float32)
+  for r, sp in enumerate(spans):
+    for j, (off, n, sc) in enumerate(sp):
+      offs[r, j], lens[r, j], scales[r, j] = off, n, sc
 
-    def build(k, spans=tuple(spans), layout=layout):
-      r_idx = jnp.arange(layout.rows, dtype=jnp.int32)
-      scale_rows = jnp.zeros((layout.rows,), dtype)
-      for off, n, sc in spans:
-        scale_rows = jnp.where((r_idx >= off) & (r_idx < off + n), sc,
-                               scale_rows)
-      return init_packed_uniform(layout, k, scale_rows, rule.aux_init,
-                                 dtype)
+  def block(k, rank):
+    off, n, sc = (jnp.asarray(t)[rank] for t in (offs, lens, scales))
+    r_idx = jnp.arange(layout.rows, dtype=jnp.int32)
+    scale_rows = jnp.zeros((layout.rows,), dtype)
+    for j in range(n_spans):
+      scale_rows = jnp.where(
+          (r_idx >= off[j]) & (r_idx < off[j] + n[j]),
+          sc[j].astype(dtype), scale_rows)
+    return init_packed_uniform(layout, jax.random.fold_in(k, rank),
+                               scale_rows, rule.aux_init, dtype)
 
-    blocks.append(jax.jit(build)(jax.random.fold_in(sub, r)))
-  return jnp.concatenate(blocks) if len(blocks) > 1 else blocks[0]
+  if mesh is not None:
+    # check_vma off: init_packed_uniform's chunk scan starts from a
+    # replicated zeros carry and writes rank-varying chunks into it, which
+    # the varying-axes check rejects; nothing here is differentiated
+    return jax.jit(shard_map(
+        lambda k: block(k, jax.lax.axis_index(axis_name)), mesh=mesh,
+        in_specs=P(), out_specs=P(axis_name), check_vma=False))(sub)
+  stacked = jax.jit(jax.vmap(block, in_axes=(None, 0)))(
+      sub, jnp.arange(world, dtype=jnp.int32))
+  return stacked.reshape(world * layout.phys_rows, layout.phys_width)
 
 
 def init_sparse_state_direct(plan: DistEmbeddingStrategy,
@@ -420,7 +447,8 @@ def init_sparse_state_direct(plan: DistEmbeddingStrategy,
                              emb_dense_optimizer: Optional[
                                  optax.GradientTransformation] = None,
                              axis_name: str = "mp",
-                             dtype=jnp.float32) -> Dict[str, Any]:
+                             dtype=jnp.float32,
+                             mesh: Optional[Mesh] = None) -> Dict[str, Any]:
   """Build the fused train state WITHOUT materializing simple-layout tables.
 
   :func:`init_sparse_state` packs tables out of a fully-initialized params
@@ -437,6 +465,11 @@ def init_sparse_state_direct(plan: DistEmbeddingStrategy,
     dense_params: the model's non-embedding params (e.g. from
       ``model.init(rng, numerical, cats, emb_acts=dummy)``, which skips
       embedding param creation entirely).
+    mesh: the mesh the state will train on. Given, the state is BORN on
+      it — each rank's packed block drawn on its own device, the rest
+      placed per :func:`shard_params` — so a multi-chip model never
+      passes through one chip's memory. ``None`` builds everything on the
+      default device (world 1, abstract evaluation, small tests).
   """
   from .layers.dist_model_parallel import make_class_initializer
 
@@ -450,20 +483,20 @@ def init_sparse_state_direct(plan: DistEmbeddingStrategy,
     sub = jax.random.fold_in(rng, ki)
     if cp.kind == "sparse":
       fused[name] = draw_packed_class(plan, key, layouts[name], rule, sub,
-                                      dtype)
+                                      dtype, mesh, axis_name)
     else:
       shape = (plan.world_size * padded_rows(plan, key), cp.width)
       emb_dense[name] = make_class_initializer(plan, key)(sub, shape, dtype)
 
   opt = emb_dense_optimizer or dense_optimizer
-  return {
+  return shard_params({
       "dense": dense_params,
       "dense_opt": dense_optimizer.init(dense_params),
       "emb_dense": emb_dense,
       "emb_dense_opt": opt.init(emb_dense),
       "fused": fused,
       "step": jnp.zeros((), jnp.int32),
-  }
+  }, mesh, axis_name)
 
 
 def unpack_sparse_state(plan: DistEmbeddingStrategy, rule: SparseRule,
@@ -584,14 +617,12 @@ def _reduce_and_apply_dense(state, loss, d_dense, d_emb_dense, d_z, rank,
   application. Returns ``(loss, dense, dense_opt, emb_dense,
   emb_dense_opt, d_z)`` — ``d_z`` rescaled for the caller's scatter."""
   if mesh is not None:
-    # replicated-param grads must be summed across devices exactly once:
-    # newer shard_map's autodiff does it implicitly, 0.4.x needs the
-    # explicit psum (compat.psum_replicated_grads is a no-op in the
-    # former case). A uniform 1/world rescale (dense grads AND sparse
-    # cotangents) then restores exact global-batch-mean semantics (see
+    # replicated-param grads arrive already summed across devices
+    # (shard_map's autodiff does it, exactly once — see compat). A
+    # uniform 1/world rescale (dense grads AND sparse cotangents) then
+    # restores exact global-batch-mean semantics (see
     # finalize_hybrid_grads). emb_dense blocks are mp-SHARDED per-rank
     # windows — never summed.
-    d_dense = psum_replicated_grads(d_dense, axis_name)
     scale = 1.0 / axis_size(axis_name)
     d_dense, d_emb_dense, d_z = jax.tree_util.tree_map(
         lambda g: g * scale, (d_dense, d_emb_dense, d_z))
@@ -891,14 +922,10 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
     streams = {name: (ids.reshape(-1), rows.reshape(-1, rows.shape[-1]))
                for name, (ids, rows) in streams_s.items()}
     if mesh is not None:
-      # the one replicated-param grad reduction for the whole step (on
-      # newer shard_map the body's autodiff already psummed each
-      # micro-batch's grads, so the shim is a no-op — an unconditional
-      # psum would double-count there); the emb_dense blocks are
-      # mp-SHARDED (per-rank windows), so their grads are already
-      # rank-local — summing them across ranks would mix different
-      # tables' windows
-      d_dense = psum_replicated_grads(d_dense, axis_name)
+      # no grad reduction is written here (see compat.shard_map); the
+      # emb_dense blocks are mp-SHARDED (per-rank windows), so their
+      # grads are already rank-local — summing them across ranks would
+      # mix different tables' windows
       loss = jax.lax.pmean(loss, axis_name)
 
     if guard:

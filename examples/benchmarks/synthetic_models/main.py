@@ -50,6 +50,7 @@ def main():
   if args.platform:
     jax.config.update("jax_platforms", args.platform)
 
+  from distributed_embeddings_tpu.compile_cache import enable_compile_cache
   from distributed_embeddings_tpu.models import (
       SYNTHETIC_MODELS,
       SyntheticModel,
@@ -58,13 +59,14 @@ def main():
       generate_batch,
       model_size_gib,
   )
-  from distributed_embeddings_tpu.parallel import create_mesh
+  from distributed_embeddings_tpu.parallel import create_mesh, device_summary
   from distributed_embeddings_tpu.training import (
       make_train_step,
       shard_batch,
       shard_params,
   )
 
+  enable_compile_cache()
   cfg = SYNTHETIC_MODELS[args.model]
   if args.shrink != 1.0:
     groups = tuple(
@@ -72,13 +74,14 @@ def main():
         for g in cfg.embedding_groups)
     cfg = dataclasses.replace(cfg, embedding_groups=groups)
 
-  devices = jax.devices()
-  world = args.world_size or len(devices)
+  dev = device_summary()
+  world = args.world_size or dev["count"]
   mesh = create_mesh(world) if world > 1 else None
   tables, tmap, hotness = expand_tables(cfg)
   print(f"model={cfg.name} tables={len(tables)} inputs={len(tmap)} "
         f"size={model_size_gib(cfg):.1f} GiB world={world} "
-        f"batch={args.batch_size} platform={devices[0].platform}")
+        f"batch={args.batch_size} platform={dev['platform']} "
+        f"device_kind={dev['kind']!r} devices={dev['count']}")
 
   model = SyntheticModel(config=cfg, world_size=world,
                          strategy=args.strategy,

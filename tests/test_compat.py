@@ -1,21 +1,11 @@
-"""Direct unit tests of the version-portability shims in `compat.py`.
+"""Pins the installed JAX's behaviour behind the names in `compat.py`.
 
-Every distributed module routes through these four names; until now they
-were exercised only transitively (a shim regression surfaced as 11
-modules failing at import). These tests pin each shim's CONTRACT so they
-hold on both jax homes (0.4.x experimental shard_map vs the promoted
-``jax.shard_map``):
-
-- ``shard_map``: resolves to whichever home exists and maps a body over
-  the mesh;
-- ``enable_x64``: context-manages 64-bit mode on and back off;
-- ``axis_size``: static mesh-axis size inside a mapped body (no
-  collective at runtime — it must constant-fold under jit);
-- ``psum_replicated_grads``: grads of a REPLICATED param, taken inside a
-  shard_map body over device-sharded data, come out as the global sum
-  EXACTLY ONCE — the explicit psum on 0.4.x, a no-op where shard_map's
-  autodiff already inserted it (summing twice would double-count; zero
-  times would train on 1/world of the gradient).
+Every distributed module routes through these three names, and the step
+builders rest on one property of ``jax.shard_map`` that nothing else
+states: grads of a REPLICATED param, taken inside a mapped body over
+device-sharded data, come out as the global sum EXACTLY ONCE, with no
+psum written by the caller (summing again would double-count; zero times
+would train on 1/world of the gradient).
 """
 
 import jax
@@ -29,14 +19,12 @@ from distributed_embeddings_tpu.parallel import create_mesh
 WORLD = 4
 
 
-def test_shard_map_home_resolution():
-  if hasattr(jax, "shard_map"):
-    assert compat.shard_map is jax.shard_map
-    assert compat.SHARD_MAP_PSUMS_REPLICATED_GRADS
-  else:
-    from jax.experimental.shard_map import shard_map as exp_shard_map
-    assert compat.shard_map is exp_shard_map
-    assert not compat.SHARD_MAP_PSUMS_REPLICATED_GRADS
+def test_names_are_the_installed_jax():
+  assert compat.shard_map is jax.shard_map
+  assert compat.axis_size is jax.lax.axis_size
+  assert compat.enable_x64 is jax.enable_x64
+  assert not hasattr(compat, "psum_replicated_grads")
+  assert not hasattr(compat, "SHARD_MAP_PSUMS_REPLICATED_GRADS")
 
 
 def test_shard_map_maps_body_over_mesh():
@@ -61,30 +49,34 @@ def test_enable_x64_context_roundtrip():
 
 def test_axis_size_is_static_inside_shard_map():
   mesh = create_mesh(WORLD)
+  seen = []
 
   def body(xl):
-    # a Python int at trace time — usable as a shape/scale constant
     world = compat.axis_size("mp")
+    seen.append(world)
     return xl + jnp.float32(world)
 
   f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P("mp"),),
                                out_specs=P("mp")))
   out = np.asarray(f(jnp.zeros(WORLD, jnp.float32)))
   np.testing.assert_array_equal(out, np.full(WORLD, WORLD, np.float32))
+  # a Python int at trace time — usable as a shape/scale constant, and no
+  # collective is left in the program to compute it
+  assert seen and all(type(w) is int and w == WORLD for w in seen)
+  assert "psum" not in str(jax.make_jaxpr(f)(jnp.zeros(WORLD, jnp.float32)))
 
 
-def test_psum_replicated_grads_sums_exactly_once():
+def test_replicated_param_grads_summed_exactly_once():
   """The hybrid-backward convention `training.py` is built on: the
   replicated param's grad equals the sum of every device's local grad —
-  not 1x the local grad (0.4.x without the shim) and not world x the
-  global sum (double-psum)."""
+  not 1x the local grad and not world x the global sum — with no
+  explicit psum in the body."""
   mesh = create_mesh(WORLD)
   x = jnp.arange(1.0, WORLD + 1.0)          # one element per device
   p0 = jnp.asarray(2.0)
 
   def local_step(p, xl):
     loss, g = jax.value_and_grad(lambda q: jnp.sum(q * xl))(p)
-    g = compat.psum_replicated_grads(g, "mp")
     return g, jax.lax.psum(loss, "mp")
 
   f = jax.jit(compat.shard_map(
@@ -95,17 +87,16 @@ def test_psum_replicated_grads_sums_exactly_once():
   assert float(loss) == float(p0) * float(np.sum(np.asarray(x)))
 
 
-def test_psum_replicated_grads_tree():
-  """Applies leaf-wise over grad pytrees (the call sites hand it the
-  whole dense-grad tree)."""
+def test_replicated_param_grads_tree():
+  """Holds leaf-wise over grad pytrees (the step builders differentiate
+  the whole dense-param tree at once)."""
   mesh = create_mesh(WORLD)
   x = jnp.ones(WORLD)
 
   def body(tree, xl):
     def loss(t):
       return jnp.sum(t["a"] * xl) + jnp.sum(t["b"] * xl) * 2.0
-    g = jax.grad(loss)(tree)
-    return compat.psum_replicated_grads(g, "mp")
+    return jax.grad(loss)(tree)
 
   f = jax.jit(compat.shard_map(
       body, mesh=mesh, in_specs=({"a": P(), "b": P()}, P("mp")),

@@ -3,7 +3,7 @@ regime selection + `ops/pallas_apply` wrapper contracts).
 
 The Pallas kernel itself needs a real TPU (its input/output aliasing has no
 faithful interpret-mode equivalent) — `tools/smoke_pallas_apply.py` /
-`make tpu-smoke` covers it on hardware. Here we pin:
+`make chip-smoke` covers it on hardware. Here we pin:
 - the XLA fallback stays numerically exact for both regimes on CPU;
 - wrapper argument validation;
 - the env-var override logic.

@@ -15,8 +15,7 @@ power-law mix).
 Measured (round 4, v5e, 1M ids / 1M rows, zipf-1.2 stream; chained
 dependency harness): XLA take 11.9 ns/row, this kernel 13.8 ns/row,
 bit-exact parity (an earlier same-args harness read 11.7 vs 11.3; the
-uniform stream's chained timings are unstable through the relay and are
-not cited) — the scalar
+uniform stream's chained timings were unstable and are not cited) — the scalar
 core sustains ~one row DMA per 11 ns, the same rate XLA's gather
 already streams at, so a DMA-per-row Pallas gather (however batched)
 cannot deliver the 2-3x the zoo's gather share would need. The A100
@@ -91,9 +90,9 @@ def pallas_gather(buf, ids, chunk=8192):
       grid=((n + pad) // chunk,),
       in_specs=[
           pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.SMEM),
-          pl.BlockSpec(memory_space=pltpu.ANY),
+          pl.BlockSpec(memory_space=pl.ANY),
       ],
-      out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+      out_specs=pl.BlockSpec(memory_space=pl.ANY),
       out_shape=jax.ShapeDtypeStruct((n + pad, W), buf.dtype),
       scratch_shapes=[pltpu.SemaphoreType.DMA((DEPTH,))],
       compiler_params=pltpu.CompilerParams(has_side_effects=True),

@@ -79,10 +79,10 @@ def rmw_scatter(buf, ids, delta, depth=DEPTH, chunk=CHUNK):
       in_specs=[
           pl.BlockSpec((chunk,), lambda i: (i,),
                        memory_space=pltpu.SMEM),  # ids chunk
-          pl.BlockSpec(memory_space=pltpu.ANY),  # buf (aliased)
+          pl.BlockSpec(memory_space=pl.ANY),  # buf (aliased)
           pl.BlockSpec((chunk, W), lambda i: (i, 0)),  # delta
       ],
-      out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+      out_specs=pl.BlockSpec(memory_space=pl.ANY),
       scratch_shapes=[
           pltpu.VMEM((DEPTH, 1, W), jnp.float32),
           pltpu.VMEM((DEPTH, 1, W), jnp.float32),
@@ -130,10 +130,10 @@ def write_only(buf, ids, delta, depth=DEPTH, chunk=CHUNK):
       grid=(n // chunk,),
       in_specs=[
           pl.BlockSpec((chunk,), lambda i: (i,), memory_space=pltpu.SMEM),
-          pl.BlockSpec(memory_space=pltpu.ANY),
+          pl.BlockSpec(memory_space=pl.ANY),
           pl.BlockSpec((chunk, W), lambda i: (i, 0)),
       ],
-      out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+      out_specs=pl.BlockSpec(memory_space=pl.ANY),
       scratch_shapes=[pltpu.SemaphoreType.DMA((DEPTH,))],
       out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
       input_output_aliases={1: 0},

@@ -6,22 +6,29 @@ ON THE REAL CHIP at the bench feature shape (F=27, D=128) — interpret
 mode covers semantics (tests/test_pallas_interact.py); this validates
 the Mosaic lowering itself (the VMEM concat/scatter + batched MXU dots).
 
-Run: python tools/smoke_pallas_interact.py   (also run by bench.py smoke)
-Exit code 0 = pass.
+Run: python tools/smoke_pallas_interact.py   (leg B of chip_smoke.py)
+Exit code 0 = pass; non-zero on any failure AND on a backend that is not
+a TPU (there is nothing to validate off the chip).
 """
 
+import json
+import os
 import sys
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_embeddings_tpu.compile_cache import enable_compile_cache
 from distributed_embeddings_tpu.models.dlrm import _tril_select_np
 from distributed_embeddings_tpu.ops.pallas_interact import (
     interact_parts_bwd,
     interact_parts_fwd,
     xla_reference,
 )
+from distributed_embeddings_tpu.parallel.mesh import require_tpu
 
 F, D, B = 27, 128, 1024
 
@@ -32,9 +39,7 @@ def _xla_reference(flat, f, k):
 
 
 def main():
-  if jax.default_backend() == "cpu":
-    print("pallas interact smoke skipped: no TPU backend")
-    return
+  print("device:", json.dumps(require_tpu("smoke_pallas_interact")), flush=True)
   rng = np.random.default_rng(5)
   parts = [jnp.asarray(rng.standard_normal((B, D)) * 0.3, jnp.bfloat16)
            for _ in range(F)]
@@ -75,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+  enable_compile_cache()
   main()
