@@ -1,0 +1,10 @@
+"""gather_ms: what it measures is in ``gather_ms.json``; the reduction is
+``benchmark/scope_reduce.py``."""
+
+from benchmark import scope_reduce
+
+SCOPES = ("de_gather",)
+
+
+def read(red, ctx):
+  return scope_reduce.scoped(red, ctx).scope_ms(*SCOPES)

@@ -1,0 +1,10 @@
+"""route_ms: what it measures is in ``route_ms.json``; the reduction is
+``benchmark/scope_reduce.py``."""
+
+from benchmark import scope_reduce
+
+SCOPES = ("de_route",)
+
+
+def read(red, ctx):
+  return scope_reduce.scoped(red, ctx).scope_ms(*SCOPES)

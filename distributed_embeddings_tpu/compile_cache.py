@@ -18,11 +18,21 @@ DEFAULT_DIR = os.path.join(
 
 
 def enable_compile_cache() -> str:
-  """Turn the persistent compilation cache on; returns its directory."""
+  """Turn the persistent compilation cache on; returns its directory.
+
+  The cache key includes the ops' metadata
+  (``jax_compilation_cache_include_metadata_in_key``): an executable carries
+  the op names the profiler shows (the name stack with the scopes of
+  ``telemetry/scopes.py``), and JAX's default key is taken after they are
+  stripped, so a cache that ignores them serves a trace with another
+  build's names, or with none. The price is a compile when traced source
+  moves; a warm run loads as before.
+  """
+  import jax
+  jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
   env_dir = os.environ.get(ENV_VAR)
   if env_dir:
     return env_dir
-  import jax
   jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
   return DEFAULT_DIR
 

@@ -35,6 +35,7 @@ from ..ops.pallas_interact import (
     interact_parts_fwd,
     use_pallas_interact,
 )
+from ..telemetry import scopes
 
 
 class MLP(nn.Module):
@@ -167,6 +168,7 @@ def _pair_bwd(f, k, parts, d_acts):
 _pair_products_pallas.defvjp(_pair_fwd, _pair_bwd)
 
 
+@jax.named_scope(scopes.INTERACT)
 def dot_interact(bottom_out: jax.Array, emb_outs: Sequence[jax.Array],
                  self_interaction: bool = False,
                  pack: int = 1) -> jax.Array:

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import scopes
+
 LANES = 128
 
 # Read ONCE at import (baking an os.environ.get into a jitted trace makes
@@ -252,6 +254,7 @@ def _grp_sub(layout: PackedLayout, ids: jax.Array):
   return grp, sub, valid
 
 
+@jax.named_scope(scopes.GATHER)
 def gather_fused(layout: PackedLayout, buf: jax.Array,
                  ids: jax.Array, masked_phys: bool = False) -> jax.Array:
   """Gather fused rows: ``[..., stride]`` = (table row | aux rows).
@@ -289,6 +292,7 @@ def gather_fused(layout: PackedLayout, buf: jax.Array,
   return out
 
 
+@jax.named_scope(scopes.GATHER)
 def gather_fused_chunked(layout: PackedLayout, buf: jax.Array,
                          ids: jax.Array,
                          chunk: Optional[int] = None,
@@ -356,6 +360,7 @@ def _use_pallas_apply() -> bool:
   return jax.default_backend() == "tpu"
 
 
+@jax.named_scope(scopes.APPLY)
 def scatter_add_fused(layout: PackedLayout, buf: jax.Array, ids: jax.Array,
                       fused_delta: jax.Array,
                       prefer_pallas: bool = False,

@@ -99,6 +99,7 @@ from ..ops.packed_table import (
 )
 from ..ops.ragged import RaggedIds
 from ..ops.sparse_grad import expand_unique_rows, unique_ids_map
+from ..telemetry import scopes
 from . import wire
 
 PAD_ID = -1  # marks hotness padding in dense-padded ragged inputs
@@ -653,6 +654,7 @@ class DistributedLookup:
     return (wire.plan_overlap(self.plan) == "fused"
             and self.plan.world_size > 1)
 
+  @jax.named_scope(scopes.EXCHANGE)
   def _wire_exchange_ids(self, x: jax.Array) -> jax.Array:
     """Integer payload exchange under the plan's overlap knob."""
     if self._pipelined_wire():
@@ -790,6 +792,7 @@ class DistributedLookup:
       all_lens.append(jnp.stack(lens_r))
     return jnp.stack(all_vals), jnp.stack(all_lens)
 
+  @jax.named_scope(scopes.ROUTE)
   def route_ids(self, inputs: Sequence[jax.Array],
                 hotness_of=None) -> Dict[tuple, jax.Array]:
     """dp->mp id exchange: per bucket, global-batch ids for my local tables.
@@ -982,6 +985,7 @@ class DistributedLookup:
         offs[rank, k] = cp.slots_per_rank[rank][idx].row_offset
     return offs
 
+  @jax.named_scope(scopes.ONEHOT)
   def _z_dense(self, key, bucket: Bucket, table_local: jax.Array,
                ids_all: jax.Array) -> jax.Array:
     """Small-vocab lookup as windowed one-hot MXU matmuls (zero row ops).
@@ -1277,6 +1281,7 @@ class DistributedLookup:
     return FusedChunks(tuple(blocks), "raw"), aux
 
   # ---- mp -> dp exchange + assembly --------------------------------------
+  @jax.named_scope(scopes.EXCHANGE)
   def exchange(self, z: Dict[tuple, jax.Array], batch_local: int,
                ids_all: Optional[Dict[tuple, jax.Array]] = None
                ) -> Dict[tuple, jax.Array]:
@@ -1504,6 +1509,7 @@ class DistributedLookup:
                        jnp.concatenate(parts, axis=-1))
     return results
 
+  @jax.named_scope(scopes.ROUTE)
   def mean_counts(self, inputs: Sequence[jax.Array]
                   ) -> Dict[int, jax.Array]:
     """Per-sample valid-id counts for mean x row-sliced inputs.
@@ -1540,6 +1546,7 @@ class DistributedLookup:
     return self.plan.global_configs[
         self.plan.input_table_map[input_id]].input_dim
 
+  @jax.named_scope(scopes.ROUTE)
   def oov_counts(self, inputs: Sequence[jax.Array]) -> Dict[str, jax.Array]:
     """Per-class out-of-vocabulary OCCURRENCE counts for one batch.
 
@@ -1575,6 +1582,7 @@ class DistributedLookup:
         out[name] = out[name] + n
     return out
 
+  @jax.named_scope(scopes.ROUTE)
   def dedup_overflow_counts(self, ids_all: Dict[tuple, jax.Array]
                             ) -> Dict[str, jax.Array]:
     """Per-class dedup-capacity overflow counts for one routed batch.
@@ -1655,11 +1663,14 @@ class DistributedLookup:
           class_params[class_param_name(*key)])
       if self.plan.classes[key].kind == "dense":
         bucket = self._find_bucket(key, bk.h, bk.vcap, hotness_of)
-        z[bk] = self._z_dense(key, bucket, table_local, ids)
+        with jax.named_scope(scopes.COMBINE):
+          z[bk] = self._z_dense(key, bucket, table_local, ids)
       else:
-        z[bk] = self._z_sparse_simple(key, table_local, ids, bk.rs)
-    received = self.exchange(z, b, ids_all)
-    outs = self.assemble(received, hotness_of, counts)
+        with jax.named_scope(scopes.GATHER):
+          z[bk] = self._z_sparse_simple(key, table_local, ids, bk.rs)
+    with jax.named_scope(scopes.COMBINE):
+      received = self.exchange(z, b, ids_all)
+      outs = self.assemble(received, hotness_of, counts)
     if return_residuals:
       return outs, ids_all
     return outs
@@ -1688,6 +1699,7 @@ class DistributedLookup:
     return p
 
   # ---- fused training path -----------------------------------------------
+  @jax.named_scope(scopes.GATHER)
   def lookup_sparse_fused(self, fused_params: Dict[str, jax.Array],
                           layouts: Dict[str, PackedLayout],
                           ids_all: Dict[tuple, jax.Array],
@@ -1726,6 +1738,7 @@ class DistributedLookup:
       aux[bk] = auxb
     return z, SparseResiduals(ids_all=dict(ids_all), aux_rows=aux)
 
+  @jax.named_scope(scopes.COMBINE)
   def finish_forward(self, z_sparse: Dict[tuple, jax.Array],
                      dense_params: Dict[str, jax.Array],
                      ids_all: Dict[tuple, jax.Array],
@@ -1943,6 +1956,7 @@ class DistributedLookup:
                 else jnp.concatenate(all_rows))
     return ids_cat, rows_cat
 
+  @jax.named_scope(scopes.APPLY)
   def sparse_delta_streams(self, layouts: Dict[str, PackedLayout],
                            d_z: Dict[tuple, jax.Array],
                            residuals: SparseResiduals,
@@ -1961,6 +1975,7 @@ class DistributedLookup:
     return {name: self._stream_of_parts(layouts[name], parts, rule, step)
             for name, parts in by_class.items()}
 
+  @jax.named_scope(scopes.APPLY)
   def apply_sparse_streams(self, fused_params: Dict[str, jax.Array],
                            layouts: Dict[str, PackedLayout],
                            streams, rule: SparseRule,
@@ -1984,6 +1999,7 @@ class DistributedLookup:
           delta_scale=(rule.linear_scale(step) if scale_only else None))
     return new_params
 
+  @jax.named_scope(scopes.APPLY)
   def apply_sparse(self, fused_params: Dict[str, jax.Array],
                    layouts: Dict[str, PackedLayout],
                    d_z: Dict[tuple, jax.Array],
@@ -2086,6 +2102,7 @@ class DistributedLookup:
     return new_params
 
   # ---- tiered storage: hot/cold routing + staging buffers ----------------
+  @jax.named_scope(scopes.ROUTE)
   def translate_tiered_ids(self, ids_all: Dict[tuple, jax.Array],
                            tier_specs: Dict[str, "TierSpec"],
                            resident: Dict[str, jax.Array],
@@ -2175,6 +2192,7 @@ class DistributedLookup:
           "route_ids directly.")
     return translator.translate_batch(inputs)
 
+  @jax.named_scope(scopes.GATHER)
   def install_staging(self, fused_params: Dict[str, jax.Array],
                       tier_specs: Dict[str, "TierSpec"],
                       staged_rows: Dict[str, jax.Array]
@@ -2203,6 +2221,7 @@ class DistributedLookup:
           buf, rows.astype(buf.dtype), (spec.cache_grps, 0))
     return out
 
+  @jax.named_scope(scopes.APPLY)
   def staged_regions(self, fused_params: Dict[str, jax.Array],
                      tier_specs: Dict[str, "TierSpec"],
                      staged_rows: Dict[str, jax.Array]
@@ -2218,6 +2237,7 @@ class DistributedLookup:
           buf, (spec.cache_grps, 0), (s, buf.shape[1]))
     return out
 
+  @jax.named_scope(scopes.APPLY)
   def trim_spill(self, fused_params: Dict[str, jax.Array],
                  tier_specs: Dict[str, "TierSpec"]
                  ) -> Dict[str, jax.Array]:
@@ -2279,14 +2299,16 @@ class DistributedLookup:
         g = ids_all.shape[1]
         if g % world:
           raise ValueError(f"Global batch {g} not divisible by world {world}")
+        bk = bucket_key(key, bucket.h, bucket.vcap, bucket.rs)
         if plan.classes[key].kind == "dense":
-          z[bucket_key(key, bucket.h, bucket.vcap, bucket.rs)] = self._z_dense(
-              key, bucket, table_local, ids_all)
+          with jax.named_scope(scopes.COMBINE):
+            z[bk] = self._z_dense(key, bucket, table_local, ids_all)
         else:
-          z[bucket_key(key, bucket.h, bucket.vcap, bucket.rs)] = self._z_sparse_simple(
-              key, table_local, ids_all)
-    received = self.exchange(z, g // world)
-    return self.assemble(received, hotness_of)
+          with jax.named_scope(scopes.GATHER):
+            z[bk] = self._z_sparse_simple(key, table_local, ids_all)
+    with jax.named_scope(scopes.COMBINE):
+      received = self.exchange(z, g // world)
+      return self.assemble(received, hotness_of)
 
 
 def _packed_input_name(key, bucket: Bucket) -> str:

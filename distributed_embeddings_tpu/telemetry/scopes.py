@@ -1,0 +1,30 @@
+"""The names of the step's device scopes: one vocabulary, no logic.
+
+Call sites wrap a stage in ``jax.named_scope(<constant>)``. That is metadata
+written while the step is traced (it lands in every HLO op's ``op_name``,
+which the profiler shows per device op) and costs nothing at run time. A
+name carries no ``/``: the name stack uses it as its separator. An op's
+layer is the OUTERMOST of these names in its name stack; it is backward
+where that component sits inside ``transpose(...)``.
+
+The table, the call sites and how to read a profile by these names:
+``docs/ARCHITECTURE.md`` section 18.6. ``benchmark/scope_reduce.py`` turns
+them into per-layer device time.
+"""
+
+# Top-level scopes: every device op of a sparse train step lies in exactly one.
+ROUTE = "de_route"  # id routing dp->mp: routing tensors, dedup/unique, the id all_to_all, mean counts
+GATHER = "de_gather"  # the fused row gather per sparse class (ops/packed_table.py)
+COMBINE = "de_combine"  # one-hot dense-class lookups, the activation exchange mp->dp (transposed: the cotangent exchange), output assembly
+MODEL = "de_model"  # the user model, forward and backward; flax's module names stay beneath it
+LOSS = "de_loss"  # the loss and the regularisers' penalties
+DENSE_UPDATE = "de_dense_update"  # gradient reduction across chips, optax on dense leaves and dense-class tables, the guard's selects, the step counter
+APPLY = "de_apply"  # delta streams, XLA's scatter-add, the Pallas apply kernel
+
+# Child scopes: always inside a top-level one.
+ONEHOT = "de_onehot"  # a dense class's windowed one-hot MXU lookup; inside de_combine
+EXCHANGE = "de_exchange"  # the collectives of parallel/wire.py; inside de_route and de_combine
+INTERACT = "de_interact"  # models/dlrm.py::dot_interact; inside de_model
+
+TOP_LEVEL = (ROUTE, GATHER, COMBINE, MODEL, LOSS, DENSE_UPDATE, APPLY)
+CHILDREN = (ONEHOT, EXCHANGE, INTERACT)

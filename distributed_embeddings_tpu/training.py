@@ -48,6 +48,7 @@ from .parallel.lookup_engine import (
     padded_rows,
     ragged_hotness,
 )
+from .telemetry import scopes
 
 
 def _per_rank_windows(plan: DistEmbeddingStrategy):
@@ -271,6 +272,8 @@ def make_train_step(loss_fn: Callable,
     rank = jax.lax.axis_index(axis_name) if mesh is not None else 0
 
     def full_loss(params, *batch):
+      # loss_fn is the caller's: lookup, model and loss in one. The engine's
+      # scopes mark the lookup inside it; the rest keeps flax's names only
       loss = loss_fn(params, *batch)
       if reg_fn is not None:
         # model-parallel penalty: each rank's term covers its own shards,
@@ -278,17 +281,19 @@ def make_train_step(loss_fn: Callable,
         # term is rank-local; scale by world to survive the uniform
         # 1/world grad rescale of DistributedOptimizer
         scale = axis_size(axis_name) if mesh is not None else 1
-        loss = loss + scale * reg_fn(params[emb_collection], rank)
+        with jax.named_scope(scopes.LOSS):
+          loss = loss + scale * reg_fn(params[emb_collection], rank)
       return loss
 
     loss, grads = jax.value_and_grad(full_loss)(params, *batch)
-    updates, new_state = dist_opt.update(grads, opt_state, params)
-    params = optax.apply_updates(params, updates)
-    if con_fn is not None:
-      params = {**params,
-                emb_collection: con_fn(params[emb_collection], rank)}
-    if mesh is not None:
-      loss = jax.lax.pmean(loss, axis_name)
+    with jax.named_scope(scopes.DENSE_UPDATE):
+      updates, new_state = dist_opt.update(grads, opt_state, params)
+      params = optax.apply_updates(params, updates)
+      if con_fn is not None:
+        params = {**params,
+                  emb_collection: con_fn(params[emb_collection], rank)}
+      if mesh is not None:
+        loss = jax.lax.pmean(loss, axis_name)
     return params, new_state, loss
 
   if mesh is None:
@@ -609,6 +614,7 @@ def _fused_rule_and_penalties(plan: DistEmbeddingStrategy, rule: SparseRule):
   return rule, reg_fn, con_fn
 
 
+@jax.named_scope(scopes.DENSE_UPDATE)
 def _reduce_and_apply_dense(state, loss, d_dense, d_emb_dense, d_z, rank,
                             mesh, axis_name, dense_optimizer, emb_opt,
                             con_fn):
@@ -677,21 +683,25 @@ def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
   oov_is_error = getattr(plan, "oov", "clip") in ("error", "allocate")
 
   def guard_gate(loss, grads, streams, oov_ok=None):
-    ok = _guards.all_finite((loss, grads, streams))
-    if oov_ok is not None:
-      ok = jnp.logical_and(ok, oov_ok)
-    if mesh is not None:
-      ok = jax.lax.pmin(ok.astype(jnp.int32), axis_name).astype(bool)
-    streams = {name: (ids, jnp.where(ok, rows, jnp.zeros_like(rows)))
-               for name, (ids, rows) in streams.items()}
+    with jax.named_scope(scopes.DENSE_UPDATE):
+      ok = _guards.all_finite((loss, grads, streams))
+      if oov_ok is not None:
+        ok = jnp.logical_and(ok, oov_ok)
+      if mesh is not None:
+        ok = jax.lax.pmin(ok.astype(jnp.int32), axis_name).astype(bool)
+    with jax.named_scope(scopes.APPLY):
+      streams = {name: (ids, jnp.where(ok, rows, jnp.zeros_like(rows)))
+                 for name, (ids, rows) in streams.items()}
     return ok, streams
 
+  @jax.named_scope(scopes.DENSE_UPDATE)
   def oov_ok(oov):
     if not oov_is_error or not oov:
       return None
     total = sum(jnp.asarray(c, jnp.int32) for c in oov.values())
     return total == 0
 
+  @jax.named_scope(scopes.DENSE_UPDATE)
   def guard_metrics(ok, oov, overflow=None):
     if mesh is not None:
       oov = {n: jax.lax.psum(c, axis_name) for n, c in oov.items()}
@@ -876,30 +886,35 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       def loss_with(dense_p, emb_dense, z_sp):
         acts = engine.finish_forward(z_sp, emb_dense, ids_all,
                                      b // n_mb, hotness_of, counts)
-        logits = model.apply({"params": dense_p}, numerical_i, cats_i,
-                             emb_acts=acts)
-        loss = loss_fn(logits, labels_i)
-        if reg_fn is not None:
-          scale = axis_size(axis_name) if mesh is not None else 1
-          loss = loss + scale * reg_fn(emb_dense, rank)
+        with jax.named_scope(scopes.MODEL):
+          logits = model.apply({"params": dense_p}, numerical_i, cats_i,
+                               emb_acts=acts)
+        with jax.named_scope(scopes.LOSS):
+          loss = loss_fn(logits, labels_i)
+          if reg_fn is not None:
+            scale = axis_size(axis_name) if mesh is not None else 1
+            loss = loss + scale * reg_fn(emb_dense, rank)
         return loss
 
-      vz = (jnp.sum(labels_i) * 0).astype(jnp.float32)
-      dense_local, emb_local = jax.tree_util.tree_map(
-          lambda x: x + vz.astype(x.dtype),
-          (state["dense"], state["emb_dense"]))
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        vz = (jnp.sum(labels_i) * 0).astype(jnp.float32)
+        dense_local, emb_local = jax.tree_util.tree_map(
+            lambda x: x + vz.astype(x.dtype),
+            (state["dense"], state["emb_dense"]))
       loss_i, (dd, de, dz) = jax.value_and_grad(
           loss_with, argnums=(0, 1, 2))(dense_local, emb_local, z_sparse)
       # uniform scale: 1/n_mb turns per-micro-batch means into the global
       # batch mean (the one-shot cotangent values, needed for non-linear
       # rule parity), folded with the mesh's 1/world grad rescale
-      dd, de, dz = jax.tree_util.tree_map(
-          lambda g: g * gscale, (dd, de, dz))
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        dd, de, dz = jax.tree_util.tree_map(
+            lambda g: g * gscale, (dd, de, dz))
       streams_i = engine.sparse_delta_streams(layouts, dz, residuals,
                                               rule, state["step"])
-      carry = jax.tree_util.tree_map(
-          jnp.add, (dd_acc, de_acc, loss_acc),
-          (dd, de, loss_i / n_mb))
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        carry = jax.tree_util.tree_map(
+            jnp.add, (dd_acc, de_acc, loss_acc),
+            (dd, de, loss_i / n_mb))
       if has_dedup_cap:
         # per-micro-batch overflow counts ride the scan outputs and sum
         # below (each micro-batch routes its own capped unique blocks)
@@ -926,7 +941,8 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       # emb_dense blocks are mp-SHARDED (per-rank windows), so their
       # grads are already rank-local — summing them across ranks would
       # mix different tables' windows
-      loss = jax.lax.pmean(loss, axis_name)
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        loss = jax.lax.pmean(loss, axis_name)
 
     if guard:
       # the guard sees the ACCUMULATED streams/grads: NaN from any
@@ -935,23 +951,24 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       ok, streams = _guard_gate(loss, (d_dense, d_emb_dense), streams,
                                 _oov_ok(oov))
 
-    upd, dense_opt = dense_optimizer.update(
-        d_dense, state["dense_opt"], state["dense"])
-    dense = optax.apply_updates(state["dense"], upd)
-    if state["emb_dense"]:
-      upd, emb_dense_opt = emb_opt.update(
-          d_emb_dense, state["emb_dense_opt"], state["emb_dense"])
-      emb_dense = optax.apply_updates(state["emb_dense"], upd)
-      if con_fn is not None:
-        emb_dense = con_fn(emb_dense, rank)
-    else:
-      emb_dense, emb_dense_opt = state["emb_dense"], state["emb_dense_opt"]
+    with jax.named_scope(scopes.DENSE_UPDATE):
+      upd, dense_opt = dense_optimizer.update(
+          d_dense, state["dense_opt"], state["dense"])
+      dense = optax.apply_updates(state["dense"], upd)
+      if state["emb_dense"]:
+        upd, emb_dense_opt = emb_opt.update(
+            d_emb_dense, state["emb_dense_opt"], state["emb_dense"])
+        emb_dense = optax.apply_updates(state["emb_dense"], upd)
+        if con_fn is not None:
+          emb_dense = con_fn(emb_dense, rank)
+      else:
+        emb_dense, emb_dense_opt = state["emb_dense"], state["emb_dense_opt"]
 
-    if guard:
-      dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
-          ok, (dense, dense_opt, emb_dense, emb_dense_opt),
-          (state["dense"], state["dense_opt"], state["emb_dense"],
-           state["emb_dense_opt"]))
+      if guard:
+        dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
+            ok, (dense, dense_opt, emb_dense, emb_dense_opt),
+            (state["dense"], state["dense_opt"], state["emb_dense"],
+             state["emb_dense_opt"]))
 
     fused = engine.apply_sparse_streams(state["fused"], layouts, streams,
                                         rule, state["step"])
@@ -983,15 +1000,17 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
     def loss_with(dense_p, emb_dense, z_sp):
       acts = engine.finish_forward(z_sp, emb_dense, ids_all, b, hotness_of,
                                    counts)
-      logits = model.apply({"params": dense_p}, numerical, cats,
-                           emb_acts=acts)
-      loss = loss_fn(logits, labels)
-      if reg_fn is not None:
-        # dense-kind tables' penalty (rank-local windows); scaled by world
-        # to survive the uniform 1/world grad rescale below — same
-        # convention as make_train_step
-        scale = axis_size(axis_name) if mesh is not None else 1
-        loss = loss + scale * reg_fn(emb_dense, rank)
+      with jax.named_scope(scopes.MODEL):
+        logits = model.apply({"params": dense_p}, numerical, cats,
+                             emb_acts=acts)
+      with jax.named_scope(scopes.LOSS):
+        loss = loss_fn(logits, labels)
+        if reg_fn is not None:
+          # dense-kind tables' penalty (rank-local windows); scaled by world
+          # to survive the uniform 1/world grad rescale below — same
+          # convention as make_train_step
+          scale = axis_size(axis_name) if mesh is not None else 1
+          loss = loss + scale * reg_fn(emb_dense, rank)
       return loss
 
     loss, (d_dense, d_emb_dense, d_z) = jax.value_and_grad(
@@ -1012,10 +1031,11 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       streams = engine.sparse_delta_streams(layouts, d_z, residuals, rule,
                                             state["step"])
       ok, streams = _guard_gate(loss, grads_chk, streams, _oov_ok(oov))
-      dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
-          ok, (dense, dense_opt, emb_dense, emb_dense_opt),
-          (state["dense"], state["dense_opt"], state["emb_dense"],
-           state["emb_dense_opt"]))
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
+            ok, (dense, dense_opt, emb_dense, emb_dense_opt),
+            (state["dense"], state["dense_opt"], state["emb_dense"],
+             state["emb_dense_opt"]))
       fused = engine.apply_sparse_streams(state["fused"], layouts, streams,
                                           rule, state["step"])
       new_state = {
@@ -1209,12 +1229,14 @@ def make_tiered_train_step(model, tplan, loss_fn: Callable,
     def loss_with(dense_p, emb_dense, z_sp):
       acts = engine.finish_forward(z_sp, emb_dense, ids_all, b, hotness_of,
                                    counts)
-      logits = model.apply({"params": dense_p}, numerical, cats,
-                           emb_acts=acts)
-      loss = loss_fn(logits, labels)
-      if reg_fn is not None:
-        scale = axis_size(axis_name) if mesh is not None else 1
-        loss = loss + scale * reg_fn(emb_dense, rank)
+      with jax.named_scope(scopes.MODEL):
+        logits = model.apply({"params": dense_p}, numerical, cats,
+                             emb_acts=acts)
+      with jax.named_scope(scopes.LOSS):
+        loss = loss_fn(logits, labels)
+        if reg_fn is not None:
+          scale = axis_size(axis_name) if mesh is not None else 1
+          loss = loss + scale * reg_fn(emb_dense, rank)
       return loss
 
     loss, (d_dense, d_emb_dense, d_z) = jax.value_and_grad(
@@ -1234,10 +1256,11 @@ def make_tiered_train_step(model, tplan, loss_fn: Callable,
       streams = engine.sparse_delta_streams(layouts, d_z, residuals, rule,
                                             state["step"])
       ok, streams = _guard_gate(loss, grads_chk, streams, _oov_ok(oov))
-      dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
-          ok, (dense, dense_opt, emb_dense, emb_dense_opt),
-          (state["dense"], state["dense_opt"], state["emb_dense"],
-           state["emb_dense_opt"]))
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
+            ok, (dense, dense_opt, emb_dense, emb_dense_opt),
+            (state["dense"], state["dense_opt"], state["emb_dense"],
+             state["emb_dense_opt"]))
       # zeroed streams scatter-add nothing: the cache region AND the
       # staging region come back bit-identical, so the write-back below
       # re-writes the staged rows' unchanged values into the host images
@@ -1355,8 +1378,9 @@ def make_sparse_eval_step(model, plan: DistEmbeddingStrategy,
     z_sparse, _ = engine.lookup_sparse_fused(state["fused"], layouts, ids_all)
     acts = engine.finish_forward(z_sparse, state["emb_dense"], ids_all, b,
                                  hotness_of, counts)
-    preds = model.apply({"params": state["dense"]}, numerical, cats,
-                        emb_acts=acts)
+    with jax.named_scope(scopes.MODEL):
+      preds = model.apply({"params": state["dense"]}, numerical, cats,
+                          emb_acts=acts)
     if not with_metrics:
       return preds
     oov = engine.oov_counts(cats)

@@ -51,6 +51,10 @@ from distributed_embeddings_tpu.utils import (
     dlrm_lr_schedule,
 )
 
+# --profile_dir: the trace starts after this many steps (the first compiles,
+# the next fill the dispatch queue) and holds this many
+PROFILE_AFTER, PROFILE_STEPS = 3, 5
+
 CRITEO_1TB_VOCAB = [
     39884406, 39043, 17289, 7420, 20263, 3, 7120, 1543, 63, 38532951,
     2953546, 403346, 10, 2208, 11938, 155, 4, 976, 14, 39979771,
@@ -101,6 +105,10 @@ def parse_args():
                  help="row (vocab) slice threshold in elements")
   p.add_argument("--vocab_scale", type=float, default=1.0,
                  help="scale Criteo vocab sizes (for memory-limited runs)")
+  p.add_argument("--profile_dir", default=None,
+                 help="write a jax.profiler trace of train steps "
+                      f"{PROFILE_AFTER + 1}..{PROFILE_AFTER + PROFILE_STEPS} "
+                      "there (docs/ARCHITECTURE.md, 'Reading a profile')")
   p.add_argument("--platform", default=None,
                  help="force a jax platform (e.g. 'cpu' for a rehearsal at "
                       "tiny sizes); same effect as JAX_PLATFORMS")
@@ -333,6 +341,7 @@ def main():
   t_start, losses = time.time(), []
   first_loss = float("nan")
   steps_done = 0
+  tracing = False
   for epoch in range(args.epochs):
     for batch in train_data:
       numerical, cats, labels = batch
@@ -346,9 +355,23 @@ def main():
       sharded = shard_batch(
           (jnp.asarray(numerical), jnp.asarray(cats_mat),
            jnp.asarray(labels)), mesh)
-      carry, loss = step_fn(carry, *sharded)
+      if args.profile_dir and steps_done == PROFILE_AFTER:
+        jax.block_until_ready(carry)  # the warm-up's work stays out of it
+        jax.profiler.start_trace(args.profile_dir)
+        tracing = True
+      if tracing:
+        with jax.profiler.StepTraceAnnotation("train", step_num=steps_done):
+          carry, loss = step_fn(carry, *sharded)
+      else:
+        carry, loss = step_fn(carry, *sharded)
       losses.append(loss)
       steps_done += 1
+      if tracing and steps_done in (PROFILE_AFTER + PROFILE_STEPS, args.steps):
+        jax.block_until_ready(loss)
+        jax.profiler.stop_trace()
+        tracing = False
+        print(f"profile of steps {PROFILE_AFTER + 1}..{steps_done} -> "
+              f"{args.profile_dir}", flush=True)
       if steps_done == 1:
         first_loss = loss
       if steps_done % 100 == 0:

@@ -96,6 +96,26 @@ def test_compile_cache_dir_follows_the_environment(monkeypatch):
     jax.config.update("jax_compilation_cache_dir", before)
 
 
+@pytest.mark.parametrize("env_dir", [None, "/some/dir"],
+                         ids=["default-dir", "dir-from-environment"])
+def test_compile_cache_keys_on_the_ops_metadata(monkeypatch, env_dir):
+  """On both paths: a cache that ignores the op names would serve a trace
+  with another build's scopes (tests/test_compile_cache.py shows it)."""
+  flag = "jax_compilation_cache_include_metadata_in_key"
+  before = (getattr(jax.config, flag), jax.config.jax_compilation_cache_dir)
+  if env_dir is None:
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+  else:
+    monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+  try:
+    jax.config.update(flag, False)
+    compile_cache.enable_compile_cache()
+    assert getattr(jax.config, flag) is True
+  finally:
+    jax.config.update(flag, before[0])
+    jax.config.update("jax_compilation_cache_dir", before[1])
+
+
 def test_compile_cache_path_is_fixed():
   """No temp dir, pid or clock in the path: a directory that moves between
   runs never hits."""
