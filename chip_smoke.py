@@ -4,7 +4,10 @@ Drives the main path — the DLRM sparse trainer, through its own command
 line — on a TPU at Criteo width, and fails unless what came out is right:
 
 - leg B: the Pallas kernels against XLA on the chip
-  (``tools/smoke_pallas_apply.py``, ``tools/smoke_pallas_interact.py``);
+  (``tools/smoke_pallas_apply.py``: the apply kernel with and without its
+  VMEM-resident heads, on a power-law and on a uniform stream, on one
+  device and under ``shard_map`` with starts that differ from rank to rank;
+  ``tools/smoke_pallas_interact.py``);
 - leg A, one chip: ``examples/dlrm/main.py --sparse`` at 26 Criteo-1TB
   tables x 1/16 (11.8 M rows), width 128, global batch 65536, 8 steps and
   an eval. Passes only if it ran on a TPU, every loss is finite, the first

@@ -364,7 +364,8 @@ def _use_pallas_apply() -> bool:
 def scatter_add_fused(layout: PackedLayout, buf: jax.Array, ids: jax.Array,
                       fused_delta: jax.Array,
                       prefer_pallas: bool = False,
-                      delta_scale: Optional[jax.Array] = None) -> jax.Array:
+                      delta_scale: Optional[jax.Array] = None,
+                      head_starts: Optional[jax.Array] = None) -> jax.Array:
   """``buf[ids] += fused_delta`` (one indexed RMW for table + all aux).
 
   ``fused_delta``: ``[..., stride]`` additive deltas in gather_fused's lane
@@ -386,6 +387,12 @@ def scatter_add_fused(layout: PackedLayout, buf: jax.Array, ids: jax.Array,
   XLA's fast-path ratio pass ``prefer_pallas=True`` (the engine computes
   this statically per class, `lookup_engine.apply_sparse`); the default
   keeps XLA. ``DE_TPU_PALLAS_APPLY=0/1`` force-overrides.
+
+  ``head_starts``: optional ``[K]`` int32 starts, in PHYSICAL rows of
+  ``buf``, of the blocks the Pallas kernel keeps resident in VMEM (each
+  table's hot first rows; `pallas_apply.head_block_starts`). A caller that
+  knows where its tables start passes them; the XLA path has no use for
+  them and every result is the same with or without.
   """
   grp, sub, valid = _grp_sub(layout, ids)
   fused_delta = jnp.where(valid[..., None], fused_delta, 0)
@@ -439,7 +446,8 @@ def scatter_add_fused(layout: PackedLayout, buf: jax.Array, ids: jax.Array,
       and buf.shape[1] == LANES
   if use_pallas:
     from .pallas_apply import apply_rows_cached
-    return apply_rows_cached(buf, flat_grp, flat_upd, scale=delta_scale)
+    return apply_rows_cached(buf, flat_grp, flat_upd, scale=delta_scale,
+                             head_starts=head_starts)
   if delta_scale is not None:
     # asarray first: a custom rule's linear_scale may return a Python
     # float outside jit (the Pallas path's jnp.reshape already accepts it)

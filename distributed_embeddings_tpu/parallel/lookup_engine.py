@@ -89,6 +89,7 @@ if TYPE_CHECKING:  # annotation-only: importing layers here would close the
   from ..layers.planner import DistEmbeddingStrategy
 
 from ..ops.packed_table import (
+    LANES,
     PackedLayout,
     SparseRule,
     _grp_sub,
@@ -579,6 +580,7 @@ class DistributedLookup:
     # rerun per bucket lookup on each trace (quadratic on big models)
     self._bucket_cache: Dict[tuple, List[Bucket]] = {}
     self._slot_map_cache: Dict[tuple, Dict[tuple, tuple]] = {}
+    self._key_of_class = {class_param_name(*k): k for k in plan.class_keys}
 
   # ---- shapes ------------------------------------------------------------
   def param_shapes(self) -> Dict[str, tuple]:
@@ -1975,6 +1977,76 @@ class DistributedLookup:
     return {name: self._stream_of_parts(layouts[name], parts, rule, step)
             for name, parts in by_class.items()}
 
+  @staticmethod
+  def _kernel_regime(n_ids: int, layout: PackedLayout) -> bool:
+    """The static scatter-regime rule: below ~0.15 ids a physical row XLA's
+    scatter never reaches its fast path and the Pallas RMW kernel wins
+    (`packed_table.scatter_add_fused`, docs/BENCHMARKS.md)."""
+    return n_ids / max(1, layout.phys_rows) < 0.15
+
+  def _apply_head_starts(self, name: str, layout: PackedLayout,
+                         n_ids: int) -> Optional[jax.Array]:
+    """This rank's ``[K]`` starts (physical rows of the class block) of the
+    blocks the apply kernel keeps resident in VMEM: the first
+    ``pallas_apply.HEAD_ROWS`` physical rows of every table of the class,
+    where a frequency-sorted vocabulary (rank = id) has its hot rows.
+
+    ``None`` where the stream would not take the kernel (the static regime
+    rule of :meth:`apply_sparse_streams`; a physical row wider than the 128
+    lanes the kernel serves), where ``layout`` is not the
+    plan's own (a host-tiered class's compact buffer: its ids are cache
+    slots, and where a table starts says nothing about them), or where no
+    block can be placed. A row-sliced shard that begins past row 0 of its
+    table holds no hot rows at its start and gets no block. The starts are
+    data indexed by the rank, as :meth:`_dense_offsets` are: under
+    ``shard_map`` every rank holds other tables."""
+    from ..ops.pallas_apply import HEAD_PAD, HEAD_ROWS, head_block_starts
+    key = self._key_of_class.get(name)
+    if key is None or not self._kernel_regime(n_ids, layout) \
+        or layout.phys_width != LANES \
+        or layout.rows != padded_rows(self.plan, key):
+      return None
+    cp = self.plan.classes[key]
+    rpp = layout.rows_per_phys
+    per_rank = []
+    for shards, offs in zip(cp.shards_per_rank, cp.row_offsets_per_rank):
+      heads = []
+      for sh, off in zip(shards, offs):
+        if sh.row_sliced and sh.row_start > 0:
+          continue
+        lo = off // rpp
+        heads.append((lo, min(lo + HEAD_ROWS, -(-(off + sh.input_dim) // rpp))))
+      per_rank.append(head_block_starts(heads, layout.phys_rows))
+    k = max(len(starts) for starts in per_rank)
+    if k == 0:
+      return None
+    const = np.full((len(per_rank), k), HEAD_PAD, np.int32)
+    for rank, starts in enumerate(per_rank):
+      const[rank, :len(starts)] = starts
+    return jnp.asarray(const)[self._my_rank()]
+
+  @jax.named_scope(scopes.APPLY)
+  def apply_head_counts(self, layouts: Dict[str, PackedLayout],
+                        streams) -> Dict[str, jax.Array]:
+    """Per sparse class ``[2]`` int32: the occurrences of ``streams``
+    (``name -> (ids [n], rows)``, as :meth:`apply_sparse_streams` takes
+    them) that fall in a VMEM-resident head of the apply kernel, and the
+    valid occurrences. Their ratio is the guarded step's
+    ``apply_head_share``: how often the head engages on this traffic. A
+    class with no heads (XLA's scatter regime, a compact layout) counts 0
+    in a head. Computed from the id stream and the same block starts the
+    kernel is handed, on any backend. This device's local counts."""
+    from ..ops.pallas_apply import head_slots
+    out = {}
+    for name, layout in layouts.items():
+      ids = streams[name][0] if name in streams else jnp.zeros((0,), jnp.int32)
+      grp, _, valid = _grp_sub(layout, ids)
+      starts = self._apply_head_starts(name, layout, ids.shape[0])
+      in_head = (jnp.zeros((), jnp.int32) if starts is None else jnp.sum(
+          head_slots(grp, starts, layout.phys_rows) >= 0, dtype=jnp.int32))
+      out[name] = jnp.stack([in_head, jnp.sum(valid, dtype=jnp.int32)])
+    return out
+
   @jax.named_scope(scopes.APPLY)
   def apply_sparse_streams(self, fused_params: Dict[str, jax.Array],
                            layouts: Dict[str, PackedLayout],
@@ -1992,11 +2064,12 @@ class DistributedLookup:
         # materialize the updates before the scatter: letting XLA fuse
         # the delta computation into the scatter slows its update loop
         ids_cat, rows_cat = lax.optimization_barrier((ids_cat, rows_cat))
-      ratio = ids_cat.shape[0] / max(1, layout.phys_rows)
       new_params[name] = scatter_add_fused(
           layout, buf, ids_cat, rows_cat,
-          prefer_pallas=ratio < 0.15,
-          delta_scale=(rule.linear_scale(step) if scale_only else None))
+          prefer_pallas=self._kernel_regime(ids_cat.shape[0], layout),
+          delta_scale=(rule.linear_scale(step) if scale_only else None),
+          head_starts=self._apply_head_starts(name, layout,
+                                              ids_cat.shape[0]))
     return new_params
 
   @jax.named_scope(scopes.APPLY)
@@ -2050,7 +2123,7 @@ class DistributedLookup:
         # Pallas RMW kernel wins (same static rule as the fast path)
         buf = scatter_add_fused(
             layout, buf, ids, delta,
-            prefer_pallas=ids.shape[0] / max(1, layout.phys_rows) < 0.15)
+            prefer_pallas=self._kernel_regime(ids.shape[0], layout))
       else:
         # fast path: ONE scatter-add for the whole class. Any chain of
         # scatters on the same buffer (lax.scan carry or unrolled
@@ -2097,7 +2170,7 @@ class DistributedLookup:
               buf = scatter_add_fused(
                   layout, buf, ids_f[c0:c0 + cn],
                   rule.delta(g_c, aux_c, step),
-                  prefer_pallas=cn / max(1, layout.phys_rows) < 0.15)
+                  prefer_pallas=self._kernel_regime(cn, layout))
       new_params[name] = buf
     return new_params
 

@@ -673,11 +673,14 @@ def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
     in-range by construction, so a nonzero counter means RAW ids leaked
     past the dynvocab translator — that batch must not train the clamp
     rows either.
-  - ``guard_metrics(ok, oov, overflow=None)``: the replicated
-    ``{'bad_step', 'oov'}`` metrics dict (counters psum'd across the
-    mesh); with ``overflow`` (per-class dedup-capacity overflow counts —
-    plans with ``dedup_capacity`` set) a psum'd ``'dedup_overflow'``
-    entry joins it.
+  - ``guard_metrics(ok, oov, overflow=None, head_counts=None)``: the
+    replicated ``{'bad_step', 'oov'}`` metrics dict (counters psum'd
+    across the mesh); with ``overflow`` (per-class dedup-capacity overflow
+    counts — plans with ``dedup_capacity`` set) a psum'd
+    ``'dedup_overflow'`` entry joins it; with ``head_counts``
+    (``engine.apply_head_counts``) an ``'apply_head_share'`` entry: per
+    sparse class the share of the step's valid occurrences that fell in
+    a VMEM-resident head of the apply kernel, over the whole mesh.
   """
   from .resilience import guards as _guards
   oov_is_error = getattr(plan, "oov", "clip") in ("error", "allocate")
@@ -702,15 +705,22 @@ def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
     return total == 0
 
   @jax.named_scope(scopes.DENSE_UPDATE)
-  def guard_metrics(ok, oov, overflow=None):
+  def guard_metrics(ok, oov, overflow=None, head_counts=None):
     if mesh is not None:
       oov = {n: jax.lax.psum(c, axis_name) for n, c in oov.items()}
       if overflow is not None:
         overflow = {n: jax.lax.psum(c, axis_name)
                     for n, c in overflow.items()}
+      if head_counts is not None:
+        head_counts = {n: jax.lax.psum(c, axis_name)
+                       for n, c in head_counts.items()}
     out = {"bad_step": 1 - ok.astype(jnp.int32), "oov": oov}
     if overflow is not None:
       out["dedup_overflow"] = overflow
+    if head_counts is not None:
+      out["apply_head_share"] = {
+          n: c[0].astype(jnp.float32) / jnp.maximum(c[1], 1)
+          for n, c in head_counts.items()}
     return out
 
   return guard_gate, oov_ok, guard_metrics
@@ -782,9 +792,12 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       discarded by scalar selects, and the step counter holds — the
       committed state is bit-identical to a run that never saw the
       batch. The step then returns ``(state, loss, metrics)`` with
-      ``metrics = {'bad_step': int32 0/1, 'oov': {class: int32 count}}``
+      ``metrics = {'bad_step': int32 0/1, 'oov': {class: int32 count},
+      'apply_head_share': {sparse class: float32}}``
       (OOV counters per the plan's ``oov`` policy, psum'd across
-      devices; loss is the observed — possibly NaN — value). With
+      devices; the share of the step's occurrences, over the whole mesh,
+      that the apply kernel's VMEM-resident heads take; loss is the
+      observed — possibly NaN — value). With
       ``plan.oov='error'`` a batch carrying out-of-range ids is gated
       the same way — it commits NOTHING — so the host-side
       ``check_oov`` raise fires with the state uncontaminated.
@@ -948,6 +961,7 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       # the guard sees the ACCUMULATED streams/grads: NaN from any
       # micro-batch survives the sums, so one check covers the scan
       oov = engine.oov_counts(cats)
+      heads = engine.apply_head_counts(layouts, streams)
       ok, streams = _guard_gate(loss, (d_dense, d_emb_dense), streams,
                                 _oov_ok(oov))
 
@@ -981,7 +995,7 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
         "step": state["step"] + (ok.astype(jnp.int32) if guard else 1),
     }
     if guard:
-      return new_state, loss, _guard_metrics(ok, oov, ovf)
+      return new_state, loss, _guard_metrics(ok, oov, ovf, heads)
     return new_state, loss
 
   def local_step(state, numerical, cats, labels):
@@ -1030,6 +1044,7 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       ovf = engine.dedup_overflow_counts(ids_all) if has_dedup_cap else None
       streams = engine.sparse_delta_streams(layouts, d_z, residuals, rule,
                                             state["step"])
+      heads = engine.apply_head_counts(layouts, streams)
       ok, streams = _guard_gate(loss, grads_chk, streams, _oov_ok(oov))
       with jax.named_scope(scopes.DENSE_UPDATE):
         dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
@@ -1049,7 +1064,7 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
           # step sequence as a run that never met the poison batch
           "step": state["step"] + ok.astype(jnp.int32),
       }
-      return new_state, loss, _guard_metrics(ok, oov, ovf)
+      return new_state, loss, _guard_metrics(ok, oov, ovf, heads)
 
     fused = engine.apply_sparse(state["fused"], layouts, d_z, residuals,
                                 rule, state["step"], exact=exact)
@@ -1081,6 +1096,7 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
     if has_dedup_cap:
       mspec["dedup_overflow"] = {
           class_param_name(*k): P() for k in plan.class_keys}
+    mspec["apply_head_share"] = {name: P() for name in layouts}
     out_specs = (sspec, P(), mspec)
   sharded = shard_map(
       step_fn, mesh=mesh,

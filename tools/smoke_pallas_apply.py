@@ -162,10 +162,128 @@ def main():
     if not ok:
       FAILED.append(f"narrow w{width}")
 
+  check_heads(rng)
+
   if FAILED:
     print("FAILED:", FAILED)
     sys.exit(1)
   print("ALL PASS")
+
+
+def check_heads(rng):
+  """VMEM-resident heads against ``buf.at[ids].add``: the DLRM cells'
+  power-law stream (rank = id, most ids in a table's first rows) and a
+  uniform one (hardly any), on one device and under ``shard_map`` with
+  starts that differ from rank to rank; a short table whose block runs on
+  into its neighbour; a stream of head ids only, which leaves every warm
+  slot of the row cache unclaimed (the write-back order's hazard)."""
+  import jax
+  from jax.sharding import NamedSharding, PartitionSpec as P
+
+  from distributed_embeddings_tpu.compat import shard_map
+  from distributed_embeddings_tpu.models.synthetic import power_law_ids
+  from distributed_embeddings_tpu.ops.pallas_apply import (
+      HEAD_PAD, HEAD_ROWS, head_block_starts)
+  from distributed_embeddings_tpu.parallel import create_mesh
+
+  def rel_err(got, want64):
+    return float(np.max(np.abs(np.asarray(got, np.float64) - want64)
+                        / (1 + np.abs(want64))))
+
+  def stream(tables, per_table, alpha):
+    """Per rank-local table ``(offset, rows)``: ids as the step's stream
+    has them, tables one after another, plus a few out of range."""
+    ids = np.concatenate(
+        [off + power_law_ids(rng, per_table, 1, rows, alpha)[:, 0]
+         for off, rows in tables] + [np.array([-1, 1 << 30, -7])])
+    return ids.astype(np.int32)
+
+  def want_of(base, ids, delta, scale):
+    want = np.asarray(base, np.float64)
+    ok = (ids >= 0) & (ids < want.shape[0])
+    np.add.at(want, ids[ok], scale * np.asarray(delta, np.float64)[ok])
+    return want
+
+  scale = -0.125
+  rows = 3 * HEAD_ROWS + 5000
+  # rank-local layouts: two long tables; a 1,000-row table before a long one
+  layouts = {
+      "two long tables": [(0, HEAD_ROWS + 3000), (HEAD_ROWS + 3000,
+                                                  2 * HEAD_ROWS + 2000)],
+      "short table first": [(0, 1000), (1000, rows - 1000)],
+  }
+  base = jnp.asarray(rng.standard_normal((rows, W)), jnp.float32)
+  for lname, tables in layouts.items():
+    starts = head_block_starts(
+        [(off, off + min(HEAD_ROWS, n)) for off, n in tables], rows)
+    for sname, alpha in (("power-law", 1.05), ("uniform", 0.0)):
+      ids = stream(tables, 20000, alpha)
+      delta = jnp.asarray(rng.standard_normal((len(ids), W)), jnp.float32)
+      got = apply_rows_cached(base + 0, jnp.asarray(ids), delta,
+                              scale=jnp.float32(scale),
+                              head_starts=jnp.asarray(starts, jnp.int32))
+      err = rel_err(got, want_of(base, ids, delta, scale))
+      name = f"heads {lname}, {sname}"
+      ok = err < 1e-4
+      print(f"{name:34s}: {'OK' if ok else 'FAIL'} (rel err {err:.2e}, "
+            f"blocks at {starts})")
+      if not ok:
+        FAILED.append(name)
+
+  # only head ids, fewer than the cache has slots: no warm slot is claimed,
+  # each flushes the row it read at start-up, and the heads must land after
+  ids = np.array([1, 1, 3, HEAD_ROWS + 3001, 127, 0])
+  ids = ids.astype(np.int32)
+  delta = jnp.asarray(rng.standard_normal((len(ids), W)), jnp.float32)
+  starts = head_block_starts([(0, HEAD_ROWS), (HEAD_ROWS + 3000,
+                                               2 * HEAD_ROWS + 3000)], rows)
+  got = apply_rows_cached(base + 0, jnp.asarray(ids), delta,
+                          head_starts=jnp.asarray(starts, jnp.int32))
+  err = rel_err(got, want_of(base, ids, delta, 1.0))
+  ok = err < 1e-5
+  print(f"{'heads, warm slots never claimed':34s}: {'OK' if ok else 'FAIL'} "
+        f"(rel err {err:.2e})")
+  if not ok:
+    FAILED.append("heads warm")
+
+  # under shard_map: every rank its own block of the buffer, its own
+  # tables and so its own starts (a constant indexed by the rank, as the
+  # engine passes them); rank r's first table is r * 512 rows longer
+  world = jax.device_count()
+  mesh = create_mesh(world)
+  per_rank = [[(0, HEAD_ROWS + 512 * r + 8),
+               (HEAD_ROWS + 512 * r + 8, rows - HEAD_ROWS - 512 * r - 8)]
+              for r in range(world)]
+  starts = [head_block_starts([(o, o + HEAD_ROWS) for o, _ in t], rows)
+            for t in per_rank]
+  const = np.full((world, 3), HEAD_PAD, np.int32)  # one entry is padding
+  for r, st in enumerate(starts):
+    const[r, :len(st)] = st
+  n = 30000 + 3
+  ids = np.stack([stream(t, 15000, 1.05) for t in per_rank])
+  delta = jnp.asarray(rng.standard_normal((world, n, W)), jnp.float32)
+  bases = jnp.asarray(rng.standard_normal((world * rows, W)), jnp.float32)
+
+  def local(buf, ids, delta):
+    st = jnp.asarray(const)[jax.lax.axis_index("mp")]
+    return apply_rows_cached(buf, ids[0], delta[0],
+                             scale=jnp.float32(scale), head_starts=st)
+
+  fn = jax.jit(shard_map(local, mesh=mesh, in_specs=(P("mp"),) * 3,
+                         out_specs=P("mp")))
+  shard = NamedSharding(mesh, P("mp"))
+  got = np.asarray(fn(jax.device_put(bases + 0, shard),
+                      jax.device_put(jnp.asarray(ids), shard),
+                      jax.device_put(delta, shard)))
+  err = max(rel_err(got[r * rows:(r + 1) * rows],
+                    want_of(bases[r * rows:(r + 1) * rows], ids[r],
+                            delta[r], scale)) for r in range(world))
+  ok = err < 1e-4
+  print(f"{'heads under shard_map, world %d' % world:34s}: "
+        f"{'OK' if ok else 'FAIL'} (rel err {err:.2e}, starts "
+        f"{const.tolist()})")
+  if not ok:
+    FAILED.append("heads shard_map")
 
 
 if __name__ == "__main__":
