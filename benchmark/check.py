@@ -17,9 +17,13 @@ Numbers compared, each with its own limit (the configuration's
   larger (a leaf whose gradient is all but zero is judged on the group's
   scale); the worst leaf is reported. The reference's step is float32 at
   the device's default matmul precision, as the configurations state it;
-- ``accumulator_stray`` (rules that keep an accumulator in the row): how
-  many accumulator values of the rows the batch read lie farther from the
-  reference's than float32 accumulation explains (limit 0). One step adds
+- ``accumulator_stray`` (rules that keep accumulators in the row; one number
+  per lane group: ``accumulator_stray`` for the first, Adagrad's sum of
+  squares or Adam's first moment, ``accumulator_2_stray`` for Adam's second
+  moment): how many accumulator values of the rows the batch read lie
+  farther from the reference's than float32 accumulation explains (limit 0).
+  An accumulator that starts at 0 is judged in float32 steps of the
+  reference's largest change. One step adds
   the squared gradients of a row's occurrences to an accumulator whose
   float32 step is far above most of them, and a float32 program that adds
   them one at a time may lose every one; so a value is astray only beyond
@@ -90,16 +94,17 @@ def worst_gap(program: Dict[Any, np.ndarray], ref: Dict[Any, np.ndarray],
 def accumulator_stray(program: Dict[int, np.ndarray],
                       ref: Dict[int, np.ndarray], initial: float
                       ) -> Tuple[float, str]:
-  """Accumulator values of touched rows farther from the reference's than
-  ``ACC_STEPS`` float32 steps of ``initial`` plus ``ACC_SHARE`` of the
+  """Accumulator values of touched rows (one lane group) farther from the
+  reference's than ``ACC_STEPS`` float32 steps of ``initial`` (of the
+  reference's largest change where that is 0) plus ``ACC_SHARE`` of the
   reference's change, and what was looked at."""
-  step = float(np.spacing(np.float32(initial)))
+  largest = max(np.abs(r).max() for r in ref.values())
+  step = float(np.spacing(np.float32(initial if initial else largest)))
   stray = sum(int(np.sum(~(np.abs(program[t] - r) <= ACC_STEPS * step
-                           + ACC_SHARE * r))) for t, r in ref.items())
+                           + ACC_SHARE * np.abs(r)))) for t, r in ref.items())
   return float(stray), (
       f"values among {sum(r.size for r in ref.values())} of the rows read; "
-      f"the reference's largest change is "
-      f"{max(r.max() for r in ref.values()) / step:.0f} float32 steps")
+      f"the reference's largest change is {largest / step:.0f} float32 steps")
 
 
 def one_step(prog, state, step, batch, ref: reference.StepChange,
@@ -131,10 +136,17 @@ def one_step(prog, state, step, batch, ref: reference.StepChange,
   out.append(Compared("table_change_gap", gap, where,
                       limits["table_change_gap"]))
   if ref.acc_delta:
-    stray, where = accumulator_stray(
-        changed_a, ref.acc_delta,
-        reference.initial_accumulator(prog.spec.optimizer))
-    out.append(Compared("accumulator_stray", stray, where, 0.0))
+    # one comparison per lane group the rule keeps beside a row
+    for j, initial in enumerate(
+        reference.initial_accumulators(prog.spec.optimizer)):
+      group = lambda d: {t: x[:, j * prog.spec.tables[t].width:
+                              (j + 1) * prog.spec.tables[t].width]
+                         for t, x in d.items()}
+      stray, where = accumulator_stray(group(changed_a),
+                                       group(ref.acc_delta), initial)
+      out.append(Compared(
+          "accumulator_stray" if j == 0 else f"accumulator_{j + 1}_stray",
+          stray, where, 0.0))
   gap, where = worst_gap(
       {n: after_d[n] - ref.dense_before[n] for n in ref.dense_delta},
       ref.dense_delta, str)

@@ -54,7 +54,8 @@ def main(argv=None) -> int:
   for seed in seeds:
     t = time.perf_counter()
     batch = traffic.make_batch(cell.traffic, spec.inputs, spec.n_numerical,
-                               seed, 0)
+                               seed, 0,
+                               traffic.family_labels(family, cell.config))
     with jax.default_device(devices[0]):
       ref = reference.one_step(spec, logits, batch, seed)
       low = reference.one_step(spec, logits, batch, seed,

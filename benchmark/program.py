@@ -263,7 +263,8 @@ class Program:
   # ---- the step, as the window calls it ------------------------------------
   def put(self, batch: traffic.Batch):
     """One host batch onto the device(s), split over the mesh by rows: the
-    window's feed."""
+    window's feed. ``labels`` is an array or the family's tree of arrays,
+    every leaf with the batch as its leading dimension."""
     arrays = (batch.numerical, batch.cats, batch.labels)
     if self.mesh is None:
       return tuple(jax.device_put(a) for a in arrays)
@@ -274,11 +275,12 @@ class Program:
     """The donated, jitted step over (state, numerical, cats matrix, labels),
     lowered and compiled for this state and batch. The categorical ids travel
     as one matrix and are split on the device, as `examples/dlrm/main.py`
-    feeds them."""
+    feeds them; the labels go to the family's ``loss_fn`` as they are."""
     parts = self.parts
     example = (jnp.zeros(batch.numerical.shape, jnp.float32),
                parts.split_cats(jnp.zeros(batch.cats.shape, jnp.int32)),
-               jnp.zeros(batch.labels.shape, jnp.float32))
+               jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, x.dtype),
+                                      batch.labels))
     inner = make_sparse_train_step(
         parts.model, parts.plan, parts.loss_fn, parts.optimizer, parts.rule,
         self.mesh, state, example, donate=False)
@@ -395,8 +397,9 @@ class Program:
                     ) -> Tuple[Dict[int, np.ndarray], Dict[int, np.ndarray]]:
     """Per table, the state's rows at ``touched`` minus the benchmark's
     initial weights of those rows, float32 ``[n, width]``; and, for the
-    tables of packed classes under a rule that keeps an accumulator in the
-    row, those rows' first accumulator minus its initial value. Packed
+    tables of packed classes under a rule that keeps accumulators in the
+    row, those rows' accumulators minus their initial values, the rule's
+    lane groups side by side ``[n, n_aux * width]``. Packed
     tables are gathered on the device in chunks of ``READ_CHUNK`` physical
     rows (so the read-back's own temporary stays far under the step's); the
     small simple-layout classes come to the host whole."""
@@ -428,7 +431,7 @@ class Program:
           np.zeros((0, lay.stride), np.float32)
       out[t] = row[:, :tb.width]
       if self.parts.rule.aux_init:
-        acc[t] = row[:, tb.width:2 * tb.width]
+        acc[t] = row[:, tb.width:]
     return out, acc
 
   def read_dense(self, state) -> Dict[str, np.ndarray]:

@@ -10,18 +10,21 @@ in float64 on the host. No kernels, no packed layout, no sharding. It
 imports nothing of the program and reads nothing the program made: the
 weights come from :mod:`benchmark.weights` (a function of the seed),
 evaluated only at the rows the batch touches. A family supplies its model's
-equations (``reference_logits``) and the plain description of its
-parameters.
+equations (``reference_logits``: from the dense leaves, one activation per
+input and the numerical features to the model's outputs) and the plain
+description of its parameters, and may supply its loss (``ModelSpec.loss``,
+of those outputs and the batch's labels; default: binary cross-entropy of
+one logit a sample).
 
 What it returns is the one-step change of every parameter the batch can
 change, as a float32 state shows it (the float32 value after the step minus
 the one before: a change far below the value's float32 step is mostly
 rounding, in any float32 program, and PR 25's chip runs read that rounding
 as the zoo's heavy-tailed gaps): per table the distinct touched rows with
-their change (and the change of their optimizer accumulator, where the rule
-keeps one), and the change of every dense leaf. The loss is computed a
-second time, forward only, at ``highest`` matmul precision: that is the loss
-the check compares.
+their change (and the change of each optimizer accumulator that rides in the
+row, where the rule keeps any), and the change of every dense leaf. The loss
+is computed a second time, forward only, at ``highest`` matmul precision:
+that is the loss the check compares.
 
 With ``precision="bfloat16"`` the same equations are computed as the
 lower-precision control, the step a later PR would be tempted by: weights
@@ -35,7 +38,7 @@ something XLA may skip ("excess precision"; PERF.md, PR 25).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import numpy as np
 
@@ -49,18 +52,28 @@ class TableSpec:
   scale: float  # weights are uniform in (-scale, scale)
 
 
+def bce_with_logits(jnp, logits, labels):
+  return jnp.mean(jnp.maximum(logits, 0) - logits * labels
+                  + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
   """A model as its configuration file describes it, in plain terms."""
   tables: Tuple[TableSpec, ...]
   inputs: Tuple[traffic.CatInput, ...]
   n_numerical: int
-  dense_leaves: Dict[str, Tuple[Tuple[int, ...], float]]  # name -> shape, scale
+  # name -> (shape, scale) or (shape, scale, offset): a leaf of any rank
+  # that starts at offset + uniform(+-scale); a norm's gain is ((w,), 0, 1)
+  dense_leaves: Dict[str, Tuple]
   optimizer: Dict[str, Any]  # name, learning_rate, and the rule's constants
   # tables whose update is computed once per distinct row from the summed
   # gradient (the configuration's small tables, updated densely); all other
   # tables are updated per occurrence
   summed_tables: frozenset = frozenset()
+  # loss(jnp, outputs, labels) -> scalar: of what ``reference_logits``
+  # returns (cast to float32) and of ``Batch.labels`` as the family drew them
+  loss: Callable = bce_with_logits
 
 
 @dataclasses.dataclass
@@ -68,8 +81,10 @@ class StepChange:
   loss: float
   table_rows: Dict[int, np.ndarray]      # table -> distinct touched ids
   table_delta: Dict[int, np.ndarray]     # table -> [n, width] change
-  # table -> [n, width] change of the rows' accumulator: per-occurrence
-  # tables under a rule that keeps one (their accumulator rides in the row)
+  # table -> [n, groups * width] change of the rows' accumulators, the
+  # rule's lane groups side by side (Adagrad: the sum of squares; Adam: the
+  # first moment, then the second): per-occurrence tables under a rule that
+  # keeps any (their accumulators ride in the row)
   acc_delta: Dict[int, np.ndarray]
   dense_delta: Dict[str, np.ndarray]
   dense_before: Dict[str, np.ndarray]
@@ -80,32 +95,37 @@ def table_name(t: int) -> str:
 
 
 def dense_weights(spec: ModelSpec, seed: int) -> Dict[str, np.ndarray]:
-  return {name: weights.dense_np(weights.leaf_key(seed, name), scale, shape)
-          for name, (shape, scale) in spec.dense_leaves.items()}
+  return {name: weights.dense_np(weights.leaf_key(seed, name), scale, shape,
+                                 *offset)
+          for name, (shape, scale, *offset) in spec.dense_leaves.items()}
 
 
-def bce_with_logits(jnp, logits, labels):
-  return jnp.mean(jnp.maximum(logits, 0) - logits * labels
-                  + jnp.log1p(jnp.exp(-jnp.abs(logits))))
-
-
-def initial_accumulator(opt: Dict[str, Any]) -> Optional[float]:
-  """The value the rule's accumulator starts from; None where it has none."""
+def initial_accumulators(opt: Dict[str, Any]) -> Tuple[float, ...]:
+  """The values the rule's accumulators start from, one per lane group the
+  rule keeps beside a row; () where it keeps none."""
   if opt["name"] == "adagrad":
-    return float(opt["initial_accumulator_value"])
-  return None
+    return (float(opt["initial_accumulator_value"]),)
+  if opt["name"] == "adam":
+    return (0.0, 0.0)
+  return ()
 
 
 def update(opt: Dict[str, Any], g: np.ndarray):
   """One optimizer step from its initial state on float64 gradients:
-  -> (the parameter's change, the accumulator's change or None)."""
+  -> (the parameter's change, the change of each accumulator)."""
   lr = float(opt["learning_rate"])
   if opt["name"] == "sgd":
-    return -lr * g, None
+    return -lr * g, ()
   if opt["name"] == "adagrad":
     g2 = g * g
     acc_new = float(opt["initial_accumulator_value"]) + g2
-    return -lr * g / np.sqrt(acc_new + float(opt["eps"])), g2
+    return -lr * g / np.sqrt(acc_new + float(opt["eps"])), (g2,)
+  if opt["name"] == "adam":
+    # the first step from zero moments, bias-corrected at t = 1
+    b1, b2 = float(opt["b1"]), float(opt["b2"])
+    m, v = (1.0 - b1) * g, (1.0 - b2) * g * g
+    m_hat, v_hat = m / (1.0 - b1), v / (1.0 - b2)
+    return -lr * m_hat / (np.sqrt(v_hat) + float(opt["eps"])), (m, v)
   raise ValueError(f"no reference for optimizer {opt['name']!r}")
 
 
@@ -126,8 +146,10 @@ def _segment_sum(x: np.ndarray, seg: np.ndarray, n: int) -> np.ndarray:
 def one_step(spec: ModelSpec, logits_fn: Callable, batch: traffic.Batch,
              seed: int, precision: str = "float32") -> StepChange:
   """The reference's change after one step on ``batch`` from the seed's
-  weights. ``logits_fn(dense, embs, numerical) -> [B]`` with ``embs`` one
-  combined ``[B, width]`` activation per input."""
+  weights. ``logits_fn(dense, embs, numerical)`` returns the model's outputs
+  (an array or a tree of arrays) from ``embs``, one activation per input:
+  ``[B, width]`` summed over its hotness, or ``[B, hotness, width]`` where
+  the input is kept as a sequence. ``spec.loss`` makes the scalar of them."""
   import jax
   import jax.numpy as jnp
 
@@ -155,9 +177,12 @@ def one_step(spec: ModelSpec, logits_fn: Callable, batch: traffic.Batch,
     return u * jnp.float32(tb.scale)
 
   def loss_of(dense, occ, numerical, labels):
-    embs = [o.sum(axis=1) for o in occ]  # sum combiner over the hotness
-    logits = logits_fn(dense, embs, numerical.astype(dt))
-    return bce_with_logits(jnp, logits.astype(jnp.float32), labels)
+    # sum combiner over the hotness, unless the input is a sequence
+    embs = [o if i.sequence else o.sum(axis=1)
+            for i, o in zip(spec.inputs, occ)]
+    outputs = logits_fn(dense, embs, numerical.astype(dt))
+    return spec.loss(jnp, jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.float32), outputs), labels)
 
   # every shape follows from the configuration alone and the seed's keys are
   # arguments: one compiled program serves every seed
@@ -176,9 +201,9 @@ def one_step(spec: ModelSpec, logits_fn: Callable, batch: traffic.Batch,
   loss, (g_dense, g_occ) = jax.device_get(jax.jit(grads)(
       {k: jnp.asarray(v) for k, v in dense0.items()},
       jnp.asarray(batch.cats), jnp.asarray(batch.numerical),
-      jnp.asarray(batch.labels), jnp.asarray(keys)))
+      jax.tree_util.tree_map(jnp.asarray, batch.labels), jnp.asarray(keys)))
 
-  t_delta, a_delta = {}, {}
+  t_delta, a_delta, acc0 = {}, {}, initial_accumulators(opt)
   for t, ids in touched.items():
     width = spec.tables[t].width
     mine = [k for k, i in enumerate(spec.inputs) if i.table == t]
@@ -193,9 +218,10 @@ def one_step(spec: ModelSpec, logits_fn: Callable, batch: traffic.Batch,
       # configurations state as theirs
       d, acc = update(opt, g.astype(np.float64))
       d = _segment_sum(d, seg, len(ids))
-      if acc is not None:
-        a_delta[t] = stored_change(initial_accumulator(opt),
-                                   _segment_sum(acc, seg, len(ids)))
+      if acc:
+        a_delta[t] = np.concatenate(
+            [stored_change(a0, _segment_sum(a, seg, len(ids)))
+             for a0, a in zip(acc0, acc)], axis=1)
     tb = spec.tables[t]
     t_delta[t] = stored_change(
         weights.rows_np(int(keys[t]), tb.scale, ids, tb.width), d)

@@ -154,7 +154,8 @@ def run_cell(cell: specs.Cell, seed: int, seconds: float, trace: bool,
   config, mix = cell.config, cell.traffic
   spec = family.model_spec(config)
   batch_size = int(mix["global_batch"])
-  pool = traffic.make_pool(mix, spec.inputs, spec.n_numerical, seed)
+  pool = traffic.make_pool(mix, spec.inputs, spec.n_numerical, seed,
+                           traffic.family_labels(family, config))
   say(f"pool of {len(pool)} batches of {batch_size}: "
       f"{time.perf_counter() - t0:.1f}s")
 

@@ -27,7 +27,7 @@ import statistics
 import struct
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
-from benchmark.trace_reduce import DEVICE_PLANE, op_name
+from benchmark.trace_reduce import DEVICE_PLANE, nesting, op_name
 
 # ---- protobuf wire format ---------------------------------------------------
 # xplane.proto field numbers (tensorflow/tsl/profiler/protobuf/xplane.proto)
@@ -347,29 +347,6 @@ def read_op_names(path: str, step_module: str) -> OpNames:
 
 # ---- self time per scope ----------------------------------------------------
 UNSCOPED = "(no scope)"
-
-
-def nesting(ops) -> Tuple[List[float], List[int], List[int]]:
-  """Per event of one device's ``XLA Ops`` line, in the order given: its
-  self time (its duration less what the events nested in it cover) and the
-  index of the event that holds it (-1 at the top); and the indices in
-  timeline order, holders before what they hold. A ``while`` or a
-  conditional holds its body's ops; an event's children are those that
-  start before it ends."""
-  order = sorted(range(len(ops)), key=lambda i: (ops[i][1], -ops[i][2]))
-  self_ns = [0.0] * len(ops)
-  parent = [-1] * len(ops)
-  open_: List[Tuple[int, float]] = []  # (index, end) of the enclosing events
-  for i in order:
-    _, start, dur, _ = ops[i]
-    while open_ and open_[-1][1] <= start:
-      open_.pop()
-    self_ns[i] = dur
-    if open_:
-      parent[i], parent_end = open_[-1]
-      self_ns[parent[i]] -= min(dur, parent_end - start)
-    open_.append((i, start + dur))
-  return self_ns, parent, order
 
 
 @dataclasses.dataclass

@@ -104,6 +104,29 @@ def _union(intervals: Sequence[Tuple[float, float]]) -> List[List[float]]:
   return merged
 
 
+def nesting(ops) -> Tuple[List[float], List[int], List[int]]:
+  """Per event of one device's ``XLA Ops`` line, in the order given: its
+  self time (its duration less what the events nested in it cover) and the
+  index of the event that holds it (-1 at the top); and the indices in
+  timeline order, holders before what they hold. A ``while`` or a
+  conditional holds its body's ops; an event's children are those that
+  start before it ends."""
+  order = sorted(range(len(ops)), key=lambda i: (ops[i][1], -ops[i][2]))
+  self_ns = [0.0] * len(ops)
+  parent = [-1] * len(ops)
+  open_: List[Tuple[int, float]] = []  # (index, end) of the enclosing events
+  for i in order:
+    _, start, dur, _ = ops[i]
+    while open_ and open_[-1][1] <= start:
+      open_.pop()
+    self_ns[i] = dur
+    if open_:
+      parent[i], parent_end = open_[-1]
+      self_ns[parent[i]] -= min(dur, parent_end - start)
+    open_.append((i, start + dur))
+  return self_ns, parent, order
+
+
 class Reduced:
   """One trace, reduced once; the metric readers pick from it."""
 
@@ -190,12 +213,14 @@ class Reduced:
 
   # ---- breakdown -----------------------------------------------------------
   def top_ops(self, n: int = 10) -> List[List[Any]]:
-    """Seconds per op name over the window, mean over devices."""
+    """Seconds of self time per op name over the window, mean over devices:
+    a ``while`` counts once, less its body's ops, which count as
+    themselves."""
     total: Dict[str, float] = {}
     for ops in self.ops:
-      for name, _, dur, _ in ops:
+      for (name, _, _, _), own in zip(ops, nesting(ops)[0]):
         key = op_name(name)
-        total[key] = total.get(key, 0.0) + dur
+        total[key] = total.get(key, 0.0) + own
     rows = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[k, v * 1e-9 / len(self.ops)] for k, v in rows]
 

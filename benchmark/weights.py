@@ -66,10 +66,16 @@ def rows_np(key: int, scale: float, rows: np.ndarray, width: int
     return unit_uniform(np, k, r, c) * np.float32(scale)
 
 
-def dense_np(key: int, scale: float, shape) -> np.ndarray:
-  """A whole small leaf (kernel, bias, small table): a 1-D leaf is one row."""
+def dense_np(key: int, scale: float, shape, offset: float = 0.0
+             ) -> np.ndarray:
+  """A whole dense leaf, ``offset + uniform(+-scale)``: a 1-D leaf is one
+  row, a leaf of rank > 2 is hashed as (every leading index, row-major) x
+  its last dimension. No offset is added where it is 0, so a leaf that
+  states none keeps its bits (a bias of scale 0 holds -0.0 too)."""
+  shape = tuple(int(d) for d in shape)
   if len(shape) == 1:
-    return rows_np(key, scale, np.zeros((1,), np.int64), shape[0])[0]
-  if len(shape) != 2:
-    raise ValueError(f"leaves are 1-D or 2-D, got {shape}")
-  return rows_np(key, scale, np.arange(shape[0]), shape[1])
+    out = rows_np(key, scale, np.zeros((1,), np.int64), shape[0])[0]
+  else:
+    rows = int(np.prod(shape[:-1]))
+    out = rows_np(key, scale, np.arange(rows), shape[-1]).reshape(shape)
+  return out + np.float32(offset) if offset else out

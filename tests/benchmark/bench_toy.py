@@ -1,6 +1,7 @@
 """Toy-size copies of the benchmark's data files for the CPU tests: the
 same families, harness and check at a size a test run can hold."""
 
+import hashlib
 import json
 import os
 import shutil
@@ -45,6 +46,38 @@ def make_root(dst: str) -> str:
     _edit(os.path.join(dst, "benchmark", "workloads", f"{mix}.json"),
           lambda c: c.update(global_batch=256, pool_batches=3))
   return dst
+
+
+def digests(root: str) -> dict:
+  """sha256 of every file under ``root``'s ``benchmark/``: a test that adds
+  files to a copy shows with it that it rewrote none."""
+  out = {}
+  for base, _, files in os.walk(os.path.join(root, "benchmark")):
+    for name in files:
+      path = os.path.join(base, name)
+      with open(path, "rb") as f:
+        out[path] = hashlib.sha256(f.read()).hexdigest()
+  return out
+
+
+def break_compile_step(monkeypatch, breaker):
+  """Break the timed path underneath the harness, where it compiles its
+  step: ``breaker(prog, step) -> call(state, *batch)`` stands in for the
+  compiled step (its HLO text stays readable)."""
+  from benchmark import program
+  compile_step = program.Program.compile_step
+
+  class Broken:
+    def __init__(self, step, call):
+      self.as_text, self._call = step.as_text, call
+
+    def __call__(self, state, *batch):
+      return self._call(state, *batch)
+
+  def broken_compile(self, state, batch):
+    step = compile_step(self, state, batch)
+    return Broken(step, breaker(self, step))
+  monkeypatch.setattr(program.Program, "compile_step", broken_compile)
 
 
 def cpu_devices(n: int):

@@ -3,7 +3,6 @@ configuration, a cell, a traffic mix and per-layer metrics (one as data, one
 as a reader of a new kind) added to a copy of the benchmark are found by
 name, with every committed file left as it was."""
 
-import hashlib
 import json
 import os
 
@@ -13,20 +12,10 @@ import bench_toy
 from benchmark import specs, trace_reduce, traffic
 
 
-def _digests(root):
-  out = {}
-  for base, _, files in os.walk(os.path.join(root, "benchmark")):
-    for name in files:
-      path = os.path.join(base, name)
-      with open(path, "rb") as f:
-        out[path] = hashlib.sha256(f.read()).hexdigest()
-  return out
-
-
 @pytest.fixture(scope="module")
 def grown(tmp_path_factory):
   root = bench_toy.make_root(str(tmp_path_factory.mktemp("grown_root")))
-  before = _digests(root)
+  before = bench_toy.digests(root)
   bdir = os.path.join(root, "benchmark")
   with open(os.path.join(bdir, "configs", "dlrm-criteo1tb.json")) as f:
     config = json.load(f)
@@ -59,7 +48,7 @@ def grown(tmp_path_factory):
         "moves": "train_samples_per_s", "workloads": ["small_top_uniform"]})
   with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
     json.dump(bench, f)
-  after = _digests(root)
+  after = bench_toy.digests(root)
   assert {p: d for p, d in after.items() if p in before} == before
   return root
 

@@ -108,16 +108,6 @@ def test_accumulator_stray_allows_what_float32_accumulation_explains():
   assert check.accumulator_stray(lost, ref, 0.1)[0] == 2
 
 
-class _Broken:
-  """A compiled step with its timed path broken underneath."""
-
-  def __init__(self, step, call):
-    self.as_text, self._call = step.as_text, call
-
-  def __call__(self, state, *batch):
-    return self._call(state, *batch)
-
-
 def _unchanged_state(prog, step):
   """A step that computes its loss and hands its state back as it was."""
   def broken(state, *batch):
@@ -176,12 +166,7 @@ def test_a_run_with_a_broken_timed_path_is_not_correct(
   cell = specs.load_cell(bench_toy.CELLS[key], root)
   devices, dev = bench_toy.cpu_devices(1)
   if breaker is not None:
-    compile_step = program.Program.compile_step
-
-    def broken_compile(self, state, batch):
-      step = compile_step(self, state, batch)
-      return _Broken(step, breaker(self, step))
-    monkeypatch.setattr(program.Program, "compile_step", broken_compile)
+    bench_toy.break_compile_step(monkeypatch, breaker)
   result = run.run_cell(cell, 2**31 + 5, 0.3, False, devices, dev)
   out = capsys.readouterr().out
   outside = [ln.split()[1].rstrip(":") for ln in out.splitlines()

@@ -57,8 +57,12 @@ def test_reductions(red):
 
 def test_breakdown(red):
   ops = dict(red.top_ops())
-  assert ops["fusion.1"] == pytest.approx(600e-9)
+  # self time: the 100 ns in which fusion.1 and the kernel that starts
+  # before it ends both run go to the kernel, as scope_reduce charges them,
+  # so the rows add up to the busy time
+  assert ops["fusion.1"] == pytest.approx(400e-9)
   assert ops["de_apply_rows_cached.2"] == pytest.approx(600e-9)
+  assert sum(ops.values()) == pytest.approx(red.busy_s())
   assert "copy.9" not in ops  # ran outside the steps' window
   gaps = dict(red.idle_gaps())
   # device 0 is busy 100-700, 800-1000, 1200-1800, 1900-2100 of the window
@@ -71,6 +75,31 @@ def test_breakdown(red):
       f"host: {trace_reduce.OUTSIDE}": pytest.approx(100e-9)}
   assert sum(gaps.values()) == pytest.approx(
       red.window_s() - sum(b - a for a, b in red.busy[0]) * 1e-9)
+
+
+def test_top_ops_counts_a_while_once():
+  """A ``while`` holds its body's ops on the same line (the four-chip
+  cell's ``while.51`` and the ``fusion.295`` inside it): the breakdown
+  gives each its own time, and ``per_step_ms`` is not touched."""
+  op = lambda name, code: f"%{name} = f32[8]{{0}} {code}(f32[8]{{0}} %p)"
+  ops = [[op("while.51", "while"), 100, 600],
+         [op("fusion.295", "fusion"), 120, 200],   # two trips of the body
+         [op("fusion.295", "fusion"), 400, 250],
+         [op("copy.1", "copy"), 350, 20],
+         [op("fusion.7", "fusion"), 750, 100]]     # after the loop
+  trace = {"planes": [{"name": "/device:TPU:0", "lines": [
+      {"name": "XLA Modules", "events": [["jit_step_fn(1)", 100, 800]]},
+      {"name": "XLA Ops", "events": ops}]}]}
+  red = trace_reduce.Reduced(trace, r"^jit_step_fn\(")
+  got = dict(red.top_ops())
+  assert got == {"fusion.295": pytest.approx(450e-9),
+                 "while.51": pytest.approx(130e-9),  # 600 - 200 - 250 - 20
+                 "fusion.7": pytest.approx(100e-9),
+                 "copy.1": pytest.approx(20e-9)}
+  assert sum(got.values()) == pytest.approx(red.busy_s())
+  assert [k for k, _ in red.top_ops(2)] == ["fusion.295", "while.51"]
+  # the declared metrics' reduction still sums what it is asked for
+  assert red.per_step_ms(lambda n: "while" in n) == pytest.approx(600e-6)
 
 
 @pytest.mark.parametrize("metric,want", [
