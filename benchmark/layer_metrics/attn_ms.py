@@ -1,0 +1,10 @@
+"""attn_ms: what it measures is in ``attn_ms.json``; the reduction is
+``benchmark/scope_children.py``."""
+
+from benchmark import scope_children
+
+SCOPES = ('de_attention',)
+
+
+def read(red, ctx):
+  return scope_children.scope_ms(red, ctx, *SCOPES)

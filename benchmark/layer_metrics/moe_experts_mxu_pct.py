@@ -1,0 +1,13 @@
+"""moe_experts_mxu_pct: what it measures is in ``moe_experts_mxu_pct.json``; the counts are
+``benchmark/roofline_lm.py``."""
+
+from benchmark import roofline_lm, scope_children
+
+
+def read(red, ctx):
+  ms = scope_children.scope_ms(red, ctx, "de_moe_experts")
+  if ms is None:
+    return None
+  cell = ctx["cell"]
+  return roofline_lm.mxu_pct(
+      roofline_lm.moe_experts_flops(cell.config, cell.traffic), ms, ctx["device_kind"])

@@ -1,0 +1,10 @@
+"""moe_ms: what it measures is in ``moe_ms.json``; the reduction is
+``benchmark/scope_children.py``."""
+
+from benchmark import scope_children
+
+SCOPES = ('de_moe',)
+
+
+def read(red, ctx):
+  return scope_children.scope_ms(red, ctx, *SCOPES)

@@ -1,0 +1,202 @@
+"""One chip's share of a mixture-of-experts layer.
+
+Under expert parallelism each chip of a group holds a contiguous range of a
+layer's experts. :func:`moe_share` is the part of the layer one such chip
+computes: it routes every token over ALL ``num_experts`` (the router keeps its
+published width and its experts per token), keeps the assignments that fall
+on the experts held here, computes those and only those, and returns
+``sum over the chosen, held e of p_e * expert_e(h)``. What the absent
+experts would add is left out; the shares of all chips, added, are the whole
+layer (``tests/test_moe.py`` holds that). ``held=(0, num_experts)`` is the
+whole layer. No code stands in for the absent chips or their exchange.
+
+Dropless and without a capacity. The assignments are sorted by expert, held
+ones first, so the rows an expert computes are contiguous, and the grouped
+matmuls (``jax.lax.ragged_dot``; on a TPU XLA's own Mosaic grouped-matmul
+kernel, which skips rows that belong to no group) go over them. How many
+assignments land here is data, not a shape: the expected count is
+``tokens * top_k * held / num_experts``, the worst case ``num_experts / held``
+times that. The sorted stream's head, ``HEAD_LOADS`` times the expected
+count, is computed in one piece; the tail, the rest of the worst case, is
+walked in pieces of the same size under one ``lax.cond`` that is taken only
+when a layer's load passes the head: memory is one piece's, and a skewed
+router costs time, never a token.
+
+The head is computed WHOLE: its rows past the live count (zeros) are put in
+the last expert's group and multiplied like the others, so that a step's
+time does not follow the load. That costs time, and what it buys is
+measured (PERF.md, PR 29, review round). On seeded weights a router
+collapses: identical tokens route alike, a block-diffusion model's mask token
+is a quarter of all positions, and after one layer of attention most
+positions look alike, so a layer's load on 16 of 128 experts is near
+``0.8 k`` expected counts, ``k`` the number of the eight popular experts held
+here: a draw per layer AND per batch (17 to 16,418 assignments against 8,192
+expected, on one seed). Computed live on the same buffers a step is 5-11%
+faster and follows that lottery batch by batch, while most layers time an
+expert layer with nothing to do; a trained router sends every chip about the
+expected count. With a head of two expected counts, live, a layer past it
+walks the tail in every step and the step is slower than this one.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..telemetry import scopes
+
+
+# Expected loads the head holds. On seeded weights 96 layers of 12 seeds'
+# batches, counted on the CPU, held 0.00 to 3.41; at 3.5 one batch in twelve
+# of one seed walked the tail on the chip (PERF.md, PR 29)
+HEAD_LOADS = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEShare:
+  """Which part of a layer of ``num_experts`` experts lives here."""
+  num_experts: int
+  top_k: int
+  held: Tuple[int, int]       # (first expert held, how many)
+
+  def __post_init__(self):
+    first, count = self.held
+    if not (0 <= first and count >= 1
+            and first + count <= self.num_experts):
+      raise ValueError(f"held={self.held} is no range of "
+                       f"{self.num_experts} experts")
+    if not 1 <= self.top_k <= self.num_experts:
+      raise ValueError(f"top_k={self.top_k} of {self.num_experts} experts")
+
+  def head_rows(self, assignments: int) -> int:
+    """Rows of the sorted stream computed in one piece: ``HEAD_LOADS`` times
+    the expected number of ``assignments`` on the held experts, in whole
+    sublanes of 8."""
+    expected = -(-assignments * self.held[1] // self.num_experts)
+    return min(assignments, 8 * -(-HEAD_LOADS * expected // 8))
+
+
+def route(h: jax.Array, w_router: jax.Array, top_k: int):
+  """-> (p ``[T, top_k]`` renormalised to sum to 1, experts ``[T, top_k]``).
+
+  Router logits, softmax over every expert and the renormalisation in
+  float32; the logits' matmul at ``highest`` precision, because a choice of
+  experts decided by a bfloat16 product is a different model."""
+  logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+  probs = jax.nn.softmax(logits, axis=-1)
+  top_p, top_e = lax.top_k(probs, top_k)
+  return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
+
+
+def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
+              w_up: jax.Array, w_down: jax.Array, share: MoEShare):
+  """``h [T, d]`` -> (``[T, d]`` this share's part of the layer's output,
+  counters).
+
+  ``w_router [d, num_experts]``; ``w_gate``, ``w_up`` ``[held, d, f]`` and
+  ``w_down [held, f, d]`` hold the held experts only. Expert ``e``:
+  ``(silu(h w_gate[e]) * (h w_up[e])) w_down[e]``. The counters (int32
+  scalars and one ``[held]`` vector, no gradient): ``assignments`` that fell
+  on held experts, ``loads`` per held expert, ``computed``: the live rows in
+  the group sizes that were handed to the grouped matmuls, summed over the
+  pieces that really ran (``assignments - computed`` is what a capacity, or
+  a tail not walked, would have dropped: 0)."""
+  first, count = share.held
+  t, k = h.shape[0], share.top_k
+  n = t * k
+  with jax.named_scope(scopes.MOE):
+    with jax.named_scope(scopes.MOE_ROUTE):
+      top_p, top_e = route(h, w_router, k)
+      local = top_e.astype(jnp.int32) - first
+      here = (local >= 0) & (local < count)
+      # sort key: the held expert's local number; `count` for the rest, so
+      # the assignments of held experts are the sorted stream's head
+      key = jnp.where(here, local, count).reshape(n)
+      order = jnp.argsort(key, stable=True).astype(jnp.int32)
+      loads = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+      ends = jnp.cumsum(loads)
+      starts = ends - loads
+      n_live = ends[-1]
+      head = share.head_rows(n)
+      tail = n - head
+      chunk = min(head, tail)
+      n_chunks = -(-tail // chunk) if tail else 0
+      pad = n_chunks * chunk - tail
+      order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+      tok = order // k
+      p_sorted = jnp.take(top_p.reshape(n), order)
+
+    def rows_of(begin, size, whole, h, tok_c, p_c, w_gate, w_up, w_down):
+      """-> (the weighted expert outputs ``[size, d]`` of the sorted stream's
+      rows ``begin .. begin + size``, how many of them were live rows of a
+      group). ``whole``: the rows past the live count (zeros) are given to
+      the last expert's group, so that every one of the ``size`` rows is
+      multiplied and the time does not follow the live count."""
+      live = begin + jnp.arange(size, dtype=jnp.int32) < n_live
+      sizes = jnp.clip(ends, begin, begin + size) \
+          - jnp.clip(starts, begin, begin + size)
+      done = jnp.sum(sizes)
+      if whole:
+        sizes = sizes.at[-1].add(size - done)
+      with jax.named_scope(scopes.MOE_ROUTE):
+        # rows past the live count are zeros going in and selected away
+        # coming out (left to no group, the grouped matmul would not even
+        # write them)
+        x = jnp.where(live[:, None], jnp.take(h, tok_c, axis=0), 0)
+      with jax.named_scope(scopes.MOE_EXPERTS):
+        gate = lax.ragged_dot(x, w_gate, sizes)
+        up = lax.ragged_dot(x, w_up, sizes)
+        y = lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
+      with jax.named_scope(scopes.MOE_ROUTE):
+        return jnp.where(live[:, None], y * p_c[:, None].astype(y.dtype),
+                         0), done
+
+    # recomputed in the backward pass: what is kept of the head's rows is
+    # this call's arguments
+    y, computed = jax.checkpoint(functools.partial(rows_of, 0, head, True))(
+        h, tok[:head], p_sorted[:head], w_gate, w_up, w_down)
+    with jax.named_scope(scopes.MOE_ROUTE):
+      out = jnp.zeros_like(h).at[tok[:head]].add(y)
+
+    if tail:
+      @jax.checkpoint
+      def tail_rows(h, tok_t, p_t, w_gate, w_up, w_down):
+        """What the rows past the head add to ``out`` and how many of them
+        were computed: zeros, at no cost, unless the router sent more than
+        the head holds. (The ``cond`` lies INSIDE the checkpoint: around it,
+        its branch's residuals, the weights and ``h`` among them, would
+        leave it as outputs.)"""
+
+        def walk():
+          def one_chunk(carry, xs):
+            acc, done = carry
+            c, tok_c, p_c = xs
+            begin = head + c * chunk
+            y, live = jax.checkpoint(functools.partial(
+                rows_of, begin, chunk, False))(
+                    h, tok_c, p_c, w_gate, w_up, w_down)
+            with jax.named_scope(scopes.MOE_ROUTE):
+              return (acc.at[tok_c].add(y), done + live), None
+          carry, _ = lax.scan(
+              one_chunk, (jnp.zeros_like(h), jnp.int32(0)),
+              (jnp.arange(n_chunks, dtype=jnp.int32),
+               tok_t.reshape(n_chunks, chunk),
+               p_t.reshape(n_chunks, chunk)))
+          return carry
+
+        return lax.cond(n_live > head, walk,
+                        lambda: (jnp.zeros_like(h), jnp.int32(0)))
+
+      more, walked = tail_rows(h, tok[head:], p_sorted[head:], w_gate, w_up,
+                               w_down)
+      out, computed = out + more, computed + walked
+  counters: Dict[str, jax.Array] = {
+      "assignments": jnp.sum(here, dtype=jnp.int32), "loads": loads,
+      "computed": computed}
+  return out, counters

@@ -1,0 +1,301 @@
+"""SDAR-MoE (``model_type`` ``sdar_moe``): a block-diffusion language model
+over a mixture of experts, on the training path.
+
+The decoder follows Qwen3-MoE's modelling code: per layer, on a residual
+stream ``x [B, S, d]``: ``h = RMSNorm(x)``; ``q, k, v = h Wq, h Wk, h Wv``
+without bias; RMSNorm over the head dimension on ``q`` and on ``k``; RoPE
+(rotate-half) on both; grouped-query attention at scale ``head_dim ** -0.5``;
+``x += attn Wo``. Then ``h = RMSNorm(x)`` and ``x += moe(h)``: router over all
+experts in float32, top-k, the k probabilities renormalised to 1, experts
+``(silu(h Wg) * (h Wu)) Wd`` (:mod:`..layers.moe`, which computes the experts
+this chip holds). A final RMSNorm and an untied head.
+
+Block diffusion, training. A clean sequence ``x0`` of ``L`` tokens is cut in
+blocks of ``block_length``. Each block draws a noise level ``t`` and each of
+its positions is masked with probability ``t``; the noisy copy ``xt`` holds the
+mask token's embedding at masked positions and ``x0``'s elsewhere. The model
+sees ``[xt ; x0]``, ``S = 2 L`` positions, both halves numbered ``0 .. L-1``
+for RoPE. With ``b(i) = i // block_length``: a noisy query sees the noisy
+keys of its own block and the clean keys of earlier blocks; a clean query sees
+the clean keys of its own and earlier blocks; nothing else
+(:func:`block_diffusion_mask`). Logits are taken at the noisy half and the
+loss is ``sum over masked positions of CE / t``, over ``B L``
+(:func:`block_diffusion_loss`).
+
+On the sparse train step the token table is a sequence input
+(``TableConfig(combiner=None)`` read at hotness ``L``): ``emb_acts`` is
+``[rows [B, L, d]]``. The noise is the batch's numerical features,
+``[B, L + L / block_length]`` uniforms in [0, 1): one per position, then one
+per block (``t = t_min + (1 - t_min) u``). The mask token's embedding is a
+dense leaf of its own (published: a row of ``embed_tokens``; the same
+mathematics), because the noisy copy is chosen, not looked up.
+
+Attention never holds ``[S, S]`` scores. It is JAX's splash-attention kernel
+under the static mask (blocks of keys that the mask empties, about 3/4 of
+them, are skipped by the kernel's own block map): a TPU kernel, and without a
+TPU the model raises rather than compute something else. ``attention="xla"``
+names the other path, for tests and counting tools on any backend: one tile
+of queries at a time against exactly the keys its blocks can see, in XLA.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..layers.moe import MoEShare, moe_share
+from ..telemetry import scopes
+
+
+@dataclasses.dataclass(frozen=True)
+class SDARMoEConfig:
+  """Widths as the published ``config.json`` names them, and the share of the
+  model that lives here."""
+  hidden_size: int = 2048
+  num_attention_heads: int = 32
+  num_key_value_heads: int = 4
+  head_dim: int = 128
+  moe_intermediate_size: int = 768
+  num_experts: int = 128
+  num_experts_per_tok: int = 8
+  rms_norm_eps: float = 1e-6
+  rope_theta: float = 1e6
+  num_hidden_layers: int = 48
+  vocab_size: int = 151936              # rows of the head (a slice: fewer)
+  experts_held: Tuple[int, int] = (0, 128)
+  block_length: int = 4
+  t_min: float = 0.1                    # t is uniform on [t_min, 1]
+  seq_len: int = 4096                   # L, tokens of a clean sequence
+  attention: str = "splash"             # splash: the TPU's kernel | xla: tests
+
+  @property
+  def n_numerical(self) -> int:
+    return self.seq_len + self.seq_len // self.block_length
+
+  @property
+  def share(self) -> MoEShare:
+    """This chip's share of every layer."""
+    return MoEShare(self.num_experts, self.num_experts_per_tok,
+                    tuple(self.experts_held))
+
+
+def rms_norm(x, gain, eps):
+  x32 = x.astype(jnp.float32)
+  var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+  return (x32 * jax.lax.rsqrt(var + eps) * gain.astype(jnp.float32)
+          ).astype(x.dtype)
+
+
+def rope(x, positions, theta):
+  """``x [..., S, heads, head_dim]``, rotate-half, angles in float32."""
+  half = x.shape[-1] // 2
+  inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float32) / half))
+  ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # [S, half]
+  cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
+  sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
+  rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+  return x * cos + rotated * sin
+
+
+def noise_of(numerical, seq_len: int, block_length: int, t_min: float):
+  """The batch's numerical features -> (masked ``[B, L]`` bool, the loss's
+  weight ``[B, L]``: ``1 / t`` at masked positions, 0 elsewhere)."""
+  u = numerical[:, :seq_len]
+  t = t_min + (1.0 - t_min) * numerical[:, seq_len:]     # [B, L / Bl]
+  t = jnp.repeat(t, block_length, axis=1)                # [B, L]
+  masked = u < t
+  return masked, jnp.where(masked, 1.0 / t, 0.0).astype(jnp.float32)
+
+
+def block_diffusion_mask(seq_len: int, block_length: int) -> np.ndarray:
+  """``[2 L, 2 L]`` bool, query x key, over ``[xt ; x0]``."""
+  blk = np.arange(seq_len) // block_length
+  same, earlier = blk[:, None] == blk[None, :], blk[None, :] < blk[:, None]
+  none = np.zeros_like(same)
+  return np.block([[same, earlier], [none, same | earlier]])
+
+
+def attention_xla(q, k, v, seq_len: int, block_length: int, tile: int):
+  """``q [B, S, Hkv, G, hd]`` (already scaled), ``k, v [B, S, Hkv, hd]`` ->
+  ``[B, S, Hkv, G, hd]``. One tile of queries at a time; a tile of the noisy
+  half sees its own noisy keys and the clean keys up to its end, a tile of
+  the clean half the clean keys up to its end: nothing beyond is computed."""
+  length = seq_len
+  tile = min(tile, length)
+  if length % tile or tile % block_length:
+    raise ValueError(f"seq_len {length}, tile {tile}, block {block_length}")
+  blk = np.arange(length) // block_length
+
+  def attend(qt, kt, vt, allowed):
+    s = jnp.einsum("bqkgd,bskd->bkgqs", qt, kt).astype(jnp.float32)
+    s = jnp.where(jnp.asarray(allowed)[None, None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(vt.dtype)
+    return jnp.einsum("bkgqs,bskd->bqkgd", p, vt)
+
+  noisy, clean = [], []
+  for a in range(0, length, tile):
+    b = a + tile
+    k_clean, v_clean = k[:, length:length + b], v[:, length:length + b]
+    earlier = blk[None, :b] < blk[a:b, None]
+    same = blk[None, a:b] == blk[a:b, None]
+    noisy.append(attend(
+        q[:, a:b], jnp.concatenate([k[:, a:b], k_clean], axis=1),
+        jnp.concatenate([v[:, a:b], v_clean], axis=1),
+        np.concatenate([same, earlier], axis=1)))
+    clean.append(attend(q[:, length + a:length + b], k_clean, v_clean,
+                        blk[None, :b] <= blk[a:b, None]))
+  return jnp.concatenate(noisy + clean, axis=1)
+
+
+# Queries and keys a block of the splash kernel, and queries a tile of the XLA
+# path: 256 cost a quarter more time, 1024 no less (and its fused backward
+# does not fit VMEM); the kernel's fused backward (dq inside dkv) was no
+# faster (my chip runs, PR 29)
+ATTENTION_BLOCK = 512
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(seq_len: int, block_length: int, group: int, block: int,
+                   interpret: bool):
+  from jax.experimental.pallas.ops.tpu import splash_attention as sa
+  mask = sa.NumpyMask(block_diffusion_mask(seq_len, block_length))
+  block = min(block, 2 * seq_len)
+  sizes = sa.BlockSizes(
+      block_q=block, block_kv=block, block_kv_compute=block,
+      block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+      block_q_dq=block, block_kv_dq=block)
+  # the kernel's block maps as host arrays, so that they are constants of
+  # whatever program calls it. The factory makes ``jnp`` arrays of them: in
+  # the middle of a trace (where this is first called) those would be that
+  # trace's tracers, kept here for the next one
+  with jax.ensure_compile_time_eval():
+    kernel = sa.make_splash_mqa_single_device(
+        sa.MultiHeadMask([mask] * group), block_sizes=sizes,
+        interpret=interpret)
+  return jax.tree_util.tree_map(np.asarray, kernel)
+
+
+def attention_splash(q, k, v, seq_len: int, block_length: int, block: int,
+                     interpret: bool = False):
+  """Same contract as :func:`attention_xla`, through the splash-attention
+  kernel: one multi-query call per (sample, key head). Its operands are
+  rounded to bfloat16, which is what the MXU's default precision makes of a
+  float32 operand; scores, softmax and accumulation are float32.
+  ``interpret`` runs the kernel in Pallas's interpreter (tests, any
+  backend)."""
+  kernel = _splash_kernel(seq_len, block_length, q.shape[3], block,
+                          interpret)
+  qh = jnp.transpose(q, (0, 2, 3, 1, 4)).astype(jnp.bfloat16)  # [B,Hkv,G,S,hd]
+  kh = jnp.transpose(k, (0, 2, 1, 3)).astype(jnp.bfloat16)     # [B,Hkv,S,hd]
+  vh = jnp.transpose(v, (0, 2, 1, 3)).astype(jnp.bfloat16)
+  out = jax.vmap(jax.vmap(kernel))(qh, kh, vh)                 # [B,Hkv,G,S,hd]
+  return jnp.transpose(out, (0, 3, 1, 2, 4)).astype(q.dtype)
+
+
+def _attention_fn(cfg: SDARMoEConfig):
+  if cfg.attention == "xla":
+    return attention_xla
+  if cfg.attention == "splash":
+    if jax.default_backend() != "tpu":
+      raise ValueError(
+          'attention="splash" is a TPU kernel and this backend is '
+          f'{jax.default_backend()!r}; a test or a counting tool on another '
+          'backend names attention="xla" itself')
+    return attention_splash
+  raise ValueError(f"attention={cfg.attention!r}: splash or xla")
+
+
+def decoder_layer(cfg: SDARMoEConfig, p, x):
+  """One layer on ``x [B, S, d]`` with its parameters ``p`` (a dict)."""
+  b, s, d = x.shape
+  hq, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+  positions = jnp.tile(jnp.arange(cfg.seq_len), 2)
+  with jax.named_scope(scopes.ATTENTION):
+    h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+    q = (h @ p["wq"]).reshape(b, s, hq, hd)
+    k = (h @ p["wk"]).reshape(b, s, hkv, hd)
+    v = (h @ p["wv"]).reshape(b, s, hkv, hd)
+    q = rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
+             cfg.rope_theta) * (hd ** -0.5)
+    k = rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
+             cfg.rope_theta)
+    q = q.reshape(b, s, hkv, hq // hkv, hd)
+    o = _attention_fn(cfg)(q, k, v, cfg.seq_len, cfg.block_length,
+                           ATTENTION_BLOCK)
+    x = x + o.reshape(b, s, hq * hd) @ p["wo"]
+  with jax.named_scope(scopes.MOE):
+    h = rms_norm(x, p["moe_norm"], cfg.rms_norm_eps)
+  y, counters = moe_share(h.reshape(b * s, d), p["router"], p["w_gate"],
+                          p["w_up"], p["w_down"], cfg.share)
+  return x + y.reshape(b, s, d), counters
+
+
+class SDARMoE(nn.Module):
+  """``__call__(numerical, cats, emb_acts=[rows [B, L, d]])`` ->
+  ``{"logits" [B, L, V], "weight" [B, L]}`` (and ``"moe"``, the layers'
+  counters stacked, where ``with_counters``)."""
+
+  config: SDARMoEConfig
+  with_counters: bool = False
+
+  @nn.compact
+  def __call__(self, numerical, cats, emb_acts=None):
+    del cats
+    cfg = self.config
+    if emb_acts is None or len(emb_acts) != 1:
+      raise ValueError("SDARMoE takes its token rows as one sequence input: "
+                       "emb_acts=[rows [B, L, hidden_size]]")
+    (x0,) = emb_acts
+    d, hq, hkv, hd = (cfg.hidden_size, cfg.num_attention_heads,
+                      cfg.num_key_value_heads, cfg.head_dim)
+    f, held = cfg.moe_intermediate_size, cfg.experts_held[1]
+    normal, ones = nn.initializers.normal(0.02), nn.initializers.ones
+    shapes = {
+        "attn_norm": ((d,), ones), "wq": ((d, hq * hd), normal),
+        "wk": ((d, hkv * hd), normal), "wv": ((d, hkv * hd), normal),
+        "wo": ((hq * hd, d), normal), "q_norm": ((hd,), ones),
+        "k_norm": ((hd,), ones), "moe_norm": ((d,), ones),
+        "router": ((d, cfg.num_experts), normal),
+        "w_gate": ((held, d, f), normal), "w_up": ((held, d, f), normal),
+        "w_down": ((held, f, d), normal)}
+    mask_embedding = self.param("mask_embedding", normal, (d,))
+    layers = [{name: self.param(f"layer_{i}_{name}", init, shape)
+               for name, (shape, init) in shapes.items()}
+              for i in range(cfg.num_hidden_layers)]
+    final_norm = self.param("final_norm", ones, (d,))
+    head = self.param("head", normal, (d, cfg.vocab_size))
+
+    masked, weight = noise_of(numerical, cfg.seq_len, cfg.block_length,
+                              cfg.t_min)
+    xt = jnp.where(masked[..., None], mask_embedding.astype(x0.dtype), x0)
+    x = jnp.concatenate([xt, x0], axis=1)                    # [B, 2 L, d]
+    # one layer's activations at a time: the others are recomputed
+    layer = jax.checkpoint(functools.partial(decoder_layer, cfg))
+    counters = []
+    for p in layers:
+      x, c = layer(p, x)
+      counters.append(c)
+    with jax.named_scope(scopes.LM_HEAD):
+      h = rms_norm(x[:, :cfg.seq_len], final_norm, cfg.rms_norm_eps)
+      logits = h @ head
+    out = {"logits": logits, "weight": weight}
+    if self.with_counters:
+      out["moe"] = jax.tree_util.tree_map(lambda *c: jnp.stack(c), *counters)
+      out["masked"] = masked
+    return out
+
+
+def block_diffusion_loss(outputs, labels):
+  """``sum over masked positions of (1 / t) CE(logits, x0) / (B L)``:
+  ``outputs`` as :class:`SDARMoE` returns them, ``labels["targets"] [B, L]``
+  the clean tokens."""
+  logits = outputs["logits"].astype(jnp.float32)
+  logp = jax.nn.log_softmax(logits, axis=-1)
+  nll = -jnp.take_along_axis(logp, labels["targets"][..., None], -1)[..., 0]
+  return jnp.mean(outputs["weight"] * nll)

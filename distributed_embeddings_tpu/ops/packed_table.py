@@ -592,6 +592,12 @@ class SparseRule:
   # cannot build the [..., n_aux, W] aux view in-kernel. Must compute
   # exactly what ``delta`` computes (tests/test_pallas_delta.py pins it)
   delta_lanes: Optional[callable] = None
+  # a rule that is only sound applied ONCE per distinct row, from the sum of
+  # the row's occurrence gradients (Adam on a table whose hot rows are read
+  # hundreds of times a step: per occurrence the first moment is multiplied
+  # by 1 - (1 - b1) k for a row read k times). The step builders then take
+  # the deduplicated path (``exact=True``) whatever their ``exact=`` says
+  summed: bool = False
 
   def init_aux(self, rows: int, width: int, dtype=jnp.float32) -> List:
     return [np.full((rows, width), v, dtype) for v in self.aux_init]
@@ -671,8 +677,13 @@ def momentum_rule(learning_rate, momentum: float = 0.9,
 
 
 def adam_rule(learning_rate, b1: float = 0.9, b2: float = 0.999,
-              eps: float = 1e-8) -> SparseRule:
+              eps: float = 1e-8, summed: bool = False) -> SparseRule:
   """Row-sparse Adam matching ``optax.adam``'s update rule.
+
+  ``summed=True`` asks the step builders for one update per distinct row
+  from the row's summed gradient, which on the rows a batch touches IS
+  ``optax.adam`` on the dense table (:attr:`SparseRule.summed`); the default
+  keeps the per-occurrence semantics below.
 
   m' = b1*m + (1-b1)*g; v' = b2*v + (1-b2)*g^2; bias-corrected with
   ``t = step + 1``; table -= lr * m_hat / (sqrt(v_hat) + eps). Both
@@ -710,7 +721,8 @@ def adam_rule(learning_rate, b1: float = 0.9, b2: float = 0.999,
     upd = m_hat / (jnp.sqrt(v_hat) + eps)
     return [-lr * upd, dm, dv]
 
-  return SparseRule("adam", 2, (0.0, 0.0), delta, delta_lanes=delta_lanes)
+  return SparseRule("adam", 2, (0.0, 0.0), delta, delta_lanes=delta_lanes,
+                    summed=summed)
 
 
 _RULES = {"sgd": sgd_rule, "adagrad": adagrad_rule,

@@ -917,8 +917,10 @@ class DistributedLookup:
     if ids_all.ndim == 2 or ids_all.shape[-1] == 1:
       return rows if ids_all.ndim == 2 else rows[:, :, 0, :]
     if cp.combiner is None:
-      raise ValueError("combiner=None requires hotness-1 inputs in the "
-                       "distributed path (2-D model-parallel outputs)")
+      # a sequence input: the h rows stay in order, side by side on the
+      # width axis through the exchange; :meth:`assemble` gives them their
+      # own axis back ([B, h, w])
+      return rows.reshape(rows.shape[:2] + (-1,))
     summed = jnp.sum(rows, axis=2)
     if cp.combiner == "mean" and not rs:
       counts = jnp.sum(ids_all < sentinel, axis=2).astype(summed.dtype)
@@ -1003,13 +1005,12 @@ class DistributedLookup:
     two_d = ids_all.ndim == 2  # hotness-1 buckets drop the h axis
     n_b, g = ids_all.shape[:2]
     h = 1 if two_d else ids_all.shape[2]
-    cp_check = self.plan.classes[key]
-    if cp_check.combiner is None and h != 1:
-      # same contract as the sparse path's _combine: without a combiner a
-      # multi-hot input has no defined reduction (the einsum below would
-      # silently sum over h)
-      raise ValueError("combiner=None requires hotness-1 inputs in the "
-                       "distributed path (2-D model-parallel outputs)")
+    if self.plan.classes[key].combiner is None and h != 1:
+      # a sequence input (same contract as the sparse path's _combine):
+      # every position is a hotness-1 lookup of its own, the h rows of a
+      # sample side by side on the width axis
+      z = self._z_dense(key, bucket, table_local, ids_all.reshape(n_b, g * h))
+      return z.reshape(n_b, g, -1)
     vcap = bucket.vcap
     offs_const = jnp.asarray(self._dense_offsets(key, bucket))  # [world, n_b]
     offs = offs_const[self._my_rank()]  # [n_b]
@@ -1078,8 +1079,9 @@ class DistributedLookup:
       fused = gather_fused_chunked(layout, buf_local, vals)
       aux = fused if (layout.n_aux or keep_rows) else fused[..., w:]
       return self._combine_ragged(fused[..., :w], vals, lens, key, rs), aux
+    sequence = self.plan.classes[key].combiner is None
     if (layout.rows_per_phys > 1 and layout.n_aux and ids_all.ndim == 3
-        and ids_all.shape[-1] > 1):
+        and ids_all.shape[-1] > 1 and not sequence):
       # Multi-hot narrow class: keep the whole pipeline at PHYSICAL width.
       # Gathered rows are window-MASKED per occurrence (zero outside the
       # occurrence's sub-row window — a fused VPU select), the bag combine
@@ -1090,9 +1092,6 @@ class DistributedLookup:
       masked = gather_fused_chunked(layout, buf_local, ids_all,
                                     masked_phys=True)
       cp = self.plan.classes[key]
-      if cp.combiner is None:
-        raise ValueError("combiner=None requires hotness-1 inputs in the "
-                         "distributed path (2-D model-parallel outputs)")
       bag = jnp.sum(masked, axis=2)  # [n_b, G, rpp*stride]
       rpp, stride = layout.rows_per_phys, layout.stride
       folded = jnp.sum(
@@ -1109,7 +1108,8 @@ class DistributedLookup:
       # rows anyway (the weight-decay delta needs the forward-time row)
       return self._combine(fused, ids_all, key, rs), (
           fused if keep_rows else fused[..., w:])
-    if ids_all.ndim == 2 or ids_all.shape[-1] == 1:
+    if ids_all.ndim == 2 or ids_all.shape[-1] == 1 or sequence:
+      # nothing is summed over the hotness: slice the table lanes first
       return self._combine(fused[..., :w], ids_all, key, rs), fused
     zf = self._combine(fused, ids_all, key, rs)  # [n_b, G, stride]
     return zf[..., :w], fused
@@ -1238,9 +1238,8 @@ class DistributedLookup:
     masked = (layout.rows_per_phys > 1 and layout.n_aux
               and ids_all.ndim == 3 and ids_all.shape[-1] > 1)
     cp = self.plan.classes[key]
-    if masked and cp.combiner is None:
-      raise ValueError("combiner=None requires hotness-1 inputs in the "
-                       "distributed path (2-D model-parallel outputs)")
+    sequence = cp.combiner is None
+    masked = masked and not sequence
     sentinel = padded_rows(self.plan, key)
     blocks, aux_rounds = [], []
     for k in range(world):
@@ -1269,7 +1268,7 @@ class DistributedLookup:
         if layout.n_aux == 0:
           zc.append(self._combine(fused, ids_c, key, rs))
           ac.append(fused if keep_rows else fused[..., w:])
-        elif ids_c.ndim == 2 or ids_c.shape[-1] == 1:
+        elif ids_c.ndim == 2 or ids_c.shape[-1] == 1 or sequence:
           zc.append(self._combine(fused[..., :w], ids_c, key, rs))
           ac.append(fused)
         else:
@@ -1489,7 +1488,11 @@ class DistributedLookup:
       parts = []
       for p in pieces:
         bk, idx = slot_map[(p.class_key, p.rank, p.slot)]
-        parts.append(received[bk][p.rank, idx])
+        part = received[bk][p.rank, idx]
+        if bk.combiner == "" and bk.h > 1:
+          # a sequence input: [B, h * w] as it travelled -> [B, h, w]
+          part = part.reshape(part.shape[0], bk.h, -1)
+        parts.append(part)
       if pieces and pieces[0].row_sliced:
         out = parts[0] if len(parts) == 1 else sum(parts[1:], parts[0])
         combiner = plan.global_configs[
@@ -1880,6 +1883,12 @@ class DistributedLookup:
           g_occ = g_occ / jnp.maximum(cnt, 1)[..., None]
         by_class.setdefault(name, []).append(
             (vals.reshape(-1), g_occ.reshape(-1, w), aux, 0))
+        continue
+      if cp.combiner is None and h > 1:
+        # a sequence input: the cotangent [n_b, G, h * w] already holds one
+        # row per occurrence (h=0 marks pre-expanded parts)
+        by_class.setdefault(name, []).append(
+            (ids.reshape(-1), dzb.reshape(-1, cp.width), aux, 0))
         continue
       if cp.combiner == "mean" and h > 1 and not bk.rs:
         # row-sliced buckets skip this: their mean division lives in the

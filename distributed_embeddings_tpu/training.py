@@ -812,6 +812,9 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
   engine = DistributedLookup(plan, dp_input=True, axis_name=axis_name)
   layouts = engine.fused_layouts(rule)
   emb_opt = emb_dense_optimizer or dense_optimizer
+  # a summed rule is applied once per distinct row: the exact path, with
+  # every refusal the exact path has
+  exact = exact or rule.summed
 
   if micro_batches > 1 and exact:
     raise NotImplementedError(
@@ -1182,6 +1185,8 @@ def make_tiered_train_step(model, tplan, loss_fn: Callable,
         "Enforcement rides the guarded step's OOV metrics plus a commit "
         "gate on the offending batch; build with guard=True or use "
         "oov='clip'.")
+  # a summed rule is applied once per distinct row: the exact path
+  exact = exact or rule.summed
   if guard and exact:
     raise NotImplementedError(
         "guard=True with exact=True: the non-finite guard gates the "

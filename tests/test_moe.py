@@ -1,0 +1,143 @@
+"""One chip's share of a mixture-of-experts layer (`layers/moe.py`): the
+shares of all chips add up to the uncut layer, no assignment is dropped
+however skewed the router, and values and gradients are those of a plain
+loop over the experts."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_embeddings_tpu.layers import moe
+from distributed_embeddings_tpu.layers.moe import MoEShare, moe_share, route
+
+T, D, F, E, K = 64, 16, 24, 32, 2
+
+
+def _weights(seed=0):
+  rng = np.random.default_rng(seed)
+  f32 = lambda *shape, s=1.0: jnp.asarray(rng.normal(size=shape) * s,
+                                          jnp.float32)
+  return (f32(T, D), f32(D, E), f32(E, D, F, s=0.3), f32(E, D, F, s=0.3),
+          f32(E, F, D, s=0.3))
+
+
+def plain_layer(h, w_router, w_gate, w_up, w_down, first=0, k=K):
+  """Every held expert over every token, one by one. ``w_gate`` and the
+  others hold experts ``first ..``; the router chooses among all."""
+  top_p, top_e = route(h, w_router, k)
+  out = jnp.zeros_like(h)
+  for e in range(w_gate.shape[0]):
+    y = (jax.nn.silu(h @ w_gate[e]) * (h @ w_up[e])) @ w_down[e]
+    chosen = jnp.sum(jnp.where(top_e == first + e, top_p, 0.0), axis=-1)
+    out = out + chosen[:, None] * y
+  return out
+
+
+def test_route_renormalises_the_chosen_probabilities():
+  h, w_router, *_ = _weights()
+  top_p, top_e = route(h, w_router, 3)
+  assert top_p.shape == top_e.shape == (T, 3)
+  np.testing.assert_allclose(np.sum(top_p, axis=-1), 1.0, atol=1e-6)
+  probs = jax.nn.softmax(h @ w_router, axis=-1)
+  assert np.array_equal(np.asarray(top_e), np.argsort(-probs, axis=-1)[:, :3])
+
+
+def test_the_head_is_a_multiple_of_the_expected_load():
+  share = MoEShare(128, 8, (0, 16))
+  assert share.head_rows(8192 * 8) == moe.HEAD_LOADS * 8192   # the cell's
+  assert share.head_rows(10) == 8 and MoEShare(8, 2, (0, 8)).head_rows(128) \
+      == 128                                       # never past the stream
+
+
+@pytest.mark.parametrize("held,head_loads", [(2, 4), (2, 1), (8, 4), (8, 1),
+                                             (32, 4)])
+def test_the_shares_add_up_to_the_uncut_layer(held, head_loads, monkeypatch):
+  """Nothing is computed by every share alike, so nothing is counted once:
+  the plain sum of the shares is the whole layer. (With a head of one
+  expected load about half the shares walk their tail.)"""
+  monkeypatch.setattr(moe, "HEAD_LOADS", head_loads)
+  h, wr, wg, wu, wd = _weights()
+  with jax.default_matmul_precision("highest"):
+    whole = plain_layer(h, wr, wg, wu, wd)
+    parts, assigned, walked = [], 0, 0
+    for first in range(0, E, held):
+      sl = slice(first, first + held)
+      share = MoEShare(E, K, (first, held))
+      out, c = moe_share(h, wr, wg[sl], wu[sl], wd[sl], share)
+      np.testing.assert_allclose(
+          out, plain_layer(h, wr, wg[sl], wu[sl], wd[sl], first), atol=2e-6)
+      assert int(c["assignments"]) == int(c["computed"]) == int(
+          np.sum(c["loads"]))
+      assigned += int(c["assignments"])
+      walked += int(c["assignments"]) > share.head_rows(T * K)
+      parts.append(out)
+  assert assigned == T * K           # every assignment lies on one share
+  assert (walked > 0) == (head_loads == 1 and held < E)
+  np.testing.assert_allclose(sum(parts), whole, atol=4e-6)
+  assert float(jnp.max(jnp.abs(parts[0]))) > 0.01
+
+
+def _skewed(seed):
+  """Weights whose router gives expert 3 every token with a positive first
+  feature, first: about half of them."""
+  h, wr, wg, wu, wd = _weights(seed)
+  wr = np.array(wr) * 0.1
+  wr[:, 3] = 0.0
+  wr[0, 3] = 40.0
+  return h, jnp.asarray(wr), wg, wu, wd
+
+
+def test_no_token_is_dropped_under_a_skewed_router():
+  """One expert is given half the tokens: at a capacity of 1.25 times the
+  mean load it would drop most of them, and its share's load is past the
+  head of four expected loads. Here every assignment is computed: the tail
+  is walked, and the counter says so from the group sizes the grouped
+  matmuls were handed."""
+  h, wr, wg, wu, wd = _skewed(1)
+  share = MoEShare(E, K, (3, 1))
+  with jax.default_matmul_precision("highest"):
+    out, c = moe_share(h, wr, wg[3:4], wu[3:4], wd[3:4], share)
+    want = plain_layer(h, wr, wg[3:4], wu[3:4], wd[3:4], 3)
+  loads = np.asarray(c["loads"])
+  positive = int(np.sum(np.asarray(h[:, 0]) > 0.05))
+  assert T // 3 < positive <= loads[0]
+  assert loads[0] > 1.25 * T * K / E          # over a usual capacity: it
+  assert loads[0] > share.head_rows(T * K)    # would have dropped tokens
+  assert int(c["computed"]) == int(c["assignments"]) == int(loads.sum())
+  np.testing.assert_allclose(out, want, atol=4e-6)
+
+
+def test_a_tail_not_walked_shows_in_the_counter(monkeypatch):
+  """The counter is no arithmetic on the load: with the walk taken out the
+  rows past the head are neither computed nor counted."""
+  h, wr, wg, wu, wd = _skewed(1)
+  real_cond = jax.lax.cond
+  monkeypatch.setattr(moe.lax, "cond",
+                      lambda pred, walk, rest: real_cond(False, walk, rest))
+  share = MoEShare(E, K, (3, 1))
+  _, c = moe_share(h, wr, wg[3:4], wu[3:4], wd[3:4], share)
+  assert int(c["computed"]) == share.head_rows(T * K) < int(c["assignments"])
+
+
+@pytest.mark.parametrize("skewed,held", [(False, (2, 4)), (True, (3, 1))],
+                         ids=["head_only", "tail_walked"])
+def test_gradients_are_the_plain_loops(skewed, held):
+  h, wr, wg, wu, wd = _skewed(2) if skewed else _weights(2)
+  sl = slice(held[0], held[0] + held[1])
+  args = (h, wr, wg[sl], wu[sl], wd[sl])
+  share = MoEShare(E, K, held)
+  with jax.default_matmul_precision("highest"):
+    got = jax.jit(jax.grad(
+        lambda a: jnp.sum(jnp.sin(moe_share(*a, share)[0]))))(args)
+    want = jax.grad(lambda a: jnp.sum(jnp.sin(plain_layer(*a, held[0]))))(
+        args)
+  for g, w in zip(got, want):
+    np.testing.assert_allclose(g, w, atol=3e-5, rtol=1e-4)
+  assert float(jnp.max(jnp.abs(got[1]))) > 0   # the router learns too
+
+
+@pytest.mark.parametrize("held", [(0, 0), (E - 2, 4), (-1, 2)])
+def test_a_share_is_a_range_of_the_layers_experts(held):
+  with pytest.raises(ValueError, match="no range"):
+    MoEShare(E, K, held)

@@ -1,0 +1,86 @@
+"""The expert layer's counters on a benchmark cell's traffic.
+
+The benchmark times the unguarded step, whose outputs carry no metrics (and
+a summed rule has no guarded step); this runs the cell's model forward on one
+pool batch from the benchmark's own weights and prints what the counters of
+`layers/moe.py::moe_share` say, per layer: the assignments that fell on the
+experts held here, the largest held expert's load over the mean, the
+assignments a capacity would have dropped (assigned less what the grouped
+matmuls were handed: must read 0), and the share of positions the noise
+masked. Counts, so any backend will do, and attention goes the XLA way
+whatever the cell names (at the cell's real size the CPU takes a minute a
+batch; ``--layers 1`` shortens it):
+
+  JAX_PLATFORMS=cpu python tools/moe_load.py sdar_moe_train_1chip [--seed N]
+"""
+
+import argparse
+import dataclasses
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmark import reference, specs, traffic, weights
+
+
+def main(argv=None):
+  ap = argparse.ArgumentParser()
+  ap.add_argument("cell")
+  ap.add_argument("--seed", type=int, default=0)
+  ap.add_argument("--batch", type=int, default=0, help="index in the pool")
+  ap.add_argument("--layers", type=int, default=0,
+                  help="only the first N layers (0: all)")
+  ap.add_argument("--root", default=specs.ROOT)
+  args = ap.parse_args(argv)
+  cell = specs.load_cell(args.cell, args.root)
+  family = cell.family()
+  spec = family.model_spec(cell.config)
+  parts = family.build_parts(dict(cell.config, attention="xla"), cell.chips,
+                             int(cell.traffic["global_batch"]))
+  if not hasattr(parts.model, "with_counters"):
+    raise SystemExit(f"{args.cell}: its model has no expert layer")
+  batch = traffic.make_batch(cell.traffic, spec.inputs, spec.n_numerical,
+                             args.seed, args.batch,
+                             traffic.family_labels(family, cell.config))
+  config = parts.model.config
+  if args.layers:
+    config = dataclasses.replace(config, num_hidden_layers=args.layers)
+  model = type(parts.model)(config, with_counters=True)
+  dense = {n: jnp.asarray(w) for n, w in
+           reference.dense_weights(spec, args.seed).items()
+           if not n.startswith("layer_")
+           or int(n.split("_")[1]) < config.num_hidden_layers}
+  table = spec.tables[0]
+  ids, inverse = np.unique(batch.cats, return_inverse=True)
+  rows = weights.rows_np(
+      weights.leaf_key(args.seed, reference.table_name(0)), table.scale, ids,
+      table.width)[inverse.reshape(batch.cats.shape)]
+  out = jax.jit(lambda d, r, n: {
+      k: v for k, v in model.apply({"params": d}, n, None,
+                                   emb_acts=[r]).items()
+      if k in ("moe", "masked")})(dense, jnp.asarray(rows),
+                                  jnp.asarray(batch.numerical))
+  moe = jax.tree_util.tree_map(np.asarray, out["moe"])
+  report = {
+      "cell": args.cell, "seed": args.seed,
+      "backend": jax.default_backend(),
+      "positions_a_layer": int(2 * batch.cats.size),
+      "experts_held": list(config.experts_held),
+      "assignments_on_held_experts": moe["assignments"].tolist(),
+      "largest_load_over_mean": [
+          float(l.max() / max(l.mean(), 1e-30)) for l in moe["loads"]],
+      "dropped": (moe["assignments"] - moe["computed"]).tolist(),
+      "masked_share": float(np.mean(np.asarray(out["masked"]))),
+  }
+  print(json.dumps(report))
+  return report
+
+
+if __name__ == "__main__":
+  main()
