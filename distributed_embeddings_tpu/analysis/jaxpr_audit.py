@@ -197,6 +197,14 @@ class Expectation:
   # not checked). The serve artifacts pin 0: a forward-only inference
   # step that scatters anywhere is reverse-mode (or a write) leaking in.
   scatter_total: Optional[int] = None
+  # exact all_gather / reduce_scatter counts (None: not checked). A
+  # dense-kind class whose TABLES travel (the class block is fewer bytes
+  # than the rows it would ship: ``wire.dense_class_side``) contributes
+  # one all_gather forward and, in a train step, one reduce_scatter
+  # backward, and NO all_to_all; a class whose rows travel contributes
+  # neither. Nothing else in a step gathers or reduce-scatters.
+  all_gather_count: Optional[int] = None
+  reduce_scatter_count: Optional[int] = None
   # a (in_dtype, out_dtype) convert that must appear at least once —
   # the int8 serve artifact pins ('int8', 'float32'), the evidence that
   # the dequant actually widens gathered bytes on device (an f32 image
@@ -249,6 +257,15 @@ def audit_summary(name: str, s: JaxprSummary, expect: Expectation
         "the pipelined schedule drifted: a missing round strands a "
         "chunk's blocks on their source ranks, an extra one is wire "
         "traffic the budget does not account for")
+  for prim, want in (("all_gather", expect.all_gather_count),
+                     ("reduce_scatter", expect.reduce_scatter_count)):
+    got = s.counts.get(prim, 0)
+    if want is not None and got != want:
+      out.append(
+          f"{name}: {got} {prim}(s), expected {want} (one a dense-kind "
+          "class whose tables travel) — a table crosses the mesh that "
+          "the byte rule keeps at home, or one that should travel ships "
+          "its rows")
   n_gather = s.counts.get("gather", 0)
   if expect.gather_count is not None and n_gather != expect.gather_count:
     out.append(
@@ -300,6 +317,7 @@ WORLD = 4
 VOCAB = (5000, 300, 40)   # host-tier / device-sparse / MXU-dense at the
 WIDTH = 16                # thresholds used below
 BATCH = 16
+BATCH_TABLES = 512        # the batch at which the 40-row table travels
 
 
 def _require_cpu_devices():
@@ -319,6 +337,11 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
 
   - ``sparse_step``:        ``make_sparse_train_step(guard=False)``
   - ``sparse_step_guard``:  ``make_sparse_train_step(guard=True)``
+  - ``sparse_step_tables``: ``sparse_step`` at a batch of 512, where the
+    dense-kind class's 40-row table is fewer bytes than the rows it would
+    ship: its TABLE travels (one all_gather, one reduce_scatter, no
+    all_to_all for it). Every other artifact runs at a batch of 16, where
+    its rows travel, and pins zero of both
   - ``sparse_step_dynvocab``: the guarded step on an ``oov='allocate'``
     plan — the dynamic-vocabulary artifact: still exactly one
     scatter-add per class and ZERO host callbacks (allocation is a
@@ -399,12 +422,23 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
         out[name] = (lay.phys_rows, lay.phys_width)
     return out
 
-  def n_padded_buckets(plan):
+  def n_padded_buckets(plan, batch=BATCH):
     # the fixture's inputs are all hotness-1 and dense, so every bucket
-    # is a padded bucket: a train step exchanges 3x per bucket (ids,
-    # activations, reverse cotangents), eval 2x
+    # whose ROWS travel is a padded bucket: a train step exchanges 3x per
+    # bucket (ids, activations, reverse cotangents), eval 2x. At BATCH
+    # (4 samples a rank) the 40-row table is more bytes than its rows:
+    # every class's rows travel.
     eng = DistributedLookup(plan, dp_input=True)
-    return sum(len(eng._buckets(k, lambda i: 1)) for k in plan.class_keys)
+    return sum(len(eng._buckets(k, lambda i: 1)) for k in plan.class_keys
+               if not eng.tables_travel(k, lambda i: 1, batch // WORLD))
+
+  def side_counts(plan, train=True, batch=BATCH):
+    # dense-kind classes whose TABLES travel instead: one all_gather
+    # each, and one reduce_scatter where there is a backward
+    eng = DistributedLookup(plan, dp_input=True)
+    n = sum(eng.tables_travel(k, lambda i: 1, batch // WORLD)
+            for k in plan.class_keys)
+    return {"all_gather_count": n, "reduce_scatter_count": n if train else 0}
 
   artifacts: Dict[str, Tuple[Any, Expectation]] = {}
 
@@ -427,7 +461,28 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
     artifacts["sparse_step_guard" if guard else "sparse_step"] = (
         jx.jaxpr, Expectation(shapes, mesh_axes, guard=guard,
                               a2a_count=3 * nb, ppermute_count=0,
-                              wire_float_dtype="float32"))
+                              wire_float_dtype="float32",
+                              **side_counts(plan)))
+
+  # ---- the same step at a batch the small table is fewer bytes than -----
+  # 128 samples a rank against a 40-row table (64 with its window): the
+  # dense-kind class's TABLE travels. One all_gather, one reduce_scatter,
+  # and all_to_alls for the sparse-kind buckets alone.
+  rt = np.random.default_rng(1)
+  batch_t = (rt.standard_normal((BATCH_TABLES, 13)).astype(np.float32),
+             [rt.integers(0, v, BATCH_TABLES, dtype=np.int32)
+              for v in VOCAB],
+             rt.integers(0, 2, BATCH_TABLES).astype(np.float32))
+  step_tab = make_sparse_train_step(model, plan, bce_loss, opt, rule, mesh,
+                                    state, batch_t, donate=False)
+  jx = jax.make_jaxpr(step_tab)(state, *shard_batch(batch_t, mesh))
+  assert side_counts(plan, batch=BATCH_TABLES)["all_gather_count"] == 1
+  artifacts["sparse_step_tables"] = (
+      jx.jaxpr, Expectation(shapes, mesh_axes, guard=False,
+                            a2a_count=3 * n_padded_buckets(plan,
+                                                           BATCH_TABLES),
+                            ppermute_count=0, wire_float_dtype="float32",
+                            **side_counts(plan, batch=BATCH_TABLES)))
 
   # ---- dynamic-vocabulary step (oov='allocate', round 13) ----------------
   # Same tables/state/batch: the dynamic id layer translates HOST-side
@@ -450,7 +505,8 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
   artifacts["sparse_step_dynvocab"] = (
       jx.jaxpr, Expectation(shapes, mesh_axes, guard=True,
                             a2a_count=3 * nb, ppermute_count=0,
-                            wire_float_dtype="float32"))
+                            wire_float_dtype="float32",
+                            **side_counts(plan_dv)))
 
   ev = make_sparse_eval_step(model, plan, rule, mesh, state, batch0)
   jx = jax.make_jaxpr(ev)(state, *bt[:2])
@@ -458,7 +514,8 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
       jx.jaxpr,
       Expectation(shapes, mesh_axes, guard=False, scatters_per_class=0,
                   a2a_count=2 * nb, ppermute_count=0,
-                  wire_float_dtype="float32"))
+                  wire_float_dtype="float32",
+                  **side_counts(plan, train=False)))
 
   # ---- serve steps on the frozen inference image (round 12) --------------
   # make_serve_step over export.freeze's stripped buffers: same exchange
@@ -485,7 +542,7 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
                     ppermute_count=0, wire_float_dtype="float32",
                     scatter_total=0,
                     require_convert=("int8", "float32") if q == "int8"
-                    else None))
+                    else None, **side_counts(plan, train=False)))
 
   # ---- compressed-wire sparse step (bf16 wire + dedup'd exchange) --------
   # identical table layout, so the f32 state and batch reuse verbatim;
@@ -503,7 +560,8 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
       jx.jaxpr, Expectation(shapes, mesh_axes, guard=False,
                             a2a_count=3 * n_padded_buckets(plan_w),
                             ppermute_count=0,
-                            wire_float_dtype="bfloat16"))
+                            wire_float_dtype="bfloat16",
+                            **side_counts(plan_w)))
 
   # ---- pipelined exchange steps (chunked ppermute schedule) --------------
   # same table layout again (the overlap knobs change no buffer); each
@@ -529,7 +587,8 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
                     ppermute_count=3 * nb_p * (WORLD - 1) * CHUNKS,
                     wire_float_dtype={
                         "f32": "float32", "bf16": "bfloat16",
-                        "fp8": "float8_e4m3fn"}[wname]))
+                        "fp8": "float8_e4m3fn"}[wname],
+                    **side_counts(plan_p)))
 
   # ---- fused exchange steps (just-in-time per-round gathers) -------------
   # overlap='fused' keeps the pipelined ROUND schedule (ids still ride
@@ -561,7 +620,8 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
                     gather_count=n_gather,
                     wire_float_dtype={
                         "f32": "float32",
-                        "fp8": "float8_e4m3fn"}[wname]))
+                        "fp8": "float8_e4m3fn"}[wname],
+                    **side_counts(plan_f)))
 
   # ---- tiered step (host-tier class + device tiers) ----------------------
   plan_t = DistEmbeddingStrategy(
@@ -596,7 +656,8 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
       jx.jaxpr, Expectation(shapes_t, mesh_axes, guard=False,
                             a2a_count=3 * n_padded_buckets(plan_t),
                             ppermute_count=0,
-                            wire_float_dtype="float32"))
+                            wire_float_dtype="float32",
+                            **side_counts(plan_t)))
 
   # ---- guarded tiered step (PR 2 carried follow-on) -----------------------
   # same plan/state/staging; the guard adds exactly one pmin (the
@@ -610,7 +671,8 @@ def build_artifacts() -> Dict[str, Tuple[Any, Expectation]]:
       jx.jaxpr, Expectation(shapes_t, mesh_axes, guard=True,
                             a2a_count=3 * n_padded_buckets(plan_t),
                             ppermute_count=0,
-                            wire_float_dtype="float32"))
+                            wire_float_dtype="float32",
+                            **side_counts(plan_t)))
   return artifacts
 
 

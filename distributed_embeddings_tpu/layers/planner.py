@@ -1133,8 +1133,21 @@ class DistEmbeddingStrategy:
         "classes": classes,
     }
 
-  def exchange_report(self) -> Dict[str, object]:
+  def exchange_report(self, global_batch: Optional[int] = None,
+                      dp_input: bool = True) -> Dict[str, object]:
     """Wire-format summary of the dp<->mp exchange path.
+
+    Per dense-kind class also WHICH SIDE TRAVELS across chips:
+    ``"moves"`` is ``"tables"`` (the class block is all-gathered and
+    looked up on each chip's own samples; its gradient is
+    reduce-scattered) or ``"rows"`` (ids cross to the owner, rows cross
+    back), with ``rows_bytes`` and ``tables_bytes``, the two static
+    counts of bytes leaving one chip each way a step that the engine
+    takes the smaller of (``parallel.wire.dense_class_side``). They
+    depend on the batch: ``global_batch``, else the plan's
+    ``batch_hint``; with neither (and more than one rank) the three
+    entries are ``None``. Hotness is the plan's ``input_hotness`` (1
+    where not given), as the engine sees it at trace time.
 
     Per class: its kind and whether the deduplicated exchange applies to
     its padded buckets (sparse-kind classes only — dense MXU classes have
@@ -1152,16 +1165,27 @@ class DistEmbeddingStrategy:
     ``jit_gather`` reports whether the fused just-in-time per-round
     gather schedule is active.
     """
-    from ..parallel.lookup_engine import class_param_name
+    from ..parallel.lookup_engine import (class_buckets, class_param_name,
+                                          dense_class_traffic)
+    batch = self.batch_hint if global_batch is None else global_batch
+    hotness_of = lambda i: (  # noqa: E731
+        1 if self.input_hotness is None else self.input_hotness[i])
     classes = {}
     for key in self.class_keys:
       cp = self.classes[key]
-      classes[class_param_name(*key)] = {
+      entry = classes[class_param_name(*key)] = {
           "kind": cp.kind,
           "width": cp.width,
           "dedup": bool(self.dedup_exchange and cp.kind == "sparse"
                         and self.world_size > 1),
       }
+      if cp.kind == "dense":
+        side = (None, None, None)
+        if batch is not None or self.world_size == 1:
+          side = dense_class_traffic(
+              self, key, class_buckets(self, key, hotness_of),
+              (batch or 0) // self.world_size, dp_input)
+        entry["moves"], entry["rows_bytes"], entry["tables_bytes"] = side
     pipelined = (self.overlap in ("pipelined", "fused")
                  and self.world_size > 1)
     return {

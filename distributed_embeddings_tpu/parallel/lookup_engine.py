@@ -36,6 +36,16 @@ either direction. All shapes static, fully jit/grad compatible; ``shard_map``
 differentiates through ``all_to_all`` natively, which is what replaces the
 reference's ~100 lines of Horovod tape patching.
 
+Small tables travel to the samples: where a dense (MXU one-hot) class's
+block is fewer bytes than the rows it would ship (padded slots x global
+batch x width against (world - 1) x class rows x width, static at trace
+time: ``wire.dense_class_side``), the class is all-gathered inside the
+step and every rank looks its OWN samples up in all of the class's real
+tables; its ids, rows and cotangents leave the exchange altogether, and
+autodiff's reduce-scatter lands each owner's summed gradient on its own
+block (:meth:`DistributedLookup.tables_travel`). Placement, state and
+checkpoints are the row exchange's: only the step gathers.
+
 Every exchange rides :mod:`parallel.wire` (the sanctioned all_to_all /
 ppermute home, graftlint GL109): the plan knobs compress and hide the wire
 without touching the f32 master state — ``wire_dtype='bf16' | 'fp8'``
@@ -228,6 +238,27 @@ def padded_rows(plan: DistEmbeddingStrategy, key) -> int:
       for s in slots:
         rows = max(rows, s.row_offset + vocab_cap(s.shard.input_dim))
   return rows
+
+
+def dense_class_traffic(plan: DistEmbeddingStrategy, key,
+                        buckets: Sequence[Bucket], batch_local: int,
+                        dp_input: bool = True):
+  """``(side, rows_bytes, tables_bytes)`` of one dense-kind class at this
+  local batch: :func:`wire.dense_class_side` on the class's own counts
+  (``buckets``: its :func:`class_buckets` at the inputs' hotness).
+
+  What a model-parallel small table costs is its PADDED slot count: every
+  rank runs every (hotness, window) bucket at ``n_b`` = the most slots any
+  rank has in it, so a class of 15 tables over four ranks can ship 11
+  slots a rank for 3.75 real ones."""
+  cp = plan.classes[key]
+  slots = sum(b.n_b * (b.h if cp.combiner is None and b.h > 1 else 1)
+              for b in buckets)
+  wd = wire.plan_wire_dtype(plan)
+  return wire.dense_class_side(
+      plan.world_size, dp_input, slots, plan.world_size * batch_local,
+      padded_rows(plan, key), cp.width,
+      4 if wd is None else jnp.dtype(wd).itemsize)
 
 
 def ragged_to_padded(ids: RaggedIds, max_hot: int) -> jax.Array:
@@ -700,40 +731,81 @@ class DistributedLookup:
       per_slot = []
       for k in range(bucket.n_b):
         if k < len(idxs):
-          slot = cp.slots_per_rank[rank][idxs[k]]
-          ids = inputs[slot.input_id]
-          if bucket.h == 1:
-            ids = ids[:, 0]
-          sh = slot.shard
-          _require_wide_ids(self.plan, sh, ids)
-          if sh.row_sliced:
-            # row shard: serve only ids inside this shard's vocab window
-            # [row_start, row_start + rows); other shards' rows and PAD go
-            # to the sentinel and contribute zeros to the partial sum.
-            # Out-of-vocab ids clamp to the last table row FIRST so
-            # enabling row_slice (a sharding knob) cannot change numerics
-            # vs the unsliced clamp policy. Arithmetic runs in the input
-            # dtype (int64 for >2B-row tables); the result is slot-local
-            # (< the per-rank buffer's 2^31 bound), so it narrows to
-            # int32 for the routing tensor.
-            vocab = self.plan.global_configs[sh.table_id].input_dim
-            clamped = jnp.clip(ids, 0, vocab - 1)
-            in_win = (ids >= 0) & (clamped >= sh.row_start) & (
-                clamped < sh.row_start + sh.input_dim)
-            routed = jnp.where(
-                in_win, clamped - sh.row_start + slot.row_offset, sentinel)
-          else:
-            # OOV clamp to the last row — COUNTED, not silent: the plan's
-            # oov policy governs it (oov_counts feeds the guarded step's
-            # per-class metrics; oov='error' raises in route_ids)
-            routed = jnp.where(ids < 0, sentinel,
-                               jnp.clip(ids, 0, sh.input_dim - 1)
-                               + slot.row_offset)
-          per_slot.append(routed.astype(jnp.int32))
+          per_slot.append(self._routed_slot(
+              cp.slots_per_rank[rank][idxs[k]], bucket.h, inputs, sentinel))
         else:
           per_slot.append(pad_block)
       per_dest.append(jnp.stack(per_slot))
     return jnp.stack(per_dest)
+
+  def _routed_slot(self, slot, h: int, inputs, sentinel: int) -> jax.Array:
+    """One slot's ids of the local batch as its owner addresses them:
+    ``[B]`` (h == 1) or ``[B, h]`` int32, row offset added, PAD and
+    out-of-window entries at the sentinel."""
+    ids = inputs[slot.input_id]
+    if h == 1:
+      ids = ids[:, 0]
+    sh = slot.shard
+    _require_wide_ids(self.plan, sh, ids)
+    if sh.row_sliced:
+      # row shard: serve only ids inside this shard's vocab window
+      # [row_start, row_start + rows); other shards' rows and PAD go
+      # to the sentinel and contribute zeros to the partial sum.
+      # Out-of-vocab ids clamp to the last table row FIRST so
+      # enabling row_slice (a sharding knob) cannot change numerics
+      # vs the unsliced clamp policy. Arithmetic runs in the input
+      # dtype (int64 for >2B-row tables); the result is slot-local
+      # (< the per-rank buffer's 2^31 bound), so it narrows to
+      # int32 for the routing tensor.
+      vocab = self.plan.global_configs[sh.table_id].input_dim
+      clamped = jnp.clip(ids, 0, vocab - 1)
+      in_win = (ids >= 0) & (clamped >= sh.row_start) & (
+          clamped < sh.row_start + sh.input_dim)
+      routed = jnp.where(
+          in_win, clamped - sh.row_start + slot.row_offset, sentinel)
+    else:
+      # OOV clamp to the last row — COUNTED, not silent: the plan's
+      # oov policy governs it (oov_counts feeds the guarded step's
+      # per-class metrics; oov='error' raises in route_ids)
+      routed = jnp.where(ids < 0, sentinel,
+                         jnp.clip(ids, 0, sh.input_dim - 1)
+                         + slot.row_offset)
+    return routed.astype(jnp.int32)
+
+  # ---- dense-kind classes: which side travels ----------------------------
+  def tables_travel(self, key, hotness_of, batch_local: int) -> bool:
+    """True where this dense-kind class's TABLES cross the mesh (the
+    class block all-gathered, looked up on the local samples) instead of
+    its ids and rows: :func:`dense_class_traffic` says that is fewer
+    bytes. Static per trace; never at world 1, never for model-parallel
+    inputs, never for a sparse-kind class."""
+    if self.plan.classes[key].kind != "dense":
+      return False
+    return dense_class_traffic(
+        self.plan, key, self._buckets(key, hotness_of), batch_local,
+        self.dp_input)[0] == "tables"
+
+  @staticmethod
+  def _real_slots(bucket: Bucket):
+    """A bucket's real slots over ALL ranks, rank-major: ``[(rank,
+    position in the rank's part of the bucket, slot index)]``. The order
+    of a gathered class's slot axis."""
+    return [(rank, pos, idx)
+            for rank, idxs in enumerate(bucket.slot_idx_per_rank)
+            for pos, idx in enumerate(idxs)]
+
+  def _build_local_ids(self, key, bucket: Bucket,
+                       inputs: Sequence[jax.Array]) -> jax.Array:
+    """``[n, B_local(, h)]`` ids of the LOCAL samples for every real slot
+    of the bucket on any rank (:meth:`_real_slots` order), addressed as
+    their owners address them. No padded slot, nothing crosses: the
+    tables come here (:meth:`tables_travel`)."""
+    cp = self.plan.classes[key]
+    sentinel = padded_rows(self.plan, key)
+    return jnp.stack([
+        self._routed_slot(cp.slots_per_rank[rank][idx], bucket.h, inputs,
+                          sentinel)
+        for rank, _, idx in self._real_slots(bucket)])
 
   def _build_ragged_routing(self, key, bucket: Bucket, inputs):
     """Value-stream routing for a ragged bucket.
@@ -802,6 +874,9 @@ class DistributedLookup:
     Returns ``bk -> [n_b, G, h]`` (bk = (class_key, h, vcap)); G = world * B.
     The all_to_all here is the reference's first Horovod exchange
     (`dist_model_parallel.py:414-423`) with splits made uniform by padding.
+    A dense-kind class whose tables travel (:meth:`tables_travel`) exchanges
+    nothing here: its buckets hold ``[n, B(, h)]``, the local samples' ids
+    for every real slot of any rank (:meth:`_build_local_ids`).
 
     Out-of-vocabulary ids: the routing clamps ``ids >= input_dim`` to the
     table's last row (reference numeric semantics) under the plan's
@@ -829,7 +904,12 @@ class DistributedLookup:
 
     ids_all: Dict[tuple, jax.Array] = {}
     for key in plan.class_keys:
+      tables = self.tables_travel(key, hotness_of, b)
       for bucket in self._buckets(key, hotness_of):
+        if tables:  # the local samples' ids stay here: [n, B(, h)]
+          ids_all[bucket_key(key, bucket.h, bucket.vcap, bucket.rs)] = \
+              self._build_local_ids(key, bucket, inputs)
+          continue
         x = self._build_routing(key, bucket, inputs)  # [world, n_b, B(, h)]
         if bucket.h < 0:  # ragged: (vals [world,n_b,V], lens [world,n_b,B])
           vals, lens = x
@@ -991,16 +1071,24 @@ class DistributedLookup:
 
   @jax.named_scope(scopes.ONEHOT)
   def _z_dense(self, key, bucket: Bucket, table_local: jax.Array,
-               ids_all: jax.Array) -> jax.Array:
+               ids_all: jax.Array, gathered: bool = False) -> jax.Array:
     """Small-vocab lookup as windowed one-hot MXU matmuls (zero row ops).
 
     The TPU equivalent of the reference's ``ConcatOneHotEmbedding``
     (`embedding.py:155-180`) — but applied automatically to every table
     under ``dense_row_threshold``. Per slot, a ``[vcap, w]`` window of the
     class buffer starting at the slot's row offset is contracted with the
-    slot's one-hot ids; out-of-window / sentinel ids one-hot to zero. SPMD
-    uniform: window starts are data (indexed by ``lax.axis_index``), window
-    size is the bucket's static ``vcap``.
+    slot's one-hot ids; out-of-window / sentinel ids one-hot to zero.
+
+    Two forms. ``table_local [rows, w]``, this rank's block, with
+    ``ids_all [n_b, G(, h)]`` the global batch's ids for this rank's
+    (padded) slots: SPMD uniform, window starts are data (indexed by
+    ``lax.axis_index``), window size is the bucket's static ``vcap``. Or
+    (``gathered``) ``[world * rows, w]``, every rank's block
+    (:func:`wire.gather_tables`, where :meth:`tables_travel`), with
+    ``[n, B_local(, h)]`` the local samples' ids for every real slot
+    (:meth:`_real_slots`): each window is a static slice ``[owner * rows
+    + offset : + vcap]``, and every rank runs the same tables.
     """
     two_d = ids_all.ndim == 2  # hotness-1 buckets drop the h axis
     n_b, g = ids_all.shape[:2]
@@ -1009,18 +1097,31 @@ class DistributedLookup:
       # a sequence input (same contract as the sparse path's _combine):
       # every position is a hotness-1 lookup of its own, the h rows of a
       # sample side by side on the width axis
-      z = self._z_dense(key, bucket, table_local, ids_all.reshape(n_b, g * h))
+      z = self._z_dense(key, bucket, table_local,
+                        ids_all.reshape(n_b, g * h), gathered)
       return z.reshape(n_b, g, -1)
     vcap = bucket.vcap
-    offs_const = jnp.asarray(self._dense_offsets(key, bucket))  # [world, n_b]
-    offs = offs_const[self._my_rank()]  # [n_b]
+    if gathered:
+      slots = self.plan.classes[key].slots_per_rank
+      rows = padded_rows(self.plan, key)
+      # (first row of the owner's block, the slot's offset inside it)
+      starts = [(rank * rows, slots[rank][idx].row_offset)
+                for rank, _, idx in self._real_slots(bucket)]
+      offs = jnp.asarray(np.array([o for _, o in starts], np.int32))  # [n]
+    else:
+      offs_const = jnp.asarray(self._dense_offsets(key, bucket))  # [world, n_b]
+      offs = offs_const[self._my_rank()]  # [n_b]
     off_bcast = offs[:, None] if two_d else offs[:, None, None]
     ids_local = ids_all - off_bcast  # slot-local; OOB -> no one-hot
 
     def window(o):
       return lax.dynamic_slice(table_local, (o, 0), (vcap, table_local.shape[1]))
 
-    wins = jax.vmap(window)(offs)  # [n_b, vcap, w]
+    if gathered:
+      wins = jnp.stack([table_local[base + o:base + o + vcap]
+                        for base, o in starts])
+    else:
+      wins = jax.vmap(window)(offs)  # [n_b, vcap, w]
 
     def z_of(ids_c):  # [n_b, C(, h)] -> [n_b, C, w]
       return _onehot_window_matmul(two_d, vcap, ids_c,
@@ -1664,17 +1765,16 @@ class DistributedLookup:
     z = {}
     for bk, ids in ids_all.items():
       key = bk.class_key
-      table_local = self._squeeze_local(
-          class_params[class_param_name(*key)])
-      if self.plan.classes[key].kind == "dense":
-        bucket = self._find_bucket(key, bk.h, bk.vcap, hotness_of)
-        with jax.named_scope(scopes.COMBINE):
-          z[bk] = self._z_dense(key, bucket, table_local, ids)
-      else:
+      if self.plan.classes[key].kind != "dense":
+        table_local = self._squeeze_local(
+            class_params[class_param_name(*key)])
         with jax.named_scope(scopes.GATHER):
           z[bk] = self._z_sparse_simple(key, table_local, ids, bk.rs)
     with jax.named_scope(scopes.COMBINE):
-      received = self.exchange(z, b, ids_all)
+      z_rows, z_here = self._lookup_dense(class_params, ids_all, b,
+                                          hotness_of, remat=False)
+      received = self.exchange({**z, **z_rows}, b, ids_all)
+      received.update(z_here)
       outs = self.assemble(received, hotness_of, counts)
     if return_residuals:
       return outs, ids_all
@@ -1758,25 +1858,51 @@ class DistributedLookup:
     required when a row-sliced table uses the mean combiner — the division
     happens in this differentiable tail, so its cotangent reaches
     :meth:`apply_sparse` pre-divided."""
-    z = dict(z_sparse)
+    z_rows, z_here = self._lookup_dense(dense_params, ids_all, batch_local,
+                                        hotness_of, self.dense_remat)
+    received = self.exchange({**z_sparse, **z_rows}, batch_local, ids_all)
+    received.update(z_here)
+    return self.assemble(received, hotness_of, mean_counts)
+
+  def _lookup_dense(self, dense_params, ids_all, batch_local: int,
+                    hotness_of, remat: bool):
+    """Every dense-kind bucket's one-hot lookup -> ``(z_rows, z_here)``.
+
+    ``z_rows[bk] = [n_b, G, w]``: the global batch's rows for this rank's
+    slots, still to cross (:meth:`exchange`). ``z_here[bk][rank, pos] =
+    [B_local, w]``: where the class's tables travel instead
+    (:meth:`tables_travel`), the local samples' rows for every real slot,
+    keyed as :meth:`assemble` reads an exchanged ``[world, n_b, B_local,
+    w]`` block, without the padded slots. A travelling class is gathered
+    once however many buckets read it, OUTSIDE the rematerialised lookup:
+    inside, the backward would fly it a second time."""
+    z_rows, z_here, gathered = {}, {}, {}
     for bk, ids in ids_all.items():
       key = bk.class_key
       if self.plan.classes[key].kind != "dense":
         continue
-      table_local = self._squeeze_local(dense_params[class_param_name(*key)])
+      table = self._squeeze_local(dense_params[class_param_name(*key)])
       bucket = self._find_bucket(key, bk.h, bk.vcap, hotness_of)
-      if self.dense_remat:
+      travels = self.tables_travel(key, hotness_of, batch_local)
+      if travels:
+        if key not in gathered:
+          with jax.named_scope(scopes.EXCHANGE):
+            gathered[key] = wire.gather_tables(table, self.axis_name)
+        table = gathered[key]
+      z_fn = lambda t, i, key=key, bucket=bucket, travels=travels: \
+          self._z_dense(key, bucket, t, i, travels)  # noqa: E731
+      if remat:
         # don't keep the [G, vcap] one-hot staging alive for the backward —
         # rebuilding it is a few VPU compares, and it saves ~1.5 GiB live
         # at batch 64k (needed when the chip is near its HBM limit)
-        z_fn = jax.checkpoint(
-            lambda t, i, key=key, bucket=bucket: self._z_dense(
-                key, bucket, t, i))
-        z[bk] = z_fn(table_local, ids)
+        z_fn = jax.checkpoint(z_fn)
+      zb = z_fn(table, ids)
+      if travels:
+        z_here[bk] = {(rank, pos): zb[i] for i, (rank, pos, _)
+                      in enumerate(self._real_slots(bucket))}
       else:
-        z[bk] = self._z_dense(key, bucket, table_local, ids)
-    received = self.exchange(z, batch_local, ids_all)
-    return self.assemble(received, hotness_of, mean_counts)
+        z_rows[bk] = zb
+    return z_rows, z_here
 
   @staticmethod
   def _aux_occ(aux, layout, rule):

@@ -66,6 +66,15 @@ Four plan knobs (``DistEmbeddingStrategy``) govern the format:
   block's row count — fp8 scales are still one per (destination block,
   chunk), now computed over each just-gathered row chunk.
 
+One choice is not a knob. A dense-kind (MXU one-hot) class crosses the
+mesh either as ROWS (ids dp->mp, looked-up rows mp->dp, cotangents back:
+the exchanges above) or as TABLES (:func:`gather_tables`: the class block
+all-gathered forward, its gradient reduce-scattered backward, the lookup
+run on each rank's own samples, no padded slot). :func:`dense_class_side`
+counts both in bytes leaving a chip each way a step, from static shapes,
+and the engine takes the smaller; the knobs above then govern only what
+still crosses as rows.
+
 With ``world_size == 1`` there is no wire: nothing is exchanged, nothing
 is narrowed, and every knob is inert (numerics stay bit-identical to the
 single-device f32 path).
@@ -133,6 +142,58 @@ def plan_overlap(plan) -> str:
 def plan_exchange_chunks(plan) -> int:
   """The plan's ``exchange_chunks`` knob (default 1 for old plans)."""
   return int(getattr(plan, "exchange_chunks", 1) or 1)
+
+
+def dense_class_side(world: int, dp_input: bool, padded_slots: int,
+                     global_batch: int, class_rows: int, width: int,
+                     row_value_bytes: int = 4):
+  """Which side of a dense-kind (MXU one-hot) class crosses the mesh.
+
+  A small table can be looked up where it is owned, its rows crossing
+  the mesh (ids dp->mp, ``[slot, sample, width]`` rows mp->dp, their
+  cotangents back), or be gathered to every rank and looked up on the
+  local samples (the class block all-gathered forward, its gradient
+  reduce-scattered backward). Both costs are static at trace time, in
+  bytes leaving one chip each way a step:
+
+  - rows: ``padded_slots * global_batch * width`` values in the wire's
+    dtype, of which ``(world - 1) / world`` leave the chip.
+    ``padded_slots`` is the sum of the buckets' ``n_b``: the program is
+    SPMD-uniform, so every rank ships the LARGEST slot count of any rank
+    per (hotness, window) bucket (a sequence input counts its hotness
+    times, its rows travel side by side);
+  - tables: ``(world - 1) * class_rows * width`` float32 values (the
+    table is not narrowed: that would change every row read, not only
+    what is in flight).
+
+  Returns ``(side, rows_bytes, tables_bytes)``; ``side`` is ``"tables"``
+  where that is strictly cheaper. Rows travel whenever there is nothing
+  to choose: one rank, or model-parallel inputs (``dp_input=False``: a
+  rank holds the global batch's ids for ITS tables only, so no rank has
+  its local samples' ids for the others)."""
+  rows_bytes = (padded_slots * global_batch * width * row_value_bytes
+                * (world - 1)) // world
+  tables_bytes = (world - 1) * class_rows * width * 4
+  tables = world > 1 and dp_input and tables_bytes < rows_bytes
+  return ("tables" if tables else "rows"), rows_bytes, tables_bytes
+
+
+def gather_tables(block: jax.Array, axis_name: str) -> jax.Array:
+  """``[rows, w]`` local class block -> ``[world * rows, w]``, every rank's
+  block on every rank, rank r's at rows ``[r * rows, (r + 1) * rows)``: the
+  global class param as its ``PartitionSpec(axis, None)`` lays it out.
+  Linear, so autodiff's transpose is the reduce-scatter that lands each
+  owner's summed gradient on its own block; no custom rule, no narrowing
+  (see :func:`dense_class_side`).
+
+  Two-dimensional on purpose. As ``[world, rows, w]`` the windows the
+  one-hot lookup stacks are ``x[r, o : o + n]``, and the TPU compiler of
+  this installation merges two such slices of neighbouring ranks into ONE
+  slice ``x[r : r + 2, o : o + n]`` at the first one's offset: the second
+  window then holds another table's rows, silently (PERF.md, PR 30; the
+  CPU compiler does not). Slices of the flat rows are not merged
+  (``tests/test_dense_table_gather.py`` compiles them for the chip)."""
+  return lax.all_gather(block, axis_name, axis=0, tiled=True)
 
 
 def exchange_ids(x: jax.Array, axis_name: str) -> jax.Array:

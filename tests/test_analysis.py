@@ -13,6 +13,7 @@ Pins the three contracts `make lint` rests on:
   baseline in ``tests/data/``.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -884,6 +885,34 @@ def test_all_to_all_count_per_mode(artifacts):
   n_plain = summarize(artifacts["sparse_step"][0]).counts["all_to_all"]
   n_wire = summarize(artifacts["sparse_step_wire"][0]).counts["all_to_all"]
   assert n_plain == n_wire
+
+
+def test_dense_class_side_per_artifact(artifacts):
+  """Which side of the dense-kind class travels is pinned per artifact:
+  at the fixture's batch of 16 the 40-row table is more bytes than its
+  rows, so every artifact gathers and reduce-scatters nothing and the
+  class's bucket rides the all_to_alls counted above; at a batch of 512
+  (``sparse_step_tables``) its table travels: one all_gather forward, one
+  reduce_scatter backward, and three all_to_alls FEWER than
+  ``sparse_step`` (the class's ids, rows and cotangents)."""
+  for name, (jaxpr, expect) in artifacts.items():
+    s = summarize(jaxpr)
+    assert expect.all_gather_count is not None, name
+    assert s.counts.get("all_gather", 0) == expect.all_gather_count, name
+    assert s.counts.get("reduce_scatter", 0) == \
+        expect.reduce_scatter_count, name
+    if name != "sparse_step_tables":
+      assert expect.all_gather_count == expect.reduce_scatter_count == 0
+  jaxpr, expect = artifacts["sparse_step_tables"]
+  s = summarize(jaxpr)
+  assert audit_summary("sparse_step_tables", s, expect) == []
+  assert (expect.all_gather_count, expect.reduce_scatter_count) == (1, 1)
+  n_rows = summarize(artifacts["sparse_step"][0]).counts["all_to_all"]
+  assert s.counts["all_to_all"] == n_rows - 3
+  # a table that crossed where the byte rule keeps it at home is flagged
+  bad = audit_summary("sparse_step_tables", s, dataclasses.replace(
+      expect, all_gather_count=0, reduce_scatter_count=0))
+  assert len(bad) == 2 and "all_gather" in bad[0]
 
 
 def test_ppermute_rounds_per_pipelined_mode(artifacts):
