@@ -119,9 +119,11 @@ lock-graph cycles, multi-root mutation, condvar misuse) — same
 
 Trace-reachable scope (GL101/GL102) is structural: any function nested —
 at any depth — inside a module-level builder whose name matches
-``make_*step*`` / ``make_*eval*`` (``local_step``, ``body``,
-``loss_with``, the guard closures, ...) is traced by ``jax.jit`` /
-``shard_map`` when the built step runs. Host syncs there either silently
+``make_*step*`` / ``make_*eval*``, or inside one of the private factories
+the builders share their pieces through (``_make_*step*``:
+``training._make_step_forward``, ``training._make_train_step_pieces``) —
+``local_step``, ``body``, ``forward_backward``, ``loss_with``, ``commit``,
+... — is traced by ``jax.jit`` / ``shard_map`` when the built step runs. Host syncs there either silently
 serialize the device pipeline or break tracing outright; host-side code
 (trainers, checkpoint I/O, the builders' own plan-time setup) is
 unrestricted. The lookup engine's methods are not statically reachable
@@ -139,7 +141,7 @@ import tokenize
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
-STEP_BUILDER_RE = re.compile(r"^make_\w*(step|eval)\w*$")
+STEP_BUILDER_RE = re.compile(r"^_?make_\w*(step|eval)\w*$")
 DURABLE_PATH_RE = re.compile(r"(checkpoint|durable)")
 SUPPRESS_RE = re.compile(r"#\s*graftlint:\s*disable=([A-Za-z0-9_,\s]+)")
 
@@ -269,7 +271,8 @@ def _call_pair(call: ast.Call):
 
 def _traced_functions(tree: ast.Module) -> List[ast.AST]:
   """Function bodies that are traced when a built step runs: every
-  function nested inside a ``make_*step*``/``make_*eval*`` builder."""
+  function nested inside a ``make_*step*``/``make_*eval*`` builder or a
+  ``_make_*step*`` factory of shared step pieces."""
   out = []
 
   class V(ast.NodeVisitor):
@@ -598,7 +601,7 @@ _TRAIN_ONLY_NAMES = frozenset({
     "init_sparse_state", "init_sparse_state_direct", "init_tiered_state",
     "apply_sparse", "apply_sparse_streams", "sparse_delta_streams",
     "scatter_add_fused", "DistributedOptimizer", "_make_guard_helpers",
-    "select_tree", "check_oov",
+    "_make_train_step_pieces", "select_tree", "check_oov",
 })
 
 

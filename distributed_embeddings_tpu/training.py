@@ -614,43 +614,118 @@ def _fused_rule_and_penalties(plan: DistEmbeddingStrategy, rule: SparseRule):
   return rule, reg_fn, con_fn
 
 
-@jax.named_scope(scopes.DENSE_UPDATE)
-def _reduce_and_apply_dense(state, loss, d_dense, d_emb_dense, d_z, rank,
-                            mesh, axis_name, dense_optimizer, emb_opt,
-                            con_fn):
-  """Shared tail of the one-shot fused train steps (all-device and
-  tiered): cross-device grad reduction + dense/emb_dense optimizer
-  application. Returns ``(loss, dense, dense_opt, emb_dense,
-  emb_dense_opt, d_z)`` — ``d_z`` rescaled for the caller's scatter."""
-  if mesh is not None:
-    # replicated-param grads arrive already summed across devices
-    # (shard_map's autodiff does it, exactly once — see compat). A
-    # uniform 1/world rescale (dense grads AND sparse cotangents) then
-    # restores exact global-batch-mean semantics (see
-    # finalize_hybrid_grads). emb_dense blocks are mp-SHARDED per-rank
-    # windows — never summed.
-    scale = 1.0 / axis_size(axis_name)
-    d_dense, d_emb_dense, d_z = jax.tree_util.tree_map(
-        lambda g: g * scale, (d_dense, d_emb_dense, d_z))
-    loss = jax.lax.pmean(loss, axis_name)
+# ---------------------------------------------------------------------------
+# The sparse step's shared pieces. The one-shot, micro-batched, tiered and
+# eval builders below compose these; none of them routes, differentiates,
+# updates or commits on its own. (The `_make_*step*` names put every closure
+# here under the lint's trace-reachable rules, GL101/GL102.)
+# ---------------------------------------------------------------------------
 
-  upd, dense_opt = dense_optimizer.update(
-      d_dense, state["dense_opt"], state["dense"])
-  dense = optax.apply_updates(state["dense"], upd)
-  if state["emb_dense"]:
-    upd, emb_dense_opt = emb_opt.update(
-        d_emb_dense, state["emb_dense_opt"], state["emb_dense"])
-    emb_dense = optax.apply_updates(state["emb_dense"], upd)
-    if con_fn is not None:
-      emb_dense = con_fn(emb_dense, rank)
-  else:
-    emb_dense, emb_dense_opt = state["emb_dense"], state["emb_dense_opt"]
-  return loss, dense, dense_opt, emb_dense, emb_dense_opt, d_z
+
+def _refuse_unsupported(builder: str, plan: DistEmbeddingStrategy, *,
+                        metrics: bool, metrics_arg: str = "guard=True",
+                        rule: Optional[SparseRule] = None,
+                        exact: bool = False, micro_batches: int = 1,
+                        tiered: bool = False) -> bool:
+  """Every combination the fused-state builders refuse, stated once.
+
+  ``builder`` names the caller in the message; ``metrics`` is its metrics
+  path (a train builder's ``guard``, the eval builder's ``with_metrics``).
+  ``rule`` is None for the eval builder, which has no update to refuse.
+  Returns ``exact``: a summed rule is applied once per distinct row, which
+  IS the exact path, with every refusal the exact path has. (What needs the
+  traced batch — ragged cats under micro-batching, an indivisible batch —
+  is refused where the batch is sliced, :func:`_micro_batch_slices`.)
+  """
+  guard = metrics
+  if tiered and getattr(plan, "oov", "clip") == "allocate":
+    raise NotImplementedError(
+        "plan.oov='allocate' with tiered storage: the tiered prefetcher "
+        "classifies RAW ids host-side, so the dynamic-id translation and "
+        "the classify stage would have to compose into one host pass — "
+        "an open follow-on (ROADMAP, dynamic-vocab direction). Keep "
+        "dynamic tables device-resident (host_row_threshold=None) or "
+        "use a static oov policy for tiered plans.")
+  if getattr(plan, "dedup_capacity", None) is not None and not metrics:
+    raise ValueError(
+        f"plan.dedup_capacity requires {builder}({metrics_arg}): a "
+        "capacity below the safe bound aliases distinct ids onto the "
+        "cap's last slot — those occurrences read (and, in training, "
+        "UPDATE) the WRONG rows — and only that path surfaces the psum'd "
+        f"'dedup_overflow' counter that makes it observable. Build with "
+        f"{metrics_arg} or drop the capacity override.")
+  if rule is None:
+    return False
+  exact = exact or rule.summed
+  if micro_batches > 1 and exact:
+    raise NotImplementedError(
+        "micro_batches > 1 with exact=True: cross-micro-batch dedup would "
+        "need the full occurrence stream the mode exists to avoid. Use "
+        "per-occurrence semantics (exact=False) or one-shot exact.")
+  if guard and exact:
+    raise NotImplementedError(
+        "guard=True with exact=True: the non-finite guard gates the "
+        "prebuilt per-class delta streams before the scatter, but the "
+        "exact path re-gathers rows and builds its deltas inside the "
+        "apply. Use per-occurrence semantics (exact=False) with the "
+        "guard.")
+  if exact and getattr(plan, "wire_dtype", "f32") != "f32":
+    raise ValueError(
+        "exact=True requires wire_dtype='f32': the exact path reproduces "
+        "the reference's deduplicated backward bit-for-bit, and a "
+        "bf16/fp8-narrowed cotangent exchange breaks that claim before "
+        "the sort ever runs. Build the plan with wire_dtype='f32' (the "
+        "dedup_exchange and overlap='pipelined' knobs compose with exact "
+        "fine — dedup only changes which ids reach the mp side, and the "
+        "pipelined f32 wire is bit-exact pure data movement).")
+  if getattr(plan, "oov", "clip") == "error" and not guard:
+    raise ValueError(
+        f"plan.oov='error' requires {builder}(guard=True): "
+        "under jit the ids are traced, so the unguarded step cannot see "
+        "them — out-of-range ids would be silently clipped to each "
+        "table's last row, exactly what oov='error' exists to forbid. "
+        "Enforcement rides the guarded step's OOV metrics "
+        "(resilience.guards.check_oov) plus a commit gate on the "
+        "offending batch; build with guard=True or use oov='clip'.")
+  return exact
+
+
+def _make_step_forward(engine: DistributedLookup, model):
+  """The sparse step's forward half: all the eval step runs, and what the
+  train steps differentiate the rest of (``forward_backward`` of
+  :func:`_make_train_step_pieces`)."""
+
+  def forward(fused, layouts, numerical, cats, keep_rows=False,
+              rewrite_ids=None):
+    """Route ids dp->mp, rewrite them if the caller says how (the tiered
+    step's logical id -> cache/staging slot), gather every sparse class.
+    Returns ``(z_sparse, residuals, ids_all, predict)``, where
+    ``predict(dense_p, emb_dense, z_sp)`` is the differentiable rest:
+    dense-class lookups, the mp->dp exchange, assembly, the model."""
+    b = numerical.shape[0]
+    hotness = [ragged_hotness(c) for c in cats]
+    hotness_of = lambda i: hotness[i]  # noqa: E731
+    ids_all = engine.route_ids(cats, hotness_of)
+    counts = engine.mean_counts(cats)
+    if rewrite_ids is not None:
+      ids_all = rewrite_ids(ids_all)
+    z_sparse, residuals = engine.lookup_sparse_fused(
+        fused, layouts, ids_all, keep_rows=keep_rows)
+
+    def predict(dense_p, emb_dense, z_sp):
+      acts = engine.finish_forward(z_sp, emb_dense, ids_all, b, hotness_of,
+                                   counts)
+      with jax.named_scope(scopes.MODEL):
+        return model.apply({"params": dense_p}, numerical, cats,
+                           emb_acts=acts)
+
+    return z_sparse, residuals, ids_all, predict
+
+  return forward
 
 
 def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
-  """The non-finite/OOV guard epilogue, shared by the all-device and
-  tiered step builders (``resilience.guards`` wiring).
+  """The non-finite/OOV guard epilogue (``resilience.guards`` wiring).
 
   Returns ``(guard_gate, oov_ok, guard_metrics)``:
 
@@ -724,6 +799,207 @@ def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
     return out
 
   return guard_gate, oov_ok, guard_metrics
+
+
+def _make_train_step_pieces(engine: DistributedLookup, model,
+                            loss_fn: Callable, dense_optimizer, emb_opt,
+                            rule: SparseRule, reg_fn, con_fn, mesh,
+                            axis_name: str, exact: bool, guard: bool,
+                            head_share: bool = True):
+  """What every fused train step is made of, after the builder's own
+  set-up. Returns ``(forward_backward, reduce_and_apply_dense, commit)``:
+  the one place the forward (:func:`_make_step_forward`) is differentiated,
+  the one place gradients cross the mesh and optax runs, and the one place
+  the guard gates, the sparse update lands and the new state is assembled.
+
+  ``head_share=False`` leaves ``'apply_head_share'`` out of the guarded
+  metrics (the tiered step's, whose keys predate it)."""
+  from .resilience import guards as _guards
+  plan = engine.plan
+  forward = _make_step_forward(engine, model)
+  guard_gate, oov_ok, guard_metrics = _make_guard_helpers(
+      plan, mesh, axis_name)
+  has_dedup_cap = getattr(plan, "dedup_capacity", None) is not None
+
+  def forward_backward(state, fused, layouts, numerical, cats, labels,
+                       keep_rows, rewrite_ids=None, local_grads=False):
+    """``jax.value_and_grad`` of the loss w.r.t. (dense params, dense-class
+    tables, sparse activations). Returns ``(loss, (d_dense, d_emb_dense,
+    d_z), residuals, ids_all)``.
+
+    ``local_grads`` (the micro-batch scan): a varying zero, derived from
+    the axis-varying labels, is added to the replicated param trees before
+    differentiating. shard_map then treats their grads as device-local, so
+    its replicated-param psum does NOT run once per micro-batch inside the
+    scan; the caller accumulates them and hands the sums to
+    ``reduce_and_apply_dense(local_grads=True)``, which writes the ONE
+    psum. Exactly 0.0, so numerics are untouched."""
+    rank = jax.lax.axis_index(axis_name) if mesh is not None else 0
+    z_sparse, residuals, ids_all, predict = forward(
+        fused, layouts, numerical, cats, keep_rows, rewrite_ids)
+
+    def loss_with(dense_p, emb_dense, z_sp):
+      logits = predict(dense_p, emb_dense, z_sp)
+      with jax.named_scope(scopes.LOSS):
+        loss = loss_fn(logits, labels)
+        if reg_fn is not None:
+          # dense-kind tables' penalty (rank-local windows); scaled by world
+          # to survive the uniform 1/world grad rescale of the dense update
+          # — same convention as make_train_step
+          scale = axis_size(axis_name) if mesh is not None else 1
+          loss = loss + scale * reg_fn(emb_dense, rank)
+      return loss
+
+    dense, emb_dense = state["dense"], state["emb_dense"]
+    if local_grads:
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        vz = (jnp.sum(labels) * 0).astype(jnp.float32)
+        dense, emb_dense = jax.tree_util.tree_map(
+            lambda x: x + vz.astype(x.dtype), (dense, emb_dense))
+    loss, grads = jax.value_and_grad(loss_with, argnums=(0, 1, 2))(
+        dense, emb_dense, z_sparse)
+    return loss, grads, residuals, ids_all
+
+  @jax.named_scope(scopes.DENSE_UPDATE)
+  def reduce_and_apply_dense(state, loss, d_dense, d_emb_dense, d_z=None,
+                             local_grads=False):
+    """Cross-device grad reduction + dense/emb_dense optimizer
+    application. Returns ``(loss, dense_side, d_z)``: the mesh's mean
+    loss, the four updated dense-side entries of the state, and ``d_z``
+    rescaled for the caller's scatter.
+
+    ``local_grads``: the gradients are sums a micro-batch scan accumulated
+    device-locally (``forward_backward(local_grads=True)``), already at
+    the global-batch-mean scale (the scan needs that scale on ``d_z``
+    before it builds each slice's delta streams). Here, and only here,
+    the replicated params' grads get their one psum."""
+    if mesh is not None:
+      if local_grads:
+        # emb_dense blocks are mp-SHARDED per-rank windows: their grads
+        # are rank-local already — summing them across ranks would mix
+        # different tables' windows
+        d_dense = jax.lax.psum(d_dense, axis_name)
+      else:
+        # replicated-param grads arrive already summed across devices
+        # (shard_map's autodiff does it, exactly once — see compat). A
+        # uniform 1/world rescale (dense grads AND sparse cotangents) then
+        # restores exact global-batch-mean semantics (see
+        # finalize_hybrid_grads). emb_dense blocks are mp-SHARDED per-rank
+        # windows — never summed.
+        scale = 1.0 / axis_size(axis_name)
+        d_dense, d_emb_dense, d_z = jax.tree_util.tree_map(
+            lambda g: g * scale, (d_dense, d_emb_dense, d_z))
+      loss = jax.lax.pmean(loss, axis_name)
+
+    upd, dense_opt = dense_optimizer.update(
+        d_dense, state["dense_opt"], state["dense"])
+    dense = optax.apply_updates(state["dense"], upd)
+    if state["emb_dense"]:
+      upd, emb_dense_opt = emb_opt.update(
+          d_emb_dense, state["emb_dense_opt"], state["emb_dense"])
+      emb_dense = optax.apply_updates(state["emb_dense"], upd)
+      if con_fn is not None:
+        rank = jax.lax.axis_index(axis_name) if mesh is not None else 0
+        emb_dense = con_fn(emb_dense, rank)
+    else:
+      emb_dense, emb_dense_opt = state["emb_dense"], state["emb_dense_opt"]
+    return loss, {"dense": dense, "dense_opt": dense_opt,
+                  "emb_dense": emb_dense,
+                  "emb_dense_opt": emb_dense_opt}, d_z
+
+  def commit(state, fused, layouts, loss, grads, dense_side, cats,
+             ids_all=None, d_z=None, residuals=None, streams=None,
+             overflow=None):
+    """Gate (guarded), apply the sparse update to ``fused``, assemble the
+    new state. Returns ``(new_state, loss)``; guarded, ``(new_state, loss,
+    metrics)``.
+
+    The sparse side arrives as the backward's ``d_z`` + ``residuals``, or
+    — from the micro-batch scan — as ready per-class delta ``streams``
+    (with the scan's summed dedup ``overflow`` counts). ``grads`` is what
+    the guard checks besides the loss and the streams: the dense-side
+    gradients PRE-optimizer — a caller's optax chain could mask NaN grads
+    into finite params (e.g. zero_nans), which must still count as a bad
+    step, since the sparse tiers saw the same poison."""
+    step = state["step"]
+    if guard:
+      oov = engine.oov_counts(cats)
+      if has_dedup_cap and overflow is None:
+        overflow = engine.dedup_overflow_counts(ids_all)
+      if streams is None:
+        streams = engine.sparse_delta_streams(layouts, d_z, residuals, rule,
+                                              step)
+      heads = engine.apply_head_counts(layouts, streams) \
+          if head_share else None
+      # micro-batched, this sees the ACCUMULATED streams/grads: NaN from
+      # any micro-batch survives the sums, so one check covers the scan
+      ok, streams = guard_gate(loss, grads, streams, oov_ok(oov))
+      with jax.named_scope(scopes.DENSE_UPDATE):
+        dense_side = _guards.select_tree(
+            ok, dense_side, {k: state[k] for k in dense_side})
+    if streams is None:
+      # unguarded one-shot: built and applied class by class, so the
+      # streams are never all live at once
+      fused = engine.apply_sparse(fused, layouts, d_z, residuals, rule, step,
+                                  exact=exact)
+    else:
+      # zeroed streams scatter-add nothing: a bad step's buffers (on the
+      # tiered path, cache AND staging region) come back bit-identical
+      fused = engine.apply_sparse_streams(fused, layouts, streams, rule,
+                                          step)
+    # the counter only advances on COMMITTED steps: schedules
+    # (rule.linear_scale) and resume offsets must see the same step
+    # sequence as a run that never met the poison batch
+    new_state = {**dense_side, "fused": fused,
+                 "step": step + (ok.astype(jnp.int32) if guard else 1)}
+    if guard:
+      return new_state, loss, guard_metrics(ok, oov, overflow, heads)
+    return new_state, loss
+
+  return forward_backward, reduce_and_apply_dense, commit
+
+
+def _jit_step(local_fn: Callable, mesh: Optional[Mesh], axis_name: str,
+              state, batch_example, out_specs, donate: bool,
+              extra_in_specs: tuple = (), returns_state: bool = True):
+  """``jax.jit`` of a per-device step, under ``shard_map`` on a mesh.
+
+  ``local_fn(state, *extra, *batch)``: the state travels by its hybrid
+  partition specs (and, ``returns_state``, comes back first by the same),
+  every batch leaf is split by rows, ``out_specs`` covers the remaining
+  outputs. Metrics are replicated after their psums/pmin, so one ``P()``
+  covers whatever dict of them a step returns."""
+  donate_argnums = (0,) if donate else ()
+  if mesh is None:
+    return jax.jit(local_fn, donate_argnums=donate_argnums)
+  sspec = hybrid_partition_specs(state, axis_name)
+  bspec = jax.tree_util.tree_map(
+      lambda _: P(axis_name), tuple(batch_example))
+  if returns_state:
+    out_specs = (sspec,) + out_specs
+  return jax.jit(
+      shard_map(local_fn, mesh=mesh,
+                in_specs=(sspec,) + extra_in_specs + bspec,
+                out_specs=out_specs),
+      donate_argnums=donate_argnums)
+
+
+def _micro_batch_slices(n_mb: int, numerical, cats, labels):
+  """The batch as ``n_mb`` equal slices stacked on a leading axis (what
+  ``lax.scan`` walks)."""
+  from .ops.ragged import RaggedIds
+  b = numerical.shape[0]
+  if b % n_mb:
+    raise ValueError(f"batch {b} not divisible by micro_batches {n_mb}")
+  if any(isinstance(c, RaggedIds) for c in cats):
+    raise NotImplementedError(
+        "micro_batches > 1 needs dense cats (ragged rows cannot be "
+        "batch-sliced statically); pad to dense multi-hot first.")
+
+  def mb_view(x):
+    return x.reshape((n_mb, b // n_mb) + x.shape[1:])
+
+  return mb_view(numerical), tuple(mb_view(c) for c in cats), mb_view(labels)
 
 
 def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
@@ -808,304 +1084,75 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
     ``step(state, numerical, cats, labels) -> (state, loss)``; with
     ``guard``, ``-> (state, loss, metrics)``.
   """
+  exact = _refuse_unsupported(
+      "make_sparse_train_step", plan, metrics=guard, rule=rule, exact=exact,
+      micro_batches=micro_batches)
   rule, reg_fn, con_fn = _fused_rule_and_penalties(plan, rule)
   engine = DistributedLookup(plan, dp_input=True, axis_name=axis_name)
   layouts = engine.fused_layouts(rule)
-  emb_opt = emb_dense_optimizer or dense_optimizer
-  # a summed rule is applied once per distinct row: the exact path, with
-  # every refusal the exact path has
-  exact = exact or rule.summed
-
-  if micro_batches > 1 and exact:
-    raise NotImplementedError(
-        "micro_batches > 1 with exact=True: cross-micro-batch dedup would "
-        "need the full occurrence stream the mode exists to avoid. Use "
-        "per-occurrence semantics (exact=False) or one-shot exact.")
-  if guard and exact:
-    raise NotImplementedError(
-        "guard=True with exact=True: the non-finite guard gates the "
-        "prebuilt per-class delta streams before the scatter, but the "
-        "exact path re-gathers rows and builds its deltas inside the "
-        "apply. Use per-occurrence semantics (exact=False) with the "
-        "guard.")
-  if exact and getattr(plan, "wire_dtype", "f32") != "f32":
-    raise ValueError(
-        "exact=True requires wire_dtype='f32': the exact path reproduces "
-        "the reference's deduplicated backward bit-for-bit, and a "
-        "bf16/fp8-narrowed cotangent exchange breaks that claim before "
-        "the sort ever runs. Build the plan with wire_dtype='f32' (the "
-        "dedup_exchange and overlap='pipelined' knobs compose with exact "
-        "fine — dedup only changes which ids reach the mp side, and the "
-        "pipelined f32 wire is bit-exact pure data movement).")
+  forward_backward, reduce_and_apply_dense, commit = _make_train_step_pieces(
+      engine, model, loss_fn, dense_optimizer,
+      emb_dense_optimizer or dense_optimizer, rule, reg_fn, con_fn, mesh,
+      axis_name, exact, guard)
+  # exact=True re-gathers rows at apply time, so saving them in the
+  # residuals would hold dead per-occurrence arrays across the step
+  keep_rows = bool(rule.weight_decay) and not rule.n_aux and not exact
   has_dedup_cap = getattr(plan, "dedup_capacity", None) is not None
-  if has_dedup_cap and not guard:
-    raise ValueError(
-        "plan.dedup_capacity requires make_sparse_train_step(guard=True): "
-        "a capacity below the safe bound aliases distinct ids onto the "
-        "cap's last slot — those occurrences gather and UPDATE the wrong "
-        "rows — and only the guarded step surfaces the psum'd "
-        "'dedup_overflow' counter that makes that observable. Build with "
-        "guard=True or drop the capacity override.")
-  oov_is_error = getattr(plan, "oov", "clip") == "error"
-  if oov_is_error and not guard:
-    raise ValueError(
-        "plan.oov='error' requires make_sparse_train_step(guard=True): "
-        "under jit the ids are traced, so the unguarded step cannot see "
-        "them — out-of-range ids would be silently clipped to each "
-        "table's last row, exactly what oov='error' exists to forbid. "
-        "Enforcement rides the guarded step's OOV metrics "
-        "(resilience.guards.check_oov) plus a commit gate on the "
-        "offending batch; build with guard=True or use oov='clip'.")
-  from .resilience import guards as _guards
-  _guard_gate, _oov_ok, _guard_metrics = _make_guard_helpers(
-      plan, mesh, axis_name)
+
+  def local_step(state, numerical, cats, labels):
+    loss, grads, residuals, ids_all = forward_backward(
+        state, state["fused"], layouts, numerical, cats, labels, keep_rows)
+    loss, dense_side, d_z = reduce_and_apply_dense(state, loss, *grads)
+    return commit(state, state["fused"], layouts, loss, grads[:2],
+                  dense_side, cats, ids_all, d_z=d_z, residuals=residuals)
 
   def local_step_mb(state, numerical, cats, labels):
     n_mb = micro_batches
-    b = numerical.shape[0]
-    if b % n_mb:
-      raise ValueError(f"batch {b} not divisible by micro_batches {n_mb}")
-    from .ops.ragged import RaggedIds
-    if any(isinstance(c, RaggedIds) for c in cats):
-      raise NotImplementedError(
-          "micro_batches > 1 needs dense cats (ragged rows cannot be "
-          "batch-sliced statically); pad to dense multi-hot first.")
-    rank = jax.lax.axis_index(axis_name) if mesh is not None else 0
-    hotness = [ragged_hotness(c) for c in cats]
-    hotness_of = lambda i: hotness[i]  # noqa: E731
     world = axis_size(axis_name) if mesh is not None else 1
+    # uniform scale: 1/n_mb turns per-micro-batch means into the global
+    # batch mean (the one-shot cotangent values, needed for non-linear
+    # rule parity), folded with the mesh's 1/world grad rescale
     gscale = 1.0 / (n_mb * world)
 
-    def mb_view(x):
-      return x.reshape((n_mb, b // n_mb) + x.shape[1:])
-
-    keep = bool(rule.weight_decay) and not rule.n_aux
-    # A varying zero (derived from the axis-varying labels): added to the
-    # replicated param trees before differentiating, it makes shard_map
-    # treat the grads as device-local, so the replicated-param psum does
-    # NOT run once per micro-batch inside the scan — ONE psum after the
-    # scan reduces the accumulated local grads. Also the version-portable
-    # varying annotation for the scan carry (jax.lax.pvary only exists on
-    # recent JAX and is already deprecated there). Exactly 0.0, so
-    # numerics are untouched.
-    vz0 = (jnp.sum(labels) * 0).astype(jnp.float32)
-
     def body(carry, mb):
-      dd_acc, de_acc, loss_acc = carry
       numerical_i, cats_i, labels_i = mb
-      cats_i = list(cats_i)
-      ids_all = engine.route_ids(cats_i, hotness_of)
-      counts = engine.mean_counts(cats_i)
-      z_sparse, residuals = engine.lookup_sparse_fused(
-          state["fused"], layouts, ids_all, keep_rows=keep)
-
-      def loss_with(dense_p, emb_dense, z_sp):
-        acts = engine.finish_forward(z_sp, emb_dense, ids_all,
-                                     b // n_mb, hotness_of, counts)
-        with jax.named_scope(scopes.MODEL):
-          logits = model.apply({"params": dense_p}, numerical_i, cats_i,
-                               emb_acts=acts)
-        with jax.named_scope(scopes.LOSS):
-          loss = loss_fn(logits, labels_i)
-          if reg_fn is not None:
-            scale = axis_size(axis_name) if mesh is not None else 1
-            loss = loss + scale * reg_fn(emb_dense, rank)
-        return loss
-
+      loss_i, grads, residuals, ids_all = forward_backward(
+          state, state["fused"], layouts, numerical_i, list(cats_i),
+          labels_i, keep_rows, local_grads=True)
       with jax.named_scope(scopes.DENSE_UPDATE):
-        vz = (jnp.sum(labels_i) * 0).astype(jnp.float32)
-        dense_local, emb_local = jax.tree_util.tree_map(
-            lambda x: x + vz.astype(x.dtype),
-            (state["dense"], state["emb_dense"]))
-      loss_i, (dd, de, dz) = jax.value_and_grad(
-          loss_with, argnums=(0, 1, 2))(dense_local, emb_local, z_sparse)
-      # uniform scale: 1/n_mb turns per-micro-batch means into the global
-      # batch mean (the one-shot cotangent values, needed for non-linear
-      # rule parity), folded with the mesh's 1/world grad rescale
-      with jax.named_scope(scopes.DENSE_UPDATE):
-        dd, de, dz = jax.tree_util.tree_map(
-            lambda g: g * gscale, (dd, de, dz))
+        dd, de, dz = jax.tree_util.tree_map(lambda g: g * gscale, grads)
       streams_i = engine.sparse_delta_streams(layouts, dz, residuals,
                                               rule, state["step"])
       with jax.named_scope(scopes.DENSE_UPDATE):
-        carry = jax.tree_util.tree_map(
-            jnp.add, (dd_acc, de_acc, loss_acc),
-            (dd, de, loss_i / n_mb))
-      if has_dedup_cap:
-        # per-micro-batch overflow counts ride the scan outputs and sum
-        # below (each micro-batch routes its own capped unique blocks)
-        return carry, (streams_i, engine.dedup_overflow_counts(ids_all))
-      return carry, streams_i
+        carry = jax.tree_util.tree_map(jnp.add, carry,
+                                       (dd, de, loss_i / n_mb))
+      # each micro-batch routes its own capped unique blocks: per-slice
+      # overflow counts ride the scan outputs and sum below
+      return carry, (streams_i, engine.dedup_overflow_counts(ids_all)
+                     if has_dedup_cap else None)
 
+    # the carry starts as varying as the local grads it accumulates (see
+    # forward_backward's local_grads); exactly 0.0
+    vz0 = (jnp.sum(labels) * 0).astype(jnp.float32)
     init = jax.tree_util.tree_map(
         lambda x: jnp.zeros_like(x) + vz0.astype(x.dtype),
         (state["dense"], state["emb_dense"])) + (vz0,)
-    mb_batches = (mb_view(numerical), tuple(mb_view(c) for c in cats),
-                  mb_view(labels))
-    (d_dense, d_emb_dense, loss), scan_out = jax.lax.scan(
-        body, init, mb_batches)
-    if has_dedup_cap:
-      streams_s, ovf_s = scan_out
-      ovf = {n: jnp.sum(v).astype(jnp.int32) for n, v in ovf_s.items()}
-    else:
-      streams_s, ovf = scan_out, None
-    # flatten the stacked [n_mb, ...] streams and scatter once per class
+    (d_dense, d_emb_dense, loss), (streams_s, ovf_s) = jax.lax.scan(
+        body, init, _micro_batch_slices(n_mb, numerical, cats, labels))
+    ovf = jax.tree_util.tree_map(lambda v: jnp.sum(v).astype(jnp.int32),
+                                 ovf_s)
+    # flatten the stacked [n_mb, ...] streams: ONE scatter per class
     streams = {name: (ids.reshape(-1), rows.reshape(-1, rows.shape[-1]))
                for name, (ids, rows) in streams_s.items()}
-    if mesh is not None:
-      # no grad reduction is written here (see compat.shard_map); the
-      # emb_dense blocks are mp-SHARDED (per-rank windows), so their
-      # grads are already rank-local — summing them across ranks would
-      # mix different tables' windows
-      with jax.named_scope(scopes.DENSE_UPDATE):
-        loss = jax.lax.pmean(loss, axis_name)
+    loss, dense_side, _ = reduce_and_apply_dense(
+        state, loss, d_dense, d_emb_dense, local_grads=True)
+    return commit(state, state["fused"], layouts, loss,
+                  (d_dense, d_emb_dense), dense_side, cats, streams=streams,
+                  overflow=ovf)
 
-    if guard:
-      # the guard sees the ACCUMULATED streams/grads: NaN from any
-      # micro-batch survives the sums, so one check covers the scan
-      oov = engine.oov_counts(cats)
-      heads = engine.apply_head_counts(layouts, streams)
-      ok, streams = _guard_gate(loss, (d_dense, d_emb_dense), streams,
-                                _oov_ok(oov))
-
-    with jax.named_scope(scopes.DENSE_UPDATE):
-      upd, dense_opt = dense_optimizer.update(
-          d_dense, state["dense_opt"], state["dense"])
-      dense = optax.apply_updates(state["dense"], upd)
-      if state["emb_dense"]:
-        upd, emb_dense_opt = emb_opt.update(
-            d_emb_dense, state["emb_dense_opt"], state["emb_dense"])
-        emb_dense = optax.apply_updates(state["emb_dense"], upd)
-        if con_fn is not None:
-          emb_dense = con_fn(emb_dense, rank)
-      else:
-        emb_dense, emb_dense_opt = state["emb_dense"], state["emb_dense_opt"]
-
-      if guard:
-        dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
-            ok, (dense, dense_opt, emb_dense, emb_dense_opt),
-            (state["dense"], state["dense_opt"], state["emb_dense"],
-             state["emb_dense_opt"]))
-
-    fused = engine.apply_sparse_streams(state["fused"], layouts, streams,
-                                        rule, state["step"])
-    new_state = {
-        "dense": dense,
-        "dense_opt": dense_opt,
-        "emb_dense": emb_dense,
-        "emb_dense_opt": emb_dense_opt,
-        "fused": fused,
-        "step": state["step"] + (ok.astype(jnp.int32) if guard else 1),
-    }
-    if guard:
-      return new_state, loss, _guard_metrics(ok, oov, ovf, heads)
-    return new_state, loss
-
-  def local_step(state, numerical, cats, labels):
-    b = numerical.shape[0]
-    rank = jax.lax.axis_index(axis_name) if mesh is not None else 0
-    hotness = [ragged_hotness(c) for c in cats]
-    hotness_of = lambda i: hotness[i]  # noqa: E731
-    ids_all = engine.route_ids(cats, hotness_of)
-    counts = engine.mean_counts(cats)
-    z_sparse, residuals = engine.lookup_sparse_fused(
-        state["fused"], layouts, ids_all,
-        # exact=True re-gathers rows at apply time, so saving them in the
-        # residuals would hold dead per-occurrence arrays across the step
-        keep_rows=bool(rule.weight_decay) and not rule.n_aux and not exact)
-
-    def loss_with(dense_p, emb_dense, z_sp):
-      acts = engine.finish_forward(z_sp, emb_dense, ids_all, b, hotness_of,
-                                   counts)
-      with jax.named_scope(scopes.MODEL):
-        logits = model.apply({"params": dense_p}, numerical, cats,
-                             emb_acts=acts)
-      with jax.named_scope(scopes.LOSS):
-        loss = loss_fn(logits, labels)
-        if reg_fn is not None:
-          # dense-kind tables' penalty (rank-local windows); scaled by world
-          # to survive the uniform 1/world grad rescale below — same
-          # convention as make_train_step
-          scale = axis_size(axis_name) if mesh is not None else 1
-          loss = loss + scale * reg_fn(emb_dense, rank)
-      return loss
-
-    loss, (d_dense, d_emb_dense, d_z) = jax.value_and_grad(
-        loss_with, argnums=(0, 1, 2))(state["dense"], state["emb_dense"],
-                                      z_sparse)
-    # checked pre-optimizer: a caller's optax chain could mask NaN grads
-    # into finite params (e.g. zero_nans), which must still count as a
-    # bad step — the sparse tiers saw the same poison
-    grads_chk = (d_dense, d_emb_dense) if guard else None
-    loss, dense, dense_opt, emb_dense, emb_dense_opt, d_z = \
-        _reduce_and_apply_dense(state, loss, d_dense, d_emb_dense, d_z,
-                                rank, mesh, axis_name, dense_optimizer,
-                                emb_opt, con_fn)
-
-    if guard:
-      oov = engine.oov_counts(cats)
-      ovf = engine.dedup_overflow_counts(ids_all) if has_dedup_cap else None
-      streams = engine.sparse_delta_streams(layouts, d_z, residuals, rule,
-                                            state["step"])
-      heads = engine.apply_head_counts(layouts, streams)
-      ok, streams = _guard_gate(loss, grads_chk, streams, _oov_ok(oov))
-      with jax.named_scope(scopes.DENSE_UPDATE):
-        dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
-            ok, (dense, dense_opt, emb_dense, emb_dense_opt),
-            (state["dense"], state["dense_opt"], state["emb_dense"],
-             state["emb_dense_opt"]))
-      fused = engine.apply_sparse_streams(state["fused"], layouts, streams,
-                                          rule, state["step"])
-      new_state = {
-          "dense": dense,
-          "dense_opt": dense_opt,
-          "emb_dense": emb_dense,
-          "emb_dense_opt": emb_dense_opt,
-          "fused": fused,
-          # the counter only advances on COMMITTED steps: schedules
-          # (rule.linear_scale) and resume offsets must see the same
-          # step sequence as a run that never met the poison batch
-          "step": state["step"] + ok.astype(jnp.int32),
-      }
-      return new_state, loss, _guard_metrics(ok, oov, ovf, heads)
-
-    fused = engine.apply_sparse(state["fused"], layouts, d_z, residuals,
-                                rule, state["step"], exact=exact)
-    new_state = {
-        "dense": dense,
-        "dense_opt": dense_opt,
-        "emb_dense": emb_dense,
-        "emb_dense_opt": emb_dense_opt,
-        "fused": fused,
-        "step": state["step"] + 1,
-    }
-    return new_state, loss
-
-  step_fn = local_step_mb if micro_batches > 1 else local_step
-
-  if mesh is None:
-    return jax.jit(step_fn, donate_argnums=(0,) if donate else ())
-
-  sspec = hybrid_partition_specs(state, axis_name)
-  bspec = jax.tree_util.tree_map(
-      lambda _: P(axis_name), tuple(batch_example))
-  out_specs = (sspec, P())
-  if guard:
-    # metrics are replicated scalars (bad_step after the pmin, oov and
-    # dedup_overflow after their psums)
-    mspec = {
-        "bad_step": P(),
-        "oov": {class_param_name(*k): P() for k in plan.class_keys}}
-    if has_dedup_cap:
-      mspec["dedup_overflow"] = {
-          class_param_name(*k): P() for k in plan.class_keys}
-    mspec["apply_head_share"] = {name: P() for name in layouts}
-    out_specs = (sspec, P(), mspec)
-  sharded = shard_map(
-      step_fn, mesh=mesh,
-      in_specs=(sspec,) + bspec,
-      out_specs=out_specs)
-  return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+  return _jit_step(local_step_mb if micro_batches > 1 else local_step, mesh,
+                   axis_name, state, batch_example,
+                   (P(), P()) if guard else (P(),), donate)
 
 
 def make_tiered_train_step(model, tplan, loss_fn: Callable,
@@ -1168,64 +1215,24 @@ def make_tiered_train_step(model, tplan, loss_fn: Callable,
   """
   plan = tplan.plan
   tier_specs = tplan.tier_specs
-  if getattr(plan, "oov", "clip") == "allocate":
-    raise NotImplementedError(
-        "plan.oov='allocate' with tiered storage: the tiered prefetcher "
-        "classifies RAW ids host-side, so the dynamic-id translation and "
-        "the classify stage would have to compose into one host pass — "
-        "an open follow-on (ROADMAP, dynamic-vocab direction). Keep "
-        "dynamic tables device-resident (host_row_threshold=None) or "
-        "use a static oov policy for tiered plans.")
-  if getattr(plan, "oov", "clip") == "error" and not guard:
-    raise ValueError(
-        "plan.oov='error' requires make_tiered_train_step(guard=True): "
-        "under jit the ids are traced, so the unguarded step cannot see "
-        "them — out-of-range ids would be silently clipped to each "
-        "table's last row, exactly what oov='error' exists to forbid. "
-        "Enforcement rides the guarded step's OOV metrics plus a commit "
-        "gate on the offending batch; build with guard=True or use "
-        "oov='clip'.")
-  # a summed rule is applied once per distinct row: the exact path
-  exact = exact or rule.summed
-  if guard and exact:
-    raise NotImplementedError(
-        "guard=True with exact=True: the non-finite guard gates the "
-        "prebuilt per-class delta streams before the scatter, but the "
-        "exact path re-gathers rows and builds its deltas inside the "
-        "apply. Use per-occurrence semantics (exact=False) with the "
-        "guard.")
-  if exact and getattr(plan, "wire_dtype", "f32") != "f32":
-    raise ValueError(
-        "exact=True requires wire_dtype='f32' (same contract as "
-        "make_sparse_train_step): the deduplicated backward's bit-for-bit "
-        "claim cannot survive a bf16/fp8-narrowed cotangent exchange. "
-        "Build the plan with wire_dtype='f32'.")
-  has_dedup_cap = getattr(plan, "dedup_capacity", None) is not None
-  if has_dedup_cap and not guard:
-    raise ValueError(
-        "plan.dedup_capacity requires make_tiered_train_step(guard=True): "
-        "a capacity below the safe bound aliases distinct ids onto the "
-        "cap's last slot — those occurrences gather and UPDATE the wrong "
-        "rows — and only the guarded step surfaces the psum'd "
-        "'dedup_overflow' counter that makes that observable. Build with "
-        "guard=True or drop the capacity override.")
+  exact = _refuse_unsupported(
+      "make_tiered_train_step", plan, metrics=guard, rule=rule, exact=exact,
+      tiered=True)
   # same penalty limits as make_sparse_train_step's fused path (and for
   # host-tier tables there is no dense-autodiff fallback at all)
   rule, reg_fn, con_fn = _fused_rule_and_penalties(plan, rule)
   engine = DistributedLookup(plan, dp_input=True, axis_name=axis_name)
   base_layouts = engine.fused_layouts(rule,
                                       rows_overrides=tplan.rows_overrides)
-  emb_opt = emb_dense_optimizer or dense_optimizer
-  from .resilience import guards as _guards
-  _guard_gate, _oov_ok, _guard_metrics = _make_guard_helpers(
-      plan, mesh, axis_name)
+  # head_share=False: the guarded tiered metrics keep the keys they had
+  # before the apply kernel's heads were counted (ROADMAP D3)
+  forward_backward, reduce_and_apply_dense, commit = _make_train_step_pieces(
+      engine, model, loss_fn, dense_optimizer,
+      emb_dense_optimizer or dense_optimizer, rule, reg_fn, con_fn, mesh,
+      axis_name, exact, guard, head_share=False)
+  keep_rows = bool(rule.weight_decay) and not rule.n_aux and not exact
 
   def local_step(state, staged, numerical, cats, labels):
-    b = numerical.shape[0]
-    rank = jax.lax.axis_index(axis_name) if mesh is not None else 0
-    hotness = [ragged_hotness(c) for c in cats]
-    hotness_of = lambda i: hotness[i]  # noqa: E731
-
     # effective layouts from THIS step's staging shapes: a spill step
     # stages S > staging_grps rows, so the compact buffer (and the 2^31
     # bound) grows with it — shapes are static per trace, so this is
@@ -1236,104 +1243,41 @@ def make_tiered_train_step(model, tplan, loss_fn: Callable,
       layouts[name] = PackedLayout(
           rows=(spec.cache_grps + s) * spec.rpp,
           width=base_layouts[name].width, n_aux=rule.n_aux)
+    tier_metrics = {}
 
-    ids_all = engine.route_ids(cats, hotness_of)
-    counts = engine.mean_counts(cats)
-    ids_all, tier_metrics = engine.translate_tiered_ids(
-        ids_all, tier_specs, staged["resident"], staged["grps"])
+    def to_slots(ids_all):
+      ids_all, counters = engine.translate_tiered_ids(
+          ids_all, tier_specs, staged["resident"], staged["grps"])
+      tier_metrics.update(counters)
+      return ids_all
+
     fused_in = engine.install_staging(state["fused"], tier_specs,
-                                     staged["rows"])
-    z_sparse, residuals = engine.lookup_sparse_fused(
-        fused_in, layouts, ids_all,
-        keep_rows=bool(rule.weight_decay) and not rule.n_aux and not exact)
-
-    def loss_with(dense_p, emb_dense, z_sp):
-      acts = engine.finish_forward(z_sp, emb_dense, ids_all, b, hotness_of,
-                                   counts)
-      with jax.named_scope(scopes.MODEL):
-        logits = model.apply({"params": dense_p}, numerical, cats,
-                             emb_acts=acts)
-      with jax.named_scope(scopes.LOSS):
-        loss = loss_fn(logits, labels)
-        if reg_fn is not None:
-          scale = axis_size(axis_name) if mesh is not None else 1
-          loss = loss + scale * reg_fn(emb_dense, rank)
-      return loss
-
-    loss, (d_dense, d_emb_dense, d_z) = jax.value_and_grad(
-        loss_with, argnums=(0, 1, 2))(state["dense"], state["emb_dense"],
-                                      z_sparse)
-    # checked pre-optimizer, like the sparse step: a caller's optax chain
-    # could mask NaN grads into finite params, which must still skip
-    grads_chk = (d_dense, d_emb_dense) if guard else None
-    loss, dense, dense_opt, emb_dense, emb_dense_opt, d_z = \
-        _reduce_and_apply_dense(state, loss, d_dense, d_emb_dense, d_z,
-                                rank, mesh, axis_name, dense_optimizer,
-                                emb_opt, con_fn)
-
-    if guard:
-      oov = engine.oov_counts(cats)
-      ovf = engine.dedup_overflow_counts(ids_all) if has_dedup_cap else None
-      streams = engine.sparse_delta_streams(layouts, d_z, residuals, rule,
-                                            state["step"])
-      ok, streams = _guard_gate(loss, grads_chk, streams, _oov_ok(oov))
-      with jax.named_scope(scopes.DENSE_UPDATE):
-        dense, dense_opt, emb_dense, emb_dense_opt = _guards.select_tree(
-            ok, (dense, dense_opt, emb_dense, emb_dense_opt),
-            (state["dense"], state["dense_opt"], state["emb_dense"],
-             state["emb_dense_opt"]))
-      # zeroed streams scatter-add nothing: the cache region AND the
-      # staging region come back bit-identical, so the write-back below
-      # re-writes the staged rows' unchanged values into the host images
-      fused = engine.apply_sparse_streams(fused_in, layouts, streams,
-                                          rule, state["step"])
-    else:
-      fused = engine.apply_sparse(fused_in, layouts, d_z, residuals,
-                                  rule, state["step"], exact=exact)
-    staged_out = engine.staged_regions(fused, tier_specs, staged["grps"])
-    fused = engine.trim_spill(fused, tier_specs)
+                                      staged["rows"])
+    loss, grads, residuals, ids_all = forward_backward(
+        state, fused_in, layouts, numerical, cats, labels, keep_rows,
+        rewrite_ids=to_slots)
+    loss, dense_side, d_z = reduce_and_apply_dense(state, loss, *grads)
+    new_state, loss, *guard_metrics = commit(
+        state, fused_in, layouts, loss, grads[:2], dense_side, cats, ids_all,
+        d_z=d_z, residuals=residuals)
+    staged_out = engine.staged_regions(new_state["fused"], tier_specs,
+                                       staged["grps"])
+    new_state["fused"] = engine.trim_spill(new_state["fused"], tier_specs)
     if mesh is not None:
       tier_metrics = {name: jax.lax.psum(m, axis_name)
                       for name, m in tier_metrics.items()}
-    new_state = {
-        "dense": dense,
-        "dense_opt": dense_opt,
-        "emb_dense": emb_dense,
-        "emb_dense_opt": emb_dense_opt,
-        "fused": fused,
-        "step": state["step"] + (ok.astype(jnp.int32) if guard else 1),
-    }
     if guard:
-      metrics = {"tier": tier_metrics, **_guard_metrics(ok, oov, ovf)}
-      return new_state, staged_out, metrics, loss
+      tier_metrics = {"tier": tier_metrics, **guard_metrics[0]}
     return new_state, staged_out, tier_metrics, loss
 
-  if mesh is None:
-    return jax.jit(local_step, donate_argnums=(0,) if donate else ())
-
-  sspec = hybrid_partition_specs(state, axis_name)
   staged_specs = {
       "grps": {n: P(axis_name) for n in tier_specs},
       "resident": {n: P(axis_name) for n in tier_specs},
       "rows": {n: P(axis_name, None) for n in tier_specs},
   }
-  bspec = jax.tree_util.tree_map(
-      lambda _: P(axis_name), tuple(batch_example))
-  metrics_spec = {n: P() for n in tier_specs}
-  if guard:
-    metrics_spec = {
-        "tier": metrics_spec,
-        "bad_step": P(),
-        "oov": {class_param_name(*k): P() for k in plan.class_keys}}
-    if has_dedup_cap:
-      metrics_spec["dedup_overflow"] = {
-          class_param_name(*k): P() for k in plan.class_keys}
-  sharded = shard_map(
-      local_step, mesh=mesh,
-      in_specs=(sspec, staged_specs) + bspec,
-      out_specs=(sspec, {n: P(axis_name, None) for n in tier_specs},
-                 metrics_spec, P()))
-  return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+  return _jit_step(local_step, mesh, axis_name, state, batch_example,
+                   ({n: P(axis_name, None) for n in tier_specs}, P(), P()),
+                   donate, extra_in_specs=(staged_specs,))
 
 
 def make_sparse_eval_step(model, plan: DistEmbeddingStrategy,
@@ -1369,14 +1313,8 @@ def make_sparse_eval_step(model, plan: DistEmbeddingStrategy,
   ``tests/test_serving.py`` pins the repeated-call behavior; the
   serving subsystem (``serving.make_serve_step``) inherits the same
   contract, donating at most the per-dispatch request arrays."""
-  has_dedup_cap = getattr(plan, "dedup_capacity", None) is not None
-  if has_dedup_cap and not with_metrics:
-    raise ValueError(
-        "plan.dedup_capacity requires make_sparse_eval_step("
-        "with_metrics=True): a capacity below the safe bound aliases "
-        "distinct ids onto the cap's last slot — those predictions read "
-        "the WRONG rows — and only the metrics path surfaces the psum'd "
-        "'dedup_overflow' counter that makes that observable.")
+  _refuse_unsupported("make_sparse_eval_step", plan, metrics=with_metrics,
+                      metrics_arg="with_metrics=True")
   if getattr(plan, "oov", "clip") == "allocate":
     raise ValueError(
         "plan.oov='allocate' is not evaluable: allocation MUTATES the id "
@@ -1387,21 +1325,15 @@ def make_sparse_eval_step(model, plan: DistEmbeddingStrategy,
         "layouts — the knob changes no buffer) and feed it ids already "
         "translated read-only (dynvocab.DynVocabTranslator."
         "translate_readonly).")
+  has_dedup_cap = getattr(plan, "dedup_capacity", None) is not None
   engine = DistributedLookup(plan, dp_input=True, axis_name=axis_name)
   layouts = engine.fused_layouts(rule)
+  forward = _make_step_forward(engine, model)
 
   def local_eval(state, numerical, cats):
-    b = numerical.shape[0]
-    hotness = [ragged_hotness(c) for c in cats]
-    hotness_of = lambda i: hotness[i]  # noqa: E731
-    ids_all = engine.route_ids(cats, hotness_of)
-    counts = engine.mean_counts(cats)
-    z_sparse, _ = engine.lookup_sparse_fused(state["fused"], layouts, ids_all)
-    acts = engine.finish_forward(z_sparse, state["emb_dense"], ids_all, b,
-                                 hotness_of, counts)
-    with jax.named_scope(scopes.MODEL):
-      preds = model.apply({"params": state["dense"]}, numerical, cats,
-                          emb_acts=acts)
+    z_sparse, _, ids_all, predict = forward(state["fused"], layouts,
+                                            numerical, cats)
+    preds = predict(state["dense"], state["emb_dense"], z_sparse)
     if not with_metrics:
       return preds
     oov = engine.oov_counts(cats)
@@ -1415,25 +1347,12 @@ def make_sparse_eval_step(model, plan: DistEmbeddingStrategy,
       metrics["dedup_overflow"] = ovf
     return preds, metrics
 
-  if mesh is None:
-    # donate_argnums stays EMPTY (see the docstring's donation
-    # contract): donating argnum 0 here would invalidate the fused
-    # state on the first call and poison every later eval/serve call
-    return jax.jit(local_eval, donate_argnums=())
-  sspec = hybrid_partition_specs(state, axis_name)
-  bspec = jax.tree_util.tree_map(
-      lambda _: P(axis_name), tuple(batch_example[:2]))
-  out_specs = P(axis_name)
-  if with_metrics:
-    mspec = {"oov": {class_param_name(*k): P() for k in plan.class_keys}}
-    if has_dedup_cap:
-      mspec["dedup_overflow"] = {
-          class_param_name(*k): P() for k in plan.class_keys}
-    out_specs = (P(axis_name), mspec)
-  return jax.jit(shard_map(
-      local_eval, mesh=mesh,
-      in_specs=(sspec,) + bspec,
-      out_specs=out_specs), donate_argnums=())
+  # donate=False (see the docstring's donation contract): donating the
+  # state here would invalidate the fused buffers on the first call and
+  # poison every later eval/serve call
+  return _jit_step(local_eval, mesh, axis_name, state, batch_example[:2],
+                   (P(axis_name), P()) if with_metrics else P(axis_name),
+                   donate=False, returns_state=False)
 
 
 def make_eval_step(pred_fn: Callable, mesh: Optional[Mesh],

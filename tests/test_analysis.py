@@ -109,6 +109,36 @@ def build_plan(plan):
   assert lint_source(ok, "m.py", CTX, ["GL102"]) == []
 
 
+@pytest.mark.parametrize("piece,anchor,planted,rule", [
+    ("forward", "ids_all = engine.route_ids(cats, hotness_of)",
+     "ids_all.block_until_ready()", "GL101"),
+    ("predict", "acts = engine.finish_forward(",
+     "np.asarray(z_sp)", "GL102"),
+    ("loss_with", "logits = predict(dense_p, emb_dense, z_sp)",
+     "jax.device_get(logits)", "GL101"),
+    ("reduce_and_apply_dense", "upd, dense_opt = dense_optimizer.update(",
+     "loss.item()", "GL101"),
+    ("commit", "step = state[\"step\"]", "jax.device_get(step)", "GL101"),
+])
+def test_host_sync_planted_in_a_shared_step_piece_is_found(
+    piece, anchor, planted, rule):
+  """The pieces the four fused-state builders share live in private
+  ``_make_*step*`` factories of ``training.py``; the trace-reachable rules
+  reach every one of them (the real file: clean as committed, one finding
+  on the planted line)."""
+  path = os.path.join(REPO, "distributed_embeddings_tpu", "training.py")
+  with open(path) as f:
+    lines = f.read().split("\n")
+  assert lint_source("\n".join(lines), path, CTX, ["GL101", "GL102"]) == []
+  (at,) = [i for i, line in enumerate(lines)
+           if line.strip().startswith(anchor)]
+  indent = lines[at][:len(lines[at]) - len(lines[at].lstrip())]
+  lines.insert(at, indent + planted)
+  found = lint_source("\n".join(lines), path, CTX, ["GL101", "GL102"])
+  # (a closure nested twice is walked from each enclosing closure)
+  assert {(f.rule, f.line) for f in found} == {(rule, at + 1)}, piece
+
+
 def test_gl103_bare_except():
   src = """
 def load(path):
