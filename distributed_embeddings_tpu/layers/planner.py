@@ -760,6 +760,7 @@ class DistEmbeddingStrategy:
                self.table_tier(sh.table_id)), []).append(sh)
         for base, group in by_base.items():
           self._assign_generations(base[0], group, occ_of)
+      self._split_small_sparse_tables()
 
     if host_row_threshold is not None:
       # Host-tier generations are renumbered after a GLOBAL offset (max
@@ -1030,6 +1031,79 @@ class DistEmbeddingStrategy:
       bnum = best_assign[id(sh)]
       sh.gen = remap.setdefault(bnum, len(remap))
 
+  def _split_small_sparse_tables(self) -> None:
+    """Give small sparse-kind tables a generation of their own where that
+    lowers the padded slots a rank of the generation they leave.
+
+    Across chips every rank runs every generation at the most slots any
+    rank holds in it and pays the global batch for each, whatever the
+    table's size: in the row gather, in both row exchanges, in the apply.
+    A table whose packed block is fewer bytes than the rows its slots ship
+    (``(world - 1) x physical rows x physical width x 4`` under ``batch_hint
+    x width x 4 x (world - 1) / world`` a slot: the two counts of
+    ``wire.dense_class_side``, for one table, without optimizer lanes) is
+    cheaper gathered to the samples (``DistributedLookup.tables_travel``),
+    but a class travels whole, so it can only once it shares no buffer with
+    a large table. Such tables move, all of one (width, combiner) into ONE
+    new generation, out of every generation where something stays, what
+    stays pads to fewer slots a rank, and what stays and the new generation
+    together pad to no more than before: where the engine keeps the rows
+    after all (model-parallel inputs, a rule whose lanes double the block),
+    the new class costs the slot it cost where it was. Static counts on the
+    plan, no knob. The assignment is left as it was, object for object, on
+    one rank, without a ``batch_hint``, under ``dedup_exchange``, and for
+    row-sliced and host-tier shards, ragged-fed and dense-kind tables."""
+    world, batch = self.world_size, self.batch_hint
+    if world == 1 or not batch or self.dedup_exchange:
+      return
+    from ..ops.packed_table import PackedLayout
+    from ..parallel import wire
+    row_bytes = {"f32": 4, "bf16": 2, "fp8": 1}[self.wire_dtype]
+    hot_of: Dict[int, List[int]] = {}  # table -> hotness of each input
+    for i, t in enumerate(self.input_table_map):
+      hot_of.setdefault(t, []).append(
+          1 if self.input_hotness is None else self.input_hotness[i])
+
+    def padded(members):  # [(rank, shard)] -> padded slots a rank
+      per: Dict[tuple, List[int]] = {}
+      for rank, sh in members:
+        for h in hot_of.get(sh.table_id, ()):
+          per.setdefault((h, sh.row_sliced), [0] * world)[rank] += 1
+      return sum(max(n) for n in per.values())
+
+    def small(sh):  # the byte rule, for one table
+      if sh.row_sliced or sh.table_id in self._ragged_tables:
+        return False
+      lay = PackedLayout(rows=sh.input_dim, width=sh.width)
+      # a sequence input's rows travel side by side: hotness slots' worth
+      slots = sum(h if sh.combiner is None else 1
+                  for h in hot_of.get(sh.table_id, ()))
+      return wire.dense_class_side(
+          world, True, slots, batch, lay.phys_rows, sh.width, row_bytes,
+          lay.phys_width)[0] == "tables"
+
+    by_base: Dict[tuple, List] = {}
+    for rank, shards in enumerate(self.rank_shards):
+      for sh in shards:
+        if (self._kind_of(sh) == "sparse"
+            and self.table_tier(sh.table_id) == "device"):
+          by_base.setdefault((sh.width, sh.combiner), []).append((rank, sh))
+    for members in by_base.values():
+      moved: List = []
+      for g in sorted({sh.gen for _, sh in members}):
+        here = [m for m in members if m[1].gen == g]
+        go = [m for m in here if small(m[1])]
+        stay = [m for m in here if m not in go]
+        if not go or not stay:
+          continue
+        before, after = padded(here), padded(stay)
+        if (after < before
+            and after + padded(moved + go) <= before + padded(moved)):
+          moved += go
+      new_gen = 1 + max(sh.gen for _, sh in members)
+      for _, sh in moved:
+        sh.gen = new_gen
+
   @staticmethod
   def _concentrate(group, occ_of, batch, rpp, cap_rows, threshold):
     """Concentration generation layout: greedy fast-generation packing in
@@ -1134,20 +1208,29 @@ class DistEmbeddingStrategy:
     }
 
   def exchange_report(self, global_batch: Optional[int] = None,
-                      dp_input: bool = True) -> Dict[str, object]:
+                      dp_input: bool = True,
+                      n_aux: int = 0) -> Dict[str, object]:
     """Wire-format summary of the dp<->mp exchange path.
 
-    Per dense-kind class also WHICH SIDE TRAVELS across chips:
-    ``"moves"`` is ``"tables"`` (the class block is all-gathered and
-    looked up on each chip's own samples; its gradient is
-    reduce-scattered) or ``"rows"`` (ids cross to the owner, rows cross
-    back), with ``rows_bytes`` and ``tables_bytes``, the two static
-    counts of bytes leaving one chip each way a step that the engine
-    takes the smaller of (``parallel.wire.dense_class_side``). They
-    depend on the batch: ``global_batch``, else the plan's
-    ``batch_hint``; with neither (and more than one rank) the three
-    entries are ``None``. Hotness is the plan's ``input_hotness`` (1
-    where not given), as the engine sees it at trace time.
+    Per class also WHICH SIDE TRAVELS across chips: ``"moves"`` is
+    ``"tables"`` (the class block is all-gathered and looked up on each
+    chip's own samples; its gradient, or for a sparse-kind class its
+    summed per-occurrence deltas, is reduce-scattered) or ``"rows"`` (ids
+    cross to the owner, rows cross back), with ``rows_bytes`` and
+    ``tables_bytes``, the two static counts of bytes leaving one chip
+    each way a step that the engine takes the smaller of
+    (``parallel.wire.dense_class_side``), and ``padded_slots``, the slots
+    every rank runs for the class (the most any rank holds, summed over
+    its hotness buckets): what the rows' side is counted from, and what
+    a rank stops paying where the tables move. They depend on the batch:
+    ``global_batch``, else the plan's ``batch_hint``; with neither (and
+    more than one rank) the three entries are ``None``. Hotness is the
+    plan's ``input_hotness`` (1 where not given), as the engine sees it
+    at trace time. A sparse-kind class is counted as the fused training
+    step packs it, ``n_aux`` optimizer lanes beside every row (0: SGD),
+    and under a per-occurrence update: with ``exact=True`` or a summed
+    rule the step keeps its rows whatever is said here
+    (``DistributedLookup.tables_travel``).
 
     Per class: its kind and whether the deduplicated exchange applies to
     its padded buckets (sparse-kind classes only — dense MXU classes have
@@ -1165,8 +1248,10 @@ class DistEmbeddingStrategy:
     ``jit_gather`` reports whether the fused just-in-time per-round
     gather schedule is active.
     """
+    from ..ops.packed_table import PackedLayout
     from ..parallel.lookup_engine import (class_buckets, class_param_name,
-                                          dense_class_traffic)
+                                          dense_class_traffic, padded_rows,
+                                          sparse_class_traffic)
     batch = self.batch_hint if global_batch is None else global_batch
     hotness_of = lambda i: (  # noqa: E731
         1 if self.input_hotness is None else self.input_hotness[i])
@@ -1179,13 +1264,19 @@ class DistEmbeddingStrategy:
           "dedup": bool(self.dedup_exchange and cp.kind == "sparse"
                         and self.world_size > 1),
       }
-      if cp.kind == "dense":
-        side = (None, None, None)
-        if batch is not None or self.world_size == 1:
-          side = dense_class_traffic(
-              self, key, class_buckets(self, key, hotness_of),
-              (batch or 0) // self.world_size, dp_input)
-        entry["moves"], entry["rows_bytes"], entry["tables_bytes"] = side
+      buckets = class_buckets(self, key, hotness_of)
+      entry["padded_slots"] = sum(b.n_b for b in buckets)
+      side = (None, None, None)
+      if batch is not None or self.world_size == 1:
+        b_local = (batch or 0) // self.world_size
+        if cp.kind == "dense":
+          side = dense_class_traffic(self, key, buckets, b_local, dp_input)
+        else:
+          side = sparse_class_traffic(
+              self, key, buckets, b_local, dp_input,
+              PackedLayout(rows=padded_rows(self, key), width=cp.width,
+                           n_aux=n_aux))
+      entry["moves"], entry["rows_bytes"], entry["tables_bytes"] = side
     pipelined = (self.overlap in ("pipelined", "fused")
                  and self.world_size > 1)
     return {

@@ -44,7 +44,15 @@ step and every rank looks its OWN samples up in all of the class's real
 tables; its ids, rows and cotangents leave the exchange altogether, and
 autodiff's reduce-scatter lands each owner's summed gradient on its own
 block (:meth:`DistributedLookup.tables_travel`). Placement, state and
-checkpoints are the row exchange's: only the step gathers.
+checkpoints are the row exchange's: only the step gathers. In the fused
+training step a sparse-kind class travels by the same byte rule, on its
+packed block: optimizer lanes and all it is all-gathered, the local
+samples read their fused rows from it by a row gather
+(:class:`LocalIds`), and their per-occurrence deltas, scatter-added into
+zeros of the gathered shape, are reduce-scattered home and added to the
+owner's block; the planner gives small sparse tables a class of their own
+where that saves every rank a padded slot
+(``DistEmbeddingStrategy._split_small_sparse_tables``).
 
 Every exchange rides :mod:`parallel.wire` (the sanctioned all_to_all /
 ppermute home, graftlint GL109): the plan knobs compress and hide the wire
@@ -240,6 +248,21 @@ def padded_rows(plan: DistEmbeddingStrategy, key) -> int:
   return rows
 
 
+def _padded_slot_rows(plan: DistEmbeddingStrategy, key,
+                      buckets: Sequence[Bucket]) -> int:
+  """Rows a sample that every rank ships for the class: the buckets'
+  padded slots, a sequence input's counted hotness times (its rows travel
+  side by side)."""
+  cp = plan.classes[key]
+  return sum(b.n_b * (b.h if cp.combiner is None and b.h > 1 else 1)
+             for b in buckets)
+
+
+def _row_value_bytes(plan: DistEmbeddingStrategy) -> int:
+  wd = wire.plan_wire_dtype(plan)
+  return 4 if wd is None else jnp.dtype(wd).itemsize
+
+
 def dense_class_traffic(plan: DistEmbeddingStrategy, key,
                         buckets: Sequence[Bucket], batch_local: int,
                         dp_input: bool = True):
@@ -251,14 +274,32 @@ def dense_class_traffic(plan: DistEmbeddingStrategy, key,
   rank runs every (hotness, window) bucket at ``n_b`` = the most slots any
   rank has in it, so a class of 15 tables over four ranks can ship 11
   slots a rank for 3.75 real ones."""
-  cp = plan.classes[key]
-  slots = sum(b.n_b * (b.h if cp.combiner is None and b.h > 1 else 1)
-              for b in buckets)
-  wd = wire.plan_wire_dtype(plan)
   return wire.dense_class_side(
-      plan.world_size, dp_input, slots, plan.world_size * batch_local,
-      padded_rows(plan, key), cp.width,
-      4 if wd is None else jnp.dtype(wd).itemsize)
+      plan.world_size, dp_input, _padded_slot_rows(plan, key, buckets),
+      plan.world_size * batch_local, padded_rows(plan, key),
+      plan.classes[key].width, _row_value_bytes(plan))
+
+
+def sparse_class_traffic(plan: DistEmbeddingStrategy, key,
+                         buckets: Sequence[Bucket], batch_local: int,
+                         dp_input: bool, layout: PackedLayout):
+  """:func:`dense_class_traffic` for a sparse-kind class packed as
+  ``layout``: the same rows' side, against the class's packed block,
+  ``phys_rows`` rows of ``phys_width`` lanes, optimizer lanes and all.
+
+  The rows stay, whatever the bytes, where the travelled form does not
+  exist: a row-sliced shard (an id outside a shard's window reads nothing
+  there, and the owner alone knows its window's partial sum), a ragged
+  value stream, a host-tier class (its device buffer is a cache, not the
+  table), the deduplicated exchange (which already ships a row once)."""
+  home = (plan.class_tiers.get(key) == "host"
+          or wire.plan_dedup_exchange(plan)
+          or any(b.rs or b.h < 0 for b in buckets))
+  return wire.dense_class_side(
+      plan.world_size, dp_input and not home,
+      _padded_slot_rows(plan, key, buckets), plan.world_size * batch_local,
+      layout.phys_rows, plan.classes[key].width, _row_value_bytes(plan),
+      layout.phys_width)
 
 
 def ragged_to_padded(ids: RaggedIds, max_hot: int) -> jax.Array:
@@ -380,6 +421,38 @@ class DedupRouted:
   def tree_unflatten(cls, aux, children):
     del aux
     return cls(*children)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class LocalIds:
+  """One bucket of a sparse-kind class whose TABLES travel
+  (:meth:`DistributedLookup.tables_travel`): the LOCAL samples' ids for
+  every real slot of the bucket on any rank, where a routed bucket holds
+  the global batch's ids for this rank's padded slots.
+
+  ``ids [n, B_local(, h)]`` address each slot's rows as its owner does
+  (row offset added, sentinel = the class's padded rows), so every
+  combiner sees the sentinel pattern it sees in a routed bucket; where the
+  rows are read from and written to the gathered block, slot ``i``'s ids
+  are re-based to its owner's rows (:meth:`DistributedLookup._gathered_ids`).
+  ``slots[i] = (owner rank, position in the owner's part of the bucket)``,
+  static: how :meth:`DistributedLookup.assemble` keys a slot.
+
+  A marker the data carries, not a flag: built once, by
+  :meth:`DistributedLookup.route_ids`, and every later stage (the gather,
+  the exchange it skips, the delta streams, the apply) follows the bucket
+  it is handed. Like :class:`DedupRouted`, deliberately not a tuple."""
+
+  ids: jax.Array
+  slots: tuple
+
+  def tree_flatten(self):
+    return (self.ids,), self.slots
+
+  @classmethod
+  def tree_unflatten(cls, aux, children):
+    return cls(children[0], aux)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -772,18 +845,34 @@ class DistributedLookup:
                          + slot.row_offset)
     return routed.astype(jnp.int32)
 
-  # ---- dense-kind classes: which side travels ----------------------------
-  def tables_travel(self, key, hotness_of, batch_local: int) -> bool:
-    """True where this dense-kind class's TABLES cross the mesh (the
-    class block all-gathered, looked up on the local samples) instead of
-    its ids and rows: :func:`dense_class_traffic` says that is fewer
-    bytes. Static per trace; never at world 1, never for model-parallel
-    inputs, never for a sparse-kind class."""
-    if self.plan.classes[key].kind != "dense":
+  # ---- small tables: which side travels ----------------------------------
+  def tables_travel(self, key, hotness_of, batch_local: int,
+                    layouts: Optional[Dict[str, PackedLayout]] = None
+                    ) -> bool:
+    """True where this class's TABLES cross the mesh (the class block
+    all-gathered, looked up on the local samples) instead of its ids and
+    rows: :func:`dense_class_traffic` says that is fewer bytes. Static per
+    trace; never at world 1, never for model-parallel inputs.
+
+    A sparse-kind class is asked about with the packed ``layouts`` it will
+    be gathered and updated in (:func:`sparse_class_traffic` on its own):
+    the travelled form reads fused rows and writes additive per-occurrence
+    deltas, so it exists in the fused training step alone. Without
+    ``layouts`` (the simple differentiable forward, a server's own gather,
+    an ``exact=True`` or summed update, which is applied once a distinct
+    row of the GLOBAL batch and so cannot be summed from ranks' parts) a
+    sparse-kind class keeps its rows."""
+    if self.plan.classes[key].kind == "dense":
+      side = dense_class_traffic(
+          self.plan, key, self._buckets(key, hotness_of), batch_local,
+          self.dp_input)
+    elif layouts is None:
       return False
-    return dense_class_traffic(
-        self.plan, key, self._buckets(key, hotness_of), batch_local,
-        self.dp_input)[0] == "tables"
+    else:
+      side = sparse_class_traffic(
+          self.plan, key, self._buckets(key, hotness_of), batch_local,
+          self.dp_input, layouts[class_param_name(*key)])
+    return side[0] == "tables"
 
   @staticmethod
   def _real_slots(bucket: Bucket):
@@ -806,6 +895,28 @@ class DistributedLookup:
         self._routed_slot(cp.slots_per_rank[rank][idx], bucket.h, inputs,
                           sentinel)
         for rank, _, idx in self._real_slots(bucket)])
+
+  def _gathered_layout(self, layout: PackedLayout) -> PackedLayout:
+    """``layout`` of one rank's packed block -> the layout of all ranks'
+    blocks as :func:`wire.gather_tables` stacks them: ``world *
+    phys_rows`` physical rows, rank r's logical rows from ``r * phys_rows
+    * rows_per_phys`` (a block's last physical row may be part filled;
+    the next block starts on a physical row all the same)."""
+    return dataclasses.replace(
+        layout, rows=(self.plan.world_size * layout.phys_rows
+                      * layout.rows_per_phys))
+
+  def _gathered_ids(self, ids: jax.Array, slots: tuple, key,
+                    layout: PackedLayout) -> jax.Array:
+    """A :class:`LocalIds` bucket's ``ids [n, ...]``, each slot's re-based
+    to its owner's rows of the gathered block (:meth:`_gathered_layout`);
+    PAD ids at that layout's ``rows``, out of range as a sentinel is. An
+    add of a constant a slot: no index into the gathered block is static."""
+    block = layout.phys_rows * layout.rows_per_phys
+    base = np.array([rank * block for rank, _ in slots], np.int32)
+    base = base.reshape((-1,) + (1,) * (ids.ndim - 1))
+    return jnp.where(ids < padded_rows(self.plan, key), ids + base,
+                     self._gathered_layout(layout).rows)
 
   def _build_ragged_routing(self, key, bucket: Bucket, inputs):
     """Value-stream routing for a ragged bucket.
@@ -867,16 +978,20 @@ class DistributedLookup:
     return jnp.stack(all_vals), jnp.stack(all_lens)
 
   @jax.named_scope(scopes.ROUTE)
-  def route_ids(self, inputs: Sequence[jax.Array],
-                hotness_of=None) -> Dict[tuple, jax.Array]:
+  def route_ids(self, inputs: Sequence[jax.Array], hotness_of=None,
+                layouts: Optional[Dict[str, PackedLayout]] = None
+                ) -> Dict[tuple, jax.Array]:
     """dp->mp id exchange: per bucket, global-batch ids for my local tables.
 
     Returns ``bk -> [n_b, G, h]`` (bk = (class_key, h, vcap)); G = world * B.
     The all_to_all here is the reference's first Horovod exchange
     (`dist_model_parallel.py:414-423`) with splits made uniform by padding.
-    A dense-kind class whose tables travel (:meth:`tables_travel`) exchanges
-    nothing here: its buckets hold ``[n, B(, h)]``, the local samples' ids
-    for every real slot of any rank (:meth:`_build_local_ids`).
+    A class whose tables travel (:meth:`tables_travel`) exchanges nothing
+    here: its buckets hold ``[n, B(, h)]``, the local samples' ids for
+    every real slot of any rank (:meth:`_build_local_ids`), a dense-kind
+    class's as the array, a sparse-kind class's as :class:`LocalIds`.
+    ``layouts``: the packed layouts the caller gathers and updates the
+    sparse classes in; only with them can a sparse-kind class travel.
 
     Out-of-vocabulary ids: the routing clamps ``ids >= input_dim`` to the
     table's last row (reference numeric semantics) under the plan's
@@ -904,11 +1019,14 @@ class DistributedLookup:
 
     ids_all: Dict[tuple, jax.Array] = {}
     for key in plan.class_keys:
-      tables = self.tables_travel(key, hotness_of, b)
+      tables = self.tables_travel(key, hotness_of, b, layouts)
       for bucket in self._buckets(key, hotness_of):
         if tables:  # the local samples' ids stay here: [n, B(, h)]
-          ids_all[bucket_key(key, bucket.h, bucket.vcap, bucket.rs)] = \
-              self._build_local_ids(key, bucket, inputs)
+          local = self._build_local_ids(key, bucket, inputs)
+          if plan.classes[key].kind == "sparse":
+            local = LocalIds(local, tuple(
+                (rank, pos) for rank, pos, _ in self._real_slots(bucket)))
+          ids_all[bucket_key(key, bucket.h, bucket.vcap, bucket.rs)] = local
           continue
         x = self._build_routing(key, bucket, inputs)  # [world, n_b, B(, h)]
         if bucket.h < 0:  # ragged: (vals [world,n_b,V], lens [world,n_b,B])
@@ -1156,8 +1274,14 @@ class DistributedLookup:
 
   def _z_sparse_fused(self, key, layout: PackedLayout, buf_local: jax.Array,
                       ids_all: jax.Array, rs: bool = False,
-                      keep_rows: bool = False):
+                      keep_rows: bool = False,
+                      rows_at: Optional[jax.Array] = None):
     """Fused gather: returns (z, fused_rows) — optimizer state rides along.
+
+    ``rows_at``: where ``ids_all``'s rows lie in ``buf_local``, if not at
+    the ids themselves (a travelled class: ``buf_local`` is every rank's
+    block, ``ids_all`` address each owner's own; the combine still reads
+    the sentinel pattern from ``ids_all``).
 
     The combine sums the FULL fused stride (table + aux lanes together) and
     slices the table half at bag granularity; the per-occurrence residual is
@@ -1181,6 +1305,7 @@ class DistributedLookup:
       aux = fused if (layout.n_aux or keep_rows) else fused[..., w:]
       return self._combine_ragged(fused[..., :w], vals, lens, key, rs), aux
     sequence = self.plan.classes[key].combiner is None
+    at = ids_all if rows_at is None else rows_at
     if (layout.rows_per_phys > 1 and layout.n_aux and ids_all.ndim == 3
         and ids_all.shape[-1] > 1 and not sequence):
       # Multi-hot narrow class: keep the whole pipeline at PHYSICAL width.
@@ -1190,7 +1315,7 @@ class DistributedLookup:
       # of extracting once per occurrence (the extraction adds measured
       # ~14 ms/step on Tiny's traces). The residual is the masked
       # phys-width rows; the apply folds their aux halves per occurrence.
-      masked = gather_fused_chunked(layout, buf_local, ids_all,
+      masked = gather_fused_chunked(layout, buf_local, at,
                                     masked_phys=True)
       cp = self.plan.classes[key]
       bag = jnp.sum(masked, axis=2)  # [n_b, G, rpp*stride]
@@ -1203,7 +1328,7 @@ class DistributedLookup:
         counts = jnp.sum(ids_all < sentinel, axis=2).astype(z.dtype)
         z = z / jnp.maximum(counts, 1)[..., None]
       return z, masked
-    fused = gather_fused_chunked(layout, buf_local, ids_all)  # [n_b,G,h,stride]
+    fused = gather_fused_chunked(layout, buf_local, at)  # [n_b,G,h,stride]
     if layout.n_aux == 0:
       # stride == width: no aux lanes ride along; keep_rows saves the full
       # rows anyway (the weight-decay delta needs the forward-time row)
@@ -1822,16 +1947,35 @@ class DistributedLookup:
     :class:`FusedChunks` of per-round just-in-time gathers instead of
     one monolithic array (:meth:`_z_sparse_fused_jit`); the residual aux
     rows keep their standard layouts either way, so everything
-    downstream of the cotangent reassembly is schedule-blind."""
+    downstream of the cotangent reassembly is schedule-blind.
+
+    A :class:`LocalIds` bucket (its class's tables travel) is read from
+    the all-gathered packed block instead: ``z[bk] = [n, B_local, w]``,
+    the local samples' combined rows for every real slot, which
+    :meth:`finish_forward` hands to :meth:`assemble` without an exchange;
+    its residual rows are the same fused rows, per local occurrence."""
     jit_gather = self._fused_wire()
     z: Dict[tuple, jax.Array] = {}
     aux: Dict[tuple, jax.Array] = {}
+    gathered: Dict[str, jax.Array] = {}
     for bk, ids in ids_all.items():
       key = bk.class_key
       if self.plan.classes[key].kind != "sparse":
         continue
       name = class_param_name(*key)
       buf_local = self._squeeze_local(fused_params[name])
+      if isinstance(ids, LocalIds):
+        # the class's tables travel: every rank's packed block comes here
+        # (once a class, however many buckets read it), and the local
+        # samples gather their fused rows from it; z is [n, B_local, w]
+        if name not in gathered:
+          with jax.named_scope(scopes.EXCHANGE):
+            gathered[name] = wire.gather_tables(buf_local, self.axis_name)
+        z[bk], aux[bk] = self._z_sparse_fused(
+            key, self._gathered_layout(layouts[name]), gathered[name], ids.ids, bk.rs, keep_rows=keep_rows,
+            rows_at=self._gathered_ids(ids.ids, ids.slots, key,
+                                       layouts[name]))
+        continue
       if jit_gather:
         zb, auxb = self._z_sparse_fused_jit(key, layouts[name], buf_local,
                                             ids, bk.rs,
@@ -1860,7 +2004,14 @@ class DistributedLookup:
     :meth:`apply_sparse` pre-divided."""
     z_rows, z_here = self._lookup_dense(dense_params, ids_all, batch_local,
                                         hotness_of, self.dense_remat)
-    received = self.exchange({**z_sparse, **z_rows}, batch_local, ids_all)
+    to_cross = {}
+    for bk, zb in z_sparse.items():
+      local = ids_all.get(bk)
+      if isinstance(local, LocalIds):  # looked up here: nothing to cross
+        z_here[bk] = {slot: zb[i] for i, slot in enumerate(local.slots)}
+      else:
+        to_cross[bk] = zb
+    received = self.exchange({**to_cross, **z_rows}, batch_local, ids_all)
     received.update(z_here)
     return self.assemble(received, hotness_of, mean_counts)
 
@@ -1944,13 +2095,20 @@ class DistributedLookup:
         row = part if row is None else row + part
     return g + (2.0 * rule.weight_decay) * row.reshape(g.shape)
 
-  def _sparse_parts_by_class(self, d_z, residuals, rule) -> Dict[str, list]:
+  def _sparse_parts_by_class(self, d_z, residuals, rule, layouts):
     """Group per-bucket cotangents into per-class ``(ids, dz, aux, h)``
     parts: ragged buckets expand to per-occurrence rows (h=0 marks them),
     mean combiners divide by the forward's valid counts. Shared by
-    :meth:`apply_sparse` and :meth:`sparse_delta_streams`."""
+    :meth:`apply_sparse` and :meth:`sparse_delta_streams`.
+
+    Returns ``(by_class, travelled)``. ``travelled``: the classes whose
+    buckets are :class:`LocalIds` (all of a class's are, or none). Their
+    parts hold the LOCAL samples' occurrences, ids re-based to the
+    gathered block (:meth:`_gathered_ids`): what :meth:`_travelled_delta`
+    scatters."""
     plan = self.plan
     by_class: Dict[str, list] = {}
+    travelled = set()
     for bk, dzb in d_z.items():
       key, h = bk.class_key, bk.h
       if plan.classes[key].kind != "sparse":
@@ -1974,6 +2132,12 @@ class DistributedLookup:
       cp = plan.classes[key]
       name = class_param_name(*key)
       ids = residuals.ids_all[bk]  # [n_b, G, h] | ragged | DedupRouted
+      placed = lambda x: x  # noqa: E731
+      if isinstance(ids, LocalIds):  # [n, B_local, h], its owners' rows
+        travelled.add(name)
+        placed = functools.partial(self._gathered_ids, slots=ids.slots,
+                                   key=key, layout=layouts[name])
+        ids = ids.ids
       sentinel = padded_rows(plan, key)
       aux = (residuals.aux_rows[bk]
              if (rule.n_aux or rule.weight_decay) else None)
@@ -2014,15 +2178,15 @@ class DistributedLookup:
         # a sequence input: the cotangent [n_b, G, h * w] already holds one
         # row per occurrence (h=0 marks pre-expanded parts)
         by_class.setdefault(name, []).append(
-            (ids.reshape(-1), dzb.reshape(-1, cp.width), aux, 0))
+            (placed(ids).reshape(-1), dzb.reshape(-1, cp.width), aux, 0))
         continue
       if cp.combiner == "mean" and h > 1 and not bk.rs:
         # row-sliced buckets skip this: their mean division lives in the
         # differentiable assemble, so d_z arrives pre-divided
         counts = jnp.sum(ids < sentinel, axis=2).astype(dzb.dtype)
         dzb = dzb / jnp.maximum(counts, 1)[..., None]
-      by_class.setdefault(name, []).append((ids, dzb, aux, h))
-    return by_class
+      by_class.setdefault(name, []).append((placed(ids), dzb, aux, h))
+    return by_class, travelled
 
   def _pallas_delta_rows(self, layout, ids, dzb, aux, h, rule, step):
     """Gate + dispatch for the Pallas delta-build kernel
@@ -2108,9 +2272,48 @@ class DistributedLookup:
     scattering once reproduces the one-shot step's numerics exactly —
     the memory win is that the per-occurrence gather/extract/backward
     temporaries only ever exist for one micro-batch at a time."""
-    by_class = self._sparse_parts_by_class(d_z, residuals, rule)
-    return {name: self._stream_of_parts(layouts[name], parts, rule, step)
-            for name, parts in by_class.items()}
+    by_class, travelled = self._sparse_parts_by_class(d_z, residuals, rule,
+                                                      layouts)
+    streams = {}
+    for name, parts in by_class.items():
+      layout = layouts[name]
+      if name in travelled:
+        # the owner's summed block delta as a stream of its own: one
+        # "occurrence" a physical row, each row already whole, so the
+        # guard's gate, the micro-batch stack and the one scatter of
+        # :meth:`apply_sparse_streams` take it as they take any other
+        streams[name] = (
+            jnp.arange(layout.phys_rows, dtype=jnp.int32)
+            * layout.rows_per_phys,
+            self._travelled_delta(layout, parts, rule, step))
+      else:
+        streams[name] = self._stream_of_parts(layout, parts, rule, step)
+    return streams
+
+  def _travelled_delta(self, layout: PackedLayout, parts, rule: SparseRule,
+                       step: jax.Array) -> jax.Array:
+    """The update of THIS rank's block of a class whose tables travelled:
+    ``[phys_rows, phys_width]``, to be added (a scale-only rule's times
+    ``rule.linear_scale(step)`` first, as its stream's rows are).
+
+    ``parts`` hold the local samples' occurrences for every table of the
+    class on any rank. Their per-occurrence deltas are what the owner
+    would have built from the same cotangents and the same forward-saved
+    state (:meth:`_stream_of_parts`); they are scatter-added into zeros
+    of the gathered shape (as many occurrences as rows, or more: XLA's
+    fast scatter regime by :meth:`_kernel_regime`'s own ratio), and
+    :func:`wire.scatter_tables` lands on every owner the sum over ranks
+    of its own rows. Deltas are additive, so every per-occurrence rule
+    keeps its result up to float32 summation order."""
+    glayout = self._gathered_layout(layout)
+    ids_cat, rows_cat = self._stream_of_parts(glayout, parts, rule, step)
+    if rule.linear_scale is None:  # as apply_sparse_streams: keep the delta
+      # computation out of the scatter's update loop
+      ids_cat, rows_cat = lax.optimization_barrier((ids_cat, rows_cat))
+    summed = scatter_add_fused(
+        glayout, jnp.zeros(glayout.shape, rows_cat.dtype), ids_cat, rows_cat)
+    with jax.named_scope(scopes.EXCHANGE):
+      return wire.scatter_tables(summed, self.axis_name)
 
   @staticmethod
   def _kernel_regime(n_ids: int, layout: PackedLayout) -> bool:
@@ -2228,14 +2431,27 @@ class DistributedLookup:
     """
     from ..ops.sparse_grad import dedup_rows
 
-    plan = self.plan
-    by_class = self._sparse_parts_by_class(d_z, residuals, rule)
+    by_class, travelled = self._sparse_parts_by_class(d_z, residuals, rule,
+                                                      layouts)
+    if travelled and (exact or rule.summed):
+      raise ValueError(
+          f"classes {sorted(travelled)} were looked up on travelled tables "
+          "(route_ids(..., layouts=...)), whose update is the sum of the "
+          "ranks' per-occurrence deltas; exact=True and summed rules apply "
+          "once a distinct row of the global batch. Route without layouts.")
 
     new_params = dict(fused_params)
     for name, parts in by_class.items():
       layout = layouts[name]
       w = layout.width
       buf = self._squeeze_local(fused_params[name])
+      if name in travelled:
+        delta = self._travelled_delta(layout, parts, rule, step)
+        if rule.linear_scale is not None:
+          delta = jnp.asarray(rule.linear_scale(step)).astype(
+              delta.dtype) * delta
+        new_params[name] = buf + delta.astype(buf.dtype)
+        continue
       if exact:
         # class-level dedup (cross-bucket duplicates of shared tables must
         # merge) — the reference's sorted/unique semantics

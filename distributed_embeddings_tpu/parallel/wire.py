@@ -66,14 +66,18 @@ Four plan knobs (``DistEmbeddingStrategy``) govern the format:
   block's row count — fp8 scales are still one per (destination block,
   chunk), now computed over each just-gathered row chunk.
 
-One choice is not a knob. A dense-kind (MXU one-hot) class crosses the
-mesh either as ROWS (ids dp->mp, looked-up rows mp->dp, cotangents back:
-the exchanges above) or as TABLES (:func:`gather_tables`: the class block
+One choice is not a knob. A class of small tables crosses the mesh either
+as ROWS (ids dp->mp, looked-up rows mp->dp, cotangents back: the
+exchanges above) or as TABLES (:func:`gather_tables`: the class block
 all-gathered forward, its gradient reduce-scattered backward, the lookup
 run on each rank's own samples, no padded slot). :func:`dense_class_side`
 counts both in bytes leaving a chip each way a step, from static shapes,
 and the engine takes the smaller; the knobs above then govern only what
-still crosses as rows.
+still crosses as rows. Dense-kind (MXU one-hot) classes, and in the fused
+training step sparse-kind classes too: their packed block, optimizer
+lanes and all, is gathered, read by a row gather on the local samples,
+and the per-occurrence deltas, scatter-added locally into zeros of the
+gathered shape, come home through :func:`scatter_tables`.
 
 With ``world_size == 1`` there is no wire: nothing is exchanged, nothing
 is narrowed, and every knob is inert (numerics stay bit-identical to the
@@ -83,6 +87,7 @@ single-device f32 path).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -146,8 +151,9 @@ def plan_exchange_chunks(plan) -> int:
 
 def dense_class_side(world: int, dp_input: bool, padded_slots: int,
                      global_batch: int, class_rows: int, width: int,
-                     row_value_bytes: int = 4):
-  """Which side of a dense-kind (MXU one-hot) class crosses the mesh.
+                     row_value_bytes: int = 4,
+                     table_width: Optional[int] = None):
+  """Which side of a class of small tables crosses the mesh.
 
   A small table can be looked up where it is owned, its rows crossing
   the mesh (ids dp->mp, ``[slot, sample, width]`` rows mp->dp, their
@@ -162,9 +168,17 @@ def dense_class_side(world: int, dp_input: bool, padded_slots: int,
     SPMD-uniform, so every rank ships the LARGEST slot count of any rank
     per (hotness, window) bucket (a sequence input counts its hotness
     times, its rows travel side by side);
-  - tables: ``(world - 1) * class_rows * width`` float32 values (the
-    table is not narrowed: that would change every row read, not only
-    what is in flight).
+  - tables: ``(world - 1) * class_rows * table_width`` float32 values
+    (the table is not narrowed: that would change every row read, not
+    only what is in flight).
+
+  The name is from the dense (MXU one-hot) kind, which came first; a
+  sparse-kind class is counted the same way on its PACKED block, whose
+  rows are what its all-gather carries: ``class_rows`` physical rows of
+  ``table_width`` lanes, optimizer lanes and all (``PackedLayout``'s
+  ``phys_rows`` and ``phys_width``; ``table_width`` defaults to
+  ``width``, the dense kind's plain ``[rows, width]`` block), against
+  the same ``width`` values a sample that a slot ships.
 
   Returns ``(side, rows_bytes, tables_bytes)``; ``side`` is ``"tables"``
   where that is strictly cheaper. Rows travel whenever there is nothing
@@ -173,7 +187,8 @@ def dense_class_side(world: int, dp_input: bool, padded_slots: int,
   its local samples' ids for the others)."""
   rows_bytes = (padded_slots * global_batch * width * row_value_bytes
                 * (world - 1)) // world
-  tables_bytes = (world - 1) * class_rows * width * 4
+  tables_bytes = ((world - 1) * class_rows
+                  * (width if table_width is None else table_width) * 4)
   tables = world > 1 and dp_input and tables_bytes < rows_bytes
   return ("tables" if tables else "rows"), rows_bytes, tables_bytes
 
@@ -186,6 +201,11 @@ def gather_tables(block: jax.Array, axis_name: str) -> jax.Array:
   owner's summed gradient on its own block; no custom rule, no narrowing
   (see :func:`dense_class_side`).
 
+  A sparse-kind class comes through here as its packed block
+  ``[phys_rows, phys_width]``, optimizer lanes beside the rows, outside
+  autodiff: the local samples gather their fused rows from the result at
+  ``owner * phys_rows + row``, and the way back is :func:`scatter_tables`.
+
   Two-dimensional on purpose. As ``[world, rows, w]`` the windows the
   one-hot lookup stacks are ``x[r, o : o + n]``, and the TPU compiler of
   this installation merges two such slices of neighbouring ranks into ONE
@@ -194,6 +214,18 @@ def gather_tables(block: jax.Array, axis_name: str) -> jax.Array:
   CPU compiler does not). Slices of the flat rows are not merged
   (``tests/test_dense_table_gather.py`` compiles them for the chip)."""
   return lax.all_gather(block, axis_name, axis=0, tiled=True)
+
+
+def scatter_tables(gathered: jax.Array, axis_name: str) -> jax.Array:
+  """``[world * rows, w]``, one rank's additive contribution to every
+  rank's block, laid out as :func:`gather_tables` returns them -> ``[rows,
+  w]``, the sum over ranks of the contributions to THIS rank's block. What
+  autodiff writes as the transpose of :func:`gather_tables`, by hand for
+  the sparse-kind classes, whose update is built outside autodiff: every
+  rank scatter-adds its local samples' per-occurrence deltas into zeros of
+  the gathered shape, and the owner adds the sum to its block."""
+  return lax.psum_scatter(gathered, axis_name, scatter_dimension=0,
+                          tiled=True)
 
 
 def exchange_ids(x: jax.Array, axis_name: str) -> jax.Array:

@@ -690,10 +690,17 @@ def _refuse_unsupported(builder: str, plan: DistEmbeddingStrategy, *,
   return exact
 
 
-def _make_step_forward(engine: DistributedLookup, model):
+def _make_step_forward(engine: DistributedLookup, model,
+                       per_occurrence: bool = True):
   """The sparse step's forward half: all the eval step runs, and what the
   train steps differentiate the rest of (``forward_backward`` of
-  :func:`_make_train_step_pieces`)."""
+  :func:`_make_train_step_pieces`).
+
+  ``per_occurrence``: the update this forward feeds, if any, is a sum of
+  per-occurrence deltas, so the engine may let a small sparse class's
+  TABLES travel (``DistributedLookup.tables_travel``: it is handed the
+  packed layouts); an ``exact`` or summed update keeps every sparse
+  class's rows."""
 
   def forward(fused, layouts, numerical, cats, keep_rows=False,
               rewrite_ids=None):
@@ -705,7 +712,8 @@ def _make_step_forward(engine: DistributedLookup, model):
     b = numerical.shape[0]
     hotness = [ragged_hotness(c) for c in cats]
     hotness_of = lambda i: hotness[i]  # noqa: E731
-    ids_all = engine.route_ids(cats, hotness_of)
+    ids_all = engine.route_ids(cats, hotness_of,
+                               layouts if per_occurrence else None)
     counts = engine.mean_counts(cats)
     if rewrite_ids is not None:
       ids_all = rewrite_ids(ids_all)
@@ -816,7 +824,7 @@ def _make_train_step_pieces(engine: DistributedLookup, model,
   metrics (the tiered step's, whose keys predate it)."""
   from .resilience import guards as _guards
   plan = engine.plan
-  forward = _make_step_forward(engine, model)
+  forward = _make_step_forward(engine, model, per_occurrence=not exact)
   guard_gate, oov_ok, guard_metrics = _make_guard_helpers(
       plan, mesh, axis_name)
   has_dedup_cap = getattr(plan, "dedup_capacity", None) is not None

@@ -110,7 +110,7 @@ def build_plan(plan):
 
 
 @pytest.mark.parametrize("piece,anchor,planted,rule", [
-    ("forward", "ids_all = engine.route_ids(cats, hotness_of)",
+    ("forward", "ids_all = engine.route_ids(cats, hotness_of,",
      "ids_all.block_until_ready()", "GL101"),
     ("predict", "acts = engine.finish_forward(",
      "np.asarray(z_sp)", "GL102"),
