@@ -1,0 +1,10 @@
+"""mlp_ms: what it measures is in ``mlp_ms.json``; the reduction is
+``benchmark/scope_children_hybrid.py``."""
+
+from benchmark import scope_children_hybrid
+
+SCOPES = ('de_mlp',)
+
+
+def read(red, ctx):
+  return scope_children_hybrid.scope_ms(red, ctx, *SCOPES)

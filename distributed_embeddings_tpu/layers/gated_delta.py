@@ -1,0 +1,179 @@
+"""The gated delta rule in chunked form, and the short causal convolution
+that feeds it, over sequences in which several documents are packed.
+
+Per head, with a state ``S [d_k, d_v]`` that starts at 0, the rule reads
+
+    S'_t = alpha_t S_{t-1}          (S'_t = 0 at a document's first token)
+    S_t  = S'_t + beta_t k_t (v_t - S'_t^T k_t)^T
+    o_t  = S_t^T q_t
+
+with ``alpha_t = exp(g_t)``. A token at a time that is ``L`` dependent steps
+of a matrix-vector product; :func:`chunk_gated_delta_rule` computes the same
+numbers ``chunk`` tokens at a time (the WY / UT-transform form of Yang et al.,
+"Gated Delta Networks", as ``fla``'s ``chunk_gated_delta_rule`` does).
+
+Inside a chunk, with ``gamma_i`` the running sum of ``g`` from the chunk's
+first token and ``w_i = beta_i (v_i - S'_i^T k_i)``, unrolling gives
+``S_i = e^{gamma_i} S_0 + sum_{j<=i} e^{gamma_i - gamma_j} k_j w_j^T`` and so
+``(I + A) W = diag(beta) (V - diag(e^gamma) K S_0)`` with the strictly lower
+triangular ``A_ij = beta_i e^{gamma_i - gamma_j} (k_i . k_j)``: one unit
+triangular solve a chunk gives ``U = T diag(beta) V`` and
+``Wk = T diag(beta e^gamma) K`` (``T = (I + A)^-1``), ``W = U - Wk S_0``. Then
+
+    O       = P U + (diag(e^gamma) Q - P Wk) S_0,  P_ij = e^{gamma_i-gamma_j} (q_i . k_j), j <= i
+    S_next  = (e^{gamma_C} I - Kd^T Wk) S_0 + Kd^T U,  Kd_i = e^{gamma_C - gamma_i} k_i
+
+so everything but the last line is computed for all chunks at once, and what
+runs chunk after chunk is ``S <- M_n S + B_n`` (:func:`linear_state_scan`:
+one ``[d_k, d_k] x [d_k, d_v]`` product a step and a head; its backward is
+written out, keeps the per-chunk states once, as the forward's own output,
+and recomputes nothing).
+
+A document's first token resets the state. In the chunked form that is a
+mask: a pair ``(i, j)`` counts only inside one document, the chunk's incoming
+state only reaches the tokens before the chunk's first reset, and only the
+tokens after its last reset reach the outgoing state. ``g`` at a reset is
+never read.
+
+Every product of the rule runs at ``highest`` matmul precision: the state
+carries rounding across thousands of tokens, and the rule is a hundredth of
+a layer's arithmetic (the projections around it are the caller's, at the
+caller's precision).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ..telemetry import scopes
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def segment_ids(starts):
+  """``starts [B, L]`` bool, true at a document's first token (position 0
+  always is one) -> the document's number at every position, ``[B, L]``
+  int32, from 0."""
+  starts = starts.at[:, 0].set(True)
+  return jnp.cumsum(starts.astype(jnp.int32), axis=1) - 1
+
+
+def causal_conv(x, w, seg):
+  """Depthwise causal convolution over time: ``x [B, L, C]``, ``w [K, C]``
+  (``w[K-1]`` multiplies the token itself), ``y_t = sum_j w_j x_{t-(K-1)+j}``.
+  A tap that would read before the document's first token reads 0."""
+  taps = w.shape[0]
+  out = x * w[taps - 1]
+  for back in range(1, taps):
+    shifted = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :x.shape[1]]
+    # -1 is no document's number: the padding is outside every document
+    same = jnp.pad(seg, ((0, 0), (back, 0)), constant_values=-1
+                   )[:, :x.shape[1]] == seg
+    out = out + jnp.where(same[..., None], shifted, 0) * w[taps - 1 - back]
+  return out
+
+
+@jax.custom_vjp
+def linear_state_scan(m, b, s0):
+  """``S_{n+1} = M_n S_n + B_n`` from ``S_0 = s0``: ``m [N, ..., dk, dk]``,
+  ``b [N, ..., dk, dv]`` -> (``[N, ..., dk, dv]`` the state BEFORE each
+  step, the state after the last)."""
+  def step(s, mb):
+    return jnp.matmul(mb[0], s, precision=_HIGHEST) + mb[1], s
+  last, before = jax.lax.scan(step, s0, (m, b))
+  return before, last
+
+
+def _scan_fwd(m, b, s0):
+  before, last = linear_state_scan(m, b, s0)
+  return (before, last), (m, before)
+
+
+def _scan_bwd(res, cts):
+  m, before = res
+  d_before, d_last = cts
+
+  def step(lam, x):
+    m_n, s_n, d_n = x
+    d_m = jnp.matmul(lam, jnp.swapaxes(s_n, -1, -2), precision=_HIGHEST)
+    back = jnp.matmul(jnp.swapaxes(m_n, -1, -2), lam, precision=_HIGHEST)
+    return back + d_n, (d_m, lam)
+  d_s0, (d_m, d_b) = jax.lax.scan(step, d_last, (m, before, d_before),
+                                  reverse=True)
+  return d_m, d_b, d_s0
+
+
+linear_state_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def chunk_gated_delta_rule(q, k, v, g, beta, seg, chunk: int = 64):
+  """``q, k [B, L, H, dk]`` (``q`` scaled and ``k`` normalised by the
+  caller), ``v [B, L, H, dv]``, ``g [B, L, H]`` the log of the decay,
+  ``beta [B, L, H]``, ``seg [B, L]`` the document's number (it never falls)
+  -> (``o [B, L, H, dv]``, the state after the last token
+  ``[B, H, dk, dv]``). ``L`` need not be a multiple of ``chunk``."""
+  with jax.named_scope(scopes.DELTA_RULE):
+    return _chunked(q, k, v, g, beta, seg, chunk)
+
+
+def _chunked(q, k, v, g, beta, seg, chunk):
+  b, length, h, dk = q.shape
+  dv = v.shape[-1]
+  n = -(-length // chunk)
+  pad = n * chunk - length
+  if pad:
+    # a padded token writes nothing (k, beta 0), decays nothing (g 0) and
+    # belongs to the last document
+    tail = lambda x: jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    q, k, v, g, beta = (tail(x) for x in (q, k, v, g, beta))
+    seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
+  mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+  dt = jnp.promote_types(q.dtype, jnp.float32)   # never below float32
+  # [B, N, H, C, ...]
+  heads = lambda x: jnp.moveaxis(
+      x.reshape((b, n, chunk) + x.shape[2:]), 3, 2).astype(dt)
+  q, k, v = heads(q), heads(k), heads(v)
+  g, beta = heads(g), heads(beta)                          # [B, N, H, C]
+  seg = seg.reshape(b, n, chunk)
+  before = jnp.pad(seg.reshape(b, -1), ((0, 0), (1, 0)),
+                   constant_values=-1)[:, :-1].reshape(b, n, chunk)
+  reset = (seg != before)[:, :, None, :]                   # [B, N, 1, C]
+  gamma = jnp.cumsum(jnp.where(reset, 0.0, g), axis=-1)    # [B, N, H, C]
+  # a pair counts inside one document; the incoming state reaches a token
+  # with no reset at or before it in the chunk; a token reaches the outgoing
+  # state with no reset after it
+  same = (seg[..., :, None] == seg[..., None, :])[:, :, None]
+  incoming = (seg == before[..., :1])[:, :, None, :]
+  outgoing = (seg == seg[..., -1:])[:, :, None, :]
+  lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+  pair = same & lower
+  # e^{gamma_i - gamma_j}, j <= i: the exponent is masked before exp, whose
+  # other half would overflow
+  decay = jnp.where(pair, jnp.exp(jnp.where(
+      pair, gamma[..., :, None] - gamma[..., None, :], 0.0)), 0.0)
+  k_t = jnp.swapaxes(k, -1, -2)
+  a = beta[..., None] * decay * mm(k, k_t) * jnp.tril(
+      jnp.ones((chunk, chunk), dt), -1)
+  into = jnp.where(incoming, jnp.exp(gamma), 0.0)          # e^{gamma_i}
+  rhs = jnp.concatenate([beta[..., None] * v,
+                         (beta * into)[..., None] * k], axis=-1)
+  # (I + A) X = rhs: the diagonal is taken as 1 and never read
+  solved = jax.lax.linalg.triangular_solve(
+      a, rhs, left_side=True, lower=True, unit_diagonal=True)
+  u, wk = solved[..., :dv], solved[..., dv:]               # [.., C, dv|dk]
+  p = decay * mm(q, k_t)
+  kd_t = jnp.swapaxes(k * jnp.where(
+      outgoing, jnp.exp(gamma[..., -1:] - gamma), 0.0)[..., None], -1, -2)
+  # the incoming state outlives a chunk that holds no reset
+  carried = jnp.where(incoming[..., -1], jnp.exp(gamma[..., -1]), 0.0)
+  m = carried[..., None, None] * jnp.eye(dk, dtype=dt) - mm(kd_t, wk)
+  states, last = linear_state_scan(
+      jnp.moveaxis(m, 1, 0), jnp.moveaxis(mm(kd_t, u), 1, 0),
+      jnp.zeros((b, h, dk, dv), dt))
+  states = jnp.moveaxis(states, 0, 1)                      # [B, N, H, dk, dv]
+  o = mm(p, u) + mm(into[..., None] * q - mm(p, wk), states)
+  o = jnp.moveaxis(o, 2, 3).reshape(b, n * chunk, h, dv)[:, :length]
+  return o, last

@@ -1,0 +1,289 @@
+"""Olmo-Hybrid (``model_type`` ``olmo_hybrid``): a decoder whose mixers are
+mostly recurrent, three gated-delta-rule layers to one full-attention layer,
+on the training path over sequences of packed documents.
+
+Per layer, on a residual stream ``x [B, L, d]``, as the family's Olmo 2 /
+Olmo 3 blocks do it: ``x += RMSNorm(mixer(x))``, then
+``x += RMSNorm(mlp(x))``; a sublayer's OUTPUT is normalised, its input is not.
+``layer_types`` names each layer's mixer.
+
+*Linear attention* (the gated delta rule). From the input ``u``:
+``q~, k~ = u Wq, u Wk`` (``H x d_k``), ``v~, z = u Wv, u Wg`` (``H x d_v``),
+``b, a = u Wb, u Wa`` (``H``). Each channel of ``q~, k~, v~`` passes a causal
+depthwise convolution of ``linear_conv_kernel_dim`` taps and SiLU. Per head
+``q = l2norm(q~) d_k^-1/2``, ``k = l2norm(k~)``; ``beta = sigmoid(b)``, times 2
+under ``linear_allow_neg_eigval``; ``g = -exp(A_log) softplus(a + dt_bias)``.
+The rule itself is :func:`..layers.gated_delta.chunk_gated_delta_rule`; its
+output goes through ``RMSNorm_{d_v}(o) * SiLU(z)`` and ``Wo``.
+
+*Full attention*: ``q, k, v = u Wq, u Wk, u Wv`` without bias, RMSNorm on
+``q`` and on ``k`` across all the channels held, no rotary embedding (the
+published ``rope_theta`` is ``null``: position comes from the recurrent
+layers), causal attention at scale ``head_dim^-1/2`` inside a document, ``Wo``.
+On a TPU that is JAX's splash-attention kernel under a causal mask with the
+documents as segment ids; without a TPU the model raises, and
+``attention="xla"`` names the other path for tests on any backend.
+
+*MLP*: ``(SiLU(u Wgate) * (u Wup)) Wdown``.
+
+*A head share.* Both mixers hold ``heads_held = (first, count)`` of the
+published heads and compute ``Wo`` over those alone (one chip of a
+tensor-parallel group, without its all-reduce): the partial sum goes on. The
+q/k norm of the full-attention mixer is taken across the channels held.
+
+*Packed documents.* The batch's numerical features are ``L`` uniforms in
+[0, 1) a sequence: position 0 starts a document, and position ``i > 0`` starts
+one where ``u_i < 1 / mean_document_length``. A document's first token resets
+the rule's state and the convolution's window, and attention stays inside a
+document. The loss is next-token cross-entropy, mean over the positions whose
+next token belongs to the same document (:func:`next_token_loss`; the
+targets are the ids shifted by one).
+
+On the sparse train step the token table is a sequence input
+(``TableConfig(combiner=None)`` read at hotness ``L``): ``emb_acts`` is
+``[rows [B, L, d]]``, as in :mod:`.sdar_moe`.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..layers.gated_delta import (
+    causal_conv,
+    chunk_gated_delta_rule,
+    segment_ids,
+)
+from ..telemetry import scopes
+from .sdar_moe import (
+    ATTENTION_BLOCK,
+    attention_path,
+    rms_norm,
+    splash_block_sizes,
+)
+
+LINEAR, FULL = "linear_attention", "full_attention"
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoHybridConfig:
+  """Widths as the published ``config.json`` names them, and the share of
+  the model that lives here."""
+  hidden_size: int = 3840
+  intermediate_size: int = 11008
+  num_attention_heads: int = 30         # published, of both kinds of mixer
+  head_dim: int = 128
+  linear_key_head_dim: int = 96
+  linear_value_head_dim: int = 192
+  linear_conv_kernel_dim: int = 4
+  linear_allow_neg_eigval: bool = True
+  rms_norm_eps: float = 1e-6
+  layer_types: Tuple[str, ...] = (LINEAR, LINEAR, LINEAR, FULL) * 8
+  vocab_size: int = 100352              # rows of the head (a slice: fewer)
+  heads_held: Tuple[int, int] = (0, 30)  # (first, count) of every mixer
+  seq_len: int = 8192
+  mean_document_length: int = 2048
+  chunk: int = 64                       # tokens a step of the chunked rule
+  attention: str = "splash"             # splash: the TPU's kernel | xla: tests
+
+  def __post_init__(self):
+    first, count = self.heads_held
+    if not 0 <= first < first + count <= self.num_attention_heads:
+      raise ValueError(f"heads_held {self.heads_held} of "
+                       f"{self.num_attention_heads} heads")
+    stray = set(self.layer_types) - {LINEAR, FULL}
+    if stray:
+      raise ValueError(f"layer_types names {sorted(stray)}: "
+                       f"{LINEAR} or {FULL}")
+
+
+def document_segments(numerical, mean_document_length: int):
+  """The batch's numerical features ``[B, L]`` -> the document's number at
+  every position, ``[B, L]`` int32."""
+  return segment_ids(numerical < 1.0 / mean_document_length)
+
+
+def l2_norm(x, eps: float = 1e-6):
+  return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                           + eps)
+
+
+def linear_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
+  """The heads held here of one gated-delta-rule mixer, ``u [B, L, d]`` ->
+  their part of ``o Wo``, ``[B, L, d]``."""
+  b, length, _ = u.shape
+  h, dk, dv = cfg.heads_held[1], cfg.linear_key_head_dim, \
+      cfg.linear_value_head_dim
+  short = lambda x, w: jax.nn.silu(causal_conv(x, w, seg))
+  q = short(u @ p["wq"], p["conv_q"]).reshape(b, length, h, dk)
+  k = short(u @ p["wk"], p["conv_k"]).reshape(b, length, h, dk)
+  v = short(u @ p["wv"], p["conv_v"]).reshape(b, length, h, dv)
+  z = (u @ p["wg"]).reshape(b, length, h, dv)
+  beta = jax.nn.sigmoid(u @ p["wb"])
+  if cfg.linear_allow_neg_eigval:
+    beta = 2.0 * beta
+  g = -jnp.exp(p["a_log"]) * jax.nn.softplus(u @ p["wa"] + p["dt_bias"])
+  o, _ = chunk_gated_delta_rule(l2_norm(q) * dk ** -0.5, l2_norm(k), v, g,
+                                beta, seg, cfg.chunk)
+  o = rms_norm(o, p["o_norm"], cfg.rms_norm_eps) * jax.nn.silu(z)
+  return o.reshape(b, length, h * dv) @ p["wo"]
+
+
+def attention_xla(q, k, v, seg, tile: int):
+  """``q [B, L, H, hd]`` (already scaled), ``k, v`` alike, ``seg [B, L]`` ->
+  ``[B, L, H, hd]``: a tile of queries at a time against the keys up to its
+  end, causal and inside the query's document."""
+  length = q.shape[1]
+  tile = min(tile, length)
+  out = []
+  for a in range(0, length, tile):
+    e = min(a + tile, length)
+    s = jnp.einsum("bqhd,bshd->bhqs", q[:, a:e], k[:, :e])
+    s = s.astype(jnp.promote_types(s.dtype, jnp.float32))
+    allowed = (np.arange(e)[None, :] <= np.arange(a, e)[:, None]) \
+        & (seg[:, a:e, None] == seg[:, None, :e])
+    s = jnp.where(allowed[:, None], s, -jnp.inf)
+    prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    out.append(jnp.einsum("bhqs,bshd->bqhd", prob, v[:, :e]))
+  return jnp.concatenate(out, axis=1)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(seq_len: int, heads: int, block: int, interpret: bool):
+  from jax.experimental.pallas.ops.tpu import splash_attention as sa
+  # host arrays, constants of whatever program calls it (as in sdar_moe)
+  with jax.ensure_compile_time_eval():
+    kernel = sa.make_splash_mha_single_device(
+        sa.MultiHeadMask([sa.CausalMask((seq_len, seq_len))] * heads),
+        block_sizes=splash_block_sizes(min(block, seq_len)),
+        interpret=interpret)
+  return jax.tree_util.tree_map(np.asarray, kernel)
+
+
+def attention_splash(q, k, v, seg, block: int, interpret: bool = False):
+  """Same contract as :func:`attention_xla`, through the splash-attention
+  kernel: one multi-head call a sequence, the documents as segment ids. Its
+  operands are rounded to bfloat16 (what the MXU's default precision makes
+  of a float32 operand); scores, softmax and accumulation are float32."""
+  from jax.experimental.pallas.ops.tpu import splash_attention as sa
+  kernel = _splash_kernel(q.shape[1], q.shape[2], block, interpret)
+  heads_first = lambda x: jnp.swapaxes(x, 1, 2).astype(jnp.bfloat16)
+  out = jax.vmap(lambda q, k, v, s: kernel(
+      q, k, v, segment_ids=sa.SegmentIds(q=s, kv=s)))(
+          heads_first(q), heads_first(k), heads_first(v), seg)
+  return jnp.swapaxes(out, 1, 2).astype(q.dtype)
+
+
+def full_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
+  """The heads held here of one full-attention mixer -> their part of
+  ``o Wo``, ``[B, L, d]``."""
+  b, length, _ = u.shape
+  h, hd = cfg.heads_held[1], cfg.head_dim
+  q = rms_norm(u @ p["wq"], p["q_norm"], cfg.rms_norm_eps) * hd ** -0.5
+  k = rms_norm(u @ p["wk"], p["k_norm"], cfg.rms_norm_eps)
+  heads = lambda x: x.reshape(b, length, h, hd)
+  attend = attention_path(cfg.attention, attention_xla, attention_splash)
+  o = attend(heads(q), heads(k), heads(u @ p["wv"]), seg, ATTENTION_BLOCK)
+  return o.reshape(b, length, h * hd) @ p["wo"]
+
+
+def decoder_layer(cfg: OlmoHybridConfig, kind: str, p, x, seg):
+  """One layer of ``kind`` on ``x [B, L, d]`` with its parameters ``p``."""
+  mixer, scope = (linear_attention_mixer, scopes.LINEAR_ATTENTION) \
+      if kind == LINEAR else (full_attention_mixer, scopes.ATTENTION)
+  with jax.named_scope(scope):
+    x = x + rms_norm(mixer(cfg, p, x, seg), p["mixer_norm"], cfg.rms_norm_eps)
+  with jax.named_scope(scopes.MLP):
+    y = (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+    return x + rms_norm(y, p["mlp_norm"], cfg.rms_norm_eps)
+
+
+def layer_shapes(cfg: OlmoHybridConfig, kind: str):
+  """name -> (shape, kind of leaf) of one layer's parameters: ``matrix``,
+  ``gain`` (starts at 1), ``conv`` (taps x channels), ``a_log``, ``dt_bias``
+  (a head each)."""
+  d, f, h = cfg.hidden_size, cfg.intermediate_size, cfg.heads_held[1]
+  mlp = {"mixer_norm": ((d,), "gain"), "w_gate": ((d, f), "matrix"),
+         "w_up": ((d, f), "matrix"), "w_down": ((f, d), "matrix"),
+         "mlp_norm": ((d,), "gain")}
+  if kind == FULL:
+    c = h * cfg.head_dim
+    return {"wq": ((d, c), "matrix"), "wk": ((d, c), "matrix"),
+            "wv": ((d, c), "matrix"), "wo": ((c, d), "matrix"),
+            "q_norm": ((c,), "gain"), "k_norm": ((c,), "gain"), **mlp}
+  ck, cv, taps = h * cfg.linear_key_head_dim, h * cfg.linear_value_head_dim, \
+      cfg.linear_conv_kernel_dim
+  return {"wq": ((d, ck), "matrix"), "wk": ((d, ck), "matrix"),
+          "wv": ((d, cv), "matrix"), "wg": ((d, cv), "matrix"),
+          "wb": ((d, h), "matrix"), "wa": ((d, h), "matrix"),
+          "conv_q": ((taps, ck), "conv"), "conv_k": ((taps, ck), "conv"),
+          "conv_v": ((taps, cv), "conv"), "a_log": ((h,), "a_log"),
+          "dt_bias": ((h,), "dt_bias"),
+          "o_norm": ((cfg.linear_value_head_dim,), "gain"),
+          "wo": ((cv, d), "matrix"), **mlp}
+
+
+def _uniform(lo: float, hi: float):
+  return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+      key, shape, dtype, lo, hi)
+
+
+# ``fla``'s ranges: A uniform in (0, 16), here e^0 .. e^2; dt log-uniform in
+# [0.001, 0.1] and dt_bias its inverse softplus, about log dt. So a seeded
+# head's decay a token, exp(-A softplus(dt_bias)), lies in 0.48 .. 0.999
+INITIALISERS = {
+    "matrix": nn.initializers.normal(0.02), "gain": nn.initializers.ones,
+    "conv": _uniform(-0.5, 0.5), "a_log": _uniform(0.0, 2.0),
+    "dt_bias": _uniform(-6.9, -2.3)}
+
+
+class OlmoHybrid(nn.Module):
+  """``__call__(numerical, cats, emb_acts=[rows [B, L, d]])`` ->
+  ``{"logits" [B, L, V], "weight" [B, L]}``: ``weight`` is 1 where the next
+  token belongs to the same document, 0 at a document's last token."""
+
+  config: OlmoHybridConfig
+
+  @nn.compact
+  def __call__(self, numerical, cats, emb_acts=None):
+    del cats
+    cfg = self.config
+    if emb_acts is None or len(emb_acts) != 1:
+      raise ValueError("OlmoHybrid takes its token rows as one sequence "
+                       "input: emb_acts=[rows [B, L, hidden_size]]")
+    (x,) = emb_acts
+    layers = [{name: self.param(f"layer_{i}_{name}", INITIALISERS[leaf], shape)
+               for name, (shape, leaf) in layer_shapes(cfg, kind).items()}
+              for i, kind in enumerate(cfg.layer_types)]
+    final_norm = self.param("final_norm", nn.initializers.ones,
+                            (cfg.hidden_size,))
+    head = self.param("head", INITIALISERS["matrix"],
+                      (cfg.hidden_size, cfg.vocab_size))
+
+    seg = document_segments(numerical, cfg.mean_document_length)
+    # one layer's activations at a time: the others are recomputed
+    for kind, p in zip(cfg.layer_types, layers):
+      x = jax.checkpoint(functools.partial(decoder_layer, cfg, kind))(
+          p, x, seg)
+    with jax.named_scope(scopes.LM_HEAD):
+      logits = rms_norm(x, final_norm, cfg.rms_norm_eps) @ head
+    same = jnp.pad(seg[:, 1:] == seg[:, :-1], ((0, 0), (0, 1)))
+    return {"logits": logits, "weight": same.astype(logits.dtype)}
+
+
+def next_token_loss(outputs, labels):
+  """Mean over the positions that are not a document's last of
+  ``CE(logits_t, targets_t)``: ``outputs`` as :class:`OlmoHybrid` returns
+  them, ``labels["targets"] [B, L]`` the ids shifted by one."""
+  logits = outputs["logits"]
+  logits = logits.astype(jnp.promote_types(logits.dtype, jnp.float32))
+  logp = jax.nn.log_softmax(logits, axis=-1)
+  nll = -jnp.take_along_axis(logp, labels["targets"][..., None], -1)[..., 0]
+  weight = outputs["weight"]
+  return jnp.sum(weight * nll) / jnp.maximum(jnp.sum(weight), 1.0)

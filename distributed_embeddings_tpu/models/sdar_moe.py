@@ -86,10 +86,11 @@ class SDARMoEConfig:
 
 
 def rms_norm(x, gain, eps):
-  x32 = x.astype(jnp.float32)
-  var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-  return (x32 * jax.lax.rsqrt(var + eps) * gain.astype(jnp.float32)
-          ).astype(x.dtype)
+  """In float32 at least (a float64 test stays float64)."""
+  dt = jnp.promote_types(x.dtype, jnp.float32)
+  wide = x.astype(dt)
+  var = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
+  return (wide * jax.lax.rsqrt(var + eps) * gain.astype(dt)).astype(x.dtype)
 
 
 def rope(x, positions, theta):
@@ -160,16 +161,22 @@ def attention_xla(q, k, v, seq_len: int, block_length: int, tile: int):
 ATTENTION_BLOCK = 512
 
 
+def splash_block_sizes(block: int):
+  """One block size for queries and keys, forward and both backward kernels
+  (no fused backward: see ``ATTENTION_BLOCK``)."""
+  from jax.experimental.pallas.ops.tpu import splash_attention as sa
+  return sa.BlockSizes(
+      block_q=block, block_kv=block, block_kv_compute=block,
+      block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+      block_q_dq=block, block_kv_dq=block)
+
+
 @functools.lru_cache(maxsize=None)
 def _splash_kernel(seq_len: int, block_length: int, group: int, block: int,
                    interpret: bool):
   from jax.experimental.pallas.ops.tpu import splash_attention as sa
   mask = sa.NumpyMask(block_diffusion_mask(seq_len, block_length))
-  block = min(block, 2 * seq_len)
-  sizes = sa.BlockSizes(
-      block_q=block, block_kv=block, block_kv_compute=block,
-      block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-      block_q_dq=block, block_kv_dq=block)
+  sizes = splash_block_sizes(min(block, 2 * seq_len))
   # the kernel's block maps as host arrays, so that they are constants of
   # whatever program calls it. The factory makes ``jnp`` arrays of them: in
   # the middle of a trace (where this is first called) those would be that
@@ -198,17 +205,20 @@ def attention_splash(q, k, v, seq_len: int, block_length: int, block: int,
   return jnp.transpose(out, (0, 3, 1, 2, 4)).astype(q.dtype)
 
 
-def _attention_fn(cfg: SDARMoEConfig):
-  if cfg.attention == "xla":
-    return attention_xla
-  if cfg.attention == "splash":
+def attention_path(name: str, xla, splash):
+  """The function a configuration's ``attention`` names: ``splash`` is the
+  TPU's kernel and raises on any other backend, ``xla`` is for tests and
+  counting tools."""
+  if name == "xla":
+    return xla
+  if name == "splash":
     if jax.default_backend() != "tpu":
       raise ValueError(
           'attention="splash" is a TPU kernel and this backend is '
           f'{jax.default_backend()!r}; a test or a counting tool on another '
           'backend names attention="xla" itself')
-    return attention_splash
-  raise ValueError(f"attention={cfg.attention!r}: splash or xla")
+    return splash
+  raise ValueError(f"attention={name!r}: splash or xla")
 
 
 def decoder_layer(cfg: SDARMoEConfig, p, x):
@@ -226,8 +236,8 @@ def decoder_layer(cfg: SDARMoEConfig, p, x):
     k = rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
              cfg.rope_theta)
     q = q.reshape(b, s, hkv, hq // hkv, hd)
-    o = _attention_fn(cfg)(q, k, v, cfg.seq_len, cfg.block_length,
-                           ATTENTION_BLOCK)
+    attend = attention_path(cfg.attention, attention_xla, attention_splash)
+    o = attend(q, k, v, cfg.seq_len, cfg.block_length, ATTENTION_BLOCK)
     x = x + o.reshape(b, s, hq * hd) @ p["wo"]
   with jax.named_scope(scopes.MOE):
     h = rms_norm(x, p["moe_norm"], cfg.rms_norm_eps)
