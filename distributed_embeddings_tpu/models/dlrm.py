@@ -29,8 +29,6 @@ from ..layers.dist_model_parallel import DistributedEmbedding
 from ..layers.embedding import TableConfig
 from ..ops.packed_table import mxu_operand_dtype as _mxu_operand_dtype
 from ..ops.pallas_interact import (
-    interact_bwd,
-    interact_fwd,
     interact_parts_bwd,
     interact_parts_fwd,
     use_pallas_interact,
@@ -102,12 +100,7 @@ def _tril_fwd(flat, f, k):
   b = flat.shape[0]
   d = flat.shape[1] // f
   feats = flat.reshape(b, f, d)
-  m_np, p = _tril_select_np(f, k)
-  if use_pallas_interact(b, f, d, flat.dtype):
-    # fused VMEM kernel: no inter round-trip, no layout copies (round 5,
-    # ~13 -> ~3 ms of the B=64k step; ops/pallas_interact.py)
-    acts = interact_fwd(feats, jnp.asarray(m_np, jnp.bfloat16))
-    return acts, feats
+  m_np, _ = _tril_select_np(f, k)
   cd = _mxu_operand_dtype(feats.dtype)
   m = jnp.asarray(m_np, cd)
   inter = jnp.einsum("bpd,bqd->bpq", feats.astype(cd), feats.astype(cd),
@@ -120,10 +113,6 @@ def _tril_fwd(flat, f, k):
 def _tril_bwd(f, k, feats, d_acts):
   b, _, d = feats.shape
   m_np, _ = _tril_select_np(f, k)
-  if use_pallas_interact(b, f, d, feats.dtype):
-    m3t = jnp.asarray(np.swapaxes(m_np, 1, 2), jnp.bfloat16)
-    d_feats = interact_bwd(d_acts, feats, m3t)
-    return (d_feats.reshape(b, f * d),)
   # under bf16 compute (AMP) the cotangent is rounded to bf16 before the
   # grad einsums — the AMP convention (the reference's fp16 backward does
   # the same); on-TPU f32 parity with autodiff holds because DEFAULT MXU
@@ -148,21 +137,20 @@ def _pair_products_pallas(parts, f: int, k: int) -> jax.Array:
   """Per-part fused-kernel form of :func:`_tril_products` (bf16, TPU).
 
   Takes the f per-table [B, D] slices directly — no flat concat exists
-  at the XLA level in either direction (see ops/pallas_interact.py)."""
+  at the XLA level in either direction; inside the kernels the parts land
+  in a sample-major VMEM scratch by strided stores and four samples (at
+  f = 27) share one MXU tile (ops/pallas_interact.py; what it costs a
+  step: PERF.md section 5, `interact_ms`)."""
   out, _ = _pair_fwd(parts, f, k)
   return out
 
 
 def _pair_fwd(parts, f, k):
-  m_np, _ = _tril_select_np(f, k)
-  acts = interact_parts_fwd(parts, jnp.asarray(m_np, jnp.bfloat16))
-  return acts, parts
+  return interact_parts_fwd(parts, _tril_select_np(f, k)[0]), parts
 
 
 def _pair_bwd(f, k, parts, d_acts):
-  m_np, _ = _tril_select_np(f, k)
-  m3t = jnp.asarray(np.swapaxes(m_np, 1, 2), jnp.bfloat16)
-  return (interact_parts_bwd(d_acts, parts, m3t),)
+  return (interact_parts_bwd(d_acts, parts, _tril_select_np(f, k)[0]),)
 
 
 _pair_products_pallas.defvjp(_pair_fwd, _pair_bwd)
@@ -177,7 +165,10 @@ def dot_interact(bottom_out: jax.Array, emb_outs: Sequence[jax.Array],
   Equivalent of `examples/dlrm/utils.py:92-113`, with the dynamic
   ``boolean_mask`` replaced by the matmul-form triangle selection
   (:func:`_tril_products`). Output: [B, F*(F-1)/2 + D] where
-  F = num embeddings + 1.
+  F = num embeddings + 1. On a TPU, with bfloat16 MXU operands and a batch
+  the kernels' blocks divide, the parts go to the fused Pallas kernels
+  instead (:func:`_pair_products_pallas`; what they cost a step:
+  `interact_ms` in PERF.md section 5).
 
   ``pack`` is accepted for API compatibility and ignored: the matmul-form
   selection has no pack concept (the round-2 pack study measured pack=1
@@ -213,7 +204,7 @@ def dot_interact(bottom_out: jax.Array, emb_outs: Sequence[jax.Array],
   k = 0 if self_interaction else -1
   if use_pallas_interact(b, len(parts), d, cd):
     # per-part kernel I/O: the slices keep their natural row-major layout
-    # and the feature concat/split lives in VMEM (ops/pallas_interact.py)
+    # and are assembled and split in VMEM (ops/pallas_interact.py)
     activations = _pair_products_pallas(
         tuple(p.astype(cd) for p in parts), len(parts), k)
   else:

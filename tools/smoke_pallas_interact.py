@@ -1,10 +1,12 @@
 """Real-TPU smoke test for the fused Pallas interaction kernels.
 
 Checks the per-part fwd/bwd kernels (the DLRM hot path,
-`ops/pallas_interact.py`) against the XLA matmul-form `_tril_products`
-ON THE REAL CHIP at the bench feature shape (F=27, D=128) — interpret
-mode covers semantics (tests/test_pallas_interact.py); this validates
-the Mosaic lowering itself (the VMEM concat/scatter + batched MXU dots).
+`ops/pallas_interact.py`) against the explicit XLA einsum form
+(`pallas_interact.xla_reference` and its `jax.vjp`) ON THE REAL CHIP at
+the bench feature shape (F=27, D=128) — interpret mode covers semantics
+(tests/test_pallas_interact.py); this validates what Mosaic makes of the
+body (strided stores and loads of the sample-major scratch, the batched
+MXU dots of four samples a tile).
 
 Run: python tools/smoke_pallas_interact.py   (leg B of chip_smoke.py)
 Exit code 0 = pass; non-zero on any failure AND on a backend that is not
@@ -46,7 +48,7 @@ def main():
   m_np, _ = _tril_select_np(F, -1)
   failed = []
 
-  got = jax.jit(interact_parts_fwd)(parts, jnp.asarray(m_np, jnp.bfloat16))
+  got = jax.jit(lambda ps: interact_parts_fwd(ps, m_np))(parts)
   flat = jnp.concatenate(parts, axis=1)
   want, vjp = jax.vjp(lambda y: _xla_reference(y, F, -1), flat)
   err = float(jnp.max(jnp.abs(got - want)))
@@ -59,8 +61,8 @@ def main():
 
   d_acts = jnp.asarray(rng.standard_normal(want.shape), jnp.float32)
   (want_flat,) = vjp(d_acts)
-  m3t = jnp.asarray(np.swapaxes(m_np, 1, 2), jnp.bfloat16)
-  got_parts = jax.jit(interact_parts_bwd)(d_acts, parts, m3t)
+  got_parts = jax.jit(
+      lambda da, ps: interact_parts_bwd(da, ps, m_np))(d_acts, parts)
   werr = 0.0
   for p in range(F):
     w = np.asarray(want_flat[:, p * D:(p + 1) * D], np.float32)
