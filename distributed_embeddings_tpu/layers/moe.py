@@ -10,6 +10,13 @@ experts would add is left out; the shares of all chips, added, are the whole
 layer (``tests/test_moe.py`` holds that). ``held=(0, num_experts)`` is the
 whole layer. No code stands in for the absent chips or their exchange.
 
+How the chosen experts are weighted is the model's, a :class:`Router` on
+the share: softmax over every expert and the chosen renormalised (the
+default), or a sigmoid an expert, the chosen renormalised and scaled. A
+shared expert, which every chip computes for its own tokens, is no part of
+a share: :func:`shared_expert` is the plain dense product, and a model adds
+it to ``moe_share``'s sum (counted once when shares are added up).
+
 Dropless and without a capacity. The assignments are sorted by expert, held
 ones first, so the rows an expert computes are contiguous, and the grouped
 matmuls (``jax.lax.ragged_dot``; on a TPU XLA's own Mosaic grouped-matmul
@@ -26,16 +33,18 @@ The head is computed WHOLE: its rows past the live count (zeros) are put in
 the last expert's group and multiplied like the others, so that a step's
 time does not follow the load. That costs time, and what it buys is
 measured (PERF.md, PR 29, review round). On seeded weights a router
-collapses: identical tokens route alike, a block-diffusion model's mask token
-is a quarter of all positions, and after one layer of attention most
+collapses: identical tokens route alike (in a block-diffusion model the mask
+token is a quarter of all positions), and after one layer of attention most
 positions look alike, so a layer's load on 16 of 128 experts is near
 ``0.8 k`` expected counts, ``k`` the number of the eight popular experts held
 here: a draw per layer AND per batch (17 to 16,418 assignments against 8,192
-expected, on one seed). Computed live on the same buffers a step is 5-11%
-faster and follows that lottery batch by batch, while most layers time an
-expert layer with nothing to do; a trained router sends every chip about the
-expected count. With a head of two expected counts, live, a layer past it
-walks the tail in every step and the step is slower than this one.
+expected, on one seed of the softmax router; a sigmoid router's draws on 32
+of 256 experts stay within 0.59 to 1.40 of the expected count: PERF.md, PR
+35). Computed live on the same buffers a step is 5-11% faster and follows
+that lottery batch by batch, while most layers time an expert layer with
+nothing to do; a trained router sends every chip about the expected count.
+With a head of two expected counts, live, a layer past it walks the tail in
+every step and the step is slower than this one.
 """
 
 from __future__ import annotations
@@ -58,11 +67,26 @@ HEAD_LOADS = 4
 
 
 @dataclasses.dataclass(frozen=True)
+class Router:
+  """How a model scores its experts and weights the chosen ones: data of
+  the model, as its config publishes them."""
+  score: str = "softmax"      # over every expert | "sigmoid": an expert each
+  renormalise: bool = True    # the chosen scores divided by their sum
+  scale: float = 1.0          # then multiplied (a routed scaling factor)
+
+  def __post_init__(self):
+    if self.score not in ("softmax", "sigmoid"):
+      raise ValueError(f"score={self.score!r}: softmax or sigmoid")
+
+
+@dataclasses.dataclass(frozen=True)
 class MoEShare:
-  """Which part of a layer of ``num_experts`` experts lives here."""
+  """Which part of a layer of ``num_experts`` experts lives here, and the
+  layer's router."""
   num_experts: int
   top_k: int
   held: Tuple[int, int]       # (first expert held, how many)
+  router: Router = Router()
 
   def __post_init__(self):
     first, count = self.held
@@ -81,17 +105,33 @@ class MoEShare:
     return min(assignments, 8 * -(-HEAD_LOADS * expected // 8))
 
 
-def route(h: jax.Array, w_router: jax.Array, top_k: int):
-  """-> (p ``[T, top_k]`` renormalised to sum to 1, experts ``[T, top_k]``).
+def route(h: jax.Array, w_router: jax.Array, top_k: int,
+          router: Router = Router()):
+  """-> (p ``[T, top_k]``, the chosen experts' weights; experts
+  ``[T, top_k]``). By default p is renormalised to sum to 1.
 
-  Router logits, softmax over every expert and the renormalisation in
+  Router logits, the score of every expert and the renormalisation in
   float32; the logits' matmul at ``highest`` precision, because a choice of
   experts decided by a bfloat16 product is a different model."""
   logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
                    precision=lax.Precision.HIGHEST)
-  probs = jax.nn.softmax(logits, axis=-1)
-  top_p, top_e = lax.top_k(probs, top_k)
-  return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
+  scores = jax.nn.softmax(logits, axis=-1) if router.score == "softmax" \
+      else jax.nn.sigmoid(logits)
+  top_p, top_e = lax.top_k(scores, top_k)
+  if router.renormalise:
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+  if router.scale != 1.0:
+    top_p = top_p * router.scale
+  return top_p, top_e
+
+
+def shared_expert(h: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                  w_down: jax.Array):
+  """``h [T, d]`` -> ``(silu(h w_gate) * (h w_up)) w_down``: the expert
+  every token passes and every chip of an expert-parallel group computes for
+  its own tokens. A plain dense product, outside the sort."""
+  with jax.named_scope(scopes.MOE), jax.named_scope(scopes.MOE_SHARED):
+    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
 
 
 def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
@@ -112,7 +152,7 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
   n = t * k
   with jax.named_scope(scopes.MOE):
     with jax.named_scope(scopes.MOE_ROUTE):
-      top_p, top_e = route(h, w_router, k)
+      top_p, top_e = route(h, w_router, k, share.router)
       local = top_e.astype(jnp.int32) - first
       here = (local >= 0) & (local < count)
       # sort key: the held expert's local number; `count` for the rest, so

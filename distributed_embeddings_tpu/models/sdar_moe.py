@@ -93,15 +93,31 @@ def rms_norm(x, gain, eps):
   return (wide * jax.lax.rsqrt(var + eps) * gain.astype(dt)).astype(x.dtype)
 
 
-def rope(x, positions, theta):
-  """``x [..., S, heads, head_dim]``, rotate-half, angles in float32."""
-  half = x.shape[-1] // 2
-  inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float32) / half))
+def rope_frequencies(theta: float, rotary_dim: int) -> np.ndarray:
+  """``theta ** (-2 i / rotary_dim)``, ``i < rotary_dim / 2``: the inverse
+  frequencies of plain RoPE over ``rotary_dim`` dimensions, float32."""
+  half = rotary_dim // 2
+  return 1.0 / (theta ** (np.arange(half, dtype=np.float32) / half))
+
+
+def rope(x, positions, inv_freq, attention_factor: float = 1.0):
+  """``x [..., S, heads, head_dim]``, rotate-half over the first
+  ``2 len(inv_freq)`` dimensions of a head (the rotated width; the rest pass
+  as they are), angles in float32; cos and sin times ``attention_factor``
+  where a scaled table (YaRN) has one."""
+  half = len(inv_freq)
   ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # [S, half]
   cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
   sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
-  rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-  return x * cos + rotated * sin
+  if attention_factor != 1.0:
+    cos, sin = cos * attention_factor, sin * attention_factor
+  whole = 2 * half == x.shape[-1]
+  turned = x if whole else x[..., :2 * half]
+  rotated = jnp.concatenate([-turned[..., half:], turned[..., :half]],
+                            axis=-1)
+  turned = turned * cos + rotated * sin
+  return turned if whole \
+      else jnp.concatenate([turned, x[..., 2 * half:]], axis=-1)
 
 
 def noise_of(numerical, seq_len: int, block_length: int, t_min: float):
@@ -231,10 +247,11 @@ def decoder_layer(cfg: SDARMoEConfig, p, x):
     q = (h @ p["wq"]).reshape(b, s, hq, hd)
     k = (h @ p["wk"]).reshape(b, s, hkv, hd)
     v = (h @ p["wv"]).reshape(b, s, hkv, hd)
+    inv_freq = rope_frequencies(cfg.rope_theta, hd)
     q = rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
-             cfg.rope_theta) * (hd ** -0.5)
+             inv_freq) * (hd ** -0.5)
     k = rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
-             cfg.rope_theta)
+             inv_freq)
     q = q.reshape(b, s, hkv, hq // hkv, hd)
     attend = attention_path(cfg.attention, attention_xla, attention_splash)
     o = attend(q, k, v, cfg.seq_len, cfg.block_length, ATTENTION_BLOCK)

@@ -25,17 +25,21 @@ APPLY = "de_apply"  # delta streams, XLA's scatter-add, the Pallas apply kernel
 ONEHOT = "de_onehot"  # a dense class's windowed one-hot MXU lookup; inside de_combine
 EXCHANGE = "de_exchange"  # the collectives of parallel/wire.py; inside de_route and de_combine
 INTERACT = "de_interact"  # models/dlrm.py::dot_interact; inside de_model
-ATTENTION = "de_attention"  # models/sdar_moe.py: q/k/v/o projections, q/k norms, RoPE, attention under the block-diffusion mask; models/olmo_hybrid.py: a full-attention mixer (no RoPE, causal within a document); inside de_model
-MOE = "de_moe"  # layers/moe.py::moe_share, the whole expert layer; inside de_model
+ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE, gate, attention under the model's mask; inside de_model
+WINDOW_ATTENTION = "de_window_attention"  # models/laguna.py: the mixer of a sliding_attention layer (causal, same document, i - j < sliding_window); inside de_attention
+FULL_ATTENTION = "de_full_attention"  # models/laguna.py: the mixer of a full_attention layer (causal, same document); inside de_attention
+MOE = "de_moe"  # layers/moe.py::moe_share and shared_expert, the whole expert layer; inside de_model
 MOE_ROUTE = "de_moe_route"  # router, top-k, sort by expert, row gather, weighted scatter-combine; inside de_moe
 MOE_EXPERTS = "de_moe_experts"  # the grouped matmuls over the held experts and the gate's silu; inside de_moe
+MOE_SHARED = "de_moe_shared"  # layers/moe.py::shared_expert: the expert every token passes, a plain dense SwiGLU; inside de_moe
 LM_HEAD = "de_lm_head"  # final norm and the vocabulary head; inside de_model
 LINEAR_ATTENTION = "de_linear_attention"  # models/olmo_hybrid.py: a gated-delta-rule mixer whole (projections, short convolutions, gates, the rule, gated output norm, W_o, the sublayer's norm); inside de_model
 DELTA_RULE = "de_delta_rule"  # layers/gated_delta.py::chunk_gated_delta_rule, the chunked rule alone; inside de_linear_attention
-MLP = "de_mlp"  # models/olmo_hybrid.py: the dense SwiGLU MLP and its sublayer norm; inside de_model
+MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_hybrid.py: every layer's; models/laguna.py: the leading dense layer's); inside de_model
 
 TOP_LEVEL = (ROUTE, GATHER, COMBINE, MODEL, LOSS, DENSE_UPDATE, APPLY)
 CHILDREN = (ONEHOT, EXCHANGE, INTERACT)
-# a language model's, all inside de_model (benchmark/scope_children.py reads them)
+# a language model's, all inside de_model (benchmark/scope_children*.py read them)
 LM_CHILDREN = (ATTENTION, MOE, MOE_ROUTE, MOE_EXPERTS, LM_HEAD,
-               LINEAR_ATTENTION, DELTA_RULE, MLP)
+               LINEAR_ATTENTION, DELTA_RULE, MLP, WINDOW_ATTENTION,
+               FULL_ATTENTION, MOE_SHARED)

@@ -1,7 +1,9 @@
 """One chip's share of a mixture-of-experts layer (`layers/moe.py`): the
 shares of all chips add up to the uncut layer, no assignment is dropped
 however skewed the router, and values and gradients are those of a plain
-loop over the experts."""
+loop over the experts; under the default softmax router, whose bits are
+what they were before a share carried a router, and under a scaled sigmoid
+one."""
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +11,13 @@ import numpy as np
 import pytest
 
 from distributed_embeddings_tpu.layers import moe
-from distributed_embeddings_tpu.layers.moe import MoEShare, moe_share, route
+from distributed_embeddings_tpu.layers.moe import (
+    MoEShare,
+    Router,
+    moe_share,
+    route,
+    shared_expert,
+)
 
 T, D, F, E, K = 64, 16, 24, 32, 2
 
@@ -22,10 +30,14 @@ def _weights(seed=0):
           f32(E, F, D, s=0.3))
 
 
-def plain_layer(h, w_router, w_gate, w_up, w_down, first=0, k=K):
+SIGMOID = Router("sigmoid", True, 2.5)
+
+
+def plain_layer(h, w_router, w_gate, w_up, w_down, first=0, k=K,
+                router=Router()):
   """Every held expert over every token, one by one. ``w_gate`` and the
   others hold experts ``first ..``; the router chooses among all."""
-  top_p, top_e = route(h, w_router, k)
+  top_p, top_e = route(h, w_router, k, router)
   out = jnp.zeros_like(h)
   for e in range(w_gate.shape[0]):
     y = (jax.nn.silu(h @ w_gate[e]) * (h @ w_up[e])) @ w_down[e]
@@ -43,30 +55,76 @@ def test_route_renormalises_the_chosen_probabilities():
   assert np.array_equal(np.asarray(top_e), np.argsort(-probs, axis=-1)[:, :3])
 
 
+def test_the_softmax_router_traces_to_what_it_did_before_it_was_data():
+  """`route` as it stood before PR 35, copied here: the default router's
+  jaxpr is that function's, so a model that names no router gets the bits
+  it got (no multiply by a scale of 1, no second normalisation)."""
+  def before(h, w_router, top_k):
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, top_k)
+    return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
+  h, w_router, *_ = _weights()
+  assert str(jax.make_jaxpr(lambda h, w: route(h, w, 3))(h, w_router)) \
+      == str(jax.make_jaxpr(lambda h, w: before(h, w, 3))(h, w_router))
+  assert str(jax.make_jaxpr(lambda h, w: route(h, w, 3, Router()))(
+      h, w_router)) == str(jax.make_jaxpr(
+          lambda h, w: before(h, w, 3))(h, w_router))
+  for got, want in zip(route(h, w_router, 3), before(h, w_router, 3)):
+    assert np.array_equal(got, want)
+  assert MoEShare(E, K, (0, 4)) == MoEShare(E, K, (0, 4), Router())
+  with pytest.raises(ValueError, match="softmax or sigmoid"):
+    Router("tanh")
+
+
+@pytest.mark.parametrize("renormalise,scale", [(True, 2.5), (True, 1.0),
+                                               (False, 1.0)])
+def test_the_sigmoid_router_scores_each_expert_on_its_own(renormalise, scale):
+  h, w_router, *_ = _weights()
+  top_p, top_e = route(h, w_router, 3, Router("sigmoid", renormalise, scale))
+  s = 1.0 / (1.0 + np.exp(-np.asarray(h @ w_router, np.float64)))
+  assert np.array_equal(np.asarray(top_e), np.argsort(-s, axis=-1)[:, :3])
+  chosen = np.take_along_axis(s, np.asarray(top_e), axis=-1)
+  want = chosen / chosen.sum(-1, keepdims=True) if renormalise else chosen
+  np.testing.assert_allclose(top_p, scale * want, rtol=2e-6)
+  if renormalise:
+    np.testing.assert_allclose(np.sum(top_p, axis=-1), scale, rtol=1e-6)
+
+
 def test_the_head_is_a_multiple_of_the_expected_load():
   share = MoEShare(128, 8, (0, 16))
   assert share.head_rows(8192 * 8) == moe.HEAD_LOADS * 8192   # the cell's
+  # a third expert shape, the same head: 32 of 256 at 8,192 tokens
+  assert MoEShare(256, 8, (0, 32), SIGMOID).head_rows(8192 * 8) \
+      == moe.HEAD_LOADS * 8192
   assert share.head_rows(10) == 8 and MoEShare(8, 2, (0, 8)).head_rows(128) \
       == 128                                       # never past the stream
 
 
-@pytest.mark.parametrize("held,head_loads", [(2, 4), (2, 1), (8, 4), (8, 1),
-                                             (32, 4)])
-def test_the_shares_add_up_to_the_uncut_layer(held, head_loads, monkeypatch):
+@pytest.mark.parametrize("held,head_loads,router", [
+    (2, 4, Router()), (2, 1, Router()), (8, 4, Router()), (8, 1, Router()),
+    (32, 4, Router()), (4, 4, SIGMOID), (4, 1, SIGMOID), (32, 4, SIGMOID)])
+def test_the_shares_add_up_to_the_uncut_layer(held, head_loads, router,
+                                              monkeypatch):
   """Nothing is computed by every share alike, so nothing is counted once:
-  the plain sum of the shares is the whole layer. (With a head of one
-  expected load about half the shares walk their tail.)"""
+  the plain sum of the shares is the whole layer, whichever router the model
+  names. (With a head of one expected load about half the shares walk their
+  tail. A shared expert is no part of a share: the next test.)"""
   monkeypatch.setattr(moe, "HEAD_LOADS", head_loads)
   h, wr, wg, wu, wd = _weights()
+  # the scaled sigmoid router's weights are 2.5 times as large
+  atol = 2e-6 * router.scale
   with jax.default_matmul_precision("highest"):
-    whole = plain_layer(h, wr, wg, wu, wd)
+    whole = plain_layer(h, wr, wg, wu, wd, router=router)
     parts, assigned, walked = [], 0, 0
     for first in range(0, E, held):
       sl = slice(first, first + held)
-      share = MoEShare(E, K, (first, held))
+      share = MoEShare(E, K, (first, held), router)
       out, c = moe_share(h, wr, wg[sl], wu[sl], wd[sl], share)
       np.testing.assert_allclose(
-          out, plain_layer(h, wr, wg[sl], wu[sl], wd[sl], first), atol=2e-6)
+          out, plain_layer(h, wr, wg[sl], wu[sl], wd[sl], first,
+                           router=router), atol=atol)
       assert int(c["assignments"]) == int(c["computed"]) == int(
           np.sum(c["loads"]))
       assigned += int(c["assignments"])
@@ -74,8 +132,30 @@ def test_the_shares_add_up_to_the_uncut_layer(held, head_loads, monkeypatch):
       parts.append(out)
   assert assigned == T * K           # every assignment lies on one share
   assert (walked > 0) == (head_loads == 1 and held < E)
-  np.testing.assert_allclose(sum(parts), whole, atol=4e-6)
+  np.testing.assert_allclose(sum(parts), whole, atol=2 * atol)
   assert float(jnp.max(jnp.abs(parts[0]))) > 0.01
+
+
+def test_a_shared_expert_is_counted_once_beside_the_shares():
+  """Every chip computes the shared expert for its own tokens, so adding
+  the chips' outputs up would count it once a chip: the layer is the routed
+  shares' sum plus the shared expert once."""
+  h, wr, wg, wu, wd = _weights(3)
+  shared = (wg[0] * 0.5, wu[1] * 0.5, wd[2] * 0.5)
+  with jax.default_matmul_precision("highest"):
+    whole = plain_layer(h, wr, wg, wu, wd, router=SIGMOID) \
+        + (jax.nn.silu(h @ shared[0]) * (h @ shared[1])) @ shared[2]
+    chips = [moe_share(h, wr, wg[f:f + 8], wu[f:f + 8], wd[f:f + 8],
+                       MoEShare(E, K, (f, 8), SIGMOID))[0]
+             + shared_expert(h, *shared) for f in range(0, E, 8)]
+    once = shared_expert(h, *shared)
+  np.testing.assert_allclose(sum(chips) - 3 * once, whole, atol=2e-5)
+  assert float(jnp.max(jnp.abs(once))) > 0.1
+  g = jax.grad(lambda s: jnp.sum(jnp.sin(shared_expert(h, *s))))(shared)
+  want = jax.grad(lambda s: jnp.sum(jnp.sin(
+      (jax.nn.silu(h @ s[0]) * (h @ s[1])) @ s[2])))(shared)
+  for a, b in zip(g, want):
+    assert np.array_equal(a, b)
 
 
 def _skewed(seed):
@@ -120,20 +200,23 @@ def test_a_tail_not_walked_shows_in_the_counter(monkeypatch):
   assert int(c["computed"]) == share.head_rows(T * K) < int(c["assignments"])
 
 
-@pytest.mark.parametrize("skewed,held", [(False, (2, 4)), (True, (3, 1))],
-                         ids=["head_only", "tail_walked"])
-def test_gradients_are_the_plain_loops(skewed, held):
+@pytest.mark.parametrize("skewed,held,router", [
+    (False, (2, 4), Router()), (True, (3, 1), Router()),
+    (False, (2, 4), SIGMOID), (True, (3, 1), SIGMOID)],
+    ids=["head_only", "tail_walked", "sigmoid_head_only",
+         "sigmoid_tail_walked"])
+def test_gradients_are_the_plain_loops(skewed, held, router):
   h, wr, wg, wu, wd = _skewed(2) if skewed else _weights(2)
   sl = slice(held[0], held[0] + held[1])
   args = (h, wr, wg[sl], wu[sl], wd[sl])
-  share = MoEShare(E, K, held)
+  share = MoEShare(E, K, held, router)
   with jax.default_matmul_precision("highest"):
     got = jax.jit(jax.grad(
         lambda a: jnp.sum(jnp.sin(moe_share(*a, share)[0]))))(args)
-    want = jax.grad(lambda a: jnp.sum(jnp.sin(plain_layer(*a, held[0]))))(
-        args)
+    want = jax.grad(lambda a: jnp.sum(jnp.sin(plain_layer(
+        *a, held[0], router=router))))(args)
   for g, w in zip(got, want):
-    np.testing.assert_allclose(g, w, atol=3e-5, rtol=1e-4)
+    np.testing.assert_allclose(g, w, atol=3e-5 * router.scale, rtol=1e-4)
   assert float(jnp.max(jnp.abs(got[1]))) > 0   # the router learns too
 
 

@@ -145,7 +145,7 @@ def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
 
 def test_the_vocabulary_is_one_flat_set_of_names():
   names = scopes.TOP_LEVEL + scopes.CHILDREN + scopes.LM_CHILDREN
-  assert len(set(names)) == len(names) == 18
+  assert len(set(names)) == len(names) == 21
   for n in names:
     assert n.startswith("de_") and "/" not in n and "(" not in n
   declared = {v for k, v in vars(scopes).items()

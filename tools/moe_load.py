@@ -6,12 +6,14 @@ pool batch from the benchmark's own weights and prints what the counters of
 `layers/moe.py::moe_share` say, per layer: the assignments that fell on the
 experts held here, the largest held expert's load over the mean, the
 assignments a capacity would have dropped (assigned less what the grouped
-matmuls were handed: must read 0), and the share of positions the noise
-masked. Counts, so any backend will do, and attention goes the XLA way
-whatever the cell names (at the cell's real size the CPU takes a minute a
-batch; ``--layers 1`` shortens it):
+matmuls were handed: must read 0), the load over the expected count (what
+`layers/moe.py::HEAD_LOADS` is held against) and, for a block-diffusion
+model, the share of positions the noise masked. Counts, so any backend will
+do, and attention goes the XLA way whatever the cell names (at the cell's
+real size the CPU takes a minute or two a batch; ``--layers 1`` shortens it):
 
   JAX_PLATFORMS=cpu python tools/moe_load.py sdar_moe_train_1chip [--seed N]
+  JAX_PLATFORMS=cpu python tools/moe_load.py laguna_moe_train_1chip --seed N
 """
 
 import argparse
@@ -67,17 +69,24 @@ def main(argv=None):
       if k in ("moe", "masked")})(dense, jnp.asarray(rows),
                                   jnp.asarray(batch.numerical))
   moe = jax.tree_util.tree_map(np.asarray, out["moe"])
+  # a block-diffusion model runs the noisy copy beside the clean one
+  positions = int(batch.cats.size) * (2 if "masked" in out else 1)
+  expected = positions * config.num_experts_per_tok \
+      * config.experts_held[1] / config.num_experts
   report = {
       "cell": args.cell, "seed": args.seed,
       "backend": jax.default_backend(),
-      "positions_a_layer": int(2 * batch.cats.size),
+      "positions_a_layer": positions,
       "experts_held": list(config.experts_held),
       "assignments_on_held_experts": moe["assignments"].tolist(),
+      "load_over_expected": [round(float(a) / expected, 3)
+                             for a in moe["assignments"]],
       "largest_load_over_mean": [
           float(l.max() / max(l.mean(), 1e-30)) for l in moe["loads"]],
       "dropped": (moe["assignments"] - moe["computed"]).tolist(),
-      "masked_share": float(np.mean(np.asarray(out["masked"]))),
   }
+  if "masked" in out:
+    report["masked_share"] = float(np.mean(np.asarray(out["masked"])))
   print(json.dumps(report))
   return report
 
