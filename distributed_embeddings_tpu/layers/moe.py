@@ -26,8 +26,11 @@ assignments land here is data, not a shape: the expected count is
 times that. The sorted stream's head, ``HEAD_LOADS`` times the expected
 count, is computed in one piece; the tail, the rest of the worst case, is
 walked in pieces of the same size under one ``lax.cond`` that is taken only
-when a layer's load passes the head: memory is one piece's, and a skewed
-router costs time, never a token.
+when a layer's load passes the head: a skewed router costs time, never a
+token. Memory: the head's residuals (its gathered rows, the two projections,
+their product) live as long as the caller lets them, so a training model runs
+the layer under ``layers/remat.py::checkpoint_layer`` and they are one layer's
+backward's; the tail rematerialises itself, a piece at a time.
 
 The head is computed WHOLE: its rows past the live count (zeros) are put in
 the last expert's group and multiplied like the others, so that a step's
@@ -56,8 +59,10 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import scopes
+from .remat import MOE_ROUTE
 
 
 # Expected loads the head holds. On seeded weights 96 layers of 12 seeds'
@@ -158,8 +163,13 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
       # sort key: the held expert's local number; `count` for the rest, so
       # the assignments of held experts are the sorted stream's head
       key = jnp.where(here, local, count).reshape(n)
-      order = jnp.argsort(key, stable=True).astype(jnp.int32)
-      loads = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+      # named: a caller that rematerialises the layer keeps these two and
+      # neither sorts nor counts the keys again (layers/remat.py)
+      order = checkpoint_name(
+          jnp.argsort(key, stable=True).astype(jnp.int32), MOE_ROUTE)
+      loads = checkpoint_name(
+          jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32),
+          MOE_ROUTE)
       ends = jnp.cumsum(loads)
       starts = ends - loads
       n_live = ends[-1]
@@ -197,10 +207,10 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
         return jnp.where(live[:, None], y * p_c[:, None].astype(y.dtype),
                          0), done
 
-    # recomputed in the backward pass: what is kept of the head's rows is
-    # this call's arguments
-    y, computed = jax.checkpoint(functools.partial(rows_of, 0, head, True))(
-        h, tok[:head], p_sorted[:head], w_gate, w_up, w_down)
+    # no checkpoint of its own: whether the head's residuals are kept or
+    # rebuilt is its caller's plan (layers/remat.py)
+    y, computed = rows_of(0, head, True, h, tok[:head], p_sorted[:head],
+                          w_gate, w_up, w_down)
     with jax.named_scope(scopes.MOE_ROUTE):
       out = jnp.zeros_like(h).at[tok[:head]].add(y)
 

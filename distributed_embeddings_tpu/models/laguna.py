@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..layers.moe import MoEShare, Router, moe_share, shared_expert
+from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
 from ..telemetry import scopes
 from .olmo_hybrid import document_segments
 from .sdar_moe import (
@@ -219,7 +220,7 @@ def _splash_kernel(seq_len: int, group: int, window: Optional[int],
     kernel = sa.make_splash_mqa_single_device(
         sa.MultiHeadMask([mask] * group),
         block_sizes=splash_block_sizes(min(block, seq_len)),
-        interpret=interpret)
+        residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
   return jax.tree_util.tree_map(np.asarray, kernel)
 
 
@@ -229,7 +230,9 @@ def attention_splash(q, k, v, seg, block: int, window: Optional[int],
   kernel: one multi-query call per (sample, key-value head) under a causal
   or a local mask, the documents as segment ids. Its operands are rounded to
   bfloat16 (what the MXU's default precision makes of a float32 operand);
-  scores, softmax and accumulation are float32."""
+  scores, softmax and accumulation are float32. Its output and log-sum-exp
+  are kept for the backward under the name ``SPLASH_RESIDUALS``, as in
+  ``sdar_moe``."""
   from jax.experimental.pallas.ops.tpu import splash_attention as sa
   kernel = _splash_kernel(q.shape[1], q.shape[3], window, block, interpret)
   qh = jnp.transpose(q, (0, 2, 3, 1, 4)).astype(jnp.bfloat16)  # [B,Hkv,G,L,hd]
@@ -343,9 +346,10 @@ class Laguna(nn.Module):
 
     seg = document_segments(numerical, cfg.mean_document_length)
     counters = []
-    # one layer's activations at a time: the others are recomputed
+    # one layer's activations at a time, plus what layers/remat.py names:
+    # the rest of the other layers is recomputed
     for i, p in enumerate(layers):
-      x, c = jax.checkpoint(functools.partial(
+      x, c = checkpoint_layer(functools.partial(
           decoder_layer, cfg, cfg.layer_types[i], cfg.mlp_layer_types[i]))(
               p, x, seg)
       if c is not None:

@@ -60,6 +60,7 @@ from ..layers.gated_delta import (
     chunk_gated_delta_rule,
     segment_ids,
 )
+from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
 from ..telemetry import scopes
 from .sdar_moe import (
     ATTENTION_BLOCK,
@@ -162,7 +163,7 @@ def _splash_kernel(seq_len: int, heads: int, block: int, interpret: bool):
     kernel = sa.make_splash_mha_single_device(
         sa.MultiHeadMask([sa.CausalMask((seq_len, seq_len))] * heads),
         block_sizes=splash_block_sizes(min(block, seq_len)),
-        interpret=interpret)
+        residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
   return jax.tree_util.tree_map(np.asarray, kernel)
 
 
@@ -170,7 +171,9 @@ def attention_splash(q, k, v, seg, block: int, interpret: bool = False):
   """Same contract as :func:`attention_xla`, through the splash-attention
   kernel: one multi-head call a sequence, the documents as segment ids. Its
   operands are rounded to bfloat16 (what the MXU's default precision makes
-  of a float32 operand); scores, softmax and accumulation are float32."""
+  of a float32 operand); scores, softmax and accumulation are float32. Its
+  output and log-sum-exp are kept for the backward under the name
+  ``SPLASH_RESIDUALS``, as in ``sdar_moe``."""
   from jax.experimental.pallas.ops.tpu import splash_attention as sa
   kernel = _splash_kernel(q.shape[1], q.shape[2], block, interpret)
   heads_first = lambda x: jnp.swapaxes(x, 1, 2).astype(jnp.bfloat16)
@@ -267,9 +270,10 @@ class OlmoHybrid(nn.Module):
                       (cfg.hidden_size, cfg.vocab_size))
 
     seg = document_segments(numerical, cfg.mean_document_length)
-    # one layer's activations at a time: the others are recomputed
+    # one layer's activations at a time, plus what layers/remat.py names:
+    # the rest of the other layers is recomputed
     for kind, p in zip(cfg.layer_types, layers):
-      x = jax.checkpoint(functools.partial(decoder_layer, cfg, kind))(
+      x = checkpoint_layer(functools.partial(decoder_layer, cfg, kind))(
           p, x, seg)
     with jax.named_scope(scopes.LM_HEAD):
       logits = rms_norm(x, final_norm, cfg.rms_norm_eps) @ head

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..layers.moe import MoEShare, moe_share
+from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
 from ..telemetry import scopes
 
 
@@ -200,7 +201,7 @@ def _splash_kernel(seq_len: int, block_length: int, group: int, block: int,
   with jax.ensure_compile_time_eval():
     kernel = sa.make_splash_mqa_single_device(
         sa.MultiHeadMask([mask] * group), block_sizes=sizes,
-        interpret=interpret)
+        residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
   return jax.tree_util.tree_map(np.asarray, kernel)
 
 
@@ -209,9 +210,11 @@ def attention_splash(q, k, v, seq_len: int, block_length: int, block: int,
   """Same contract as :func:`attention_xla`, through the splash-attention
   kernel: one multi-query call per (sample, key head). Its operands are
   rounded to bfloat16, which is what the MXU's default precision makes of a
-  float32 operand; scores, softmax and accumulation are float32.
-  ``interpret`` runs the kernel in Pallas's interpreter (tests, any
-  backend)."""
+  float32 operand; scores, softmax and accumulation are float32. For its
+  backward the kernel keeps its output and the scores' log-sum-exp, under the
+  name ``SPLASH_RESIDUALS``: a layer rematerialised by ``checkpoint_layer``
+  runs the forward kernel once. ``interpret`` runs the kernel in Pallas's
+  interpreter (tests, any backend)."""
   kernel = _splash_kernel(seq_len, block_length, q.shape[3], block,
                           interpret)
   qh = jnp.transpose(q, (0, 2, 3, 1, 4)).astype(jnp.bfloat16)  # [B,Hkv,G,S,hd]
@@ -302,8 +305,9 @@ class SDARMoE(nn.Module):
                               cfg.t_min)
     xt = jnp.where(masked[..., None], mask_embedding.astype(x0.dtype), x0)
     x = jnp.concatenate([xt, x0], axis=1)                    # [B, 2 L, d]
-    # one layer's activations at a time: the others are recomputed
-    layer = jax.checkpoint(functools.partial(decoder_layer, cfg))
+    # one layer's activations at a time, plus what layers/remat.py names:
+    # the rest of the other layers is recomputed
+    layer = checkpoint_layer(functools.partial(decoder_layer, cfg))
     counters = []
     for p in layers:
       x, c = layer(p, x)

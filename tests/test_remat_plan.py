@@ -1,0 +1,378 @@
+"""A decoder layer's rematerialisation plan (`layers/remat.py`): under
+`checkpoint_layer` a model's gradients are those of the same layers with no
+checkpoint at all; the splash kernel's output and log-sum-exp outlive the
+layer, so its backward holds no second forward kernel; the expert layer's
+head is computed twice, not three times, and its keys are sorted once; and
+`tools/step_recompute.py` counts those calls in a compiled step's text."""
+
+import dataclasses
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.ad_checkpoint import print_saved_residuals
+
+from distributed_embeddings_tpu.layers import remat
+from distributed_embeddings_tpu.layers.moe import MoEShare, Router, moe_share
+from distributed_embeddings_tpu.models import laguna, olmo_hybrid, sdar_moe
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+import step_recompute  # noqa: E402
+
+ROPE = {"full_attention": {"rope_type": "default", "rope_theta": 1e4},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 1e4}}
+# toy widths of each model, every kind of layer it has; `attention="xla"`
+TOYS = {
+    "sdar_moe": (sdar_moe, sdar_moe.SDARMoE, sdar_moe.SDARMoEConfig(
+        hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, moe_intermediate_size=16, num_experts=16,
+        num_experts_per_tok=2, num_hidden_layers=2, vocab_size=50,
+        experts_held=(4, 4), block_length=4, seq_len=16, attention="xla")),
+    "laguna": (laguna, laguna.Laguna, laguna.LagunaConfig(
+        hidden_size=32, intermediate_size=48, num_key_value_heads=2,
+        head_dim=16, moe_intermediate_size=12,
+        shared_expert_intermediate_size=12, num_experts=16,
+        num_experts_per_tok=3, sliding_window=5, num_hidden_layers=3,
+        layer_types=(laguna.FULL, laguna.SLIDING, laguna.FULL),
+        mlp_layer_types=(laguna.DENSE, laguna.SPARSE, laguna.SPARSE),
+        num_attention_heads_per_layer=(4, 6, 4),
+        rope_parameters=laguna.freeze_rope_parameters(ROPE), vocab_size=50,
+        experts_held=(4, 4), seq_len=24, mean_document_length=8,
+        attention="xla")),
+    "olmo_hybrid": (olmo_hybrid, olmo_hybrid.OlmoHybrid,
+                    olmo_hybrid.OlmoHybridConfig(
+                        hidden_size=32, intermediate_size=48,
+                        num_attention_heads=4, head_dim=8,
+                        linear_key_head_dim=6, linear_value_head_dim=10,
+                        layer_types=(olmo_hybrid.LINEAR, olmo_hybrid.FULL),
+                        vocab_size=50, heads_held=(0, 4), seq_len=24,
+                        mean_document_length=6, chunk=8, attention="xla")),
+}
+
+
+def _case(model_cls, cfg, batch=2, seed=0):
+  """-> (params with every matrix large enough to matter, numerical, rows)."""
+  rng = np.random.default_rng(seed)
+  rows = jnp.asarray(rng.normal(size=(batch, cfg.seq_len, cfg.hidden_size))
+                     * 0.5, jnp.float32)
+  width = getattr(cfg, "n_numerical", cfg.seq_len)
+  numerical = jnp.asarray(rng.random((batch, width)), jnp.float32)
+  params = model_cls(cfg).init(jax.random.PRNGKey(seed), numerical, None,
+                               emb_acts=[rows])["params"]
+  params = jax.tree_util.tree_map(
+      lambda x: x * 8 if x.ndim > 1 else x + 0.1 * jnp.asarray(
+          rng.normal(size=x.shape), jnp.float32), params)
+  return params, numerical, rows
+
+
+def _loss(model_cls, cfg, numerical):
+  return lambda params, rows: jnp.sum(jnp.sin(model_cls(cfg).apply(
+      {"params": params}, numerical, None, emb_acts=[rows])["logits"]))
+
+
+def _count(jaxpr, wanted, skip=()):
+  """Equations of ``jaxpr`` and of everything it calls for which
+  ``wanted(eqn)``; the bodies of the primitives named in ``skip`` are not
+  entered."""
+  n = 0
+  for eqn in jaxpr.eqns:
+    n += bool(wanted(eqn))
+    if eqn.primitive.name in skip:
+      continue
+    for sub in jax.core.jaxprs_in_params(eqn.params):
+      n += _count(sub, wanted, skip)
+  return n
+
+
+def _primitive(name):
+  return lambda eqn: eqn.primitive.name == name
+
+
+# `jax.checkpoint`'s equation, as this JAX names its primitive
+_checkpoint = _primitive("remat2")
+
+
+def _residuals(capsys, f, *args, named=""):
+  """The types of the residuals of ``f(*args)``, as `print_saved_residuals`
+  prints them; with ``named``, of those it says were kept under that name (it
+  says so only of a value named outside any `jit`)."""
+  capsys.readouterr()
+  print_saved_residuals(f, *args)
+  return sorted(line.split(" ")[0]
+                for line in capsys.readouterr().out.splitlines()
+                if not named or f"named '{named}'" in line)
+
+
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_gradients_under_the_plan_are_those_with_no_checkpoint(
+    name, monkeypatch):
+  """Leaf by leaf. Rematerialisation repeats the forward's own operations on
+  the forward's own operands, so the two MoE models agree to the bit (op by
+  op, outside `jit`). A checkpointed layer is still compiled as one program,
+  whose fusions order the recurrent layers' sums otherwise: Olmo-Hybrid's toy
+  is compared in float64 (in float32 it amplifies rounding a thousandfold:
+  `tests/test_olmo_hybrid.py`), to 1e-12 of a leaf's largest value."""
+  module, model_cls, cfg = TOYS[name]
+  exact = name != "olmo_hybrid"
+  with jax.enable_x64(not exact):
+    params, numerical, rows = _case(model_cls, cfg)
+    if not exact:
+      params, numerical, rows = jax.tree_util.tree_map(
+          lambda x: x.astype(jnp.float64), (params, numerical, rows))
+    loss = _loss(model_cls, cfg, numerical)
+    got = jax.grad(loss, argnums=(0, 1))(params, rows)
+    monkeypatch.setattr(module, "checkpoint_layer", lambda layer: layer)
+    want = jax.grad(loss, argnums=(0, 1))(params, rows)
+  flat_got = jax.tree_util.tree_leaves_with_path(got)
+  flat_want = jax.tree_util.tree_leaves(want)
+  assert len(flat_got) == len(flat_want) > 10
+  for (path, g), w in zip(flat_got, flat_want):
+    largest = float(np.max(np.abs(w)))
+    assert largest > 0, jax.tree_util.keystr(path)
+    if exact:
+      assert np.array_equal(g, w), jax.tree_util.keystr(path)
+    else:
+      assert float(np.max(np.abs(np.asarray(g) - np.asarray(w)))) \
+          <= 1e-12 * largest, jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_every_decoder_layer_runs_under_the_plan(name, monkeypatch):
+  """One checkpoint a decoder layer, each with the plan's policy, and none
+  beside them: with the helper taken out the backward holds no checkpoint at
+  all (the toys' shares have no tail)."""
+  module, model_cls, cfg = TOYS[name]
+  params, numerical, rows = _case(model_cls, cfg)
+  # a function object each: `make_jaxpr` remembers what it traced
+  grad = lambda: jax.grad(_loss(model_cls, cfg, numerical))
+  tops = [eqn for eqn in jax.make_jaxpr(grad())(params, rows).jaxpr.eqns
+          if _checkpoint(eqn)]
+  layers = len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
+  assert len(tops) == layers
+  assert all(eqn.params["policy"] is not None for eqn in tops)
+  monkeypatch.setattr(module, "checkpoint_layer", lambda layer: layer)
+  assert _count(jax.make_jaxpr(grad())(params, rows).jaxpr, _checkpoint) == 0
+
+
+def _splash_layer(name):
+  """One attention layer of model ``name`` through its own splash path in
+  Pallas's interpreter, a projection before it (so that ``q``, ``k``, ``v``
+  are rebuilt, not arguments) -> (loss(w, x), w, x, layers)."""
+  rng = np.random.default_rng(0)
+  length, hkv, group, hd = 128, 1, 2, 128
+  x = jnp.asarray(rng.normal(size=(1, length, 32)), jnp.float32)
+  w = jnp.asarray(rng.normal(size=(2, 32, (group + 2) * hkv * hd)) * 0.2,
+                  jnp.float32)
+  seg = jnp.asarray(np.arange(length)[None, :] >= 70, jnp.int32)
+
+  def attend(q, k, v):
+    if name == "sdar_moe":     # [xt ; x0]: 2 L positions of L = 64
+      return sdar_moe.attention_splash(
+          q.reshape(1, length, hkv, group, hd), k, v, length // 2, 4, 128,
+          interpret=True)
+    if name == "laguna":
+      return laguna.attention_splash(
+          q.reshape(1, length, hkv, group, hd), k, v, seg, 128, 40,
+          interpret=True)
+    kv = lambda t: jnp.repeat(t, group, axis=2)
+    return olmo_hybrid.attention_splash(q, kv(k), kv(v), seg, 128,
+                                        interpret=True)
+
+  def layer(wl, x):
+    qkv = (x @ wl).reshape(1, length, (group + 2) * hkv, hd)
+    o = attend(qkv[:, :, :group * hkv] * hd ** -0.5,
+               qkv[:, :, group * hkv:(group + 1) * hkv],
+               qkv[:, :, (group + 1) * hkv:])
+    return x + jnp.tanh(o.reshape(1, length, -1))[..., :32]
+
+  def loss(wrap, w, x):
+    for wl in w:
+      x = wrap(layer)(wl, x)
+    return jnp.sum(jnp.sin(x))
+
+  return loss, w, x, len(w)
+
+
+def _forward_kernel(eqn):
+  return eqn.primitive.name == "pallas_call" and "fwd" in str(
+      eqn.params.get("name", eqn.params.get("name_and_src_info", "")))
+
+
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_the_splash_output_outlives_its_layer(name, capsys):
+  """Under the plan the backward holds ONE forward kernel a layer (the
+  forward pass's own), the two named values are among the layer's residuals,
+  and the gradients are those of the layers with no checkpoint."""
+  loss, w, x, layers = _splash_layer(name)
+  plan = functools.partial(loss, remat.checkpoint_layer)
+  bare = functools.partial(loss, jax.checkpoint)
+  plain = functools.partial(loss, lambda layer: layer)
+  forward_kernels = lambda f: _count(
+      jax.make_jaxpr(jax.grad(f, argnums=(0, 1)))(w, x).jaxpr,
+      _forward_kernel)
+  assert forward_kernels(plan) == layers
+  assert forward_kernels(bare) == 2 * layers      # what the models ran before
+  # a layer's `out` in bfloat16 and its log-sum-exp (a float32 a query and
+  # head) are kept by the plan and by no bare checkpoint
+  for wrap, times in [(plan, layers), (bare, 0)]:
+    kept = _residuals(capsys, wrap, w, x)
+    assert sum(k.startswith("bf16[") for k in kept) == times
+    assert sum(k.endswith(",2,128]") and k.startswith("f32[")
+               for k in kept) == times
+  got = jax.jit(jax.grad(plan, argnums=(0, 1)))(w, x)
+  want = jax.jit(jax.grad(plain, argnums=(0, 1)))(w, x)
+  for g, t in zip(got, want):
+    np.testing.assert_allclose(g, t, atol=1e-5 * float(jnp.max(jnp.abs(t))))
+    assert float(jnp.max(jnp.abs(t))) > 0
+
+
+def _moe_weights(seed):
+  """`tests/test_moe.py`'s weights on the same seeds."""
+  t, d, f, e = 64, 16, 24, 32
+  rng = np.random.default_rng(seed)
+  f32 = lambda *shape, s=1.0: jnp.asarray(rng.normal(size=shape) * s,
+                                          jnp.float32)
+  return (f32(t, d), f32(d, e), f32(e, d, f, s=0.3), f32(e, d, f, s=0.3),
+          f32(e, f, d, s=0.3))
+
+
+@pytest.mark.parametrize("router", [Router(), Router("sigmoid", True, 2.5)],
+                         ids=["softmax", "sigmoid"])
+def test_the_expert_head_is_computed_twice_and_sorted_once(router, capsys):
+  """Under the plan the backward of one expert layer holds 12 grouped
+  matmuls outside the tail's `cond` (3 forward, 3 rematerialised, 6
+  backward) and one sort; under a bare checkpoint the keys are sorted twice.
+  Called with no checkpoint round it the head keeps its residuals: 9 (12
+  while the head rematerialised itself)."""
+  h, wr, wg, wu, wd = _moe_weights(2)
+  share = MoEShare(32, 2, (2, 4), router)
+  assert share.head_rows(64 * 2) < 64 * 2          # a tail exists, as in a cell
+  args = (h, wr, wg[2:6], wu[2:6], wd[2:6])
+  layer = lambda *a: moe_share(*a, share)[0]
+  count = lambda wrap, what: _count(jax.make_jaxpr(jax.grad(
+      lambda a: jnp.sum(jnp.sin(wrap(layer)(*a)))))(args).jaxpr,
+                                    _primitive(what), skip=("cond",))
+  assert count(remat.checkpoint_layer, "ragged_dot_general") == 12
+  assert count(remat.checkpoint_layer, "sort") == 1
+  assert count(jax.checkpoint, "ragged_dot_general") == 12
+  assert count(jax.checkpoint, "sort") == 2
+  assert count(lambda f: f, "ragged_dot_general") == 9
+  assert _residuals(capsys, lambda a: jnp.sum(
+      remat.checkpoint_layer(layer)(*a)), args, named=remat.MOE_ROUTE) \
+      == ["i32[128]", "i32[4]"]                        # order, loads
+  with jax.default_matmul_precision("highest"):
+    got = jax.grad(lambda a: jnp.sum(jnp.sin(
+        remat.checkpoint_layer(layer)(*a))))(args)
+    want = jax.grad(lambda a: jnp.sum(jnp.sin(layer(*a))))(args)
+  for g, w in zip(got, want):
+    assert np.array_equal(g, w)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_the_counters_are_what_they_were(seed):
+  """`assignments`, `loads`, `computed` on the seeds `tests/test_moe.py`
+  uses: the named order and loads are the values they name, and the counters
+  come out of a rematerialised layer as out of a plain one."""
+  h, wr, wg, wu, wd = _moe_weights(seed)
+  top_e = np.asarray(jax.lax.top_k(jax.nn.softmax(jnp.dot(
+      h, wr, precision="highest"), axis=-1), 2)[1])
+  for first, held in [(0, 32), (2, 4), (24, 8), (3, 1)]:
+    share = MoEShare(32, 2, (first, held))
+    sl = slice(first, first + held)
+    run = lambda wrap: wrap(lambda *a: moe_share(*a, share))(
+        h, wr, wg[sl], wu[sl], wd[sl])
+    (out, c), (planned, pc) = run(lambda f: f), run(remat.checkpoint_layer)
+    loads = np.bincount(top_e.reshape(-1), minlength=32)[sl]
+    assert np.array_equal(c["loads"], loads)
+    assert int(c["assignments"]) == int(c["computed"]) == int(loads.sum())
+    for key in ("assignments", "loads", "computed"):
+      assert np.array_equal(c[key], pc[key])
+    assert np.array_equal(out, planned)
+
+
+def _toy_step(wrap):
+  """A hand-built step of two expert layers -> its compiled HLO's text."""
+  h, wr, wg, wu, wd = _moe_weights(0)
+  share = MoEShare(32, 2, (0, 32))
+  layer = lambda h, *w: h + moe_share(h, *w, share)[0]
+
+  def loss(w, h):
+    for _ in range(2):
+      h = wrap(layer)(h, *w)
+    return jnp.sum(jnp.sin(h))
+
+  return jax.jit(jax.grad(loss)).lower((wr, wg, wu, wd), h)
+
+
+def test_the_tool_counts_a_steps_calls():
+  """`tools/step_recompute.py::count_ops` on a hand-written module in the
+  compiled text's form (kernel names as the TPU compiler gives them), and on
+  a toy step's sorts as this backend compiles them."""
+  text = """HloModule jit_step
+
+%compare.1 (a: s32[], b: s32[]) -> pred[] {
+  %a = s32[] parameter(0)
+  %b = s32[] parameter(1)
+  ROOT %lt = pred[] compare(%a, %b), direction=LT
+}
+
+%branch_walk.3 (p: f32[8,4]) -> f32[8,4] {
+  %p = f32[8,4]{1,0} parameter(0)
+  %ragged-dot-metadata.9 = (s32[17]{0}, s32[1]{0}) custom-call(%p), custom_call_target="tpu_custom_call"
+  ROOT %ragged-dot-none.7 = f32[8,4]{1,0:T(8,128)} custom-call(%p, %p), custom_call_target="tpu_custom_call"
+}
+
+%branch_rest.4 (p.1: f32[8,4]) -> f32[8,4] {
+  ROOT %p.1 = f32[8,4]{1,0} parameter(0)
+}
+
+ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
+  %x = f32[8,4]{1,0} parameter(0)
+  %k = s32[8]{0} parameter(1)
+  %iota.1 = s32[8]{0} iota(), iota_dimension=0
+  %sort.31 = (s32[8]{0:T(1024)}, s32[8]{0}) sort(%k, %iota.1), dimensions={0}, is_stable=true, to_apply=%compare.1, metadata={op_name="jit(step)/jvp(de_model)/M/de_moe/de_moe_route/jit(argsort)/sort"}
+  %sort.30 = (f32[8,4]{0,1}, s32[8,4]{0,1}) sort(%x, %x), dimensions={1}, to_apply=%compare.1, metadata={op_name="jit(step)/jvp(de_model)/M/de_moe/de_moe_route/top_k"}
+  %sort.2 = (s32[8]{0}, s32[8]{0}) sort(%k, %iota.1), dimensions={0}, to_apply=%compare.1, metadata={op_name="jit(step)/de_apply/sort"}
+  %splash_mqa_fwd_segmented_residuals.15 = (bf16[8,4]{1,0}, f32[8]{0}) custom-call(%x), custom_call_target="tpu_custom_call"
+  %splash_mqa_dkv_segmented_no_residuals.10 = bf16[8,4]{1,0} custom-call(%x), custom_call_target="tpu_custom_call"
+  %splash_mha_fwd_segmented_residuals.2 = (bf16[8,4]{1,0}, f32[8]{0}) custom-call(%x), custom_call_target="tpu_custom_call"
+  %ragged-dot-metadata.5 = (s32[17]{0}, s32[1]{0}) custom-call(%k), custom_call_target="tpu_custom_call"
+  %ragged-dot-none.12 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
+  %ragged-dot-none.13 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
+  %pred = pred[] constant(true)
+  ROOT %conditional.1 = f32[8,4]{1,0} conditional(%pred, %ragged-dot-none.12, %x), true_computation=%branch_walk.3, false_computation=%branch_rest.4
+}
+"""
+  assert step_recompute.count_ops(text) == {
+      "splash_fwd": 2, "ragged_dot": 2, "ragged_dot_tail": 1, "sort": 3,
+      "route_sort": 1, "route_top_k": 1}
+  # a toy step as this backend compiles it: the route's argsort once a layer
+  # under the plan, twice under a bare checkpoint
+  counts = {name: step_recompute.count_ops(_toy_step(wrap).compile().as_text())
+            for name, wrap in [("plan", remat.checkpoint_layer),
+                               ("bare", jax.checkpoint)]}
+  assert counts["plan"]["route_sort"] == 2
+  assert counts["bare"]["route_sort"] == 4
+  assert counts["plan"]["splash_fwd"] == counts["plan"]["ragged_dot_tail"] == 0
+
+
+def test_kept_is_what_the_code_names():
+  """`KEPT` lists the names the kernels' builders and the expert layer give,
+  and nothing else: no configuration, option or environment says what is
+  kept."""
+  assert set(remat.KEPT) == {remat.SPLASH_RESIDUALS, remat.MOE_ROUTE}
+  for build, args in [
+      (sdar_moe._splash_kernel, (16, 4, 2, 128, True)),
+      (laguna._splash_kernel, (128, 2, None, 128, True)),
+      (laguna._splash_kernel, (128, 2, 40, 128, True)),
+      (olmo_hybrid._splash_kernel, (128, 2, 128, True))]:
+    assert build(*args).kwargs["residual_checkpoint_name"] \
+        == remat.SPLASH_RESIDUALS
+  assert not [f.name for cfg in (sdar_moe.SDARMoEConfig, laguna.LagunaConfig,
+                                 olmo_hybrid.OlmoHybridConfig)
+              for f in dataclasses.fields(cfg)
+              if "remat" in f.name or "checkpoint" in f.name]

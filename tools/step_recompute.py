@@ -1,0 +1,179 @@
+"""What a language-model cell's compiled step runs twice, and what it costs
+to keep what it does not.
+
+`hbm_peak_gib` is the allocator's peak after a window and leaves a step's
+temporaries out, so it cannot see what a rematerialisation plan
+(`layers/remat.py`) spends; and a trace names the kernels a step ran, not how
+many of them were repeats. This compiles the cell's training step, as the
+benchmark builds it, for a DESCRIBED v5e (no chip: the TPU's compiler is
+installed beside JAX) and prints, for one step, from the compiled HLO:
+
+  splash_fwd   calls of the splash-attention forward kernel: one a layer
+               where its output is kept, two where the layer's forward is
+               run again whole
+  ragged_dot   grouped matmuls of the expert layers' heads (a layer: 3
+               forward, 3 rematerialised, 6 backward = 12); ragged_dot_tail:
+               those inside a conditional (the tail's, walked only where a
+               router overflows the head)
+  route_sort   the expert layers' stable argsort of the assignments: once a
+               layer where the sorted order is kept; route_top_k: the sorts
+               the router's `top_k` compiles to (forward and rematerialised);
+               sort: every sort op of the step (scatters' and the summed
+               table rule's among them)
+  state_gb, temporaries_gb, total_gb   the compiled step's arguments (the
+               train state and a batch) and its temporaries, in GB of the
+               chip's 17.18
+
+Counts and the compiler's own byte counts: nothing runs and nothing here is
+a time. The model's own check of its backend is answered "tpu" in this
+process, as `benchmark/README.md` step 3 says a rehearsal may; a minute a cell.
+
+  JAX_PLATFORMS=cpu python tools/step_recompute.py laguna_moe_train_1chip
+"""
+
+import argparse
+import json
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+# an instruction of HLO text: `%name = type opcode(operands), attributes`
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?:\([^=]*?\)|\S+)\s+"
+    r"(?P<opcode>[\w\-]+)\(")
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}"
+                       r"|(?:true|false)_computation=([^,\s]+)")
+_CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _computations(hlo_text: str):
+  """name -> the lines of each computation of an HLO module's text."""
+  out, name = {}, None
+  for line in hlo_text.splitlines():
+    m = _COMPUTATION.match(line)
+    if m:
+      name = m.group(1)
+      out[name] = []
+    elif line.strip() == "}":
+      name = None
+    elif name is not None:
+      out[name].append(line)
+  return out
+
+
+def _branches(line: str):
+  """Names of the computations an instruction's line gives as branches."""
+  return [name.strip().lstrip("%") for m in _BRANCHES.finditer(line)
+          for name in (m.group(1) or m.group(2)).split(",")]
+
+
+def _under_conditionals(comps):
+  """Names of the computations reached through a ``conditional``'s branches
+  (and whatever those call)."""
+  todo = [name for lines in comps.values() for line in lines
+          for name in _branches(line)]
+  seen = set()
+  while todo:
+    name = todo.pop()
+    if name in seen or name not in comps:
+      continue
+    seen.add(name)
+    for line in comps[name]:
+      todo.extend(_CALLS.findall(line) + _branches(line))
+  return seen
+
+
+def count_ops(hlo_text: str):
+  """-> calls in a compiled program's HLO text, each instruction counted once,
+  where it is defined. The TPU compiler names a Pallas or Mosaic call after
+  its kernel: a splash forward kernel is a custom call named
+  ``splash_*fwd*``, a grouped matmul one named ``ragged-dot-*`` (its
+  ``ragged-dot-metadata`` calls, a few hundred bytes each, are not counted;
+  ``ragged_dot_tail``: those inside a conditional). A sort is told by the
+  ``op_name`` the program gave it: ``route_sort`` is the expert layer's stable
+  argsort, ``route_top_k`` the sort the router's ``top_k`` compiles to."""
+  comps = _computations(hlo_text)
+  tail = _under_conditionals(comps)
+  counts = dict.fromkeys(("splash_fwd", "ragged_dot", "ragged_dot_tail",
+                          "sort", "route_sort", "route_top_k"), 0)
+  for comp, lines in comps.items():
+    for line in lines:
+      m = _INSTRUCTION.match(line)
+      if not m:
+        continue
+      opcode, name = m.group("opcode"), m.group("name")
+      if opcode == "sort":
+        counts["sort"] += 1
+        op_name = _OP_NAME.search(line)
+        where = op_name.group(1) if op_name else ""
+        if where.endswith("de_moe_route/jit(argsort)/sort"):
+          counts["route_sort"] += 1
+        elif where.endswith("de_moe_route/top_k"):
+          counts["route_top_k"] += 1
+      elif opcode == "ragged-dot" or (
+          opcode == "custom-call"
+          and re.match(r"ragged-dot-(?!metadata)", name)):
+        counts["ragged_dot_tail" if comp in tail else "ragged_dot"] += 1
+      elif opcode == "custom-call" and re.match(r"splash_\w*fwd", name):
+        counts["splash_fwd"] += 1
+  return counts
+
+
+def compile_step(cell_name: str):
+  """The cell's training step compiled for one chip of a described v5e ->
+  the compiled executable."""
+  import jax
+  from jax.experimental import topologies
+  from jax.sharding import SingleDeviceSharding
+
+  from benchmark import program, specs, traffic
+
+  cell = specs.load_cell(cell_name)
+  if cell.chips != 1:
+    raise SystemExit(f"{cell_name}: a cell of {cell.chips} chips; this tool "
+                     "describes one")
+  # the models ask the backend before they name a TPU kernel, and this
+  # process compiles for a chip it does not have
+  jax.default_backend = lambda: "tpu"
+  family = cell.family()
+  spec = family.model_spec(cell.config)
+  parts = family.build_parts(cell.config, cell.chips,
+                             int(cell.traffic["global_batch"]))
+  batch = traffic.make_batch(cell.traffic, spec.inputs, spec.n_numerical, 0,
+                             0, traffic.family_labels(family, cell.config))
+  topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+  chip = SingleDeviceSharding(topo.devices[0])
+  on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
+  prog = program.Program(parts, spec, 0, None)
+  # the benchmark's own builder of the step, fed shapes on the described chip
+  # where its window feeds arrays on a real one
+  prog.put = lambda b: jax.tree_util.tree_map(
+      on_chip, (b.numerical, b.cats, b.labels))
+  return prog.compile_step(
+      jax.tree_util.tree_map(on_chip, prog.state_avals()), batch)
+
+
+def main(argv=None):
+  ap = argparse.ArgumentParser()
+  ap.add_argument("cell")
+  args = ap.parse_args(argv)
+  compiled = compile_step(args.cell)
+  mem = compiled.memory_analysis()
+  gb = lambda n: round(n / 1e9, 3)
+  report = {"cell": args.cell, "compiled_for": "v5e (described, no chip)",
+            **count_ops(compiled.as_text()),
+            "state_gb": gb(mem.argument_size_in_bytes),
+            "temporaries_gb": gb(mem.temp_size_in_bytes),
+            "total_gb": gb(mem.argument_size_in_bytes
+                           + mem.temp_size_in_bytes)}
+  print(json.dumps(report))
+  return report
+
+
+if __name__ == "__main__":
+  main()
