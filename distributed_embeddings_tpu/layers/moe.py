@@ -156,31 +156,35 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
   t, k = h.shape[0], share.top_k
   n = t * k
   with jax.named_scope(scopes.MOE):
+    # de_moe_route's four parts partition it: every statement under it lies
+    # under exactly one of them (telemetry/scopes.py)
     with jax.named_scope(scopes.MOE_ROUTE):
-      top_p, top_e = route(h, w_router, k, share.router)
-      local = top_e.astype(jnp.int32) - first
-      here = (local >= 0) & (local < count)
-      # sort key: the held expert's local number; `count` for the rest, so
-      # the assignments of held experts are the sorted stream's head
-      key = jnp.where(here, local, count).reshape(n)
-      # named: a caller that rematerialises the layer keeps these two and
-      # neither sorts nor counts the keys again (layers/remat.py)
-      order = checkpoint_name(
-          jnp.argsort(key, stable=True).astype(jnp.int32), MOE_ROUTE)
-      loads = checkpoint_name(
-          jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32),
-          MOE_ROUTE)
-      ends = jnp.cumsum(loads)
-      starts = ends - loads
-      n_live = ends[-1]
-      head = share.head_rows(n)
-      tail = n - head
-      chunk = min(head, tail)
-      n_chunks = -(-tail // chunk) if tail else 0
-      pad = n_chunks * chunk - tail
-      order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
-      tok = order // k
-      p_sorted = jnp.take(top_p.reshape(n), order)
+      with jax.named_scope(scopes.MOE_ROUTER):
+        top_p, top_e = route(h, w_router, k, share.router)
+      with jax.named_scope(scopes.MOE_SORT):
+        local = top_e.astype(jnp.int32) - first
+        here = (local >= 0) & (local < count)
+        # sort key: the held expert's local number; `count` for the rest, so
+        # the assignments of held experts are the sorted stream's head
+        key = jnp.where(here, local, count).reshape(n)
+        # named: a caller that rematerialises the layer keeps these two and
+        # neither sorts nor counts the keys again (layers/remat.py)
+        order = checkpoint_name(
+            jnp.argsort(key, stable=True).astype(jnp.int32), MOE_ROUTE)
+        loads = checkpoint_name(
+            jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32),
+            MOE_ROUTE)
+        ends = jnp.cumsum(loads)
+        starts = ends - loads
+        n_live = ends[-1]
+        head = share.head_rows(n)
+        tail = n - head
+        chunk = min(head, tail)
+        n_chunks = -(-tail // chunk) if tail else 0
+        pad = n_chunks * chunk - tail
+        order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+        tok = order // k
+        p_sorted = jnp.take(top_p.reshape(n), order)
 
     def rows_of(begin, size, whole, h, tok_c, p_c, w_gate, w_up, w_down):
       """-> (the weighted expert outputs ``[size, d]`` of the sorted stream's
@@ -194,7 +198,8 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
       done = jnp.sum(sizes)
       if whole:
         sizes = sizes.at[-1].add(size - done)
-      with jax.named_scope(scopes.MOE_ROUTE):
+      with jax.named_scope(scopes.MOE_ROUTE), \
+          jax.named_scope(scopes.MOE_DISPATCH):
         # rows past the live count are zeros going in and selected away
         # coming out (left to no group, the grouped matmul would not even
         # write them)
@@ -203,7 +208,8 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
         gate = lax.ragged_dot(x, w_gate, sizes)
         up = lax.ragged_dot(x, w_up, sizes)
         y = lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
-      with jax.named_scope(scopes.MOE_ROUTE):
+      with jax.named_scope(scopes.MOE_ROUTE), \
+          jax.named_scope(scopes.MOE_RETURN):
         return jnp.where(live[:, None], y * p_c[:, None].astype(y.dtype),
                          0), done
 
@@ -211,7 +217,8 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     # rebuilt is its caller's plan (layers/remat.py)
     y, computed = rows_of(0, head, True, h, tok[:head], p_sorted[:head],
                           w_gate, w_up, w_down)
-    with jax.named_scope(scopes.MOE_ROUTE):
+    with jax.named_scope(scopes.MOE_ROUTE), \
+        jax.named_scope(scopes.MOE_RETURN):
       out = jnp.zeros_like(h).at[tok[:head]].add(y)
 
     if tail:
@@ -231,7 +238,8 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
             y, live = jax.checkpoint(functools.partial(
                 rows_of, begin, chunk, False))(
                     h, tok_c, p_c, w_gate, w_up, w_down)
-            with jax.named_scope(scopes.MOE_ROUTE):
+            with jax.named_scope(scopes.MOE_ROUTE), \
+                jax.named_scope(scopes.MOE_RETURN):
               return (acc.at[tok_c].add(y), done + live), None
           carry, _ = lax.scan(
               one_chunk, (jnp.zeros_like(h), jnp.int32(0)),

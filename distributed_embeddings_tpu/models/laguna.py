@@ -252,17 +252,25 @@ def attention_mixer(cfg: LagunaConfig, kind: str, p, h, seg):
   group = p["wq"].shape[1] // (hkv * hd)
   inv_freq, factor = rotary_table(cfg, kind)
   positions = jnp.arange(length)
-  q = rope((h @ p["wq"]).reshape(b, length, hkv * group, hd), positions,
-           inv_freq, factor) * hd ** -0.5
-  k = rope((h @ p["wk"]).reshape(b, length, hkv, hd), positions, inv_freq,
-           factor)
-  v = (h @ p["wv"]).reshape(b, length, hkv, hd)
+
+  def proj(x, w):
+    with jax.named_scope(scopes.ATTN_PROJ):
+      return x @ p[w]
+
+  q = proj(h, "wq").reshape(b, length, hkv * group, hd)
+  with jax.named_scope(scopes.ATTN_QK):
+    q = rope(q, positions, inv_freq, factor) * hd ** -0.5
+  k = proj(h, "wk").reshape(b, length, hkv, hd)
+  with jax.named_scope(scopes.ATTN_QK):
+    k = rope(k, positions, inv_freq, factor)
+  v = proj(h, "wv").reshape(b, length, hkv, hd)
   window = cfg.sliding_window if kind == SLIDING else None
   attend = attention_path(cfg.attention, attention_xla, attention_splash)
-  a = attend(q.reshape(b, length, hkv, group, hd), k, v, seg,
-             ATTENTION_BLOCK, window)
-  gate = jax.nn.sigmoid(h @ p["wg"])
-  return (gate * a.reshape(b, length, hkv * group * hd)) @ p["wo"]
+  with jax.named_scope(scopes.ATTN_CORE):
+    a = attend(q.reshape(b, length, hkv, group, hd), k, v, seg,
+               ATTENTION_BLOCK, window)
+  gate = jax.nn.sigmoid(proj(h, "wg"))
+  return proj(gate * a.reshape(b, length, hkv * group * hd), "wo")
 
 
 def decoder_layer(cfg: LagunaConfig, kind: str, mlp: str, p, x, seg):

@@ -121,19 +121,27 @@ def linear_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
   b, length, _ = u.shape
   h, dk, dv = cfg.heads_held[1], cfg.linear_key_head_dim, \
       cfg.linear_value_head_dim
-  short = lambda x, w: jax.nn.silu(causal_conv(x, w, seg))
-  q = short(u @ p["wq"], p["conv_q"]).reshape(b, length, h, dk)
-  k = short(u @ p["wk"], p["conv_k"]).reshape(b, length, h, dk)
-  v = short(u @ p["wv"], p["conv_v"]).reshape(b, length, h, dv)
-  z = (u @ p["wg"]).reshape(b, length, h, dv)
-  beta = jax.nn.sigmoid(u @ p["wb"])
+
+  def proj(x, w):
+    with jax.named_scope(scopes.LINATTN_PROJ):
+      return x @ p[w]
+
+  def short(x, w):
+    with jax.named_scope(scopes.LINATTN_CONV):
+      return jax.nn.silu(causal_conv(x, p[w], seg))
+
+  q = short(proj(u, "wq"), "conv_q").reshape(b, length, h, dk)
+  k = short(proj(u, "wk"), "conv_k").reshape(b, length, h, dk)
+  v = short(proj(u, "wv"), "conv_v").reshape(b, length, h, dv)
+  z = proj(u, "wg").reshape(b, length, h, dv)
+  beta = jax.nn.sigmoid(proj(u, "wb"))
   if cfg.linear_allow_neg_eigval:
     beta = 2.0 * beta
-  g = -jnp.exp(p["a_log"]) * jax.nn.softplus(u @ p["wa"] + p["dt_bias"])
+  g = -jnp.exp(p["a_log"]) * jax.nn.softplus(proj(u, "wa") + p["dt_bias"])
   o, _ = chunk_gated_delta_rule(l2_norm(q) * dk ** -0.5, l2_norm(k), v, g,
                                 beta, seg, cfg.chunk)
   o = rms_norm(o, p["o_norm"], cfg.rms_norm_eps) * jax.nn.silu(z)
-  return o.reshape(b, length, h * dv) @ p["wo"]
+  return proj(o.reshape(b, length, h * dv), "wo")
 
 
 def attention_xla(q, k, v, seg, tile: int):
@@ -188,12 +196,23 @@ def full_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
   ``o Wo``, ``[B, L, d]``."""
   b, length, _ = u.shape
   h, hd = cfg.heads_held[1], cfg.head_dim
-  q = rms_norm(u @ p["wq"], p["q_norm"], cfg.rms_norm_eps) * hd ** -0.5
-  k = rms_norm(u @ p["wk"], p["k_norm"], cfg.rms_norm_eps)
+
+  def proj(x, w):
+    with jax.named_scope(scopes.ATTN_PROJ):
+      return x @ p[w]
+
+  q = proj(u, "wq")
+  with jax.named_scope(scopes.ATTN_QK):
+    q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps) * hd ** -0.5
+  k = proj(u, "wk")
+  with jax.named_scope(scopes.ATTN_QK):
+    k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
   heads = lambda x: x.reshape(b, length, h, hd)
   attend = attention_path(cfg.attention, attention_xla, attention_splash)
-  o = attend(heads(q), heads(k), heads(u @ p["wv"]), seg, ATTENTION_BLOCK)
-  return o.reshape(b, length, h * hd) @ p["wo"]
+  q, k, v = heads(q), heads(k), heads(proj(u, "wv"))
+  with jax.named_scope(scopes.ATTN_CORE):
+    o = attend(q, k, v, seg, ATTENTION_BLOCK)
+  return proj(o.reshape(b, length, h * hd), "wo")
 
 
 def decoder_layer(cfg: OlmoHybridConfig, kind: str, p, x, seg):

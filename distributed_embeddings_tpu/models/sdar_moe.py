@@ -247,18 +247,23 @@ def decoder_layer(cfg: SDARMoEConfig, p, x):
   positions = jnp.tile(jnp.arange(cfg.seq_len), 2)
   with jax.named_scope(scopes.ATTENTION):
     h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-    q = (h @ p["wq"]).reshape(b, s, hq, hd)
-    k = (h @ p["wk"]).reshape(b, s, hkv, hd)
-    v = (h @ p["wv"]).reshape(b, s, hkv, hd)
+    with jax.named_scope(scopes.ATTN_PROJ):
+      q = (h @ p["wq"]).reshape(b, s, hq, hd)
+      k = (h @ p["wk"]).reshape(b, s, hkv, hd)
+      v = (h @ p["wv"]).reshape(b, s, hkv, hd)
     inv_freq = rope_frequencies(cfg.rope_theta, hd)
-    q = rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
-             inv_freq) * (hd ** -0.5)
-    k = rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
-             inv_freq)
+    with jax.named_scope(scopes.ATTN_QK):
+      q = rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
+               inv_freq) * (hd ** -0.5)
+      k = rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
+               inv_freq)
     q = q.reshape(b, s, hkv, hq // hkv, hd)
     attend = attention_path(cfg.attention, attention_xla, attention_splash)
-    o = attend(q, k, v, cfg.seq_len, cfg.block_length, ATTENTION_BLOCK)
-    x = x + o.reshape(b, s, hq * hd) @ p["wo"]
+    with jax.named_scope(scopes.ATTN_CORE):
+      o = attend(q, k, v, cfg.seq_len, cfg.block_length, ATTENTION_BLOCK)
+    with jax.named_scope(scopes.ATTN_PROJ):
+      o = o.reshape(b, s, hq * hd) @ p["wo"]
+    x = x + o
   with jax.named_scope(scopes.MOE):
     h = rms_norm(x, p["moe_norm"], cfg.rms_norm_eps)
   y, counters = moe_share(h.reshape(b * s, d), p["router"], p["w_gate"],

@@ -9,7 +9,8 @@ Why: XLA stages this chain through batch-minor layouts (the h-broadcast
 materializes `{0,1}`, the window-expansion einsum's output is occurrence-
 minor) and transposes back at the EXPANDED stream right before the
 scatter — ~14 ms/step of copies/reshapes/broadcast-multiplies on Tiny
-(traced, tools/trace_zoo.py; two XLA-level reorderings and a layout-pin
+(a round-5 device trace; the zoo cell's delta streams as traced now:
+PERF.md section 5; two XLA-level reorderings and a layout-pin
 identity kernel all measured neutral-to-negative before this kernel —
 the layout choice is XLA's, not the graph's).
 

@@ -5,7 +5,8 @@ XLA's layout assignment keeps the sparse cotangent pipeline batch-minor
 transposes to row-major at the scatter's operand — i.e. at the EXPANDED
 per-occurrence delta stream, after the hotness broadcast and the window
 expansion have multiplied the bytes ~17x (Tiny: ~9 ms/step of
-[1.4M, 128] {0,1}->{1,0} copies, traced in tools/trace_zoo.py).
+[1.4M, 128] {0,1}->{1,0} copies in a round-5 device trace; what the zoo
+cell's trace reads of them now: PERF.md section 5).
 
 `row_major(x)` forces a tensor into default row-major layout at a chosen
 point: pallas_call operands and results use default layouts, so an

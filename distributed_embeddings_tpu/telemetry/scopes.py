@@ -37,9 +37,28 @@ LINEAR_ATTENTION = "de_linear_attention"  # models/olmo_hybrid.py: a gated-delta
 DELTA_RULE = "de_delta_rule"  # layers/gated_delta.py::chunk_gated_delta_rule, the chunked rule alone; inside de_linear_attention
 MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_hybrid.py: every layer's; models/laguna.py: the leading dense layer's); inside de_model
 
+# Parts: always inside the child scope of their layer, one level finer: what an
+# op-level account of a language-model step is read by (benchmark/scope_parts.py).
+# The four de_moe_* parts partition de_moe_route; the others leave their
+# layer a remainder (norms, gates, the residual add) that is read as the
+# layer less its parts and kernels. XLA fuses across a part's line, so a
+# part is exact to a fusion.
+ATTN_PROJ = "de_attn_proj"  # the matmuls with wq, wk, wv, wo (models/laguna.py: and wg); inside de_attention (Laguna: inside de_window_attention / de_full_attention)
+ATTN_QK = "de_attn_qk"  # q and k between projection and kernel: q/k norms, rope, the head_dim ** -0.5 scaling; inside de_attention (Laguna: as above)
+ATTN_CORE = "de_attn_core"  # the call of attend(...): transposes and casts to the kernel's layout, the kernel, the way back; inside de_attention (Laguna: as above)
+MOE_ROUTER = "de_moe_router"  # layers/moe.py::route whole: the router's matmul, the scores, top_k, renormalisation; inside de_moe_route
+MOE_SORT = "de_moe_sort"  # the sort key, argsort, bincount, the cumulative sums, tok, p_sorted; inside de_moe_route
+MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h with its select (transposed: the scatter-add of the cotangent into h); inside de_moe_route
+MOE_RETURN = "de_moe_return"  # the weighting y * p with its select and the scatter-add into the output, head and tail (transposed: a gather); inside de_moe_route
+LINATTN_PROJ = "de_linattn_proj"  # the matmuls with wq, wk, wv, wg, wb, wa, wo; inside de_linear_attention
+LINATTN_CONV = "de_linattn_conv"  # short(...): causal_conv with its reset and the silu, three times; inside de_linear_attention
+
 TOP_LEVEL = (ROUTE, GATHER, COMBINE, MODEL, LOSS, DENSE_UPDATE, APPLY)
 CHILDREN = (ONEHOT, EXCHANGE, INTERACT)
 # a language model's, all inside de_model (benchmark/scope_children*.py read them)
 LM_CHILDREN = (ATTENTION, MOE, MOE_ROUTE, MOE_EXPERTS, LM_HEAD,
                LINEAR_ATTENTION, DELTA_RULE, MLP, WINDOW_ATTENTION,
                FULL_ATTENTION, MOE_SHARED)
+# a language model's parts, each inside one of LM_CHILDREN
+PARTS = (ATTN_PROJ, ATTN_QK, ATTN_CORE, MOE_ROUTER, MOE_SORT, MOE_DISPATCH,
+         MOE_RETURN, LINATTN_PROJ, LINATTN_CONV)

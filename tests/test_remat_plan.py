@@ -310,8 +310,9 @@ def _toy_step(wrap):
 
 def test_the_tool_counts_a_steps_calls():
   """`tools/step_recompute.py::count_ops` on a hand-written module in the
-  compiled text's form (kernel names as the TPU compiler gives them), and on
-  a toy step's sorts as this backend compiles them."""
+  compiled text's form (kernel names as the TPU compiler gives them; the
+  route's sorts with and without the part's scope of PR 38 between), and on a
+  toy step's sorts as this backend compiles them."""
   text = """HloModule jit_step
 
 %compare.1 (a: s32[], b: s32[]) -> pred[] {
@@ -337,6 +338,8 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
   %sort.31 = (s32[8]{0:T(1024)}, s32[8]{0}) sort(%k, %iota.1), dimensions={0}, is_stable=true, to_apply=%compare.1, metadata={op_name="jit(step)/jvp(de_model)/M/de_moe/de_moe_route/jit(argsort)/sort"}
   %sort.30 = (f32[8,4]{0,1}, s32[8,4]{0,1}) sort(%x, %x), dimensions={1}, to_apply=%compare.1, metadata={op_name="jit(step)/jvp(de_model)/M/de_moe/de_moe_route/top_k"}
   %sort.2 = (s32[8]{0}, s32[8]{0}) sort(%k, %iota.1), dimensions={0}, to_apply=%compare.1, metadata={op_name="jit(step)/de_apply/sort"}
+  %sort.33 = (s32[8]{0:T(1024)}, s32[8]{0}) sort(%k, %iota.1), dimensions={0}, is_stable=true, to_apply=%compare.1, metadata={op_name="jit(step)/jvp(de_model)/M/de_moe/de_moe_route/de_moe_sort/jit(argsort)/sort"}
+  %sort.34 = (f32[8,4]{0,1}, s32[8,4]{0,1}) sort(%x, %x), dimensions={1}, to_apply=%compare.1, metadata={op_name="jit(step)/jvp(de_model)/M/de_moe/de_moe_route/de_moe_router/top_k"}
   %splash_mqa_fwd_segmented_residuals.15 = (bf16[8,4]{1,0}, f32[8]{0}) custom-call(%x), custom_call_target="tpu_custom_call"
   %splash_mqa_dkv_segmented_no_residuals.10 = bf16[8,4]{1,0} custom-call(%x), custom_call_target="tpu_custom_call"
   %splash_mha_fwd_segmented_residuals.2 = (bf16[8,4]{1,0}, f32[8]{0}) custom-call(%x), custom_call_target="tpu_custom_call"
@@ -348,8 +351,8 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
 }
 """
   assert step_recompute.count_ops(text) == {
-      "splash_fwd": 2, "ragged_dot": 2, "ragged_dot_tail": 1, "sort": 3,
-      "route_sort": 1, "route_top_k": 1}
+      "splash_fwd": 2, "ragged_dot": 2, "ragged_dot_tail": 1, "sort": 5,
+      "route_sort": 2, "route_top_k": 2}
   # a toy step as this backend compiles it: the route's argsort once a layer
   # under the plan, twice under a bare checkpoint
   counts = {name: step_recompute.count_ops(_toy_step(wrap).compile().as_text())
@@ -358,6 +361,64 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
   assert counts["plan"]["route_sort"] == 2
   assert counts["bare"]["route_sort"] == 4
   assert counts["plan"]["splash_fwd"] == counts["plan"]["ragged_dot_tail"] == 0
+
+
+def test_program_sha_sees_the_program_and_not_where_it_came_from():
+  """`program_sha`: names of scopes, files and lines change nothing (the
+  instruction's `metadata`, its `frontend_attributes`, the module's tables of
+  locations, the locations inside a kernel's body); an instruction does."""
+  text = """HloModule jit_step, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
+
+FileNames
+1 "/somewhere/models/sdar_moe.py"
+
+FunctionNames
+1 "decoder_layer"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=255 end_line=255 column=4 end_column=20}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+
+ENTRY %main.3 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %neg.1 = f32[8]{0} negate(%x), metadata={op_name="jit(step)/de_model/de_attention/neg" source_file="/somewhere/models/sdar_moe.py" source_line=255 stack_frame_id=1}
+  ROOT %sin.2 = f32[8]{0} sine(%neg.1), frontend_attributes={_scope="{a}"}, metadata={op_name="jit(step)/de_model/de_attention/sin"}
+}
+"""
+  moved = text.replace("de_attention/", "de_attention/de_attn_qk/") \
+      .replace("=255", "=263").replace("/somewhere/", "/elsewhere/") \
+      .replace('_scope="{a}"', '_scope="{b}"')
+  assert moved != text
+  assert step_recompute.program_sha(moved) == step_recompute.program_sha(text)
+  bare = step_recompute.without_provenance(text)
+  assert "metadata" not in bare and "sdar_moe.py" not in bare \
+      and "frontend_attributes" not in bare and "negate(%x)" in bare
+  assert step_recompute.program_sha(text.replace("negate(", "abs(")) \
+      != step_recompute.program_sha(text)
+  # a Pallas kernel's body carries the line of every call on the way to it
+  import base64
+
+  from jax._src.interpreters import mlir
+  from jax._src.lib.mlir import ir
+
+  def custom_call(line):
+    with mlir.make_ir_context() as ctx, ir.Location.file("model.py", line, 1):
+      ctx.allow_unregistered_dialects = True
+      module = ir.Module.create()
+      with ir.InsertionPoint(module.body):
+        ir.Operation.create("kernel.body", attributes={
+            "block": ir.IntegerAttr.get(ir.IntegerType.get_signless(32), 512)})
+      body = base64.b64encode(module.operation.get_asm(
+          enable_debug_info=True).encode()).decode()
+    return text.replace(
+        "negate(%x)", 'custom-call(%x), custom_call_target="tpu_custom_call", '
+        'backend_config={"custom_call_config":{"body":"' + body + '"}}')
+  assert custom_call(255) != custom_call(263)
+  assert step_recompute.program_sha(custom_call(255)) \
+      == step_recompute.program_sha(custom_call(263)) \
+      != step_recompute.program_sha(text)
 
 
 def test_kept_is_what_the_code_names():

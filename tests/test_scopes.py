@@ -1,9 +1,15 @@
 """Every instruction of the sparse train step lies under a scope of
 ``telemetry/scopes.py``: coverage by count, on the compiled HLO of the toy
 DLRM and the toy zoo step, at world 1 and on a mesh of four virtual devices.
+And the parts of a language-model step (``scopes.PARTS``): where each lies,
+in which passes, and what a rematerialised layer does not run again, on the
+LOWERED text of the three toy models' steps (the CPU's compiler merges a
+rebuilt op with its forward twin; the TPU's barrier forbids that).
 """
 
 import collections
+import contextlib
+import dataclasses
 import re
 
 import jax
@@ -11,15 +17,30 @@ import jax.numpy as jnp
 import optax
 import pytest
 
+import test_laguna
+import test_olmo_hybrid
+import test_sdar_moe
+from distributed_embeddings_tpu.layers import TableConfig, remat
 from distributed_embeddings_tpu.layers.planner import DistEmbeddingStrategy
-from distributed_embeddings_tpu.models import DLRM, SyntheticModel, bce_loss
+from distributed_embeddings_tpu.models import (
+    DLRM,
+    SyntheticModel,
+    bce_loss,
+    laguna,
+    olmo_hybrid,
+    sdar_moe,
+)
 from distributed_embeddings_tpu.models.dlrm import dlrm_embedding_plan
 from distributed_embeddings_tpu.models.synthetic import (
     EmbeddingGroup,
     SyntheticModelConfig,
     expand_tables,
 )
-from distributed_embeddings_tpu.ops.packed_table import adagrad_rule, sgd_rule
+from distributed_embeddings_tpu.ops.packed_table import (
+    adagrad_rule,
+    adam_rule,
+    sgd_rule,
+)
 from distributed_embeddings_tpu.parallel import create_mesh
 from distributed_embeddings_tpu.telemetry import scopes
 from distributed_embeddings_tpu.training import (
@@ -144,13 +165,224 @@ def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
 
 
 def test_the_vocabulary_is_one_flat_set_of_names():
-  names = scopes.TOP_LEVEL + scopes.CHILDREN + scopes.LM_CHILDREN
-  assert len(set(names)) == len(names) == 21
+  names = scopes.TOP_LEVEL + scopes.CHILDREN + scopes.LM_CHILDREN \
+      + scopes.PARTS
+  assert len(set(names)) == len(names) == 30
   for n in names:
     assert n.startswith("de_") and "/" not in n and "(" not in n
   declared = {v for k, v in vars(scopes).items()
               if k.isupper() and isinstance(v, str)}
   assert declared == set(names)
+
+
+# ---- the parts of a language-model step ---------------------------------------
+# the toy models of tests/test_sdar_moe.py, test_olmo_hybrid.py, test_laguna.py
+LM_TOYS = {
+    "sdar_moe": (sdar_moe.SDARMoE, sdar_moe.block_diffusion_loss,
+                 dataclasses.replace(test_sdar_moe.TOY, num_experts=8,
+                                     num_experts_per_tok=2,
+                                     experts_held=(0, 4))),
+    "olmo_hybrid": (olmo_hybrid.OlmoHybrid, olmo_hybrid.next_token_loss,
+                    test_olmo_hybrid.TOY),
+    "laguna": (laguna.Laguna, olmo_hybrid.next_token_loss, test_laguna.TOY),
+}
+# part -> the scope the vocabulary's table puts it inside
+INSIDE = {
+    scopes.ATTN_PROJ: scopes.ATTENTION, scopes.ATTN_QK: scopes.ATTENTION,
+    scopes.ATTN_CORE: scopes.ATTENTION, scopes.MOE_ROUTER: scopes.MOE_ROUTE,
+    scopes.MOE_SORT: scopes.MOE_ROUTE, scopes.MOE_DISPATCH: scopes.MOE_ROUTE,
+    scopes.MOE_RETURN: scopes.MOE_ROUTE,
+    scopes.LINATTN_PROJ: scopes.LINEAR_ATTENTION,
+    scopes.LINATTN_CONV: scopes.LINEAR_ATTENTION}
+ROUTE_PARTS = (scopes.MOE_ROUTER, scopes.MOE_SORT, scopes.MOE_DISPATCH,
+               scopes.MOE_RETURN)
+PARTS_OF = {
+    "sdar_moe": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
+    + ROUTE_PARTS,
+    "olmo_hybrid": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE,
+                    scopes.LINATTN_PROJ, scopes.LINATTN_CONV),
+    "laguna": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
+    + ROUTE_PARTS}
+REMAT = "rematted_computation"   # jax.checkpoint's rebuilt forward
+_LOC = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
+
+
+def _lowered_step(name) -> str:
+  """The toy model's sparse train step (token table as a sequence input
+  under summed Adam), lowered, with every op's name stack."""
+  model_cls, loss, cfg = LM_TOYS[name]
+  batch = 2
+  cats = jnp.zeros((batch, cfg.seq_len), jnp.int32)
+  numerical = jnp.full((batch, getattr(cfg, "n_numerical", cfg.seq_len)),
+                       0.5, jnp.float32)
+  labels = {"targets": cats}
+  plan = DistEmbeddingStrategy(
+      [TableConfig(cfg.vocab_size, cfg.hidden_size, combiner=None)], 1,
+      "memory_balanced", input_table_map=[0], dense_row_threshold=0,
+      input_hotness=[cfg.seq_len], batch_hint=batch)
+  model = model_cls(cfg)
+  dense = model.init(jax.random.PRNGKey(0), numerical, None, emb_acts=[
+      jnp.zeros((batch, cfg.seq_len, cfg.hidden_size))])["params"]
+  rule, opt = adam_rule(3e-3, summed=True), optax.adam(3e-3)
+  state = init_sparse_state_direct(plan, rule, dense, opt,
+                                   jax.random.PRNGKey(1))
+  step = make_sparse_train_step(model, plan, loss, opt, rule, None, state,
+                                (numerical, [cats], labels), donate=False)
+  return step.lower(state, numerical, [cats], labels).as_text(
+      debug_info=True)
+
+
+def _components(name_stack: str):
+  """The names of a name stack, each out of its ``jvp(``/``transpose(``
+  wrappers."""
+  return [m.group(1) if (m := _WRAPPED.match(part)) else part
+          for part in name_stack.split("/")]
+
+
+def _pass_of(name_stack: str) -> str:
+  if REMAT in name_stack.split("/"):
+    return "rebuilt"
+  return "backward" if "transpose(" in name_stack else "forward"
+
+
+@pytest.fixture(scope="module", params=sorted(LM_TOYS))
+def lm_stacks(request):
+  """(toy, the name stacks of its lowered step's ops)."""
+  text = _lowered_step(request.param)
+  return request.param, sorted({s for _, s in _LOC.findall(text)
+                                if s.startswith("jit(")})
+
+
+def test_every_part_lies_only_inside_its_layers_scope(lm_stacks):
+  name, stacks = lm_stacks
+  seen = collections.Counter()
+  for stack in stacks:
+    names = _components(stack)
+    parts = [n for n in names if n in scopes.PARTS]
+    assert len(parts) <= 1, stack           # a part holds no other part
+    for part in parts:
+      seen[part] += 1
+      before = names[:names.index(part)]
+      assert INSIDE[part] in before and scopes.MODEL in before, stack
+      if name == "laguna" and INSIDE[part] == scopes.ATTENTION:
+        # a part of a Laguna mixer lies inside the layer's kind
+        assert before[-1] in (scopes.WINDOW_ATTENTION,
+                              scopes.FULL_ATTENTION), stack
+      else:
+        assert before[-1] == INSIDE[part], stack
+  assert set(seen) == set(PARTS_OF[name])
+
+
+def test_the_four_route_parts_partition_the_expert_route(lm_stacks):
+  name, stacks = lm_stacks
+  under = [s for s in stacks if scopes.MOE_ROUTE in _components(s)]
+  assert bool(under) == (name != "olmo_hybrid")
+  for stack in under:
+    assert sum(p in _components(stack) for p in ROUTE_PARTS) == 1, stack
+
+
+def test_every_part_has_ops_in_all_three_passes(lm_stacks):
+  """Every decoder layer runs under ``checkpoint_layer``, so what a part
+  holds is traced forward, rebuilt and backward."""
+  name, stacks = lm_stacks
+  passes = collections.defaultdict(set)
+  for stack in stacks:
+    for part in set(_components(stack)) & set(scopes.PARTS):
+      passes[part].add(_pass_of(stack))
+  for part in PARTS_OF[name]:
+    assert passes[part] == {"forward", "rebuilt", "backward"}, part
+
+
+def test_what_the_plan_keeps_is_not_rebuilt_under_its_part(lm_stacks):
+  """``remat.KEPT`` by name (``tests/test_remat_plan.py`` holds it by
+  count): the expert route's argsort and bincount are traced in the forward
+  and under no ``rematted_computation/.../de_moe_sort``."""
+  name, stacks = lm_stacks
+  assert remat.MOE_ROUTE in remat.KEPT
+  sorts = [s for s in stacks if scopes.MOE_SORT in _components(s)
+           and re.search(r"jit\((argsort|bincount)\)", s)]
+  assert bool(sorts) == (name != "olmo_hybrid")
+  assert {_pass_of(s) for s in sorts} <= {"forward"}, sorts
+  # the rest of the part is rebuilt: the keys, the sums, `tok`, `p_sorted`
+  if sorts:
+    assert any(_pass_of(s) == "rebuilt" for s in stacks
+               if scopes.MOE_SORT in _components(s))
+
+
+SPLASH_TOYS = {
+    "sdar_moe": dict(head_dim=128, seq_len=64),
+    "olmo_hybrid": dict(head_dim=128, seq_len=128, chunk=64),
+    "laguna": dict(head_dim=128, seq_len=128)}
+
+
+@pytest.mark.parametrize("name", sorted(SPLASH_TOYS))
+def test_no_splash_forward_kernel_is_called_in_a_rebuilt_core(
+    name, monkeypatch):
+  """The toy at a head the kernel takes, ``attention="splash"``, lowered for
+  the TPU with no chip: the forward kernel's program is called once an
+  attention layer, under ``de_attn_core`` in the forward pass; the rebuilt
+  forward (``rematted_computation/.../de_attn_core``) calls none, because
+  its output and log-sum-exp are kept (``remat.SPLASH_RESIDUALS``)."""
+  model_cls, _, cfg = LM_TOYS[name]
+  cfg = dataclasses.replace(cfg, attention="splash", **SPLASH_TOYS[name])
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  model = model_cls(cfg)
+  rows = jnp.zeros((1, cfg.seq_len, cfg.hidden_size))
+  numerical = jnp.full((1, getattr(cfg, "n_numerical", cfg.seq_len)), 0.5,
+                       jnp.float32)
+  params = jax.eval_shape(lambda: model.init(
+      jax.random.PRNGKey(0), numerical, None, emb_acts=[rows]))["params"]
+  grad = jax.value_and_grad(lambda p, r: jnp.sum(model.apply(
+      {"params": p}, numerical, None, emb_acts=[r])["logits"]))
+  text = jax.jit(grad).trace(params, rows).lower(
+      lowering_platforms=("tpu",)).as_text(debug_info=True)
+  locs = dict(_LOC.findall(text))
+  # the private functions that hold a forward kernel, and who calls them
+  forward = set()
+  for body in text.split("func.func ")[1:]:
+    kernels = [locs.get(ref, "") for ref in re.findall(
+        r"tpu_custom_call.*loc\((#loc\d+)\)", body)]
+    if any("fwd" in k for k in kernels):
+      forward.add(re.match(r"(?:private |public )?@([\w.]+)", body).group(1))
+  assert forward
+  sites = [locs[ref] for callee, ref in re.findall(
+      r"call @([\w.]+)\(.*loc\((#loc\d+)\)", text) if callee in forward]
+  layers = len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
+  attention_layers = layers if name != "olmo_hybrid" else sum(
+      kind == olmo_hybrid.FULL for kind in cfg.layer_types)
+  assert len(sites) == attention_layers
+  for stack in sites:
+    assert scopes.ATTN_CORE in _components(stack), stack
+    assert _pass_of(stack) == "forward", stack
+  # the rebuilt core is there all the same (the layout's way in), without it
+  assert any(_pass_of(s) == "rebuilt" and scopes.ATTN_CORE in _components(s)
+             for s in locs.values())
+
+
+@pytest.mark.parametrize("name", sorted(LM_TOYS))
+def test_the_parts_add_no_equation(name, monkeypatch):
+  """A part is a name: the jaxpr of the toy's value-and-gradient is, equation
+  for equation, the one traced with the parts' scopes left out."""
+  model_cls, _, cfg = LM_TOYS[name]
+  model = model_cls(cfg)
+  rows = jnp.zeros((2, cfg.seq_len, cfg.hidden_size))
+  numerical = jnp.full((2, getattr(cfg, "n_numerical", cfg.seq_len)), 0.5,
+                       jnp.float32)
+  params = jax.eval_shape(lambda: model.init(
+      jax.random.PRNGKey(0), numerical, None, emb_acts=[rows]))["params"]
+
+  def jaxpr():
+    # a function object each: `make_jaxpr` remembers what it traced
+    grad = jax.value_and_grad(lambda p, r: jnp.sum(model.apply(
+        {"params": p}, numerical, None, emb_acts=[r])["logits"]))
+    return re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(grad)(
+        params, rows)))
+
+  with_parts = jaxpr()
+  named_scope = jax.named_scope
+  monkeypatch.setattr(jax, "named_scope", lambda n: (
+      contextlib.nullcontext() if n in scopes.PARTS else named_scope(n)))
+  assert jaxpr() == with_parts
 
 
 def test_main_py_profile_dir_traces_five_annotated_steps(tmp_path):

@@ -23,6 +23,11 @@ installed beside JAX) and prints, for one step, from the compiled HLO:
   state_gb, temporaries_gb, total_gb   the compiled step's arguments (the
                train state and a batch) and its temporaries, in GB of the
                chip's 17.18
+  program_sha  sha256 of the compiled step's HLO text with every
+               `metadata={...}`, `frontend_attributes={...}` and source
+               location taken out: what the chip runs, less what a profiler
+               reads. Equal on two commits: the same program, whatever its
+               scopes are named (run this file over each commit's checkout)
 
 Counts and the compiler's own byte counts: nothing runs and nothing here is
 a time. The model's own check of its backend is answered "tpu" in this
@@ -32,6 +37,8 @@ process, as `benchmark/README.md` step 3 says a rehearsal may; a minute a cell.
 """
 
 import argparse
+import base64
+import hashlib
 import json
 import os
 import re
@@ -49,6 +56,20 @@ _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}"
                        r"|(?:true|false)_computation=([^,\s]+)")
 _CALLS = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+# under de_moe_route, with or without the part's scope between
+_ROUTE_SORT = re.compile(r"de_moe_route/(?:de_moe_sort/)?jit\(argsort\)/sort$")
+_ROUTE_TOP_K = re.compile(r"de_moe_route/(?:de_moe_router/)?top_k$")
+# what an instruction's line says of where it came from, and nothing of what
+# it computes: `metadata={op_name=".." source_file=".." ..}` and
+# `frontend_attributes={..}` (one level of braces inside, strings skipped)
+_PROVENANCE = re.compile(
+    r',?\s*(?:metadata|frontend_attributes)=\{(?:[^{}"]|"(?:[^"\\]|\\.)*"'
+    r'|\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\})*\}')
+# the module's own tables of source locations, each up to its blank line
+_TABLES = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:.+\n)*",
+    re.M)
+_KERNEL_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
 
 
 def _computations(hlo_text: str):
@@ -111,9 +132,9 @@ def count_ops(hlo_text: str):
         counts["sort"] += 1
         op_name = _OP_NAME.search(line)
         where = op_name.group(1) if op_name else ""
-        if where.endswith("de_moe_route/jit(argsort)/sort"):
+        if _ROUTE_SORT.search(where):
           counts["route_sort"] += 1
-        elif where.endswith("de_moe_route/top_k"):
+        elif _ROUTE_TOP_K.search(where):
           counts["route_top_k"] += 1
       elif opcode == "ragged-dot" or (
           opcode == "custom-call"
@@ -122,6 +143,37 @@ def count_ops(hlo_text: str):
       elif opcode == "custom-call" and re.match(r"splash_\w*fwd", name):
         counts["splash_fwd"] += 1
   return counts
+
+
+def without_provenance(hlo_text: str) -> str:
+  """A compiled program's HLO text less what says where an instruction came
+  from and nothing of what it computes: every ``metadata={...}`` and
+  ``frontend_attributes={...}``, the module's tables of files, functions,
+  locations and stack frames, and the locations inside a Mosaic kernel's
+  body (MLIR bytecode or text in the custom call's ``backend_config``,
+  base64: a Pallas kernel's carries the file and line of every call on the
+  way to it; it is printed again without them)."""
+  from jax._src.interpreters import mlir
+  from jax._src.lib import tpu
+  from jax._src.lib.mlir import ir
+  ctx = mlir.make_ir_context()
+  tpu.register_dialect(ctx)                # XLA's own kernels come as text
+  ctx.allow_unregistered_dialects = True   # `stable_mosaic`: read, never run
+
+  def kernel_without_locations(match) -> str:
+    with ctx:
+      body = ir.Module.parse(base64.b64decode(match.group(1)))
+      return '"body":' + json.dumps(
+          body.operation.get_asm(enable_debug_info=False))
+
+  text = _PROVENANCE.sub("", _TABLES.sub("", hlo_text))
+  return _KERNEL_BODY.sub(kernel_without_locations, text)
+
+
+def program_sha(hlo_text: str) -> str:
+  """sha256 of :func:`without_provenance`: equal for two programs that
+  differ in names of scopes, files and lines alone."""
+  return hashlib.sha256(without_provenance(hlo_text).encode()).hexdigest()
 
 
 def compile_step(cell_name: str):
@@ -165,12 +217,14 @@ def main(argv=None):
   compiled = compile_step(args.cell)
   mem = compiled.memory_analysis()
   gb = lambda n: round(n / 1e9, 3)
+  text = compiled.as_text()
   report = {"cell": args.cell, "compiled_for": "v5e (described, no chip)",
-            **count_ops(compiled.as_text()),
+            **count_ops(text),
             "state_gb": gb(mem.argument_size_in_bytes),
             "temporaries_gb": gb(mem.temp_size_in_bytes),
             "total_gb": gb(mem.argument_size_in_bytes
-                           + mem.temp_size_in_bytes)}
+                           + mem.temp_size_in_bytes),
+            "program_sha": program_sha(text)}
   print(json.dumps(report))
   return report
 
