@@ -62,6 +62,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import scopes
+from .dense import mxu_dot
 from .remat import MOE_ROUTE
 
 
@@ -134,9 +135,11 @@ def shared_expert(h: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                   w_down: jax.Array):
   """``h [T, d]`` -> ``(silu(h w_gate) * (h w_up)) w_down``: the expert
   every token passes and every chip of an expert-parallel group computes for
-  its own tokens. A plain dense product, outside the sort."""
+  its own tokens. A plain dense product, outside the sort: on a TPU handed
+  bfloat16 operands, float32 out of both passes (``layers/dense.py``)."""
   with jax.named_scope(scopes.MOE), jax.named_scope(scopes.MOE_SHARED):
-    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+    return mxu_dot(jax.nn.silu(mxu_dot(h, w_gate)) * mxu_dot(h, w_up),
+                   w_down)
 
 
 def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,

@@ -45,6 +45,10 @@ sequence input are :mod:`.olmo_hybrid`'s: the batch's numerical features are
 ``u_i < 1 / mean_document_length``; next-token cross-entropy over the
 positions whose next token belongs to the same document; ``emb_acts`` is
 ``[rows [B, L, d]]``.
+
+The projections', the dense MLP's and the head's products are
+:func:`..layers.dense.mxu_dot`: on a TPU handed bfloat16 operands, float32
+out of both passes.
 """
 
 from __future__ import annotations
@@ -59,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..layers.dense import mxu_dot
 from ..layers.moe import MoEShare, Router, moe_share, shared_expert
 from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
 from ..telemetry import scopes
@@ -255,7 +260,7 @@ def attention_mixer(cfg: LagunaConfig, kind: str, p, h, seg):
 
   def proj(x, w):
     with jax.named_scope(scopes.ATTN_PROJ):
-      return x @ p[w]
+      return mxu_dot(x, p[w])
 
   q = proj(h, "wq").reshape(b, length, hkv * group, hd)
   with jax.named_scope(scopes.ATTN_QK):
@@ -285,7 +290,8 @@ def decoder_layer(cfg: LagunaConfig, kind: str, mlp: str, p, x, seg):
   if mlp == DENSE:
     with jax.named_scope(scopes.MLP):
       h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-      y = (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) @ p["w_down"]
+      y = mxu_dot(jax.nn.silu(mxu_dot(h, p["w_gate"]))
+                  * mxu_dot(h, p["w_up"]), p["w_down"])
     return x + y, None
   with jax.named_scope(scopes.MOE):
     h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps).reshape(b * length, d)
@@ -363,7 +369,7 @@ class Laguna(nn.Module):
       if c is not None:
         counters.append(c)
     with jax.named_scope(scopes.LM_HEAD):
-      logits = rms_norm(x, final_norm, cfg.rms_norm_eps) @ head
+      logits = mxu_dot(rms_norm(x, final_norm, cfg.rms_norm_eps), head)
     same = jnp.pad(seg[:, 1:] == seg[:, :-1], ((0, 0), (0, 1)))
     out = {"logits": logits, "weight": same.astype(logits.dtype)}
     if self.with_counters and counters:

@@ -42,6 +42,10 @@ targets are the ids shifted by one).
 On the sparse train step the token table is a sequence input
 (``TableConfig(combiner=None)`` read at hotness ``L``): ``emb_acts`` is
 ``[rows [B, L, d]]``, as in :mod:`.sdar_moe`.
+
+Both mixers' projections, the MLP's and the head's products are
+:func:`..layers.dense.mxu_dot`: on a TPU handed bfloat16 operands, float32
+out of both passes; the rule itself stays at ``highest``.
 """
 
 from __future__ import annotations
@@ -55,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..layers.dense import mxu_dot
 from ..layers.gated_delta import (
     causal_conv,
     chunk_gated_delta_rule,
@@ -124,7 +129,7 @@ def linear_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
 
   def proj(x, w):
     with jax.named_scope(scopes.LINATTN_PROJ):
-      return x @ p[w]
+      return mxu_dot(x, p[w])
 
   def short(x, w):
     with jax.named_scope(scopes.LINATTN_CONV):
@@ -199,7 +204,7 @@ def full_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
 
   def proj(x, w):
     with jax.named_scope(scopes.ATTN_PROJ):
-      return x @ p[w]
+      return mxu_dot(x, p[w])
 
   q = proj(u, "wq")
   with jax.named_scope(scopes.ATTN_QK):
@@ -222,7 +227,8 @@ def decoder_layer(cfg: OlmoHybridConfig, kind: str, p, x, seg):
   with jax.named_scope(scope):
     x = x + rms_norm(mixer(cfg, p, x, seg), p["mixer_norm"], cfg.rms_norm_eps)
   with jax.named_scope(scopes.MLP):
-    y = (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+    y = mxu_dot(jax.nn.silu(mxu_dot(x, p["w_gate"]))
+                * mxu_dot(x, p["w_up"]), p["w_down"])
     return x + rms_norm(y, p["mlp_norm"], cfg.rms_norm_eps)
 
 
@@ -295,7 +301,7 @@ class OlmoHybrid(nn.Module):
       x = checkpoint_layer(functools.partial(decoder_layer, cfg, kind))(
           p, x, seg)
     with jax.named_scope(scopes.LM_HEAD):
-      logits = rms_norm(x, final_norm, cfg.rms_norm_eps) @ head
+      logits = mxu_dot(rms_norm(x, final_norm, cfg.rms_norm_eps), head)
     same = jnp.pad(seg[:, 1:] == seg[:, :-1], ((0, 0), (0, 1)))
     return {"logits": logits, "weight": same.astype(logits.dtype)}
 

@@ -36,6 +36,9 @@ them, are skipped by the kernel's own block map): a TPU kernel, and without a
 TPU the model raises rather than compute something else. ``attention="xla"``
 names the other path, for tests and counting tools on any backend: one tile
 of queries at a time against exactly the keys its blocks can see, in XLA.
+
+The projections' and the head's products are :func:`..layers.dense.mxu_dot`:
+on a TPU handed bfloat16 operands, float32 out of both passes.
 """
 
 from __future__ import annotations
@@ -49,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..layers.dense import mxu_dot
 from ..layers.moe import MoEShare, moe_share
 from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
 from ..telemetry import scopes
@@ -248,9 +252,9 @@ def decoder_layer(cfg: SDARMoEConfig, p, x):
   with jax.named_scope(scopes.ATTENTION):
     h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
     with jax.named_scope(scopes.ATTN_PROJ):
-      q = (h @ p["wq"]).reshape(b, s, hq, hd)
-      k = (h @ p["wk"]).reshape(b, s, hkv, hd)
-      v = (h @ p["wv"]).reshape(b, s, hkv, hd)
+      q = mxu_dot(h, p["wq"]).reshape(b, s, hq, hd)
+      k = mxu_dot(h, p["wk"]).reshape(b, s, hkv, hd)
+      v = mxu_dot(h, p["wv"]).reshape(b, s, hkv, hd)
     inv_freq = rope_frequencies(cfg.rope_theta, hd)
     with jax.named_scope(scopes.ATTN_QK):
       q = rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
@@ -262,7 +266,7 @@ def decoder_layer(cfg: SDARMoEConfig, p, x):
     with jax.named_scope(scopes.ATTN_CORE):
       o = attend(q, k, v, cfg.seq_len, cfg.block_length, ATTENTION_BLOCK)
     with jax.named_scope(scopes.ATTN_PROJ):
-      o = o.reshape(b, s, hq * hd) @ p["wo"]
+      o = mxu_dot(o.reshape(b, s, hq * hd), p["wo"])
     x = x + o
   with jax.named_scope(scopes.MOE):
     h = rms_norm(x, p["moe_norm"], cfg.rms_norm_eps)
@@ -319,7 +323,7 @@ class SDARMoE(nn.Module):
       counters.append(c)
     with jax.named_scope(scopes.LM_HEAD):
       h = rms_norm(x[:, :cfg.seq_len], final_norm, cfg.rms_norm_eps)
-      logits = h @ head
+      logits = mxu_dot(h, head)
     out = {"logits": logits, "weight": weight}
     if self.with_counters:
       out["moe"] = jax.tree_util.tree_map(lambda *c: jnp.stack(c), *counters)

@@ -311,8 +311,9 @@ def _toy_step(wrap):
 def test_the_tool_counts_a_steps_calls():
   """`tools/step_recompute.py::count_ops` on a hand-written module in the
   compiled text's form (kernel names as the TPU compiler gives them; the
-  route's sorts with and without the part's scope of PR 38 between), and on a
-  toy step's sorts as this backend compiles them."""
+  route's sorts with and without the part's scope of PR 38 between; plain
+  products by the type of their operands), and on a toy step's sorts as this
+  backend compiles them."""
   text = """HloModule jit_step
 
 %compare.1 (a: s32[], b: s32[]) -> pred[] {
@@ -329,6 +330,18 @@ def test_the_tool_counts_a_steps_calls():
 
 %branch_rest.4 (p.1: f32[8,4]) -> f32[8,4] {
   ROOT %p.1 = f32[8,4]{1,0} parameter(0)
+}
+
+%fused_dense.6 (p.2: f32[8,4], p.3: f32[4,4]) -> f32[8,4] {
+  %p.2 = f32[8,4]{1,0} parameter(0)
+  %p.3 = f32[4,4]{1,0} parameter(1)
+  %fusion.9 = bf16[8,4]{1,0:T(8,128)(2,1)} fusion(%p.2), kind=kLoop, calls=%branch_rest.4
+  %fusion.10 = bf16[4,4]{1,0:T(8,128)(2,1)} fusion(%p.3), kind=kLoop, calls=%branch_rest.4
+  %convolution.7 = f32[8,4]{1,0:T(8,128)} convolution(%fusion.9, %fusion.10), dim_labels=bf_io->bf
+  %convolution.8 = f32[8,4]{1,0:T(8,128)} convolution(%p.2, %p.3), dim_labels=bf_io->bf, operand_precision={highest,highest}
+  %dot.3 = f32[8,4]{1,0} dot(f32[8,4]{1,0:T(8,128)} %p.2, bf16[4,4]{1,0:T(8,128)(2,1)} %fusion.10), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %dot.5 = s32[8,4]{1,0} dot(s8[8,4]{1,0} %p.2, s8[4,4]{1,0} %p.3), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  ROOT %dot.4 = f32[8,4]{1,0} dot(bf16[8,4]{1,0:T(8,128)(2,1)} %fusion.9, bf16[4,4]{1,0} %fusion.10), lhs_contracting_dims={1}, rhs_contracting_dims={0}
 }
 
 ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
@@ -352,7 +365,11 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
 """
   assert step_recompute.count_ops(text) == {
       "splash_fwd": 2, "ragged_dot": 2, "ragged_dot_tail": 1, "sort": 5,
-      "route_sort": 2, "route_top_k": 2}
+      "route_sort": 2, "route_top_k": 2,
+      # plain products by what they are handed, operands by name or with
+      # their types beside them: two of bfloat16 alone, two with a float32
+      # operand (one of them mixed), an integer one in neither
+      "dense_dot_f32": 2, "dense_dot_bf16": 2}
   # a toy step as this backend compiles it: the route's argsort once a layer
   # under the plan, twice under a bare checkpoint
   counts = {name: step_recompute.count_ops(_toy_step(wrap).compile().as_text())

@@ -20,6 +20,13 @@ installed beside JAX) and prints, for one step, from the compiled HLO:
                the router's `top_k` compiles to (forward and rematerialised);
                sort: every sort op of the step (scatters' and the summed
                table rule's among them)
+  dense_dot_f32, dense_dot_bf16   the step's plain products (`dot` and
+               `convolution` instructions, which is what XLA makes of a
+               matmul; a Mosaic kernel's products are inside its body and
+               not counted) by what they are handed: every operand bfloat16,
+               or some operand float32 (the router's logits and the delta
+               rule's products, which stay at `highest`, and any product
+               `layers/dense.py` does not serve)
   state_gb, temporaries_gb, total_gb   the compiled step's arguments (the
                train state and a batch) and its temporaries, in GB of the
                chip's 17.18
@@ -49,7 +56,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 # an instruction of HLO text: `%name = type opcode(operands), attributes`
 _INSTRUCTION = re.compile(
-    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?:\([^=]*?\)|\S+)\s+"
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?P<type>\([^=]*?\)|\S+)\s+"
     r"(?P<opcode>[\w\-]+)\(")
 _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}"
@@ -87,6 +94,25 @@ def _computations(hlo_text: str):
   return out
 
 
+def _operands(line: str, start: int):
+  """The operands of the instruction whose ``(`` ends at ``start``, split at
+  the commas outside every bracket."""
+  out, depth, piece = [], 0, []
+  for ch in line[start:]:
+    if ch in "([{":
+      depth += 1
+    elif ch in ")]}":
+      if depth == 0:
+        break
+      depth -= 1
+    if ch == "," and depth == 0:
+      out.append("".join(piece).strip())
+      piece = []
+    else:
+      piece.append(ch)
+  return out + ["".join(piece).strip()]
+
+
 def _branches(line: str):
   """Names of the computations an instruction's line gives as branches."""
   return [name.strip().lstrip("%") for m in _BRANCHES.finditer(line)
@@ -117,18 +143,31 @@ def count_ops(hlo_text: str):
   ``ragged-dot-metadata`` calls, a few hundred bytes each, are not counted;
   ``ragged_dot_tail``: those inside a conditional). A sort is told by the
   ``op_name`` the program gave it: ``route_sort`` is the expert layer's stable
-  argsort, ``route_top_k`` the sort the router's ``top_k`` compiles to."""
+  argsort, ``route_top_k`` the sort the router's ``top_k`` compiles to. A
+  plain product is a ``dot`` or a ``convolution`` instruction, counted by its
+  operands' element types: ``dense_dot_bf16`` where all are bfloat16,
+  ``dense_dot_f32`` where one is float32."""
   comps = _computations(hlo_text)
   tail = _under_conditionals(comps)
   counts = dict.fromkeys(("splash_fwd", "ragged_dot", "ragged_dot_tail",
-                          "sort", "route_sort", "route_top_k"), 0)
+                          "sort", "route_sort", "route_top_k",
+                          "dense_dot_f32", "dense_dot_bf16"), 0)
   for comp, lines in comps.items():
-    for line in lines:
-      m = _INSTRUCTION.match(line)
-      if not m:
-        continue
+    instructions = [m for m in map(_INSTRUCTION.match, lines) if m]
+    element = {m.group("name"): m.group("type").split("[")[0]
+               for m in instructions}
+    for m in instructions:
+      line = m.string
       opcode, name = m.group("opcode"), m.group("name")
-      if opcode == "sort":
+      if opcode in ("dot", "convolution"):
+        # an operand is a name of this computation, or carries its type
+        handed = {o.split("[")[0] if " " in o else element.get(o.lstrip("%"))
+                  for o in _operands(line, m.end())}
+        if "f32" in handed:
+          counts["dense_dot_f32"] += 1
+        elif handed == {"bf16"}:
+          counts["dense_dot_bf16"] += 1
+      elif opcode == "sort":
         counts["sort"] += 1
         op_name = _OP_NAME.search(line)
         where = op_name.group(1) if op_name else ""
