@@ -19,8 +19,24 @@ which every later line of that layer hangs on: the stable argsort and the
 bincount run once. Nothing differentiable of the expert layer is named: its
 head's grouped matmuls are rematerialised with the layer, once.
 
-A layer that makes none of the named values (``attention="xla"``, a dense MLP)
-is rematerialised whole. No other ``jax.checkpoint`` stands on a decoder
+``SPARSE_SELECTION``: the keys a learned indexer chose for every query
+(:func:`..layers.sparse_index.sparse_attention`), bit-packed: uint8
+``[tiles, tile, key extent / 8]`` a run of tiles, 21 MB a layer at 16,384
+positions and 5 MB at 8,192. The top-k over a tile's scores, the dearest
+thing the indexer does that is not a product, runs once a layer a step, and
+the backward attends under the very mask the forward did.
+
+``SPARSE_ATTN_RESIDUALS``: that attention's output (float32
+``[T, heads x head_dim]``, 268 MB a layer at 16,384 positions) and its
+log-sum-exp (float32 ``[heads, T]``), what ``SPLASH_RESIDUALS`` is to the
+kernel: the attention is XLA's there, a tile of queries at a time with a
+written-out backward that rebuilds a tile's scores from the log-sum-exp, so
+with the two kept the rematerialised layer computes no score at all.
+
+A layer that makes none of the named values is rematerialised whole: the
+other models' ``attention="xla"`` (a tile loop under JAX's own transpose,
+for tests and counting tools: nothing of it is kept, scores and
+probabilities are rebuilt with the layer) and a dense MLP. No other ``jax.checkpoint`` stands on a decoder
 layer's path but the two round the expert layer's tail, which no step walks
 unless a router overflows the head (``layers/moe.py``).
 ``tools/step_recompute.py <cell>`` counts, in a cell's compiled step, the calls
@@ -31,7 +47,9 @@ import jax
 
 SPLASH_RESIDUALS = "splash_residuals"
 MOE_ROUTE = "moe_route"
-KEPT = (SPLASH_RESIDUALS, MOE_ROUTE)
+SPARSE_SELECTION = "sparse_selection"
+SPARSE_ATTN_RESIDUALS = "sparse_attn_residuals"
+KEPT = (SPLASH_RESIDUALS, MOE_ROUTE, SPARSE_SELECTION, SPARSE_ATTN_RESIDUALS)
 
 
 def checkpoint_layer(layer):

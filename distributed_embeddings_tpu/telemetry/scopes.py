@@ -25,7 +25,7 @@ APPLY = "de_apply"  # delta streams, XLA's scatter-add, the Pallas apply kernel
 ONEHOT = "de_onehot"  # a dense class's windowed one-hot MXU lookup; inside de_combine
 EXCHANGE = "de_exchange"  # the collectives of parallel/wire.py; inside de_route and de_combine
 INTERACT = "de_interact"  # models/dlrm.py::dot_interact; inside de_model
-ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE, gate, attention under the model's mask; inside de_model
+ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE, gate, attention under the model's mask; inside de_model
 WINDOW_ATTENTION = "de_window_attention"  # models/laguna.py: the mixer of a sliding_attention layer (causal, same document, i - j < sliding_window); inside de_attention
 FULL_ATTENTION = "de_full_attention"  # models/laguna.py: the mixer of a full_attention layer (causal, same document); inside de_attention
 MOE = "de_moe"  # layers/moe.py::moe_share and shared_expert, the whole expert layer; inside de_model
@@ -35,17 +35,22 @@ MOE_SHARED = "de_moe_shared"  # layers/moe.py::shared_expert: the expert every t
 LM_HEAD = "de_lm_head"  # final norm and the vocabulary head; inside de_model
 LINEAR_ATTENTION = "de_linear_attention"  # models/olmo_hybrid.py: a gated-delta-rule mixer whole (projections, short convolutions, gates, the rule, gated output norm, W_o, the sublayer's norm); inside de_model
 DELTA_RULE = "de_delta_rule"  # layers/gated_delta.py::chunk_gated_delta_rule, the chunked rule alone; inside de_linear_attention
+SPARSE_INDEX = "de_sparse_index"  # models/keye_sparse.py, layers/sparse_index.py: the learned indexer whole (its projections, scores, top-k and its own KL loss); inside de_attention
 MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_hybrid.py: every layer's; models/laguna.py: the leading dense layer's); inside de_model
 
 # Parts: always inside the child scope of their layer, one level finer: what an
 # op-level account of a language-model step is read by (benchmark/scope_parts.py).
-# The four de_moe_* parts partition de_moe_route; the others leave their
+# The four de_moe_* parts partition de_moe_route and the three de_index_*
+# parts de_sparse_index; the others leave their
 # layer a remainder (norms, gates, the residual add) that is read as the
 # layer less its parts and kernels. XLA fuses across a part's line, so a
 # part is exact to a fusion.
 ATTN_PROJ = "de_attn_proj"  # the matmuls with wq, wk, wv, wo (models/laguna.py: and wg); inside de_attention (Laguna: inside de_window_attention / de_full_attention)
 ATTN_QK = "de_attn_qk"  # q and k between projection and kernel: q/k norms, rope, the head_dim ** -0.5 scaling; inside de_attention (Laguna: as above)
-ATTN_CORE = "de_attn_core"  # the call of attend(...): transposes and casts to the kernel's layout, the kernel, the way back; inside de_attention (Laguna: as above)
+ATTN_CORE = "de_attn_core"  # the call of attend(...): transposes and casts to the kernel's layout, the kernel, the way back; inside de_attention (Laguna: as above). models/keye_sparse.py: no kernel, the attention under the selection in XLA (layers/sparse_index.py: scores, masked softmax and the products with v, forward and the written-out backward)
+INDEX_SCORES = "de_index_scores"  # the indexer's projections, its key's LayerNorm, its rotary pass, the score product, the ReLU and the weighted sum over index heads (backward: the score again and the three products of its gradient); inside de_sparse_index
+INDEX_SELECT = "de_index_select"  # layers/sparse_index.py::select_topk and the packing of the mask: forward only, the plan keeps the selection; inside de_sparse_index
+INDEX_LOSS = "de_index_loss"  # the KL's target (the heads' mean of the main attention's probabilities), the indexer's softmax over the selection and the KL (backward: its gradient into the score); inside de_sparse_index
 MOE_ROUTER = "de_moe_router"  # layers/moe.py::route whole: the router's matmul, the scores, top_k, renormalisation; inside de_moe_route
 MOE_SORT = "de_moe_sort"  # the sort key, argsort, bincount, the cumulative sums, tok, p_sorted; inside de_moe_route
 MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h with its select (transposed: the scatter-add of the cotangent into h); inside de_moe_route
@@ -58,7 +63,8 @@ CHILDREN = (ONEHOT, EXCHANGE, INTERACT)
 # a language model's, all inside de_model (benchmark/scope_children*.py read them)
 LM_CHILDREN = (ATTENTION, MOE, MOE_ROUTE, MOE_EXPERTS, LM_HEAD,
                LINEAR_ATTENTION, DELTA_RULE, MLP, WINDOW_ATTENTION,
-               FULL_ATTENTION, MOE_SHARED)
+               FULL_ATTENTION, MOE_SHARED, SPARSE_INDEX)
 # a language model's parts, each inside one of LM_CHILDREN
 PARTS = (ATTN_PROJ, ATTN_QK, ATTN_CORE, MOE_ROUTER, MOE_SORT, MOE_DISPATCH,
-         MOE_RETURN, LINATTN_PROJ, LINATTN_CONV)
+         MOE_RETURN, LINATTN_PROJ, LINATTN_CONV, INDEX_SCORES, INDEX_SELECT,
+         INDEX_LOSS)

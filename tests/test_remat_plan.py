@@ -7,6 +7,7 @@ head is computed twice, not three times, and its keys are sorted once; and
 
 import dataclasses
 import functools
+import inspect
 import os
 import sys
 
@@ -16,9 +17,14 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
-from distributed_embeddings_tpu.layers import remat
+from distributed_embeddings_tpu.layers import remat, sparse_index
 from distributed_embeddings_tpu.layers.moe import MoEShare, Router, moe_share
-from distributed_embeddings_tpu.models import laguna, olmo_hybrid, sdar_moe
+from distributed_embeddings_tpu.models import (
+    keye_sparse,
+    laguna,
+    olmo_hybrid,
+    sdar_moe,
+)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import step_recompute  # noqa: E402
@@ -442,7 +448,13 @@ def test_kept_is_what_the_code_names():
   """`KEPT` lists the names the kernels' builders and the expert layer give,
   and nothing else: no configuration, option or environment says what is
   kept."""
-  assert set(remat.KEPT) == {remat.SPLASH_RESIDUALS, remat.MOE_ROUTE}
+  assert set(remat.KEPT) == {remat.SPLASH_RESIDUALS, remat.MOE_ROUTE,
+                             remat.SPARSE_SELECTION,
+                             remat.SPARSE_ATTN_RESIDUALS}
+  # the two a learned indexer's attention names are made in one place
+  source = inspect.getsource(sparse_index)
+  assert source.count("SPARSE_SELECTION)") == 1
+  assert source.count("SPARSE_ATTN_RESIDUALS)") == 2
   for build, args in [
       (sdar_moe._splash_kernel, (16, 4, 2, 128, True)),
       (laguna._splash_kernel, (128, 2, None, 128, True)),
@@ -451,6 +463,7 @@ def test_kept_is_what_the_code_names():
     assert build(*args).kwargs["residual_checkpoint_name"] \
         == remat.SPLASH_RESIDUALS
   assert not [f.name for cfg in (sdar_moe.SDARMoEConfig, laguna.LagunaConfig,
-                                 olmo_hybrid.OlmoHybridConfig)
+                                 olmo_hybrid.OlmoHybridConfig,
+                                 keye_sparse.KeyeSparseConfig)
               for f in dataclasses.fields(cfg)
               if "remat" in f.name or "checkpoint" in f.name]

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import optax
 import pytest
 
+import test_keye_sparse
 import test_laguna
 import test_olmo_hybrid
 import test_sdar_moe
@@ -26,6 +27,7 @@ from distributed_embeddings_tpu.models import (
     DLRM,
     SyntheticModel,
     bce_loss,
+    keye_sparse,
     laguna,
     olmo_hybrid,
     sdar_moe,
@@ -167,7 +169,7 @@ def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
 def test_the_vocabulary_is_one_flat_set_of_names():
   names = scopes.TOP_LEVEL + scopes.CHILDREN + scopes.LM_CHILDREN \
       + scopes.PARTS
-  assert len(set(names)) == len(names) == 30
+  assert len(set(names)) == len(names) == 34
   for n in names:
     assert n.startswith("de_") and "/" not in n and "(" not in n
   declared = {v for k, v in vars(scopes).items()
@@ -185,6 +187,10 @@ LM_TOYS = {
     "olmo_hybrid": (olmo_hybrid.OlmoHybrid, olmo_hybrid.next_token_loss,
                     test_olmo_hybrid.TOY),
     "laguna": (laguna.Laguna, olmo_hybrid.next_token_loss, test_laguna.TOY),
+    "keye_sparse": (keye_sparse.KeyeSparse, keye_sparse.sparse_training_loss,
+                    dataclasses.replace(test_keye_sparse.TOY,
+                                        num_hidden_layers=1,
+                                        experts_held=(0, 4))),
 }
 # part -> the scope the vocabulary's table puts it inside
 INSIDE = {
@@ -193,7 +199,11 @@ INSIDE = {
     scopes.MOE_SORT: scopes.MOE_ROUTE, scopes.MOE_DISPATCH: scopes.MOE_ROUTE,
     scopes.MOE_RETURN: scopes.MOE_ROUTE,
     scopes.LINATTN_PROJ: scopes.LINEAR_ATTENTION,
-    scopes.LINATTN_CONV: scopes.LINEAR_ATTENTION}
+    scopes.LINATTN_CONV: scopes.LINEAR_ATTENTION,
+    scopes.INDEX_SCORES: scopes.SPARSE_INDEX,
+    scopes.INDEX_SELECT: scopes.SPARSE_INDEX,
+    scopes.INDEX_LOSS: scopes.SPARSE_INDEX}
+INDEX_PARTS = (scopes.INDEX_SCORES, scopes.INDEX_SELECT, scopes.INDEX_LOSS)
 ROUTE_PARTS = (scopes.MOE_ROUTER, scopes.MOE_SORT, scopes.MOE_DISPATCH,
                scopes.MOE_RETURN)
 PARTS_OF = {
@@ -202,14 +212,23 @@ PARTS_OF = {
     "olmo_hybrid": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE,
                     scopes.LINATTN_PROJ, scopes.LINATTN_CONV),
     "laguna": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
-    + ROUTE_PARTS}
+    + ROUTE_PARTS,
+    "keye_sparse": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
+    + INDEX_PARTS + ROUTE_PARTS}
+# the passes a part has ops in where not all three: what `remat.KEPT` names is
+# made in the forward and never rebuilt (the selection; the attention's output
+# and log-sum-exp, so the rebuilt layer computes no score)
+PASSES_OF = {
+    ("keye_sparse", scopes.ATTN_CORE): {"forward", "backward"},
+    ("keye_sparse", scopes.INDEX_SELECT): {"forward"},
+    ("keye_sparse", scopes.INDEX_LOSS): {"forward", "backward"}}
 REMAT = "rematted_computation"   # jax.checkpoint's rebuilt forward
 _LOC = re.compile(r'^(#loc\d+) = loc\("([^"]*)"', re.M)
 
 
-def _lowered_step(name) -> str:
+def _lowered(name):
   """The toy model's sparse train step (token table as a sequence input
-  under summed Adam), lowered, with every op's name stack."""
+  under summed Adam), lowered."""
   model_cls, loss, cfg = LM_TOYS[name]
   batch = 2
   cats = jnp.zeros((batch, cfg.seq_len), jnp.int32)
@@ -228,8 +247,12 @@ def _lowered_step(name) -> str:
                                    jax.random.PRNGKey(1))
   step = make_sparse_train_step(model, plan, loss, opt, rule, None, state,
                                 (numerical, [cats], labels), donate=False)
-  return step.lower(state, numerical, [cats], labels).as_text(
-      debug_info=True)
+  return step.lower(state, numerical, [cats], labels)
+
+
+def _lowered_step(name) -> str:
+  """:func:`_lowered` as text, with every op's name stack."""
+  return _lowered(name).as_text(debug_info=True)
 
 
 def _components(name_stack: str):
@@ -248,9 +271,14 @@ def _pass_of(name_stack: str) -> str:
 @pytest.fixture(scope="module", params=sorted(LM_TOYS))
 def lm_stacks(request):
   """(toy, the name stacks of its lowered step's ops)."""
-  text = _lowered_step(request.param)
-  return request.param, sorted({s for _, s in _LOC.findall(text)
-                                if s.startswith("jit(")})
+  lowered = _lowered(request.param)
+  stacks = {s for _, s in _LOC.findall(lowered.as_text(debug_info=True))}
+  if request.param == "keye_sparse":
+    # a tile loop's body is lowered as a function of its own, whose ops'
+    # name stacks start at the call; the compiled program's `op_name`s are
+    # whole again (what a trace of the chip shows)
+    stacks |= set(_OP_NAME.findall(lowered.compile().as_text()))
+  return request.param, sorted(s for s in stacks if s.startswith("jit("))
 
 
 def test_every_part_lies_only_inside_its_layers_scope(lm_stacks):
@@ -268,9 +296,24 @@ def test_every_part_lies_only_inside_its_layers_scope(lm_stacks):
         # a part of a Laguna mixer lies inside the layer's kind
         assert before[-1] in (scopes.WINDOW_ATTENTION,
                               scopes.FULL_ATTENTION), stack
+      elif name == "keye_sparse" and INSIDE[part] != scopes.MOE_ROUTE:
+        # the tile loop's own components (`while`, `body`) may lie between
+        assert [n for n in before if n.startswith("de_")][-1] \
+            == INSIDE[part], stack
       else:
         assert before[-1] == INSIDE[part], stack
   assert set(seen) == set(PARTS_OF[name])
+
+
+def test_the_three_index_parts_partition_the_indexer(lm_stacks):
+  name, stacks = lm_stacks
+  under = [s for s in stacks if scopes.SPARSE_INDEX in _components(s)]
+  assert bool(under) == (name == "keye_sparse")
+  for stack in under:
+    names = _components(stack)
+    assert sum(p in names for p in INDEX_PARTS) == 1, stack
+    assert scopes.ATTENTION in names[:names.index(scopes.SPARSE_INDEX)]
+    assert scopes.ATTN_CORE not in names, stack
 
 
 def test_the_four_route_parts_partition_the_expert_route(lm_stacks):
@@ -290,7 +333,8 @@ def test_every_part_has_ops_in_all_three_passes(lm_stacks):
     for part in set(_components(stack)) & set(scopes.PARTS):
       passes[part].add(_pass_of(stack))
   for part in PARTS_OF[name]:
-    assert passes[part] == {"forward", "rebuilt", "backward"}, part
+    assert passes[part] == PASSES_OF.get(
+        (name, part), {"forward", "rebuilt", "backward"}), part
 
 
 def test_what_the_plan_keeps_is_not_rebuilt_under_its_part(lm_stacks):
