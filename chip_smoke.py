@@ -8,6 +8,15 @@ line — on a TPU at Criteo width, and fails unless what came out is right:
   VMEM-resident heads, on a power-law and on a uniform stream, on one
   device and under ``shard_map`` with starts that differ from rank to rank;
   ``tools/smoke_pallas_interact.py``);
+- leg D: the sparse-attention kernels (``ops/pallas_sparse_attn.py``)
+  against the XLA tile loop at the shapes of ``keye_dsa_train_1chip``
+  (``tools/smoke_pallas_sparse_attn.py``: 8,192 x 32 x 128, ``topk`` 2,048,
+  two documents; output, the indexer's loss, counters and six gradients,
+  outside any timed window), then that cell's model forward on the
+  benchmark's own weights and traffic, seed 2147483659, through the kernels
+  (``tools/sparse_index_load.py --reference``): every layer selects the
+  14,681,088 pairs the plain reference and the documents' own counts give,
+  and the blocks attended and skipped add up to the grid;
 - leg A, one chip: ``examples/dlrm/main.py --sparse`` at 26 Criteo-1TB
   tables x 1/16 (11.8 M rows), width 128, global batch 65536, 8 steps and
   an eval. Passes only if it ran on a TPU, every loss is finite, the first
@@ -48,6 +57,14 @@ MAIN = os.path.join("examples", "dlrm", "main.py")
 KERNEL_SMOKES = (os.path.join("tools", "smoke_pallas_apply.py"),
                  os.path.join("tools", "smoke_pallas_interact.py"))
 
+SPARSE_ATTN_SMOKE = os.path.join("tools", "smoke_pallas_sparse_attn.py")
+INDEX_LOAD = os.path.join("tools", "sparse_index_load.py")
+INDEX_LOAD_SEED = 2147483659
+# a layer, on that seed: what the tool printed on the CPU, the plain
+# reference's count and the documents' own (PERF.md section 6, PR 40)
+SELECTED_PAIRS = 14681088
+ATTENTION_BLOCKS = (8192 // 512) ** 2
+
 # the names ops/pallas_apply.py and ops/pallas_interact.py give their
 # pallas_calls (tests/test_chip_smoke.py keeps the two in step)
 REQUIRED_KERNELS = ("de_apply_rows_cached", "de_interact_parts_fwd",
@@ -59,7 +76,7 @@ LEG_A_ARGS = TRAINER_ARGS + ["--world_size", "1", "--vocab_scale", "0.0625"]
 LEG_C_ARGS = TRAINER_ARGS + ["--world_size", "4", "--vocab_scale", "0.25"]
 
 DEADLINE_S = 1140  # the whole check, under the driver's 1200 s
-LEG_TIMEOUT_S = {"B": 240, "A": 480, "C": 540}
+LEG_TIMEOUT_S = {"B": 240, "D": 300, "A": 480, "C": 540}
 MIB = 1 << 20
 
 
@@ -172,6 +189,31 @@ def check_trainer(out: str, rc: int, world: int) -> str:
           f"auc={auc:.5f} init_hbm_mib={held} peak_hbm_mib={peaks}")
 
 
+def check_index_load(out: str, rc: int) -> str:
+  """Pass conditions of leg D's second child (``tools/sparse_index_load.py``
+  prints one JSON object); returns the summary for the leg's line."""
+  if rc != 0:
+    raise LegFailed(f"{INDEX_LOAD} exited with code {rc}")
+  report = json.loads(find(r'^(\{"cell".*\})$', out, "report").group(1))
+  if report["backend"] != "tpu":
+    raise LegFailed(f"the counters were taken on {report['backend']!r}")
+  layers = len(report["selected_pairs"])
+  want = [SELECTED_PAIRS] * layers
+  for name in ("selected_pairs", "reference_selected_pairs"):
+    if report[name] != want:
+      raise LegFailed(f"{name} {report[name]}, not {SELECTED_PAIRS} a layer")
+  if not report["counts_agree"]:
+    raise LegFailed("the counters, the documents' counts and the "
+                    "reference's do not agree")
+  blocks = [a + b for a, b in zip(report["attended_blocks"],
+                                  report["skipped_blocks"])]
+  if blocks != [ATTENTION_BLOCKS] * layers:
+    raise LegFailed(f"attended + skipped blocks {blocks}, not "
+                    f"{ATTENTION_BLOCKS} a layer")
+  return (f"selected_pairs={SELECTED_PAIRS}x{layers} "
+          f"attended_blocks={report['attended_blocks']}")
+
+
 def result_line(ok: bool, device: dict) -> str:
   """The last line of stdout: the verdict and the device as JAX reported
   it (``jax.devices()[0].platform``, ``.device_kind``, ``len(jax.devices())``)
@@ -185,7 +227,7 @@ def result_line(ok: bool, device: dict) -> str:
 
 
 def main() -> int:
-  needed = (MAIN,) + KERNEL_SMOKES
+  needed = (MAIN, SPARSE_ATTN_SMOKE, INDEX_LOAD) + KERNEL_SMOKES
   absent = [p for p in needed if not os.path.exists(os.path.join(HERE, p))]
   if absent:
     print(f"chip_smoke: not a checkout of the repository ({absent[0]} is "
@@ -224,6 +266,27 @@ def main() -> int:
       break
   legs["B"] = status
   print(f"leg B (kernels vs XLA): {status} wall={wall_b:.1f}s", flush=True)
+
+  # leg D: the sparse-attention kernels against the tile loop, then the
+  # cell's own selection through them
+  rc, out, wall_d = run_child("D_smoke_pallas_sparse_attn",
+                              [SPARSE_ATTN_SMOKE], remaining("D"))
+  summary = ""
+  try:
+    if rc != 0:
+      raise LegFailed(f"{SPARSE_ATTN_SMOKE} exited with code {rc}")
+    rc, out, wall = run_child(
+        "D_sparse_index_load",
+        [INDEX_LOAD, "keye_dsa_train_1chip", "--seed", str(INDEX_LOAD_SEED),
+         "--reference"], remaining("D"))
+    wall_d += wall
+    summary = check_index_load(out, rc)
+    legs["D"] = "passed"
+  except LegFailed as e:
+    legs["D"] = f"FAILED: {e}"
+    print(out[-2000:], file=sys.stderr)
+  print(f"leg D (sparse attention vs XLA): {legs['D']} wall={wall_d:.1f}s "
+        f"{summary}", flush=True)
 
   trainer_legs = [("A", LEG_A_ARGS, 1)]
   if device["count"] >= 4:

@@ -29,9 +29,13 @@ the backward attends under the very mask the forward did.
 ``SPARSE_ATTN_RESIDUALS``: that attention's output (float32
 ``[T, heads x head_dim]``, 268 MB a layer at 16,384 positions) and its
 log-sum-exp (float32 ``[heads, T]``), what ``SPLASH_RESIDUALS`` is to the
-kernel: the attention is XLA's there, a tile of queries at a time with a
-written-out backward that rebuilds a tile's scores from the log-sum-exp, so
-with the two kept the rematerialised layer computes no score at all.
+splash kernel. On a TPU the attention is the kernels of
+``ops/pallas_sparse_attn.py``, elsewhere XLA's, a tile of queries at a time;
+on both the written-out backward rebuilds a block's probabilities from the
+log-sum-exp, so with the two kept the rematerialised layer computes no score
+at all. The heads' mean probabilities the indexer's loss is held to are NOT
+kept (268 MB a layer at 8,192 positions): the backward runs their kernel
+again.
 
 A layer that makes none of the named values is rematerialised whole: the
 other models' ``attention="xla"`` (a tile loop under JAX's own transpose,

@@ -26,22 +26,42 @@ and the indexer is trained by a loss of its own, which pulls its softmax over
 So the language-model loss reaches ``q, k, v`` and never the indexer (a
 selection has no gradient), and the KL reaches ``qI, kI, w`` and nothing else.
 
-:func:`sparse_attention` is all of that from the projected operands, a tile
-of ``tile`` queries at a time against the keys up to the tile's end: no
-``[T, T]`` array lives whole (at 16,384 positions one float32 head of it is
-1.07 GB). A tile's mask is data, which no splash ``Mask`` can be, so the
-products are XLA's. Forward and backward are written out (``jax.custom_vjp``):
-JAX's own transpose of a loop over tiles keeps every tile's probabilities at
-once, and a ``jax.checkpoint`` a tile runs each tile's forward three times
-under a rematerialised layer. Here a tile's backward rebuilds its scores from
-the kept log-sum-exp, as a flash kernel's does, and the KL's target comes out
-of the same probabilities the attention's own backward needs.
+:func:`sparse_attention` is all of that from the projected operands. The
+selection is made a tile of ``tile`` queries at a time against the keys up to
+the end of the tile's run, in XLA on every backend: index scores, the k-th
+value, the mask, packed to bits. What runs the attention under that mask is
+read off the backend (:func:`attention_kernels`; no option names it):
 
-What outlives the forward, under names a rematerialised layer keeps
-(``layers/remat.py``): the selection, bit-packed (``T x extent / 8`` bytes:
-21 MB a layer at 16,384 positions), so that the top-k runs ONCE a layer a
-step; and the attention's output and log-sum-exp, so that the rebuilt layer
-runs no attention at all.
+*On a TPU* the four Mosaic kernels of ``ops/pallas_sparse_attn.py``, one call
+a pass a sequence: the mask goes in as data (int8 ``[T, T]``, assembled from
+the tiles' selections and alive for the layer only), a block of ``tile``
+queries against a block of ``tile`` keys at a time, all ``G`` heads of a
+key-value head under one read of the mask block, scores and probabilities in
+VMEM only, and a block with no selected pair (above the diagonal, another
+document's) neither fetched nor multiplied. The forward kernel leaves ``o``
+and the log-sum-exp; a second kernel the heads' mean probabilities ``[T, T]``
+(float32, summed from float32), which is the KL's target: the KL itself and
+its gradient stay XLA over ``[tile, extent]`` arrays. The backward
+(``jax.custom_vjp``) unpacks the kept bits to the same mask, runs ``dq`` and
+``dk, dv`` kernels that rebuild a block's probabilities from the kept
+log-sum-exp; the ``dq`` kernel sums them over the heads as it goes, so the
+KL's target costs the backward no pass of its own.
+
+*Elsewhere* (and for shapes the kernels do not take) the tile loop: a tile's
+scores against ``k[:extent]`` whole in XLA, no ``[T, T]`` array of a head
+alive (at 16,384 positions one float32 head of it is 1.07 GB), forward and
+backward written out: JAX's own transpose of a loop over tiles keeps every
+tile's probabilities at once, and a ``jax.checkpoint`` a tile runs each
+tile's forward three times under a rematerialised layer. This is the CPU's
+path, every test's and counting tool's, and the kernels' oracle
+(``tests/test_pallas_sparse_attn.py``, ``tools/smoke_pallas_sparse_attn.py``).
+
+What outlives the forward on either path, under names a rematerialised layer
+keeps (``layers/remat.py``): the selection, bit-packed (``T x extent / 8``
+bytes: 21 MB a layer at 16,384 positions), so that the top-k runs ONCE a
+layer a step; and the attention's output and log-sum-exp, so that the rebuilt
+layer runs no attention at all. The heads' mean probabilities are rebuilt,
+never kept (268 MB a layer at 8,192 positions).
 
 Selecting (:func:`select_topk`) is a k-th value and a comparison, not a sort:
 the floats are mapped to integers of the same order and the k-th largest is
@@ -51,8 +71,10 @@ taken from the lowest index by a running count. The set is exactly
 
 The main attention's products are handed what the MXU multiplies at default
 precision (``ops.packed_table.mxu_operand_dtype``: bfloat16 on a TPU, the
-operands' own type elsewhere), float32 out; so are the three products of the
-indexer's BACKWARD. Only the forward score, which decides, is at ``highest``.
+operands' own type elsewhere), float32 out, in the kernels as in the tile loop;
+so are the three products of the indexer's BACKWARD. Only the forward score,
+which decides, is at ``highest``. Max, exp, sums, the log-sum-exp and the
+heads' mean are float32 on both paths.
 """
 
 from __future__ import annotations
@@ -65,6 +87,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import pallas_sparse_attn as psa
 from ..ops.packed_table import mxu_operand_dtype
 from ..telemetry import scopes
 from .remat import SPARSE_ATTN_RESIDUALS, SPARSE_SELECTION
@@ -177,6 +200,62 @@ def _tiles(x, first: int, count: int, tile: int):
   return x[first:first + count * tile].reshape((count, tile) + x.shape[1:])
 
 
+def attention_kernels(length: int, head_dim: int, tile: int):
+  """What runs the attention under the selection here: ``None`` for the tile
+  loop in XLA (any backend but a TPU, and shapes the kernels do not take),
+  else the kernels of ``ops/pallas_sparse_attn.py`` with this for their
+  ``interpret`` (``False``: on the chip). It reads what it can observe; no
+  option names a path. A test that wants the kernels in Pallas's interpreter
+  replaces this function."""
+  if jax.default_backend() == "tpu" and psa.fits(length, head_dim, tile, tile):
+    return False
+  return None
+
+
+def _select_tile(topk, tile, at, qi_t, wi_t, seg_t, ki_e, seg_e):
+  """The tile of queries that starts at position ``at`` against the keys of
+  its run -> (the index score ``[tile, extent]``, the selection, its bits,
+  int32 ``[4]``: selected pairs, visible pairs, queries with more than
+  ``topk`` visible keys, ``tile x tile`` blocks with a selected pair)."""
+  seen = _visible(seg_t, seg_e, at)
+  with jax.named_scope(scopes.SPARSE_INDEX):
+    with jax.named_scope(scopes.INDEX_SCORES):
+      score, _ = index_scores(qi_t, wi_t, ki_e)
+    with jax.named_scope(scopes.INDEX_SELECT):
+      chosen = select_topk(score, seen, topk)
+      bits = pack_bits(chosen)
+  n_seen = jnp.sum(seen, axis=-1, dtype=jnp.int32)
+  attended = jnp.any(chosen.reshape(tile, -1, tile), axis=(0, 2))
+  counts = jnp.stack([jnp.sum(chosen, dtype=jnp.int32), jnp.sum(n_seen),
+                      jnp.sum(n_seen > topk, dtype=jnp.int32),
+                      jnp.sum(attended, dtype=jnp.int32)])
+  return score, chosen, bits, counts
+
+
+def _index_backward_tile(dkl, target, chosen, qi_t, wi_t, ki_e):
+  """The KL's gradient through one tile's index score, rebuilt in one pass
+  of the operands' type (``ki_e`` comes cast): ``target [tile, extent]`` the
+  heads' mean probabilities -> (``dqi``, ``dwi`` of the tile, what the tile
+  adds to ``dki`` of its run's keys)."""
+  cd, f32 = ki_e.dtype, jnp.float32
+  with jax.named_scope(scopes.SPARSE_INDEX):
+    with jax.named_scope(scopes.INDEX_SCORES):
+      score, raw = index_scores(qi_t.astype(cd), wi_t, ki_e, precision=None)
+    with jax.named_scope(scopes.INDEX_LOSS):
+      p_index, _ = _masked_softmax(score, chosen)
+      dscore = dkl * (p_index * jnp.sum(target, axis=-1, keepdims=True)
+                      - target)
+    with jax.named_scope(scopes.INDEX_SCORES):
+      dwi_t = jnp.sum(dscore[None] * jax.nn.relu(raw), axis=-1).T
+      draw = jnp.where(raw > 0, dscore[None] * wi_t.T[:, :, None],
+                       0.0).astype(cd)
+      dqi_t = jnp.einsum("hqs,sd->qhd", draw, ki_e,
+                         preferred_element_type=f32)
+      dki_t = jnp.einsum("hqs,qhd->sd", draw, qi_t.astype(cd),
+                         preferred_element_type=f32)
+  return dqi_t, dwi_t, dki_t
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _sparse_attention(topk, tile, q, k, v, qi, ki, wi, seg):
   return _forward(topk, tile, q, k, v, qi, ki, wi, seg)[0]
@@ -185,9 +264,36 @@ def _sparse_attention(topk, tile, q, k, v, qi, ki, wi, seg):
 def _forward(topk, tile, q, k, v, qi, ki, wi, seg):
   """One sequence: ``q [T, Hkv, G, hd]`` (scaled), ``k, v [T, Hkv, hd]``,
   ``qi [T, Hi, di]``, ``ki [T, di]``, ``wi [T, Hi]``, ``seg [T]`` ->
-  ((``o`` like ``q``, the KLs summed over queries, int32 ``[3]``: selected
-  pairs, visible pairs, queries with more than ``topk`` visible keys),
-  residuals)."""
+  ((``o`` like ``q``, the KLs summed over queries, int32 ``[4]``: selected
+  pairs, visible pairs, queries with more than ``topk`` visible keys,
+  ``tile x tile`` blocks with a selected pair), residuals)."""
+  interpret = attention_kernels(q.shape[0], q.shape[-1], tile)
+  if interpret is None:
+    return _forward_tiles(topk, tile, q, k, v, qi, ki, wi, seg)
+  return _forward_kernels(topk, tile, interpret, q, k, v, qi, ki, wi, seg)
+
+
+def _backward(topk, tile, residuals, cotangents):
+  del topk
+  q = residuals[0]
+  interpret = attention_kernels(q.shape[0], q.shape[-1], tile)
+  if interpret is None:
+    return _backward_tiles(tile, residuals, cotangents)
+  return _backward_kernels(tile, interpret, residuals, cotangents)
+
+
+def _named(operands, packed, o, lse, kls, counts):
+  """What a forward returns, ``(outputs, residuals)``, with what outlives a
+  rematerialised layer named (``layers/remat.py``): the runs' packed
+  selections, the attention's output and its log-sum-exp."""
+  packed = tuple(checkpoint_name(bits, SPARSE_SELECTION) for bits in packed)
+  o = checkpoint_name(o, SPARSE_ATTN_RESIDUALS)
+  lse = checkpoint_name(lse, SPARSE_ATTN_RESIDUALS)
+  return (o, kls, counts), (*operands, packed, o, lse)
+
+
+def _forward_tiles(topk, tile, q, k, v, qi, ki, wi, seg):
+  """The forward a tile of queries at a time, every product XLA's."""
   cd = mxu_operand_dtype(q.dtype)
   outs, packed, lses, kls, counts = [], [], [], 0.0, 0
 
@@ -197,13 +303,8 @@ def _forward(topk, tile, q, k, v, qi, ki, wi, seg):
 
     def one_tile(xs, first=first, k_e=k_e, v_e=v_e, ki_e=ki_e, seg_e=seg_e):
       i, q_t, qi_t, wi_t, seg_t = xs
-      seen = _visible(seg_t, seg_e, first + i * tile)
-      with jax.named_scope(scopes.SPARSE_INDEX):
-        with jax.named_scope(scopes.INDEX_SCORES):
-          score, _ = index_scores(qi_t, wi_t, ki_e)
-        with jax.named_scope(scopes.INDEX_SELECT):
-          chosen = select_topk(score, seen, topk)
-          bits = pack_bits(chosen)
+      score, chosen, bits, count_t = _select_tile(
+          topk, tile, first + i * tile, qi_t, wi_t, seg_t, ki_e, seg_e)
       with jax.named_scope(scopes.ATTN_CORE):
         s = jnp.einsum("qkgd,skd->kgqs", q_t.astype(cd), k_e,
                        preferred_element_type=jnp.float32)
@@ -219,29 +320,24 @@ def _forward(topk, tile, q, k, v, qi, ki, wi, seg):
           jax.named_scope(scopes.INDEX_LOSS):
         target = jnp.mean(ex / total, axis=(0, 1))              # [q, s]
         kl_t = jnp.sum(_kl(target, score, chosen))
-      n_seen = jnp.sum(seen, axis=-1, dtype=jnp.int32)
-      count_t = jnp.stack([jnp.sum(chosen, dtype=jnp.int32), jnp.sum(n_seen),
-                           jnp.sum(n_seen > topk, dtype=jnp.int32)])
       return o_t.astype(q.dtype), bits, lse_t, kl_t, count_t
 
     o_r, bits_r, lse_r, kl_r, count_r = lax.map(one_tile, (
         jnp.arange(count), *(_tiles(x, first, count, tile)
                              for x in (q, qi, wi, seg))))
     outs.append(o_r.reshape((count * tile,) + q.shape[1:]))
-    packed.append(checkpoint_name(bits_r, SPARSE_SELECTION))
+    packed.append(bits_r)
     lses.append(lse_r)
     kls, counts = kls + jnp.sum(kl_r), counts + jnp.sum(count_r, axis=0)
 
-  o = checkpoint_name(jnp.concatenate(outs), SPARSE_ATTN_RESIDUALS)
-  lse = checkpoint_name(jnp.concatenate(lses), SPARSE_ATTN_RESIDUALS)
-  return (o, kls, counts), (q, k, v, qi, ki, wi, seg, tuple(packed), o, lse)
+  return _named((q, k, v, qi, ki, wi, seg), packed, jnp.concatenate(outs),
+                jnp.concatenate(lses), kls, counts)
 
 
-def _backward(topk, tile, residuals, cotangents):
+def _backward_tiles(tile, residuals, cotangents):
   """A tile at a time: the scores again from the kept log-sum-exp, then the
   attention's four products; the target again from those probabilities, the
   indexer's score again, and the KL's gradient through it."""
-  del topk
   q, k, v, qi, ki, wi, seg, packed, o, lse = residuals
   do, dkl, _ = cotangents
   cd = mxu_operand_dtype(q.dtype)
@@ -274,25 +370,12 @@ def _backward(topk, tile, residuals, cotangents):
                                  preferred_element_type=f32)
         dq_t = jnp.einsum("kgqs,skd->qkgd", ds, k_e,
                           preferred_element_type=f32)
-      with jax.named_scope(scopes.SPARSE_INDEX):
-        with jax.named_scope(scopes.INDEX_LOSS):
-          target = jnp.mean(p, axis=(0, 1))
-        with jax.named_scope(scopes.INDEX_SCORES):
-          score, raw = index_scores(qi_t.astype(cd), wi_t, ki_e,
-                                    precision=None)
-        with jax.named_scope(scopes.INDEX_LOSS):
-          p_index, _ = _masked_softmax(score, chosen)
-          dscore = dkl * (p_index * jnp.sum(target, axis=-1, keepdims=True)
-                          - target)
-        with jax.named_scope(scopes.INDEX_SCORES):
-          dwi_t = jnp.sum(dscore[None] * jax.nn.relu(raw), axis=-1).T
-          draw = jnp.where(raw > 0, dscore[None] * wi_t.T[:, :, None],
-                           0.0).astype(cd)
-          dqi_t = jnp.einsum("hqs,sd->qhd", draw, ki_e,
-                             preferred_element_type=f32)
-          dki_e = dki_e + jnp.einsum("hqs,qhd->sd", draw, qi_t.astype(cd),
-                                     preferred_element_type=f32)
-      return (dk_e, dv_e, dki_e), (dq_t, dqi_t, dwi_t)
+      with jax.named_scope(scopes.SPARSE_INDEX), \
+          jax.named_scope(scopes.INDEX_LOSS):
+        target = jnp.mean(p, axis=(0, 1))
+      dqi_t, dwi_t, dki_t = _index_backward_tile(dkl, target, chosen, qi_t,
+                                                 wi_t, ki_e)
+      return (dk_e, dv_e, dki_e + dki_t), (dq_t, dqi_t, dwi_t)
 
     rows = slice(first // tile, first // tile + count)
     (dk_e, dv_e, dki_e), (dq_r, dqi_r, dwi_r) = lax.scan(
@@ -311,6 +394,137 @@ def _backward(topk, tile, residuals, cotangents):
                zip(grads, (q, k, v, qi, ki, wi))) + (None,)
 
 
+def _whole_mask(chosen_runs, length: int):
+  """Runs of int8 ``[tiles, tile, extent]`` -> ``[T, T]``: a run's rows
+  padded with zeros over the keys past its extent."""
+  return jnp.concatenate([
+      jnp.pad(c.reshape(-1, c.shape[-1]), ((0, 0), (0, length - c.shape[-1])))
+      for c in chosen_runs])
+
+
+def unpacked_runs(packed, length: int, tile: int):
+  """The forward's kept bits, a run of tiles each -> the selections as int8
+  ``[tiles, tile, extent]`` a run."""
+  return [
+      unpack_bits(bits.reshape(count * tile, -1), extent).astype(jnp.int8)
+      .reshape(count, tile, extent)
+      for (_, count, extent), bits in zip(tile_runs(length, tile), packed)]
+
+
+def _kernel_blocks(q, tile: int, interpret: bool):
+  """What every kernel call of a sequence is told beside its operands: the
+  kernels' blocks are the selection's tiles."""
+  return dict(group=q.shape[2], hd=q.shape[3], block_q=tile, block_k=tile,
+              interpret=interpret)
+
+
+def _kernel_operands(cd, *arrays):
+  """``[T, ...]`` -> ``[T, heads x head_dim]`` in the products' type: the
+  kernels' layout is the model's own."""
+  return tuple(x.astype(cd).reshape(x.shape[0], -1) for x in arrays)
+
+
+def _forward_kernels(topk, tile, interpret, q, k, v, qi, ki, wi, seg):
+  """The forward on a TPU: the selection a tile at a time in XLA as ever,
+  then the whole sequence's attention in one kernel under that mask, the
+  heads' mean probabilities in a second, and the KL a tile at a time in XLA
+  over ``[tile, extent]`` arrays."""
+  length = q.shape[0]
+  cd = mxu_operand_dtype(q.dtype)
+  runs = tile_runs(length, tile)
+  at = _kernel_blocks(q, tile, interpret)
+  packed, scores, chosen_runs, counts = [], [], [], 0
+
+  for first, count, extent in runs:
+    ki_e, seg_e = ki[:extent], seg[:extent]
+
+    def select(xs, first=first, ki_e=ki_e, seg_e=seg_e):
+      i, qi_t, wi_t, seg_t = xs
+      score, chosen, bits, count_t = _select_tile(
+          topk, tile, first + i * tile, qi_t, wi_t, seg_t, ki_e, seg_e)
+      return score, chosen.astype(jnp.int8), bits, count_t
+
+    score_r, chosen_r, bits_r, count_r = lax.map(select, (
+        jnp.arange(count), *(_tiles(x, first, count, tile)
+                             for x in (qi, wi, seg))))
+    packed.append(bits_r)
+    scores.append(score_r)
+    chosen_runs.append(chosen_r)
+    counts = counts + jnp.sum(count_r, axis=0)
+
+  with jax.named_scope(scopes.ATTN_CORE):
+    mask = _whole_mask(chosen_runs, length)
+    plan = psa.block_plan(psa.block_counts(mask, tile, tile))
+    q2, k2, v2 = _kernel_operands(cd, q, k, v)
+    o, lse = psa.attend(q2, k2, v2, mask, plan, **at)           # [Hkv, T, G]
+  with jax.named_scope(scopes.SPARSE_INDEX), \
+      jax.named_scope(scopes.INDEX_LOSS):
+    target = psa.head_mean(q2, k2, lse, mask, plan, **at)       # [T, T]
+    kls = 0.0
+    for (first, count, extent), score_r, chosen_r in zip(runs, scores,
+                                                         chosen_runs):
+      kl_r = lax.map(
+          lambda xs: jnp.sum(_kl(xs[0], xs[1], xs[2] != 0)),
+          (_tiles(target[:, :extent], first, count, tile), score_r, chosen_r))
+      kls = kls + jnp.sum(kl_r)
+
+  return _named((q, k, v, qi, ki, wi, seg), packed,
+                o.reshape(q.shape).astype(q.dtype), jnp.swapaxes(lse, 1, 2),
+                kls, counts)
+
+
+def _backward_kernels(tile, interpret, residuals, cotangents):
+  """The backward on a TPU: the mask again from its bits, ``dq`` and
+  ``dk, dv`` from two kernels that rebuild a block's probabilities from the
+  kept log-sum-exp, the heads' mean probabilities out of the ``dq`` kernel
+  (it forms every head's anyway), and the KL's gradient through the index
+  score a tile at a time in XLA."""
+  q, k, v, qi, ki, wi, seg, packed, o, lse = residuals           # [Hkv, G, T]
+  do, dkl, _ = cotangents
+  length = q.shape[0]
+  cd = mxu_operand_dtype(q.dtype)
+  f32 = jnp.float32
+  at = _kernel_blocks(q, tile, interpret)
+
+  with jax.named_scope(scopes.ATTN_CORE):
+    chosen_runs = unpacked_runs(packed, length, tile)
+    mask = _whole_mask(chosen_runs, length)
+    counts = psa.block_counts(mask, tile, tile)
+    plan, plan_t = psa.block_plan(counts), psa.block_plan(counts.T)
+    q2, k2, v2, do2 = _kernel_operands(cd, q, k, v, do)
+    delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)     # [T, Hkv, G]
+    # the heads' mean probabilities come out of the same pass as dq
+    dq, target = psa.grad_q(q2, k2, v2, do2, jnp.swapaxes(lse, 1, 2),
+                            jnp.swapaxes(delta, 0, 1), mask, plan, **at)
+    dk, dv = psa.grad_kv(q2, k2, v2, do2, lse, jnp.moveaxis(delta, 0, 2),
+                         mask.T, plan_t, **at)
+
+  dki = jnp.zeros(ki.shape, f32)
+  dqis, dwis = [], []
+  for (first, count, extent), chosen_r in zip(tile_runs(length, tile),
+                                              chosen_runs):
+    ki_e = ki[:extent].astype(cd)
+
+    def one_tile(dki_e, xs, ki_e=ki_e):
+      qi_t, wi_t, target_t, chosen_t = xs
+      dqi_t, dwi_t, dki_t = _index_backward_tile(
+          dkl, target_t, chosen_t != 0, qi_t, wi_t, ki_e)
+      return dki_e + dki_t, (dqi_t, dwi_t)
+
+    dki_e, (dqi_r, dwi_r) = lax.scan(
+        one_tile, dki[:extent],
+        (*(_tiles(x, first, count, tile)
+           for x in (qi, wi, target[:, :extent])), chosen_r))
+    dki = dki.at[:extent].set(dki_e)
+    dqis.append(dqi_r.reshape((count * tile,) + qi.shape[1:]))
+    dwis.append(dwi_r.reshape((count * tile,) + wi.shape[1:]))
+
+  grads = (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+           jnp.concatenate(dqis), dki, jnp.concatenate(dwis))
+  return tuple(g.astype(x.dtype) for g, x in
+               zip(grads, (q, k, v, qi, ki, wi))) + (None,)
+
+
 _sparse_attention.defvjp(_forward, _backward)
 
 
@@ -321,11 +535,23 @@ def sparse_attention(q, k, v, qi, ki, wi, seg, *, topk: int, tile: int):
   (``o`` like ``q``; the indexer's loss, ``mean_t KL[t]`` over every
   position of the batch; counters, int32 scalars: ``selected_pairs``,
   ``visible_pairs``, ``active_queries``: those with more than ``topk``
-  visible keys). Module docstring."""
-  tile = min(tile, q.shape[1])
+  visible keys; ``attended_blocks``, the ``tile x tile`` blocks of queries
+  and keys with a selected pair, and ``skipped_blocks``, the rest of the
+  ``(T / tile) ** 2`` a sequence: what the kernels run and what they pass
+  over). Module docstring."""
+  batch, length = q.shape[:2]
+  tile = min(tile, length)
   one = functools.partial(_sparse_attention, topk, tile)
-  o, kl, counts = jax.vmap(one)(q, k, v, qi, ki, wi, seg)
+  operands = (q, k, v, qi, ki, wi, seg)
+  if attention_kernels(length, q.shape[-1], tile) is None:
+    o, kl, counts = jax.vmap(one)(*operands)
+  else:
+    # a kernel call a sequence: its block plan goes in by scalar prefetch
+    o, kl, counts = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs),
+        *(one(*(x[b] for x in operands)) for b in range(batch)))
   counts = jnp.sum(counts, axis=0)
-  return o, jnp.sum(kl) / (q.shape[0] * q.shape[1]), {
+  return o, jnp.sum(kl) / (batch * length), {
       "selected_pairs": counts[0], "visible_pairs": counts[1],
-      "active_queries": counts[2]}
+      "active_queries": counts[2], "attended_blocks": counts[3],
+      "skipped_blocks": batch * (length // tile) ** 2 - counts[3]}

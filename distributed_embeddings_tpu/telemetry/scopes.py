@@ -47,10 +47,10 @@ MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_h
 # part is exact to a fusion.
 ATTN_PROJ = "de_attn_proj"  # the matmuls with wq, wk, wv, wo (models/laguna.py: and wg); inside de_attention (Laguna: inside de_window_attention / de_full_attention)
 ATTN_QK = "de_attn_qk"  # q and k between projection and kernel: q/k norms, rope, the head_dim ** -0.5 scaling; inside de_attention (Laguna: as above)
-ATTN_CORE = "de_attn_core"  # the call of attend(...): transposes and casts to the kernel's layout, the kernel, the way back; inside de_attention (Laguna: as above). models/keye_sparse.py: no kernel, the attention under the selection in XLA (layers/sparse_index.py: scores, masked softmax and the products with v, forward and the written-out backward)
+ATTN_CORE = "de_attn_core"  # the call of attend(...): transposes and casts to the kernel's layout, the kernel, the way back; inside de_attention (Laguna: as above). models/keye_sparse.py: the attention under the selection (layers/sparse_index.py): on a TPU the de_sparse_attn_fwd/dq/dkv kernels of ops/pallas_sparse_attn.py with the assembly of their int8 mask, its block counts and the casts to their layout; elsewhere XLA's scores, masked softmax and products with v a tile at a time, forward and the written-out backward
 INDEX_SCORES = "de_index_scores"  # the indexer's projections, its key's LayerNorm, its rotary pass, the score product, the ReLU and the weighted sum over index heads (backward: the score again and the three products of its gradient); inside de_sparse_index
 INDEX_SELECT = "de_index_select"  # layers/sparse_index.py::select_topk and the packing of the mask: forward only, the plan keeps the selection; inside de_sparse_index
-INDEX_LOSS = "de_index_loss"  # the KL's target (the heads' mean of the main attention's probabilities), the indexer's softmax over the selection and the KL (backward: its gradient into the score); inside de_sparse_index
+INDEX_LOSS = "de_index_loss"  # the KL's target (the heads' mean of the main attention's probabilities: on a TPU the de_sparse_attn_mean kernel, once a direction), the indexer's softmax over the selection and the KL (backward: its gradient into the score); inside de_sparse_index
 MOE_ROUTER = "de_moe_router"  # layers/moe.py::route whole: the router's matmul, the scores, top_k, renormalisation; inside de_moe_route
 MOE_SORT = "de_moe_sort"  # the sort key, argsort, bincount, the cumulative sums, tok, p_sorted; inside de_moe_route
 MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h with its select (transposed: the scatter-add of the cotangent into h); inside de_moe_route

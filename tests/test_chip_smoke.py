@@ -71,6 +71,7 @@ def test_chip_smoke_kernel_names_match_the_ops():
 
 @pytest.mark.parametrize("relpath", [
     "tools/smoke_pallas_apply.py", "tools/smoke_pallas_interact.py",
+    "tools/smoke_pallas_sparse_attn.py",
     "bench.py"])
 def test_chip_only_programs_refuse_a_cpu_backend(relpath, capsys):
   assert jax.default_backend() == "cpu"
@@ -222,7 +223,9 @@ def test_four_chip_leg_requires_a_state_born_sharded():
 
 
 @pytest.mark.parametrize("chips,bad_leg,want_ok", [
-    (1, None, True), (4, None, True), (1, "A", False), (4, "C", False)])
+    (1, None, True), (4, None, True), (1, "A", False), (4, "C", False),
+    (1, "D_smoke_pallas_sparse_attn", False),
+    (1, "D_sparse_index_load", False)])
 def test_last_stdout_line_is_exactly_the_verdict_and_the_device(
     chips, bad_leg, want_ok, monkeypatch, capsys):
   """The parent run whole over canned children: the driver reads the last
@@ -234,6 +237,9 @@ def test_last_stdout_line_is_exactly_the_verdict_and_the_device(
     if name.startswith("B_"):
       dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": chips}
       return 0, f"device: {json.dumps(dev)}\nOK\n", 1.0
+    if name.startswith("D_"):
+      report = _index_report() if name == "D_sparse_index_load" else "PASS\n"
+      return (1 if name == bad_leg else 0), report, 1.0
     world = 4 if name == "C" else 1
     return (1 if name == bad_leg else 0), _transcript(world=world), 1.0
 
@@ -249,6 +255,8 @@ def test_last_stdout_line_is_exactly_the_verdict_and_the_device(
   assert type(last["ok"]) is bool and type(last["device"]["count"]) is int
   legs = json.loads(lines[-2].removeprefix("summary: "))["legs"]
   assert legs["B"] == "passed"
+  assert legs["D"].startswith("FAILED" if str(bad_leg).startswith("D_")
+                              else "passed")
   assert legs["C"].startswith("not run (1 chips)" if chips == 1 else
                               "FAILED" if bad_leg == "C" else "passed")
 
@@ -301,3 +309,39 @@ def test_sparse_step_lowers_for_the_chip_with_its_kernels(world, monkeypatch):
       lowering_platforms=("tpu",)).as_text()
   found = set(re.findall(r'kernel_name = "(\w+)"', text))
   assert found >= set(chip_smoke.REQUIRED_KERNELS), found
+
+
+# --- leg D's verdict, on what tools/sparse_index_load.py prints ---
+
+
+def _index_report(layers=4, **changed):
+  pairs = [chip_smoke.SELECTED_PAIRS] * layers
+  report = {"cell": "keye_dsa_train_1chip", "seed": 2147483659,
+            "backend": "tpu", "selected_pairs": pairs,
+            "reference_selected_pairs": list(pairs),
+            "attended_blocks": [100] * layers,
+            "skipped_blocks": [chip_smoke.ATTENTION_BLOCKS - 100] * layers,
+            "counts_agree": True, **changed}
+  return "some warning of the runtime\n" + chip_smoke.json.dumps(report) + "\n"
+
+
+def test_index_load_leg_passes_a_good_run():
+  summary = chip_smoke.check_index_load(_index_report(), 0)
+  assert f"selected_pairs={chip_smoke.SELECTED_PAIRS}x4" in summary
+
+
+@pytest.mark.parametrize("why,changed,rc", [
+    ("the tool failed", {}, 1),
+    ("counted on the CPU", dict(backend="cpu"), 0),
+    ("a layer selected another number of pairs",
+     dict(selected_pairs=[chip_smoke.SELECTED_PAIRS] * 3
+          + [chip_smoke.SELECTED_PAIRS - 2]), 0),
+    ("the reference selected another number",
+     dict(reference_selected_pairs=[chip_smoke.SELECTED_PAIRS + 1] * 4), 0),
+    ("the documents' counts disagree", dict(counts_agree=False), 0),
+    ("blocks that are neither attended nor skipped",
+     dict(skipped_blocks=[chip_smoke.ATTENTION_BLOCKS - 101] * 4), 0),
+])
+def test_index_load_leg_rejects(why, changed, rc):
+  with pytest.raises(chip_smoke.LegFailed):
+    chip_smoke.check_index_load(_index_report(**changed), rc)

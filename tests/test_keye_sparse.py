@@ -291,7 +291,8 @@ def test_the_shares_add_up_with_attention_and_indexer_counted_once():
 
 def test_the_counters_are_the_documents_own_counts():
   """Per layer the same three numbers, whatever the weights: a query keeps
-  ``min(visible, topk)`` keys."""
+  ``min(visible, topk)`` keys; and the blocks attended and skipped add up to
+  the grid, the blocks above the diagonal among the skipped."""
   cfg = TOY
   rows, numerical, _ = _batch(cfg, 6)
   out = jax.jit(lambda p, r: _apply(cfg, p, r, numerical,
@@ -304,9 +305,16 @@ def test_the_counters_are_the_documents_own_counts():
   want = {"visible_pairs": int(seen.sum()),
           "selected_pairs": int(np.minimum(seen, cfg.topk).sum()),
           "active_queries": int((seen > cfg.topk).sum())}
-  assert set(out["index"]) == set(want)
+  assert set(out["index"]) == set(want) | {"attended_blocks",
+                                           "skipped_blocks"}
   for name, n in want.items():
     assert out["index"][name].tolist() == [n] * cfg.num_hidden_layers, name
+  # tile x tile blocks of queries and keys: with a selected pair or without
+  blocks = B * (cfg.seq_len // cfg.q_chunk_size) ** 2
+  assert (out["index"]["attended_blocks"]
+          + out["index"]["skipped_blocks"]).tolist() \
+      == [blocks] * cfg.num_hidden_layers
+  assert (out["index"]["skipped_blocks"] >= blocks // 2 - 3 * B).all()
   assert 0 < want["active_queries"] < B * cfg.seq_len
   assert set(out["moe"]) == {"assignments", "loads", "computed"}
 

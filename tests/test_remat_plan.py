@@ -362,6 +362,10 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
   %splash_mqa_fwd_segmented_residuals.15 = (bf16[8,4]{1,0}, f32[8]{0}) custom-call(%x), custom_call_target="tpu_custom_call"
   %splash_mqa_dkv_segmented_no_residuals.10 = bf16[8,4]{1,0} custom-call(%x), custom_call_target="tpu_custom_call"
   %splash_mha_fwd_segmented_residuals.2 = (bf16[8,4]{1,0}, f32[8]{0}) custom-call(%x), custom_call_target="tpu_custom_call"
+  %de_sparse_attn_fwd.3 = (f32[8,4]{1,0}, f32[1,8,128]{2,1,0}) custom-call(%k, %k, %x), custom_call_target="tpu_custom_call"
+  %de_sparse_attn_mean.4 = f32[8,8]{1,0} custom-call(%k, %k, %x), custom_call_target="tpu_custom_call"
+  %de_sparse_attn_mean.5 = f32[8,8]{1,0} custom-call(%k, %k, %x), custom_call_target="tpu_custom_call"
+  %de_sparse_attn_dkv.6 = (f32[8,4]{1,0}, f32[8,4]{1,0}) custom-call(%k, %k, %x), custom_call_target="tpu_custom_call"
   %ragged-dot-metadata.5 = (s32[17]{0}, s32[1]{0}) custom-call(%k), custom_call_target="tpu_custom_call"
   %ragged-dot-none.12 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
   %ragged-dot-none.13 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
@@ -375,7 +379,10 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
       # plain products by what they are handed, operands by name or with
       # their types beside them: two of bfloat16 alone, two with a float32
       # operand (one of them mixed), an integer one in neither
-      "dense_dot_f32": 2, "dense_dot_bf16": 2}
+      "dense_dot_f32": 2, "dense_dot_bf16": 2,
+      # the kernels of ops/pallas_sparse_attn.py, each by its name
+      "sparse_attn_fwd": 1, "sparse_attn_mean": 2, "sparse_attn_dq": 0,
+      "sparse_attn_dkv": 1}
   # a toy step as this backend compiles it: the route's argsort once a layer
   # under the plan, twice under a bare checkpoint
   counts = {name: step_recompute.count_ops(_toy_step(wrap).compile().as_text())

@@ -11,6 +11,11 @@ installed beside JAX) and prints, for one step, from the compiled HLO:
   splash_fwd   calls of the splash-attention forward kernel: one a layer
                where its output is kept, two where the layer's forward is
                run again whole
+  sparse_attn_fwd, sparse_attn_mean, sparse_attn_dq, sparse_attn_dkv
+               calls of the four kernels of `ops/pallas_sparse_attn.py`
+               (attention under an indexer's selection): a layer runs each
+               once (the heads' mean in the forward, `dq` sums the backward's
+               as it goes); a rebuilt layer runs none
   ragged_dot   grouped matmuls of the expert layers' heads (a layer: 3
                forward, 3 rematerialised, 6 backward = 12); ragged_dot_tail:
                those inside a conditional (the tail's, walked only where a
@@ -141,7 +146,9 @@ def count_ops(hlo_text: str):
   its kernel: a splash forward kernel is a custom call named
   ``splash_*fwd*``, a grouped matmul one named ``ragged-dot-*`` (its
   ``ragged-dot-metadata`` calls, a few hundred bytes each, are not counted;
-  ``ragged_dot_tail``: those inside a conditional). A sort is told by the
+  ``ragged_dot_tail``: those inside a conditional), a kernel of
+  ``ops/pallas_sparse_attn.py`` one named ``de_sparse_attn_<which>``. A sort
+  is told by the
   ``op_name`` the program gave it: ``route_sort`` is the expert layer's stable
   argsort, ``route_top_k`` the sort the router's ``top_k`` compiles to. A
   plain product is a ``dot`` or a ``convolution`` instruction, counted by its
@@ -151,7 +158,9 @@ def count_ops(hlo_text: str):
   tail = _under_conditionals(comps)
   counts = dict.fromkeys(("splash_fwd", "ragged_dot", "ragged_dot_tail",
                           "sort", "route_sort", "route_top_k",
-                          "dense_dot_f32", "dense_dot_bf16"), 0)
+                          "dense_dot_f32", "dense_dot_bf16",
+                          *(f"sparse_attn_{which}" for which in
+                            ("fwd", "mean", "dq", "dkv"))), 0)
   for comp, lines in comps.items():
     instructions = [m for m in map(_INSTRUCTION.match, lines) if m]
     element = {m.group("name"): m.group("type").split("[")[0]
@@ -181,6 +190,9 @@ def count_ops(hlo_text: str):
         counts["ragged_dot_tail" if comp in tail else "ragged_dot"] += 1
       elif opcode == "custom-call" and re.match(r"splash_\w*fwd", name):
         counts["splash_fwd"] += 1
+      elif opcode == "custom-call" and (
+          kernel := re.match(r"de_(sparse_attn_(?:fwd|mean|dq|dkv))\b", name)):
+        counts[kernel.group(1)] += 1
   return counts
 
 
