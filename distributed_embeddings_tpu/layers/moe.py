@@ -12,7 +12,11 @@ whole layer. No code stands in for the absent chips or their exchange.
 
 How the chosen experts are weighted is the model's, a :class:`Router` on
 the share: softmax over every expert and the chosen renormalised (the
-default), or a sigmoid an expert, the chosen renormalised and scaled. A
+default), or a sigmoid an expert, the chosen renormalised and scaled; and
+where the model chooses under a selection bias (``Router.selection_bias``),
+the choice is made on ``score + bias`` and the weights are gathered from the
+unbiased scores: the bias, one value an expert, is a leaf of the model that
+enters nothing differentiable. A
 shared expert, which every chip computes for its own tokens, is no part of
 a share: :func:`shared_expert` is the plain dense product, and a model adds
 it to ``moe_share``'s sum (counted once when shares are added up).
@@ -79,6 +83,8 @@ class Router:
   score: str = "softmax"      # over every expert | "sigmoid": an expert each
   renormalise: bool = True    # the chosen scores divided by their sum
   scale: float = 1.0          # then multiplied (a routed scaling factor)
+  selection_bias: bool = False  # experts chosen on score + bias, weighted
+                                # by the score alone
 
   def __post_init__(self):
     if self.score not in ("softmax", "sigmoid"):
@@ -112,9 +118,12 @@ class MoEShare:
 
 
 def route(h: jax.Array, w_router: jax.Array, top_k: int,
-          router: Router = Router()):
+          router: Router = Router(), bias=None):
   """-> (p ``[T, top_k]``, the chosen experts' weights; experts
-  ``[T, top_k]``). By default p is renormalised to sum to 1.
+  ``[T, top_k]``). By default p is renormalised to sum to 1. With a
+  ``bias [num_experts]`` the experts are the ``top_k`` of ``scores + bias``
+  and p their unbiased scores: the bias moves the choice and never a
+  weight, so it has no gradient.
 
   Router logits, the score of every expert and the renormalisation in
   float32; the logits' matmul at ``highest`` precision, because a choice of
@@ -123,7 +132,11 @@ def route(h: jax.Array, w_router: jax.Array, top_k: int,
                    precision=lax.Precision.HIGHEST)
   scores = jax.nn.softmax(logits, axis=-1) if router.score == "softmax" \
       else jax.nn.sigmoid(logits)
-  top_p, top_e = lax.top_k(scores, top_k)
+  if bias is None:
+    top_p, top_e = lax.top_k(scores, top_k)
+  else:
+    _, top_e = lax.top_k(scores + bias.astype(scores.dtype), top_k)
+    top_p = jnp.take_along_axis(scores, top_e, axis=-1)
   if router.renormalise:
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
   if router.scale != 1.0:
@@ -143,7 +156,8 @@ def shared_expert(h: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 
 
 def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
-              w_up: jax.Array, w_down: jax.Array, share: MoEShare):
+              w_up: jax.Array, w_down: jax.Array, share: MoEShare,
+              bias=None):
   """``h [T, d]`` -> (``[T, d]`` this share's part of the layer's output,
   counters).
 
@@ -154,7 +168,13 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
   on held experts, ``loads`` per held expert, ``computed``: the live rows in
   the group sizes that were handed to the grouped matmuls, summed over the
   pieces that really ran (``assignments - computed`` is what a capacity, or
-  a tail not walked, would have dropped: 0)."""
+  a tail not walked, would have dropped: 0). ``bias [num_experts]`` where and
+  only where the share's router chooses under one; the counters then gain
+  ``moved``, the (token, slot) choices that the unbiased scores would not
+  have made."""
+  if (bias is None) == share.router.selection_bias:
+    raise ValueError(f"selection_bias={share.router.selection_bias} and "
+                     f"{'no' if bias is None else 'a'} bias")
   first, count = share.held
   t, k = h.shape[0], share.top_k
   n = t * k
@@ -163,7 +183,7 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
     # under exactly one of them (telemetry/scopes.py)
     with jax.named_scope(scopes.MOE_ROUTE):
       with jax.named_scope(scopes.MOE_ROUTER):
-        top_p, top_e = route(h, w_router, k, share.router)
+        top_p, top_e = route(h, w_router, k, share.router, bias)
       with jax.named_scope(scopes.MOE_SORT):
         local = top_e.astype(jnp.int32) - first
         here = (local >= 0) & (local < count)
@@ -260,4 +280,13 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
   counters: Dict[str, jax.Array] = {
       "assignments": jnp.sum(here, dtype=jnp.int32), "loads": loads,
       "computed": computed}
+  if bias is not None:
+    # read by counting tools alone: a step that returns no counters runs no
+    # second choice
+    with jax.named_scope(scopes.MOE), jax.named_scope(scopes.MOE_ROUTE), \
+        jax.named_scope(scopes.MOE_ROUTER):
+      _, unbiased = route(h, w_router, k, share.router)
+      counters["moved"] = jnp.sum(
+          jnp.all(top_e[:, :, None] != unbiased[:, None, :], axis=-1),
+          dtype=jnp.int32)
   return out, counters

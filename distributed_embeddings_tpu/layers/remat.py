@@ -37,6 +37,15 @@ at all. The heads' mean probabilities the indexer's loss is held to are NOT
 kept (268 MB a layer at 8,192 positions): the backward runs their kernel
 again.
 
+``SHORT_CONV_IN``: a short-convolution mixer's first product
+(:func:`..layers.short_conv.short_conv_mixer`): ``h W_in``, float32
+``[T, 3 x hidden]`` (403 MB a layer at 16,384 positions of hidden 2,048),
+the gates ``B``, ``C`` and the convolution's input ``u`` side by side: three
+quarters of the mixer's forward arithmetic. With it kept the rematerialised
+mixer rebuilds the gate chain from it (two multiplies and a three-tap
+convolution an element, bound by memory) and runs ``W_out``'s product alone,
+whose output the layer's feed-forward reads.
+
 A layer that makes none of the named values is rematerialised whole: the
 other models' ``attention="xla"`` (a tile loop under JAX's own transpose,
 for tests and counting tools: nothing of it is kept, scores and
@@ -53,7 +62,9 @@ SPLASH_RESIDUALS = "splash_residuals"
 MOE_ROUTE = "moe_route"
 SPARSE_SELECTION = "sparse_selection"
 SPARSE_ATTN_RESIDUALS = "sparse_attn_residuals"
-KEPT = (SPLASH_RESIDUALS, MOE_ROUTE, SPARSE_SELECTION, SPARSE_ATTN_RESIDUALS)
+SHORT_CONV_IN = "short_conv_in"
+KEPT = (SPLASH_RESIDUALS, MOE_ROUTE, SPARSE_SELECTION, SPARSE_ATTN_RESIDUALS,
+        SHORT_CONV_IN)
 
 
 def checkpoint_layer(layer):

@@ -25,7 +25,7 @@ APPLY = "de_apply"  # delta streams, XLA's scatter-add, the Pallas apply kernel
 ONEHOT = "de_onehot"  # a dense class's windowed one-hot MXU lookup; inside de_combine
 EXCHANGE = "de_exchange"  # the collectives of parallel/wire.py; inside de_route and de_combine
 INTERACT = "de_interact"  # models/dlrm.py::dot_interact; inside de_model
-ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE, gate, attention under the model's mask; inside de_model
+ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/lfm2_moe.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE, gate, attention under the model's mask; inside de_model
 WINDOW_ATTENTION = "de_window_attention"  # models/laguna.py: the mixer of a sliding_attention layer (causal, same document, i - j < sliding_window); inside de_attention
 FULL_ATTENTION = "de_full_attention"  # models/laguna.py: the mixer of a full_attention layer (causal, same document); inside de_attention
 MOE = "de_moe"  # layers/moe.py::moe_share and shared_expert, the whole expert layer; inside de_model
@@ -36,12 +36,14 @@ LM_HEAD = "de_lm_head"  # final norm and the vocabulary head; inside de_model
 LINEAR_ATTENTION = "de_linear_attention"  # models/olmo_hybrid.py: a gated-delta-rule mixer whole (projections, short convolutions, gates, the rule, gated output norm, W_o, the sublayer's norm); inside de_model
 DELTA_RULE = "de_delta_rule"  # layers/gated_delta.py::chunk_gated_delta_rule, the chunked rule alone; inside de_linear_attention
 SPARSE_INDEX = "de_sparse_index"  # models/keye_sparse.py, layers/sparse_index.py: the learned indexer whole (its projections, scores, top-k and its own KL loss); inside de_attention
-MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_hybrid.py: every layer's; models/laguna.py: the leading dense layer's); inside de_model
+MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_hybrid.py: every layer's; models/laguna.py and models/lfm2_moe.py: the leading dense layers'); inside de_model
+SHORT_CONV = "de_short_conv"  # models/lfm2_moe.py, layers/short_conv.py: a double-gated short-convolution mixer whole (the input's norm, W_in, the gate B * u, the causal convolution with its reset, the gate C * c, W_out); inside de_model
 
 # Parts: always inside the child scope of their layer, one level finer: what an
 # op-level account of a language-model step is read by (benchmark/scope_parts.py).
-# The four de_moe_* parts partition de_moe_route and the three de_index_*
-# parts de_sparse_index; the others leave their
+# The four de_moe_* parts partition de_moe_route, the three de_index_*
+# parts de_sparse_index and the two de_conv_* parts de_short_conv but for the
+# input's norm; the others leave their
 # layer a remainder (norms, gates, the residual add) that is read as the
 # layer less its parts and kernels. XLA fuses across a part's line, so a
 # part is exact to a fusion.
@@ -57,14 +59,16 @@ MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h 
 MOE_RETURN = "de_moe_return"  # the weighting y * p with its select and the scatter-add into the output, head and tail (transposed: a gather); inside de_moe_route
 LINATTN_PROJ = "de_linattn_proj"  # the matmuls with wq, wk, wv, wg, wb, wa, wo; inside de_linear_attention
 LINATTN_CONV = "de_linattn_conv"  # short(...): causal_conv with its reset and the silu, three times; inside de_linear_attention
+CONV_PROJ = "de_conv_proj"  # the matmuls with w_in and w_out; inside de_short_conv
+CONV_GATE = "de_conv_gate"  # the gate chain between them: B * u, causal_conv with its reset, C * c; bound by memory where the products are bound by the MXU; inside de_short_conv
 
 TOP_LEVEL = (ROUTE, GATHER, COMBINE, MODEL, LOSS, DENSE_UPDATE, APPLY)
 CHILDREN = (ONEHOT, EXCHANGE, INTERACT)
 # a language model's, all inside de_model (benchmark/scope_children*.py read them)
 LM_CHILDREN = (ATTENTION, MOE, MOE_ROUTE, MOE_EXPERTS, LM_HEAD,
                LINEAR_ATTENTION, DELTA_RULE, MLP, WINDOW_ATTENTION,
-               FULL_ATTENTION, MOE_SHARED, SPARSE_INDEX)
+               FULL_ATTENTION, MOE_SHARED, SPARSE_INDEX, SHORT_CONV)
 # a language model's parts, each inside one of LM_CHILDREN
 PARTS = (ATTN_PROJ, ATTN_QK, ATTN_CORE, MOE_ROUTER, MOE_SORT, MOE_DISPATCH,
          MOE_RETURN, LINATTN_PROJ, LINATTN_CONV, INDEX_SCORES, INDEX_SELECT,
-         INDEX_LOSS)
+         INDEX_LOSS, CONV_PROJ, CONV_GATE)

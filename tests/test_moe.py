@@ -3,11 +3,18 @@ shares of all chips add up to the uncut layer, no assignment is dropped
 however skewed the router, and values and gradients are those of a plain
 loop over the experts; under the default softmax router, whose bits are
 what they were before a share carried a router, and under a scaled sigmoid
-one."""
+one; and under a selection bias, which moves the choice and never a weight,
+has no gradient, and without which `route` and `moe_share` trace to what the
+parent of the PR that added it traced."""
+
+import gzip
+import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from distributed_embeddings_tpu.layers import moe
@@ -224,3 +231,133 @@ def test_gradients_are_the_plain_loops(skewed, held, router):
 def test_a_share_is_a_range_of_the_layers_experts(held):
   with pytest.raises(ValueError, match="no range"):
     MoEShare(E, K, held)
+
+
+# ---- the selection bias ------------------------------------------------------
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+BIASED = Router("sigmoid", True, 1.0, selection_bias=True)
+
+
+def _recorded(text):
+  """``# name`` -> the jaxpr under it, of a file recorded on the parent."""
+  parts = re.split(r"^# (\S+)\n", text, flags=re.M)[1:]
+  return dict(zip(parts[::2], parts[1::2]))
+
+
+def test_without_a_bias_route_traces_to_the_parents_text():
+  """`tests/data/route_jaxpr_parent.txt`: ``jax.make_jaxpr(route)`` at a toy
+  shape under three routers, taken on the parent of PR 42 (commit 77113ce)
+  before `route` took a bias: SDAR's, Laguna's and Keye's steps trace as
+  they did."""
+  with open(os.path.join(DATA, "route_jaxpr_parent.txt")) as f:
+    want = _recorded(f.read())
+  h = jax.ShapeDtypeStruct((64, 16), jnp.float32)
+  w = jax.ShapeDtypeStruct((16, 32), jnp.float32)
+  routers = {"softmax": Router(), "sigmoid_2.5": SIGMOID,
+             "sigmoid_1": Router("sigmoid", True, 1.0)}
+  assert set(want) == set(routers)
+  for name, router in routers.items():
+    for call in (lambda h, w: route(h, w, 3, router),
+                 lambda h, w: route(h, w, 3, router, None),
+                 lambda h, w: route(h, w, 3, router, bias=None)):
+      assert str(jax.make_jaxpr(call)(h, w)) + "\n" == want[name], name
+
+
+def test_without_a_bias_moe_share_traces_to_the_parents_text():
+  """`tests/data/moe_share_jaxpr_parent.txt.gz`: the whole share at a toy
+  shape (4 of 32 experts from the fourth, top 2), recorded on the same
+  parent, object addresses struck out."""
+  with gzip.open(os.path.join(DATA, "moe_share_jaxpr_parent.txt.gz"),
+                 "rt") as f:
+    want = _recorded(f.read())
+  f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+  args = (f32(64, 16), f32(16, 32), f32(4, 16, 24), f32(4, 16, 24),
+          f32(4, 24, 16))
+  for name, router in (("softmax", Router()), ("sigmoid_2.5", SIGMOID)):
+    share = MoEShare(32, 2, (4, 4), router)
+    text = str(jax.make_jaxpr(lambda *a: moe_share(*a, share))(*args))
+    assert re.sub(r"0x[0-9a-f]+", "0x", text) + "\n" == want[name], name
+
+
+def test_a_bias_moves_the_choice_and_never_a_weight():
+  h, w_router, *_ = _weights(4)
+  rng = np.random.default_rng(4)
+  bias = jnp.asarray(rng.uniform(-0.2, 0.2, E), jnp.float32)
+  plain_p, plain_e = route(h, w_router, 3, BIASED)
+  top_p, top_e = route(h, w_router, 3, BIASED, bias)
+  s = 1.0 / (1.0 + np.exp(-np.asarray(h @ w_router, np.float64)))
+  # the choice is the top 3 of s + b ...
+  assert np.array_equal(np.asarray(top_e),
+                        np.argsort(-(s + np.asarray(bias, np.float64)),
+                                   axis=-1)[:, :3])
+  moved = np.asarray(top_e) != np.asarray(plain_e)
+  assert 0.1 < moved.mean() < 0.9
+  # ... and the weights are the chosen experts' UNBIASED scores, renormalised
+  chosen = np.take_along_axis(s, np.asarray(top_e), axis=-1)
+  np.testing.assert_allclose(top_p, chosen / chosen.sum(-1, keepdims=True),
+                             rtol=2e-6)
+  # a bias of 0 chooses as no bias does, to the bit
+  for got, want in zip(route(h, w_router, 3, BIASED, jnp.zeros(E)),
+                       (plain_p, plain_e)):
+    assert np.array_equal(got, want)
+  # one constant on every expert moves nothing either
+  for got, want in zip(route(h, w_router, 3, BIASED, jnp.full(E, 0.25)),
+                       (plain_p, plain_e)):
+    assert np.array_equal(got, want)
+  # without renormalisation the weights are the scores themselves
+  raw_p, raw_e = route(h, w_router, 3, Router("sigmoid", False, 1.0, True),
+                       bias)
+  assert np.array_equal(raw_e, top_e)
+  np.testing.assert_allclose(raw_p, chosen, rtol=2e-6)
+
+
+def test_the_shares_under_a_bias_add_up_and_count_what_it_moved():
+  h, wr, wg, wu, wd = _weights(5)
+  bias = jnp.asarray(np.random.default_rng(5).uniform(-0.2, 0.2, E),
+                     jnp.float32)
+  with jax.default_matmul_precision("highest"):
+    top_p, top_e = route(h, wr, K, BIASED, bias)
+    whole = jnp.zeros_like(h)
+    for e in range(E):
+      y = (jax.nn.silu(h @ wg[e]) * (h @ wu[e])) @ wd[e]
+      whole = whole + jnp.sum(jnp.where(top_e == e, top_p, 0.0),
+                              axis=-1)[:, None] * y
+    parts, moved = [], set()
+    for first in range(0, E, 4):
+      sl = slice(first, first + 4)
+      out, c = moe_share(h, wr, wg[sl], wu[sl], wd[sl],
+                         MoEShare(E, K, (first, 4), BIASED), bias)
+      assert int(c["assignments"]) == int(c["computed"])
+      parts.append(out)
+      moved.add(int(c["moved"]))   # of all experts' choices: every share's
+  np.testing.assert_allclose(sum(parts), whole, atol=4e-6)
+  _, plain_e = route(h, wr, K, BIASED)
+  want = sum(int(e not in plain_e[t]) for t in range(T) for e in top_e[t])
+  assert moved == {want} and 0 < want < T * K
+  with pytest.raises(ValueError, match="selection_bias=True and no bias"):
+    moe_share(h, wr, wg[:4], wu[:4], wd[:4], MoEShare(E, K, (0, 4), BIASED))
+  with pytest.raises(ValueError, match="selection_bias=False and a bias"):
+    moe_share(h, wr, wg[:4], wu[:4], wd[:4], MoEShare(E, K, (0, 4), SIGMOID),
+              bias)
+
+
+def test_the_bias_has_no_gradient_and_an_adam_step_leaves_it_bit_for_bit():
+  h, wr, wg, wu, wd = _weights(6)
+  bias = jnp.asarray(np.random.default_rng(6).uniform(-0.2, 0.2, E),
+                     jnp.float32)
+  share = MoEShare(E, K, (8, 8), BIASED)
+  params = {"router": wr, "bias": bias, "w_gate": wg[8:16], "w_up": wu[8:16],
+            "w_down": wd[8:16]}
+  loss = lambda p: jnp.sum(jnp.sin(moe_share(
+      h, p["router"], p["w_gate"], p["w_up"], p["w_down"], share,
+      p["bias"])[0]))
+  grads = jax.jit(jax.grad(loss))(params)
+  assert grads["bias"].shape == (E,) and grads["bias"].dtype == jnp.float32
+  assert not np.asarray(grads["bias"]).any()     # exactly zero
+  assert float(jnp.max(jnp.abs(grads["router"]))) > 0
+  tx = optax.adam(1e-2)
+  updates, _ = tx.update(grads, tx.init(params), params)
+  after = optax.apply_updates(params, updates)
+  assert np.array_equal(np.asarray(after["bias"]).view(np.uint32),
+                        np.asarray(bias).view(np.uint32))
+  assert not np.array_equal(after["router"], wr)

@@ -17,11 +17,12 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
-from distributed_embeddings_tpu.layers import remat, sparse_index
+from distributed_embeddings_tpu.layers import remat, short_conv, sparse_index
 from distributed_embeddings_tpu.layers.moe import MoEShare, Router, moe_share
 from distributed_embeddings_tpu.models import (
     keye_sparse,
     laguna,
+    lfm2_moe,
     olmo_hybrid,
     sdar_moe,
 )
@@ -57,7 +58,21 @@ TOYS = {
                         layer_types=(olmo_hybrid.LINEAR, olmo_hybrid.FULL),
                         vocab_size=50, heads_held=(0, 4), seq_len=24,
                         mean_document_length=6, chunk=8, attention="xla")),
+    # published layers 1, 2, 3: conv + dense, attention + experts, conv + experts
+    "lfm2_moe": (lfm2_moe, lfm2_moe.Lfm2Moe, lfm2_moe.Lfm2MoeConfig(
+        hidden_size=32, intermediate_size=48, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, moe_intermediate_size=12,
+        num_experts=16, num_experts_per_tok=4, layers_here=(1, 2, 3),
+        vocab_size=50, experts_held=(4, 4), seq_len=24,
+        mean_document_length=8, attention="xla")),
 }
+
+
+def _layers(cfg):
+  """How many decoder layers the toy runs."""
+  if hasattr(cfg, "layers_here"):
+    return len(cfg.layers_here)
+  return len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
 
 
 def _case(model_cls, cfg, batch=2, seed=0):
@@ -138,7 +153,9 @@ def test_gradients_under_the_plan_are_those_with_no_checkpoint(
   assert len(flat_got) == len(flat_want) > 10
   for (path, g), w in zip(flat_got, flat_want):
     largest = float(np.max(np.abs(w)))
-    assert largest > 0, jax.tree_util.keystr(path)
+    # a selection bias enters the choice alone: its gradient is zero
+    assert (largest > 0) != ("expert_bias" in jax.tree_util.keystr(path)), \
+        jax.tree_util.keystr(path)
     if exact:
       assert np.array_equal(g, w), jax.tree_util.keystr(path)
     else:
@@ -157,8 +174,7 @@ def test_every_decoder_layer_runs_under_the_plan(name, monkeypatch):
   grad = lambda: jax.grad(_loss(model_cls, cfg, numerical))
   tops = [eqn for eqn in jax.make_jaxpr(grad())(params, rows).jaxpr.eqns
           if _checkpoint(eqn)]
-  layers = len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
-  assert len(tops) == layers
+  assert len(tops) == _layers(cfg)
   assert all(eqn.params["policy"] is not None for eqn in tops)
   monkeypatch.setattr(module, "checkpoint_layer", lambda layer: layer)
   assert _count(jax.make_jaxpr(grad())(params, rows).jaxpr, _checkpoint) == 0
@@ -169,7 +185,8 @@ def _splash_layer(name):
   Pallas's interpreter, a projection before it (so that ``q``, ``k``, ``v``
   are rebuilt, not arguments) -> (loss(w, x), w, x, layers)."""
   rng = np.random.default_rng(0)
-  length, hkv, group, hd = 128, 1, 2, 128
+  # LFM2's head is 64, half a lane tile; the others' 128
+  length, hkv, group, hd = 128, 1, 2, 64 if name == "lfm2_moe" else 128
   x = jnp.asarray(rng.normal(size=(1, length, 32)), jnp.float32)
   w = jnp.asarray(rng.normal(size=(2, 32, (group + 2) * hkv * hd)) * 0.2,
                   jnp.float32)
@@ -180,10 +197,10 @@ def _splash_layer(name):
       return sdar_moe.attention_splash(
           q.reshape(1, length, hkv, group, hd), k, v, length // 2, 4, 128,
           interpret=True)
-    if name == "laguna":
+    if name in ("laguna", "lfm2_moe"):   # a window of 40, and none
       return laguna.attention_splash(
-          q.reshape(1, length, hkv, group, hd), k, v, seg, 128, 40,
-          interpret=True)
+          q.reshape(1, length, hkv, group, hd), k, v, seg, 128,
+          40 if name == "laguna" else None, interpret=True)
     kv = lambda t: jnp.repeat(t, group, axis=2)
     return olmo_hybrid.attention_splash(q, kv(k), kv(v), seg, 128,
                                         interpret=True)
@@ -234,6 +251,40 @@ def test_the_splash_output_outlives_its_layer(name, capsys):
   for g, t in zip(got, want):
     np.testing.assert_allclose(g, t, atol=1e-5 * float(jnp.max(jnp.abs(t))))
     assert float(jnp.max(jnp.abs(t))) > 0
+
+
+def test_a_convolution_layer_rebuilds_its_mixers_smaller_product(capsys):
+  """A layer of a short convolution and a dense MLP (`models/lfm2_moe.py`):
+  five products forward and ten backward on any plan. The plan keeps
+  ``h W_in`` (`remat.SHORT_CONV_IN`), so the rebuilt forward holds ``W_out``
+  (whose output the MLP reads) and the MLP's gate and up (``W_down``'s
+  output is needed by nothing); a bare checkpoint runs ``W_in`` again."""
+  _, _, cfg = TOYS["lfm2_moe"]
+  rng = np.random.default_rng(0)
+  p = {n: jnp.asarray(rng.normal(size=shape) * 0.3, jnp.float32)
+       for n, (shape, _) in lfm2_moe.layer_shapes(
+           cfg, lfm2_moe.CONV, lfm2_moe.DENSE).items()}
+  x = jnp.asarray(rng.normal(size=(2, cfg.seq_len, cfg.hidden_size)),
+                  jnp.float32)
+  seg = jnp.asarray(np.arange(cfg.seq_len)[None, :] >= 9, jnp.int32) \
+      * jnp.ones((2, 1), jnp.int32)
+  layer = lambda p, x: lfm2_moe.decoder_layer(
+      cfg, lfm2_moe.CONV, lfm2_moe.DENSE, p, x, seg)[0]
+  loss = lambda wrap: lambda p, x: jnp.sum(jnp.sin(wrap(layer)(p, x)))
+  products = lambda wrap: _count(jax.make_jaxpr(jax.grad(
+      loss(wrap), argnums=(0, 1)))(p, x).jaxpr, _primitive("dot_general"))
+  assert products(lambda f: f) == 5 + 10
+  assert products(remat.checkpoint_layer) == 5 + 3 + 10
+  assert products(jax.checkpoint) == 5 + 4 + 10
+  kept = _residuals(capsys, loss(remat.checkpoint_layer), p, x)
+  assert kept.count(f"f32[2,{cfg.seq_len},{3 * cfg.hidden_size}]") == 1
+  assert f"f32[2,{cfg.seq_len},{3 * cfg.hidden_size}]" not in _residuals(
+      capsys, loss(jax.checkpoint), p, x)
+  got = jax.grad(loss(remat.checkpoint_layer), argnums=(0, 1))(p, x)
+  want = jax.grad(loss(lambda f: f), argnums=(0, 1))(p, x)
+  for g, w in zip(jax.tree_util.tree_leaves(got),
+                  jax.tree_util.tree_leaves(want)):
+    assert np.array_equal(g, w)
 
 
 def _moe_weights(seed):
@@ -457,7 +508,10 @@ def test_kept_is_what_the_code_names():
   kept."""
   assert set(remat.KEPT) == {remat.SPLASH_RESIDUALS, remat.MOE_ROUTE,
                              remat.SPARSE_SELECTION,
-                             remat.SPARSE_ATTN_RESIDUALS}
+                             remat.SPARSE_ATTN_RESIDUALS,
+                             remat.SHORT_CONV_IN}
+  # a short convolution's first product is named where it is made
+  assert inspect.getsource(short_conv).count(", SHORT_CONV_IN)") == 1
   # the two a learned indexer's attention names are made in one place
   source = inspect.getsource(sparse_index)
   assert source.count("SPARSE_SELECTION)") == 1
@@ -471,6 +525,7 @@ def test_kept_is_what_the_code_names():
         == remat.SPLASH_RESIDUALS
   assert not [f.name for cfg in (sdar_moe.SDARMoEConfig, laguna.LagunaConfig,
                                  olmo_hybrid.OlmoHybridConfig,
-                                 keye_sparse.KeyeSparseConfig)
+                                 keye_sparse.KeyeSparseConfig,
+                                 lfm2_moe.Lfm2MoeConfig)
               for f in dataclasses.fields(cfg)
               if "remat" in f.name or "checkpoint" in f.name]

@@ -3,7 +3,7 @@
 DLRM and the toy zoo step, at world 1 and on a mesh of four virtual devices.
 And the parts of a language-model step (``scopes.PARTS``): where each lies,
 in which passes, and what a rematerialised layer does not run again, on the
-LOWERED text of the three toy models' steps (the CPU's compiler merges a
+LOWERED text of the five toy models' steps (the CPU's compiler merges a
 rebuilt op with its forward twin; the TPU's barrier forbids that).
 """
 
@@ -19,6 +19,7 @@ import pytest
 
 import test_keye_sparse
 import test_laguna
+import test_lfm2_moe
 import test_olmo_hybrid
 import test_sdar_moe
 from distributed_embeddings_tpu.layers import TableConfig, remat
@@ -29,6 +30,7 @@ from distributed_embeddings_tpu.models import (
     bce_loss,
     keye_sparse,
     laguna,
+    lfm2_moe,
     olmo_hybrid,
     sdar_moe,
 )
@@ -169,7 +171,7 @@ def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
 def test_the_vocabulary_is_one_flat_set_of_names():
   names = scopes.TOP_LEVEL + scopes.CHILDREN + scopes.LM_CHILDREN \
       + scopes.PARTS
-  assert len(set(names)) == len(names) == 34
+  assert len(set(names)) == len(names) == 37
   for n in names:
     assert n.startswith("de_") and "/" not in n and "(" not in n
   declared = {v for k, v in vars(scopes).items()
@@ -178,8 +180,11 @@ def test_the_vocabulary_is_one_flat_set_of_names():
 
 
 # ---- the parts of a language-model step ---------------------------------------
-# the toy models of tests/test_sdar_moe.py, test_olmo_hybrid.py, test_laguna.py
+# the toy models of tests/test_sdar_moe.py, test_olmo_hybrid.py, test_laguna.py,
+# test_keye_sparse.py, test_lfm2_moe.py
 LM_TOYS = {
+    "lfm2_moe": (lfm2_moe.Lfm2Moe, olmo_hybrid.next_token_loss,
+                 test_lfm2_moe.TOY),
     "sdar_moe": (sdar_moe.SDARMoE, sdar_moe.block_diffusion_loss,
                  dataclasses.replace(test_sdar_moe.TOY, num_experts=8,
                                      num_experts_per_tok=2,
@@ -202,7 +207,8 @@ INSIDE = {
     scopes.LINATTN_CONV: scopes.LINEAR_ATTENTION,
     scopes.INDEX_SCORES: scopes.SPARSE_INDEX,
     scopes.INDEX_SELECT: scopes.SPARSE_INDEX,
-    scopes.INDEX_LOSS: scopes.SPARSE_INDEX}
+    scopes.INDEX_LOSS: scopes.SPARSE_INDEX,
+    scopes.CONV_PROJ: scopes.SHORT_CONV, scopes.CONV_GATE: scopes.SHORT_CONV}
 INDEX_PARTS = (scopes.INDEX_SCORES, scopes.INDEX_SELECT, scopes.INDEX_LOSS)
 ROUTE_PARTS = (scopes.MOE_ROUTER, scopes.MOE_SORT, scopes.MOE_DISPATCH,
                scopes.MOE_RETURN)
@@ -214,7 +220,9 @@ PARTS_OF = {
     "laguna": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
     + ROUTE_PARTS,
     "keye_sparse": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
-    + INDEX_PARTS + ROUTE_PARTS}
+    + INDEX_PARTS + ROUTE_PARTS,
+    "lfm2_moe": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE,
+                 scopes.CONV_PROJ, scopes.CONV_GATE) + ROUTE_PARTS}
 # the passes a part has ops in where not all three: what `remat.KEPT` names is
 # made in the forward and never rebuilt (the selection; the attention's output
 # and log-sum-exp, so the rebuilt layer computes no score)
@@ -324,6 +332,27 @@ def test_the_four_route_parts_partition_the_expert_route(lm_stacks):
     assert sum(p in _components(stack) for p in ROUTE_PARTS) == 1, stack
 
 
+def test_the_two_conv_parts_partition_the_mixer_but_for_its_norm(lm_stacks):
+  """Every op under ``de_short_conv`` lies under one of its two parts or is
+  the input's norm or the residual add, which lie straight under it."""
+  name, stacks = lm_stacks
+  under = [s for s in stacks if scopes.SHORT_CONV in _components(s)]
+  assert bool(under) == (name == "lfm2_moe")
+  for stack in under:
+    names = _components(stack)
+    parts = [p for p in (scopes.CONV_PROJ, scopes.CONV_GATE) if p in names]
+    assert len(parts) <= 1, stack
+    assert scopes.MODEL in names[:names.index(scopes.SHORT_CONV)], stack
+    assert scopes.ATTENTION not in names and scopes.MLP not in names, stack
+  if not under:
+    return
+  gate = {_components(s)[-1] for s in under
+          if scopes.CONV_GATE in _components(s)}
+  assert {"mul", "pad"} <= gate and "dot_general" not in gate
+  assert {_components(s)[-1] for s in under
+          if scopes.CONV_PROJ in _components(s)} >= {"dot_general"}
+
+
 def test_every_part_has_ops_in_all_three_passes(lm_stacks):
   """Every decoder layer runs under ``checkpoint_layer``, so what a part
   holds is traced forward, rebuilt and backward."""
@@ -356,7 +385,9 @@ def test_what_the_plan_keeps_is_not_rebuilt_under_its_part(lm_stacks):
 SPLASH_TOYS = {
     "sdar_moe": dict(head_dim=128, seq_len=64),
     "olmo_hybrid": dict(head_dim=128, seq_len=128, chunk=64),
-    "laguna": dict(head_dim=128, seq_len=128)}
+    "laguna": dict(head_dim=128, seq_len=128),
+    # the published head: half a lane tile
+    "lfm2_moe": dict(head_dim=64, seq_len=128)}
 
 
 @pytest.mark.parametrize("name", sorted(SPLASH_TOYS))
@@ -391,9 +422,12 @@ def test_no_splash_forward_kernel_is_called_in_a_rebuilt_core(
   assert forward
   sites = [locs[ref] for callee, ref in re.findall(
       r"call @([\w.]+)\(.*loc\((#loc\d+)\)", text) if callee in forward]
-  layers = len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
-  attention_layers = layers if name != "olmo_hybrid" else sum(
-      kind == olmo_hybrid.FULL for kind in cfg.layer_types)
+  if name == "lfm2_moe":
+    attention_layers = sum(mixer == lfm2_moe.FULL for mixer, _ in cfg.kinds)
+  else:
+    layers = len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
+    attention_layers = layers if name != "olmo_hybrid" else sum(
+        kind == olmo_hybrid.FULL for kind in cfg.layer_types)
   assert len(sites) == attention_layers
   for stack in sites:
     assert scopes.ATTN_CORE in _components(stack), stack

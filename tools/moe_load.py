@@ -14,6 +14,7 @@ real size the CPU takes a minute or two a batch; ``--layers 1`` shortens it):
 
   JAX_PLATFORMS=cpu python tools/moe_load.py sdar_moe_train_1chip [--seed N]
   JAX_PLATFORMS=cpu python tools/moe_load.py laguna_moe_train_1chip --seed N
+  JAX_PLATFORMS=cpu python tools/moe_load.py lfm2_moe_train_1chip --seed N
 """
 
 import argparse
@@ -51,13 +52,19 @@ def main(argv=None):
                              args.seed, args.batch,
                              traffic.family_labels(family, cell.config))
   config = parts.model.config
+  # a model names the layers that run here by count or, where a layer's
+  # kinds follow from its published number, by number
+  by_number = hasattr(config, "layers_here")
   if args.layers:
-    config = dataclasses.replace(config, num_hidden_layers=args.layers)
+    config = dataclasses.replace(
+        config, **({"layers_here": config.layers_here[:args.layers]}
+                   if by_number else {"num_hidden_layers": args.layers}))
+  n_layers = len(config.layers_here) if by_number \
+      else config.num_hidden_layers
   model = type(parts.model)(config, with_counters=True)
   dense = {n: jnp.asarray(w) for n, w in
            reference.dense_weights(spec, args.seed).items()
-           if not n.startswith("layer_")
-           or int(n.split("_")[1]) < config.num_hidden_layers}
+           if not n.startswith("layer_") or int(n.split("_")[1]) < n_layers}
   table = spec.tables[0]
   ids, inverse = np.unique(batch.cats, return_inverse=True)
   rows = weights.rows_np(
@@ -87,6 +94,10 @@ def main(argv=None):
   }
   if "masked" in out:
     report["masked_share"] = float(np.mean(np.asarray(out["masked"])))
+  if "moved" in moe:
+    report["moved_share"] = [
+        round(float(m) / (positions * config.num_experts_per_tok), 4)
+        for m in moe["moved"]]
   print(json.dumps(report))
   return report
 
