@@ -7,7 +7,9 @@ line — on a TPU at Criteo width, and fails unless what came out is right:
   (``tools/smoke_pallas_apply.py``: the apply kernel with and without its
   VMEM-resident heads, on a power-law and on a uniform stream, on one
   device and under ``shard_map`` with starts that differ from rank to rank;
-  ``tools/smoke_pallas_interact.py``);
+  ``tools/smoke_pallas_interact.py``; ``tools/smoke_pallas_moe_combine.py``:
+  the expert layer's combine kernel against the scatter-add at a small shape
+  and at a cell's, and its ``custom_vjp`` pair through ``jax.grad``);
 - leg D: the sparse-attention kernels (``ops/pallas_sparse_attn.py``)
   against the XLA tile loop at the shapes of ``keye_dsa_train_1chip``
   (``tools/smoke_pallas_sparse_attn.py``: 8,192 x 32 x 128, ``topk`` 2,048,
@@ -55,7 +57,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 LOG_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 MAIN = os.path.join("examples", "dlrm", "main.py")
 KERNEL_SMOKES = (os.path.join("tools", "smoke_pallas_apply.py"),
-                 os.path.join("tools", "smoke_pallas_interact.py"))
+                 os.path.join("tools", "smoke_pallas_interact.py"),
+                 os.path.join("tools", "smoke_pallas_moe_combine.py"))
 
 SPARSE_ATTN_SMOKE = os.path.join("tools", "smoke_pallas_sparse_attn.py")
 INDEX_LOAD = os.path.join("tools", "sparse_index_load.py")

@@ -36,6 +36,18 @@ their product) live as long as the caller lets them, so a training model runs
 the layer under ``layers/remat.py::checkpoint_layer`` and they are one layer's
 backward's; the tail rematerialises itself, a piece at a time.
 
+Two sums add the head's rows up by token: the layer's output (``out[tok[r]]
++= p[r] * y[r]``) and, transposed, the cotangent of ``h`` through the gather
+that made the stream. XLA runs each as a scatter-add, a read-modify-write a
+row (81-94 ns a row of 8 KiB on a v5e where its gather takes 37: PERF.md, PR
+43). On a TPU, for shapes it takes, both are the token-major kernel of
+``ops/pallas_moe_combine.py``: every token owns ``top_k`` positions of the
+sorted stream (:func:`sorted_positions`, the inverse of the order), fetches
+its rows below the head and sums them once, the weight and the select riding
+the read. :func:`combine_kernel` decides by what it can observe (the backend,
+the shapes); everywhere else, and in the tail, the scatter-adds stand as they
+were, and are the kernel's oracle.
+
 The head is computed WHOLE: its rows past the live count (zeros) are put in
 the last expert's group and multiplied like the others, so that a step's
 time does not follow the load. That costs time, and what it buys is
@@ -65,6 +77,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import pallas_moe_combine
 from ..telemetry import scopes
 from .dense import mxu_dot
 from .remat import MOE_ROUTE
@@ -155,6 +168,115 @@ def shared_expert(h: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                    w_down)
 
 
+def sorted_positions(key: jax.Array, classes: int) -> jax.Array:
+  """Where a stable sort of ``key`` (int32 ``[n]``, values in ``[0,
+  classes)``) puts each element: the inverse of ``argsort(key,
+  stable=True)`` as int32 ``[n]``, made without a second sort and without a
+  scatter: an element lies at its class's start plus the number of its class
+  that come before it."""
+  mine = key[:, None] == jnp.arange(classes, dtype=key.dtype)
+  upto = jnp.cumsum(mine.astype(jnp.int32), axis=0)
+  sizes = upto[-1]
+  starts = jnp.cumsum(sizes) - sizes
+  return jnp.sum(jnp.where(mine, upto - 1 + starts, 0), axis=1)
+
+
+def combine_kernel(rows: int, h: jax.Array, top_k: int):
+  """What adds the head's ``rows`` rows up by token here: ``None`` for XLA's
+  scatter-add (any backend but a TPU, and shapes the kernel does not take),
+  else ``ops/pallas_moe_combine.py`` with this for its ``interpret``
+  (``False``: on the chip). It reads what it can observe; no option names a
+  path. A test that wants the kernel in Pallas's interpreter replaces this
+  function."""
+  if (jax.default_backend() == "tpu" and h.dtype == jnp.float32
+      and pallas_moe_combine.fits(rows, h.shape[0], top_k, h.shape[1])):
+    return False
+  return None
+
+
+# The head's way to the experts and back where the kernel runs: ``dispatch``
+# gathers the sorted stream's rows of ``h``, ``combine`` adds the experts'
+# rows up by token. They are each other's transposes, and what XLA's own
+# transpose makes a scatter-add is written here as the token-major kernel:
+# the backward of ``dispatch`` IS ``combine`` with a scale of one. ``pos [T,
+# k]`` is the inverse of the sorted order; a row at or past ``n_live``
+# belongs to no held expert (zeros going in, selected away coming out).
+#
+# The kernel is entered through two jitted functions, one a part of the route.
+# A step calls each once a layer and a pass, and a ``jax.jit`` traces its
+# Python once a process and shape, where the kernel's body alone was traced
+# sixteen times a step (seconds of set-up on every warm start, before the
+# compile cache can be asked: PERF.md, PR 44). Two, and each with its part's
+# scopes INSIDE: a jitted function is lowered once a module, and its ops carry
+# the names opened inside it whichever call it was lowered for (what lies
+# outside, the layer's and the pass's, XLA joins on from each call), so one
+# shared function could not name its kernel after two parts.
+@functools.partial(jax.jit, static_argnums=(0,))
+def _dispatch_sum(interpret, dx, pos, n_live):
+  """The cotangent of ``h`` through the gather of the head's rows."""
+  with jax.named_scope(scopes.MOE_ROUTE), \
+      jax.named_scope(scopes.MOE_DISPATCH):
+    return pallas_moe_combine.combine(
+        dx, pos, (pos < n_live).astype(dx.dtype), interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _return_sum(interpret, y, pos, p_tok, n_live):
+  """The layer's output: the head's rows by token, each under its weight."""
+  with jax.named_scope(scopes.MOE_ROUTE), jax.named_scope(scopes.MOE_RETURN):
+    return pallas_moe_combine.combine(
+        y, pos, jnp.where(pos < n_live, p_tok.astype(y.dtype), 0),
+        interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(interpret, h, tok_c, pos, n_live):
+  return _dispatch_fwd(interpret, h, tok_c, pos, n_live)[0]
+
+
+def _dispatch_fwd(interpret, h, tok_c, pos, n_live):
+  with jax.named_scope(scopes.MOE_ROUTE), \
+      jax.named_scope(scopes.MOE_DISPATCH):
+    live = jnp.arange(tok_c.shape[0], dtype=jnp.int32) < n_live
+    x = jnp.where(live[:, None], jnp.take(h, tok_c, axis=0), 0)
+  return x, (pos, n_live)
+
+
+def _dispatch_bwd(interpret, kept, dx):
+  pos, n_live = kept
+  return _dispatch_sum(interpret, dx, pos, n_live), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(interpret, y, p_c, tok_c, pos, p_tok, n_live):
+  """``zeros([T, d]).at[tok_c].add(where(live, y * p_c, 0))``. ``p_tok [T,
+  k]`` holds the values of ``p_c`` token-major (the router's own ``top_p``),
+  for the kernel's read alone: the weights' gradient leaves through
+  ``p_c``, in sorted order, as it does without the kernel."""
+  return _combine_fwd(interpret, y, p_c, tok_c, pos, p_tok, n_live)[0]
+
+
+def _combine_fwd(interpret, y, p_c, tok_c, pos, p_tok, n_live):
+  out = _return_sum(interpret, y, pos, p_tok, n_live)
+  return out, (y, p_c, tok_c, n_live)
+
+
+def _combine_bwd(interpret, kept, dout):
+  y, p_c, tok_c, n_live = kept
+  with jax.named_scope(scopes.MOE_ROUTE), jax.named_scope(scopes.MOE_RETURN):
+    live = jnp.arange(tok_c.shape[0], dtype=jnp.int32) < n_live
+    g = jnp.where(live[:, None], jnp.take(dout, tok_c, axis=0), 0)
+    dy = g * p_c[:, None].astype(g.dtype)
+    dp = jnp.sum(g * y, axis=1).astype(p_c.dtype)
+  return dy, dp, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
               w_up: jax.Array, w_down: jax.Array, share: MoEShare,
               bias=None):
@@ -208,29 +330,44 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
         order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
         tok = order // k
         p_sorted = jnp.take(top_p.reshape(n), order)
+        kernel = combine_kernel(head, h, k)
+        if kernel is not None:
+          # kept with `order`: the backward's combine reads it too
+          pos = checkpoint_name(
+              sorted_positions(key, count + 1).reshape(t, k), MOE_ROUTE)
 
-    def rows_of(begin, size, whole, h, tok_c, p_c, w_gate, w_up, w_down):
-      """-> (the weighted expert outputs ``[size, d]`` of the sorted stream's
-      rows ``begin .. begin + size``, how many of them were live rows of a
-      group). ``whole``: the rows past the live count (zeros) are given to
-      the last expert's group, so that every one of the ``size`` rows is
-      multiplied and the time does not follow the live count."""
+    def groups_of(begin, size, whole):
+      """The sorted stream's rows ``begin .. begin + size`` -> (which of them
+      are live, the held experts' group sizes among them, how many were live
+      rows of a group). ``whole``: the rows past the live count (zeros) are
+      given to the last expert's group, so that every one of the ``size``
+      rows is multiplied and the time does not follow the live count."""
       live = begin + jnp.arange(size, dtype=jnp.int32) < n_live
       sizes = jnp.clip(ends, begin, begin + size) \
           - jnp.clip(starts, begin, begin + size)
       done = jnp.sum(sizes)
       if whole:
         sizes = sizes.at[-1].add(size - done)
+      return live, sizes, done
+
+    def experts_of(x, sizes, w_gate, w_up, w_down):
+      with jax.named_scope(scopes.MOE_EXPERTS):
+        gate = lax.ragged_dot(x, w_gate, sizes)
+        up = lax.ragged_dot(x, w_up, sizes)
+        return lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
+
+    def rows_of(begin, size, whole, h, tok_c, p_c, w_gate, w_up, w_down):
+      """-> (the weighted expert outputs ``[size, d]`` of the sorted stream's
+      rows ``begin .. begin + size``, how many of them were live rows of a
+      group)."""
+      live, sizes, done = groups_of(begin, size, whole)
       with jax.named_scope(scopes.MOE_ROUTE), \
           jax.named_scope(scopes.MOE_DISPATCH):
         # rows past the live count are zeros going in and selected away
         # coming out (left to no group, the grouped matmul would not even
         # write them)
         x = jnp.where(live[:, None], jnp.take(h, tok_c, axis=0), 0)
-      with jax.named_scope(scopes.MOE_EXPERTS):
-        gate = lax.ragged_dot(x, w_gate, sizes)
-        up = lax.ragged_dot(x, w_up, sizes)
-        y = lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
+      y = experts_of(x, sizes, w_gate, w_up, w_down)
       with jax.named_scope(scopes.MOE_ROUTE), \
           jax.named_scope(scopes.MOE_RETURN):
         return jnp.where(live[:, None], y * p_c[:, None].astype(y.dtype),
@@ -238,11 +375,20 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
 
     # no checkpoint of its own: whether the head's residuals are kept or
     # rebuilt is its caller's plan (layers/remat.py)
-    y, computed = rows_of(0, head, True, h, tok[:head], p_sorted[:head],
-                          w_gate, w_up, w_down)
-    with jax.named_scope(scopes.MOE_ROUTE), \
-        jax.named_scope(scopes.MOE_RETURN):
-      out = jnp.zeros_like(h).at[tok[:head]].add(y)
+    if kernel is None:
+      y, computed = rows_of(0, head, True, h, tok[:head], p_sorted[:head],
+                            w_gate, w_up, w_down)
+      with jax.named_scope(scopes.MOE_ROUTE), \
+          jax.named_scope(scopes.MOE_RETURN):
+        out = jnp.zeros_like(h).at[tok[:head]].add(y)
+    else:
+      # the same sums read from the token's side (ops/pallas_moe_combine.py):
+      # the weighting and the select ride the kernel's read of a row
+      _, sizes, computed = groups_of(0, head, True)
+      y = experts_of(_dispatch(kernel, h, tok[:head], pos, n_live), sizes,
+                     w_gate, w_up, w_down)
+      out = _combine(kernel, y, p_sorted[:head], tok[:head], pos,
+                     lax.stop_gradient(top_p), n_live)
 
     if tail:
       @jax.checkpoint

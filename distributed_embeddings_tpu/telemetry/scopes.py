@@ -55,8 +55,8 @@ INDEX_SELECT = "de_index_select"  # layers/sparse_index.py::select_topk and the 
 INDEX_LOSS = "de_index_loss"  # the KL's target (the heads' mean of the main attention's probabilities: on a TPU the de_sparse_attn_mean kernel, once a direction), the indexer's softmax over the selection and the KL (backward: its gradient into the score); inside de_sparse_index
 MOE_ROUTER = "de_moe_router"  # layers/moe.py::route whole: the router's matmul, the scores, top_k, renormalisation; inside de_moe_route
 MOE_SORT = "de_moe_sort"  # the sort key, argsort, bincount, the cumulative sums, tok, p_sorted; inside de_moe_route
-MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h with its select (transposed: the scatter-add of the cotangent into h); inside de_moe_route
-MOE_RETURN = "de_moe_return"  # the weighting y * p with its select and the scatter-add into the output, head and tail (transposed: a gather); inside de_moe_route
+MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h with its select (transposed: the scatter-add of the cotangent into h; on a TPU the head's is the kernel de_moe_combine, ops/pallas_moe_combine.py); inside de_moe_route
+MOE_RETURN = "de_moe_return"  # the weighting y * p with its select and the scatter-add into the output, head and tail (on a TPU the head's three are one call of the kernel de_moe_combine; transposed: a gather); inside de_moe_route
 LINATTN_PROJ = "de_linattn_proj"  # the matmuls with wq, wk, wv, wg, wb, wa, wo; inside de_linear_attention
 LINATTN_CONV = "de_linattn_conv"  # short(...): causal_conv with its reset and the silu, three times; inside de_linear_attention
 CONV_PROJ = "de_conv_proj"  # the matmuls with w_in and w_out; inside de_short_conv

@@ -71,7 +71,8 @@ def test_chip_smoke_kernel_names_match_the_ops():
 
 @pytest.mark.parametrize("relpath", [
     "tools/smoke_pallas_apply.py", "tools/smoke_pallas_interact.py",
-    "tools/smoke_pallas_sparse_attn.py",
+    "tools/smoke_pallas_sparse_attn.py", "tools/smoke_pallas_moe_combine.py",
+    "tools/bench_moe_combine.py",
     "bench.py"])
 def test_chip_only_programs_refuse_a_cpu_backend(relpath, capsys):
   assert jax.default_backend() == "cpu"

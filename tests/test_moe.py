@@ -279,6 +279,39 @@ def test_without_a_bias_moe_share_traces_to_the_parents_text():
     assert re.sub(r"0x[0-9a-f]+", "0x", text) + "\n" == want[name], name
 
 
+@pytest.mark.parametrize("d,dtype,kernel", [
+    (128, jnp.float32, True),      # whole lane tiles of float32
+    (16, jnp.float32, False),      # the toy width of this file: no lane tile
+    (192, jnp.float32, False),
+])
+def test_on_a_tpu_the_head_takes_the_kernel_only_where_it_fits(
+    monkeypatch, d, dtype, kernel):
+  """`moe.combine_kernel` reads the backend and `pallas_moe_combine.fits`:
+  where either says no, the share traces to the two scatter-adds it always
+  had (the jaxpr of the test above), with no kernel call and no `pos`."""
+  f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+  args = (jax.ShapeDtypeStruct((64, d), dtype), f32(d, 32), f32(4, d, 24),
+          f32(4, d, 24), f32(4, 24, d))
+  share = MoEShare(32, 2, (4, 4))
+  trace = lambda: str(jax.make_jaxpr(lambda *a: moe_share(*a, share))(*args))
+  on_cpu = trace()
+  assert "pallas_call" not in on_cpu and "cumsum" in on_cpu
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  on_tpu = trace()
+  # the kernel sums float32 and nothing else
+  assert moe.combine_kernel(
+      256, jax.ShapeDtypeStruct((64, 128), jnp.bfloat16), 2) is None
+  assert ("pallas_call" in on_tpu) == kernel
+  if not kernel:
+    strike = lambda text: re.sub(r"0x[0-9a-f]+", "0x", text)
+    assert strike(on_tpu) == strike(on_cpu)
+  else:
+    # the head's scatter-add is gone, the tail's (inside the conditional)
+    # stays; the inverse of the order is counted, not sorted or scattered
+    assert on_tpu.count("scatter-add") == on_cpu.count("scatter-add") - 1
+    assert on_tpu.count("sort[") == on_cpu.count("sort[")
+
+
 def test_a_bias_moves_the_choice_and_never_a_weight():
   h, w_router, *_ = _weights(4)
   rng = np.random.default_rng(4)

@@ -417,6 +417,8 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
   %de_sparse_attn_mean.4 = f32[8,8]{1,0} custom-call(%k, %k, %x), custom_call_target="tpu_custom_call"
   %de_sparse_attn_mean.5 = f32[8,8]{1,0} custom-call(%k, %k, %x), custom_call_target="tpu_custom_call"
   %de_sparse_attn_dkv.6 = (f32[8,4]{1,0}, f32[8,4]{1,0}) custom-call(%k, %k, %x), custom_call_target="tpu_custom_call"
+  %de_moe_combine.1 = f32[1,1,8,128]{3,2,1,0} custom-call(%k, %x, %x), custom_call_target="tpu_custom_call"
+  %de_moe_combine.2 = f32[1,1,8,128]{3,2,1,0} custom-call(%k, %x, %x), custom_call_target="tpu_custom_call"
   %ragged-dot-metadata.5 = (s32[17]{0}, s32[1]{0}) custom-call(%k), custom_call_target="tpu_custom_call"
   %ragged-dot-none.12 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
   %ragged-dot-none.13 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
@@ -433,7 +435,9 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
       "dense_dot_f32": 2, "dense_dot_bf16": 2,
       # the kernels of ops/pallas_sparse_attn.py, each by its name
       "sparse_attn_fwd": 1, "sparse_attn_mean": 2, "sparse_attn_dq": 0,
-      "sparse_attn_dkv": 1}
+      "sparse_attn_dkv": 1,
+      # the expert layer's combine (ops/pallas_moe_combine.py)
+      "moe_combine": 2}
   # a toy step as this backend compiles it: the route's argsort once a layer
   # under the plan, twice under a bare checkpoint
   counts = {name: step_recompute.count_ops(_toy_step(wrap).compile().as_text())
@@ -442,6 +446,7 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
   assert counts["plan"]["route_sort"] == 2
   assert counts["bare"]["route_sort"] == 4
   assert counts["plan"]["splash_fwd"] == counts["plan"]["ragged_dot_tail"] == 0
+  assert counts["plan"]["moe_combine"] == 0   # this backend runs XLA's form
 
 
 def test_program_sha_sees_the_program_and_not_where_it_came_from():

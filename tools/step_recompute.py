@@ -16,6 +16,11 @@ installed beside JAX) and prints, for one step, from the compiled HLO:
                (attention under an indexer's selection): a layer runs each
                once (the heads' mean in the forward, `dq` sums the backward's
                as it goes); a rebuilt layer runs none
+  moe_combine  calls of `ops/pallas_moe_combine.py` (`de_moe_combine`, the
+               expert layer's combine read token-major): two an expert layer,
+               the layer's output in the forward and the cotangent of its
+               input in the backward; none in the rebuilt forward; 0 in a
+               cell without experts
   ragged_dot   grouped matmuls of the expert layers' heads (a layer: 3
                forward, 3 rematerialised, 6 backward = 12); ragged_dot_tail:
                those inside a conditional (the tail's, walked only where a
@@ -147,8 +152,8 @@ def count_ops(hlo_text: str):
   ``splash_*fwd*``, a grouped matmul one named ``ragged-dot-*`` (its
   ``ragged-dot-metadata`` calls, a few hundred bytes each, are not counted;
   ``ragged_dot_tail``: those inside a conditional), a kernel of
-  ``ops/pallas_sparse_attn.py`` one named ``de_sparse_attn_<which>``. A sort
-  is told by the
+  ``ops/pallas_sparse_attn.py`` one named ``de_sparse_attn_<which>``, the
+  expert layer's combine ``de_moe_combine``. A sort is told by the
   ``op_name`` the program gave it: ``route_sort`` is the expert layer's stable
   argsort, ``route_top_k`` the sort the router's ``top_k`` compiles to. A
   plain product is a ``dot`` or a ``convolution`` instruction, counted by its
@@ -158,7 +163,7 @@ def count_ops(hlo_text: str):
   tail = _under_conditionals(comps)
   counts = dict.fromkeys(("splash_fwd", "ragged_dot", "ragged_dot_tail",
                           "sort", "route_sort", "route_top_k",
-                          "dense_dot_f32", "dense_dot_bf16",
+                          "dense_dot_f32", "dense_dot_bf16", "moe_combine",
                           *(f"sparse_attn_{which}" for which in
                             ("fwd", "mean", "dq", "dkv"))), 0)
   for comp, lines in comps.items():
@@ -193,6 +198,8 @@ def count_ops(hlo_text: str):
       elif opcode == "custom-call" and (
           kernel := re.match(r"de_(sparse_attn_(?:fwd|mean|dq|dkv))\b", name)):
         counts[kernel.group(1)] += 1
+      elif opcode == "custom-call" and re.match(r"de_moe_combine\b", name):
+        counts["moe_combine"] += 1
   return counts
 
 
@@ -227,14 +234,12 @@ def program_sha(hlo_text: str) -> str:
   return hashlib.sha256(without_provenance(hlo_text).encode()).hexdigest()
 
 
-def compile_step(cell_name: str):
-  """The cell's training step compiled for one chip of a described v5e ->
-  the compiled executable."""
+def build_program(cell_name: str):
+  """What `benchmark/run.py` times as "plan and model", for one chip of a
+  described v5e -> (the cell, its model spec, the `program.Program`)."""
   import jax
-  from jax.experimental import topologies
-  from jax.sharding import SingleDeviceSharding
 
-  from benchmark import program, specs, traffic
+  from benchmark import program, specs
 
   cell = specs.load_cell(cell_name)
   if cell.chips != 1:
@@ -247,18 +252,36 @@ def compile_step(cell_name: str):
   spec = family.model_spec(cell.config)
   parts = family.build_parts(cell.config, cell.chips,
                              int(cell.traffic["global_batch"]))
+  return cell, spec, program.Program(parts, spec, 0, None)
+
+
+def compile_built(cell, spec, prog):
+  """What :func:`build_program` gave -> the cell's training step compiled
+  for one chip of a described v5e."""
+  import jax
+  from jax.experimental import topologies
+  from jax.sharding import SingleDeviceSharding
+
+  from benchmark import traffic
+
   batch = traffic.make_batch(cell.traffic, spec.inputs, spec.n_numerical, 0,
-                             0, traffic.family_labels(family, cell.config))
+                             0, traffic.family_labels(cell.family(),
+                                                      cell.config))
   topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
   chip = SingleDeviceSharding(topo.devices[0])
   on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip)
-  prog = program.Program(parts, spec, 0, None)
   # the benchmark's own builder of the step, fed shapes on the described chip
   # where its window feeds arrays on a real one
   prog.put = lambda b: jax.tree_util.tree_map(
       on_chip, (b.numerical, b.cats, b.labels))
   return prog.compile_step(
       jax.tree_util.tree_map(on_chip, prog.state_avals()), batch)
+
+
+def compile_step(cell_name: str):
+  """The cell's training step compiled for one chip of a described v5e ->
+  the compiled executable."""
+  return compile_built(*build_program(cell_name))
 
 
 def main(argv=None):
