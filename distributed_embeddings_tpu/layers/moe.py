@@ -24,7 +24,13 @@ it to ``moe_share``'s sum (counted once when shares are added up).
 Dropless and without a capacity. The assignments are sorted by expert, held
 ones first, so the rows an expert computes are contiguous, and the grouped
 matmuls (``jax.lax.ragged_dot``; on a TPU XLA's own Mosaic grouped-matmul
-kernel, which skips rows that belong to no group) go over them. How many
+kernel, which skips rows that belong to no group) go over them. On a TPU at
+default precision they are handed what the MXU multiplies anyway
+(``layers/dense.py::grouped_mxu_dots``): the rows, ``silu(gate) * up`` and the
+cotangents each rounded to bfloat16 once, where they are made, and read by
+every product that wants them; float32 sums, float32 ``y``, ``dx`` and ``dw``
+from a written-out backward. Everywhere else the three ``lax.ragged_dot``
+stand as they were. How many
 assignments land here is data, not a shape: the expected count is
 ``tokens * top_k * held / num_experts``, the worst case ``num_experts / held``
 times that. The sorted stream's head, ``HEAD_LOADS`` times the expected
@@ -79,7 +85,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import pallas_moe_combine
 from ..telemetry import scopes
-from .dense import mxu_dot
+from .dense import grouped_mxu_dots, mxu_dot
 from .remat import MOE_ROUTE
 
 
@@ -352,9 +358,8 @@ def moe_share(h: jax.Array, w_router: jax.Array, w_gate: jax.Array,
 
     def experts_of(x, sizes, w_gate, w_up, w_down):
       with jax.named_scope(scopes.MOE_EXPERTS):
-        gate = lax.ragged_dot(x, w_gate, sizes)
-        up = lax.ragged_dot(x, w_up, sizes)
-        return lax.ragged_dot(jax.nn.silu(gate) * up, w_down, sizes)
+        gate, up = grouped_mxu_dots(x, (w_gate, w_up), sizes)
+        return grouped_mxu_dots(jax.nn.silu(gate) * up, (w_down,), sizes)[0]
 
     def rows_of(begin, size, whole, h, tok_c, p_c, w_gate, w_up, w_down):
       """-> (the weighted expert outputs ``[size, d]`` of the sorted stream's

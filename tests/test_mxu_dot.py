@@ -7,7 +7,11 @@ the policy's own answer here the function is `x @ w`, bit for bit, gradients
 too. Over the three models' toys with the operand type forced to bfloat16:
 every `dot_general` that takes a weight has bfloat16 operands and a float32
 result, the router's and the delta rule's keep float32 operands at `highest`,
-and no gradient left float32."""
+and no gradient left float32. The expert layer's grouped products
+(`grouped_mxu_dots`) the same way: against `lax.ragged_dot` and JAX's own
+`vjp` on operands rounded beforehand, `lax.ragged_dot` bit for bit on this
+backend, and over the three MoE toys twelve bfloat16-in float32-out grouped
+products a checkpointed layer with one rounded copy of the rows a pass."""
 
 import inspect
 
@@ -18,10 +22,12 @@ import pytest
 from jax import lax
 
 import test_laguna
+import test_lfm2_moe
 import test_olmo_hybrid
 import test_sdar_moe
 from distributed_embeddings_tpu.layers import dense
 from distributed_embeddings_tpu.models.laguna import Laguna
+from distributed_embeddings_tpu.models.lfm2_moe import Lfm2Moe
 from distributed_embeddings_tpu.models.olmo_hybrid import (
     OlmoHybrid,
     next_token_loss,
@@ -105,16 +111,41 @@ def _forced(dtype):
   return BF16 if dtype == F32 else dtype
 
 
-def _dots(jaxpr):
-  """Every `dot_general` equation of a jaxpr and of the jaxprs inside it."""
+def _jaxprs(jaxpr):
+  """A jaxpr and every jaxpr inside it."""
+  yield jaxpr
   for eqn in jaxpr.eqns:
-    if eqn.primitive.name == "dot_general":
-      yield eqn
     for value in eqn.params.values():
       for inner in value if isinstance(value, (tuple, list)) else (value,):
         inner = getattr(inner, "jaxpr", inner)
         if hasattr(inner, "eqns"):
-          yield from _dots(inner)
+          yield from _jaxprs(inner)
+
+
+def _eqns(jaxpr, name):
+  """Every equation of primitive ``name`` in a jaxpr and the jaxprs inside
+  it."""
+  return [eqn for inner in _jaxprs(jaxpr) for eqn in inner.eqns
+          if eqn.primitive.name == name]
+
+
+def _grad_jaxpr(monkeypatch, toy):
+  """-> (the jaxpr of ``value_and_grad`` of a toy's step over its parameters
+  and its rows with the operand type forced to a TPU's, the rows)."""
+  monkeypatch.setattr(dense, "mxu_operand_dtype", _forced)
+  model, params, rows, numerical, targets, loss = toy()
+
+  def step(p, r):
+    out = model.apply({"params": p}, numerical, None, emb_acts=[r])
+    return loss(out, {"targets": targets})
+
+  closed = jax.make_jaxpr(jax.value_and_grad(step, argnums=(0, 1)))(
+      params, rows)
+  # nothing that was float32 came out narrower: the loss, every leaf's
+  # gradient, the rows' gradient
+  assert all(v.aval.dtype == F32 for v in closed.jaxpr.outvars)
+  assert len(closed.jaxpr.outvars) == 2 + len(params)
+  return closed.jaxpr, rows
 
 
 def _sdar():
@@ -154,21 +185,9 @@ def _olmo():
     ids=["sdar_moe", "laguna", "olmo_hybrid"])
 def test_every_product_with_a_weight_is_handed_bfloat16(
     monkeypatch, toy, in_layers, end_a_layer):
-  monkeypatch.setattr(dense, "mxu_operand_dtype", _forced)
-  model, params, rows, numerical, targets, loss = toy()
-
-  def step(p, r):
-    out = model.apply({"params": p}, numerical, None, emb_acts=[r])
-    return loss(out, {"targets": targets})
-
-  closed = jax.make_jaxpr(jax.value_and_grad(step, argnums=(0, 1)))(
-      params, rows)
-  # nothing that was float32 came out narrower: the loss, every leaf's
-  # gradient, the rows' gradient
-  assert all(v.aval.dtype == F32 for v in closed.jaxpr.outvars)
-  assert len(closed.jaxpr.outvars) == 2 + len(params)
+  jaxpr, _ = _grad_jaxpr(monkeypatch, toy)
   rounded, highest = 0, 0
-  for eqn in _dots(closed.jaxpr):
+  for eqn in _eqns(jaxpr, "dot_general"):
     operands = {v.aval.dtype for v in eqn.invars}
     precision = eqn.params["precision"]
     precision = set(precision) if isinstance(precision, tuple) \
@@ -189,3 +208,109 @@ def test_every_product_with_a_weight_is_handed_bfloat16(
   assert rounded == 4 * in_layers - end_a_layer + 3
   # the router's logits (both MoE models), the delta rule's products
   assert highest > 0
+
+
+# --- the expert layer's grouped products (`dense.grouped_mxu_dots`) --------
+
+def _grouped_case(sizes, k=24, n=40, seed=0):
+  rng = np.random.default_rng(seed)
+  draw = lambda *shape: jnp.asarray(rng.normal(size=shape), F32)
+  m, g = int(np.sum(sizes)), len(sizes)
+  return (draw(m, k), (draw(g, k, n), draw(g, k, n)),
+          jnp.asarray(sizes, jnp.int32), (draw(m, n), draw(m, n)))
+
+
+@pytest.mark.parametrize("sizes", [
+    (10, 7, 23), (10, 0, 7, 23), (0, 5, 0, 3),
+    # as `moe.groups_of(..., whole=True)` pads: the zeros past the live rows
+    # in the last expert's group
+    (6, 2, 0, 8 + 48)],
+    ids=["ragged", "an_empty_group", "empty_first", "padded_as_whole_pads"])
+def test_the_grouped_products_are_those_of_the_rounded_operands(sizes):
+  x, ws, sizes, dys = _grouped_case(sizes)
+  if int(sizes[-1]) > 48:
+    x = x.at[-48:].set(0)       # the head's rows past the live count
+  ys, vjp = jax.vjp(
+      lambda x, ws: dense.grouped_dots_rounded(BF16, x, ws, sizes), x, ws)
+  dx, dws = vjp(dys)
+  # `lax.ragged_dot` and JAX's own transpose of it on the operands rounded
+  # beforehand, every product exact in float32
+  wide = lambda a: a.astype(BF16).astype(F32)
+  exact = lambda x, ws: tuple(
+      lax.ragged_dot(x, w, sizes, precision=HIGHEST) for w in ws)
+  want_ys, want_vjp = jax.vjp(exact, wide(x), tuple(map(wide, ws)))
+  want_dx, want_dws = want_vjp(tuple(map(wide, dys)))
+  got = {"y": ys, "dx": (dx,), "dw": dws}
+  want = {"y": want_ys, "dx": (want_dx,), "dw": want_dws}
+  for name in got:
+    assert len(got[name]) == len(want[name])
+    for a, b in zip(got[name], want[name]):
+      assert a.dtype == F32 and a.shape == b.shape, name
+      np.testing.assert_allclose(a, b, rtol=0, atol=2e-5, err_msg=name)
+  # an empty group's weights take no row: their gradient is exactly zero
+  for dw in dws:
+    np.testing.assert_array_equal(np.asarray(dw)[np.asarray(sizes) == 0], 0)
+  # and it is a rounding: the float32 products stand a bfloat16 ulp away
+  assert float(jnp.max(jnp.abs(ys[0] - exact(x, ws)[0]))) > 1e-3
+
+
+def test_on_this_backend_the_grouped_products_are_ragged_dot_bit_for_bit():
+  assert dense.mxu_operand_dtype(F32) == F32
+  x, ws, sizes, dys = _grouped_case((10, 0, 7, 23), seed=1)
+  plain = lambda x, ws: tuple(lax.ragged_dot(x, w, sizes) for w in ws)
+  got = jax.vjp(lambda x, ws: dense.grouped_mxu_dots(x, ws, sizes), x, ws)
+  want = jax.vjp(plain, x, ws)
+  for a, b in zip(jax.tree_util.tree_leaves((got[0], got[1](dys))),
+                  jax.tree_util.tree_leaves((want[0], want[1](dys)))):
+    np.testing.assert_array_equal(a, b)
+  text = str(jax.make_jaxpr(jax.grad(
+      lambda x, ws: sum(jnp.sum(y) for y in dense.grouped_mxu_dots(
+          x, ws, sizes)), (0, 1)))(x, ws))
+  assert "bf16" not in text and "custom_vjp" not in text
+  # operands of two dtypes: `lax.ragged_dot`'s own rules, on any backend
+  assert dense.grouped_mxu_dots(x.astype(BF16), ws, sizes)[0].dtype == F32
+
+
+def _lfm2():
+  cfg = test_lfm2_moe.TOY
+  rows, numerical, targets = test_lfm2_moe._batch(cfg)
+  return (Lfm2Moe(cfg), test_lfm2_moe._params(cfg), rows, numerical, targets,
+          next_token_loss)
+
+
+@pytest.mark.parametrize("toy,expert_layers", [
+    (_sdar, 2), (_laguna, 4), (_lfm2, 4)],
+    ids=["sdar_moe", "laguna", "lfm2_moe"])
+def test_every_grouped_product_of_the_head_is_handed_bfloat16(
+    monkeypatch, toy, expert_layers):
+  jaxpr, rows = _grad_jaxpr(monkeypatch, toy)
+  grouped = _eqns(jaxpr, "ragged_dot_general")
+  # a checkpointed expert layer: 3 forward, 3 rebuilt, 6 backward
+  assert len(grouped) == 12 * expert_layers
+  for eqn in grouped:
+    lhs, rhs, sizes = eqn.invars
+    assert lhs.aval.dtype == rhs.aval.dtype == BF16, eqn
+    assert sizes.aval.dtype == jnp.int32
+    assert eqn.params["preferred_element_type"] == F32
+    assert eqn.params["precision"] is None
+    assert eqn.outvars[0].aval.dtype == F32
+  # ONE rounded copy of the dispatched rows a pass. What is rounded at the
+  # head's length: `x [rows, d]` forward and rebuilt and the cotangent of `y`
+  # (each read by two to three products), `silu(gate) * up [rows, f]` forward
+  # and rebuilt and the cotangents of `gate` and `up`; a copy a product would
+  # be 5 and 4
+  head, d = grouped[0].invars[0].aval.shape[0], rows.shape[-1]
+  written = [eqn.invars[0].aval.shape
+             for eqn in _eqns(jaxpr, "convert_element_type")
+             if eqn.params["new_dtype"] == BF16
+             and eqn.invars[0].aval.shape[0] == head]
+  assert written.count((head, d)) == 3 * expert_layers
+  assert len(written) == 7 * expert_layers
+  # and none of them is rounded twice in one jaxpr, or from a narrower type
+  for inner in _jaxprs(jaxpr):
+    rounded = [eqn.invars[0] for eqn in inner.eqns
+               if eqn.primitive.name == "convert_element_type"
+               and eqn.params["new_dtype"] == BF16
+               and eqn.invars[0].aval.shape[0] == head]
+    assert all(v.aval.dtype == F32 for v in rounded)
+    assert len(rounded) == len(set(map(id, rounded)))

@@ -421,13 +421,19 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
   %de_moe_combine.2 = f32[1,1,8,128]{3,2,1,0} custom-call(%k, %x, %x), custom_call_target="tpu_custom_call"
   %ragged-dot-metadata.5 = (s32[17]{0}, s32[1]{0}) custom-call(%k), custom_call_target="tpu_custom_call"
   %ragged-dot-none.12 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
-  %ragged-dot-none.13 = f32[8,4]{1,0:T(8,128)} custom-call(%x, %x), custom_call_target="tpu_custom_call"
+  %x16 = bf16[8,4]{1,0:T(8,128)(2,1)} convert(%x)
+  %ragged-dot-none.13 = f32[8,4]{1,0:T(8,128)} custom-call(%k, %ragged-dot-metadata.5, %k, /*index=3*/%x16, %x16), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[8]{0}, s32[17]{0}, s32[8]{0}, bf16[8,4]{1,0}, bf16[8,4]{1,0}}
+  %ragged-dot-none.14 = f32[8,4]{1,0:T(8,128)} custom-call(%k, %x16, %x), custom_call_target="tpu_custom_call"
   %pred = pred[] constant(true)
   ROOT %conditional.1 = f32[8,4]{1,0} conditional(%pred, %ragged-dot-none.12, %x), true_computation=%branch_walk.3, false_computation=%branch_rest.4
 }
 """
   assert step_recompute.count_ops(text) == {
-      "splash_fwd": 2, "ragged_dot": 2, "ragged_dot_tail": 1, "sort": 5,
+      "splash_fwd": 2, "ragged_dot": 3, "ragged_dot_tail": 1, "sort": 5,
+      # those of each whose two matrices, the kernel's last operands, are
+      # bfloat16: one of the head's three (one float32, one mixed), not the
+      # tail's
+      "ragged_dot_bf16": 1, "ragged_dot_tail_bf16": 0,
       "route_sort": 2, "route_top_k": 2,
       # plain products by what they are handed, operands by name or with
       # their types beside them: two of bfloat16 alone, two with a float32

@@ -83,10 +83,11 @@ def timed(name, fn, args, iters, flops, **said):
         jax.block_until_ready(step(*args))
     ms, n = module_ms(tdir, name)
   tflops = flops / ms * 1e-9
-  print(json.dumps({**said, "device_ms": round(ms, 4), "calls": n,
-                    "tflops": round(tflops, 2),
-                    "peak_pct": round(100 * tflops / PEAK_TFLOPS, 1)}),
-        flush=True)
+  line = {**said, "device_ms": round(ms, 4), "calls": n,
+          "tflops": round(tflops, 2),
+          "peak_pct": round(100 * tflops / PEAK_TFLOPS, 1)}
+  print(json.dumps(line), flush=True)
+  return line
 
 
 def products(m, k, n, iters):

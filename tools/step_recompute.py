@@ -24,7 +24,10 @@ installed beside JAX) and prints, for one step, from the compiled HLO:
   ragged_dot   grouped matmuls of the expert layers' heads (a layer: 3
                forward, 3 rematerialised, 6 backward = 12); ragged_dot_tail:
                those inside a conditional (the tail's, walked only where a
-               router overflows the head)
+               router overflows the head); ragged_dot_bf16,
+               ragged_dot_tail_bf16: those of each that are handed bfloat16
+               rows and weights (all of them on a TPU at default precision
+               since PR 45, `layers/dense.py::grouped_mxu_dots`; 0 before)
   route_sort   the expert layers' stable argsort of the assignments: once a
                layer where the sorted order is kept; route_top_k: the sorts
                the router's `top_k` compiles to (forward and rematerialised);
@@ -151,7 +154,9 @@ def count_ops(hlo_text: str):
   its kernel: a splash forward kernel is a custom call named
   ``splash_*fwd*``, a grouped matmul one named ``ragged-dot-*`` (its
   ``ragged-dot-metadata`` calls, a few hundred bytes each, are not counted;
-  ``ragged_dot_tail``: those inside a conditional), a kernel of
+  ``ragged_dot_tail``: those inside a conditional; ``ragged_dot_bf16``,
+  ``ragged_dot_tail_bf16``: those of each whose two matrices, its last
+  operands, are bfloat16), a kernel of
   ``ops/pallas_sparse_attn.py`` one named ``de_sparse_attn_<which>``, the
   expert layer's combine ``de_moe_combine``. A sort is told by the
   ``op_name`` the program gave it: ``route_sort`` is the expert layer's stable
@@ -162,8 +167,9 @@ def count_ops(hlo_text: str):
   comps = _computations(hlo_text)
   tail = _under_conditionals(comps)
   counts = dict.fromkeys(("splash_fwd", "ragged_dot", "ragged_dot_tail",
-                          "sort", "route_sort", "route_top_k",
-                          "dense_dot_f32", "dense_dot_bf16", "moe_combine",
+                          "ragged_dot_bf16", "ragged_dot_tail_bf16", "sort",
+                          "route_sort", "route_top_k", "dense_dot_f32",
+                          "dense_dot_bf16", "moe_combine",
                           *(f"sparse_attn_{which}" for which in
                             ("fwd", "mean", "dq", "dkv"))), 0)
   for comp, lines in comps.items():
@@ -192,7 +198,15 @@ def count_ops(hlo_text: str):
       elif opcode == "ragged-dot" or (
           opcode == "custom-call"
           and re.match(r"ragged-dot-(?!metadata)", name)):
-        counts["ragged_dot_tail" if comp in tail else "ragged_dot"] += 1
+        which = "ragged_dot_tail" if comp in tail else "ragged_dot"
+        counts[which] += 1
+        # the kernel takes the group sizes and its tables first and the two
+        # matrices last; the plain instruction the matrices first
+        operands = _operands(line, m.end())
+        matrices = operands[:2] if opcode == "ragged-dot" else operands[-2:]
+        if {element.get(re.sub(r"^/\*.*?\*/", "", o).lstrip("%"))
+            for o in matrices} == {"bf16"}:
+          counts[which + "_bf16"] += 1
       elif opcode == "custom-call" and re.match(r"splash_\w*fwd", name):
         counts["splash_fwd"] += 1
       elif opcode == "custom-call" and (
