@@ -122,9 +122,12 @@ class JaxprSummary:
   convert_pairs: List[Tuple[str, str]] = field(default_factory=list)
 
 
+# `psum_invariant`: what jax 0.9.0 traces a `psum` (and so a `pmean`) as in a
+# `shard_map` body; `all_gather_invariant` is the gather of that family
 _COLLECTIVES = frozenset({
-    "psum", "psum2", "pmin", "pmax", "pmean", "all_to_all", "all_gather",
-    "ppermute", "pbroadcast", "reduce_scatter", "axis_index",
+    "psum", "psum2", "psum_invariant", "pmin", "pmax", "pmean", "all_to_all",
+    "all_gather", "all_gather_invariant", "ppermute", "pbroadcast",
+    "reduce_scatter", "axis_index",
 })
 
 

@@ -53,14 +53,6 @@ from ..telemetry import scopes
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def segment_ids(starts):
-  """``starts [B, L]`` bool, true at a document's first token (position 0
-  always is one) -> the document's number at every position, ``[B, L]``
-  int32, from 0."""
-  starts = starts.at[:, 0].set(True)
-  return jnp.cumsum(starts.astype(jnp.int32), axis=1) - 1
-
-
 def causal_conv(x, w, seg):
   """Depthwise causal convolution over time: ``x [B, L, C]``, ``w [K, C]``
   (``w[K-1]`` multiplies the token itself), ``y_t = sum_j w_j x_{t-(K-1)+j}``.

@@ -686,7 +686,7 @@ class DistEmbeddingStrategy:
     # hold (2^31 elements — XLA's 32-bit buffer indexing) splits into
     # generations, each a separate buffer with its own gather and backward
     # scatter. Two measured facts drive the assignment
-    # (tools/profile_scatter_regimes.py, docs/BENCHMARKS.md):
+    # (docs/BENCHMARKS.md):
     #
     # 1. XLA's scatter-add has two regimes: a fast path at ~16-25 ns/row
     #    it only picks when the id stream is a large enough fraction of

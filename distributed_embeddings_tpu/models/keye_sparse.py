@@ -4,10 +4,9 @@ decoder whose every attention layer chooses its keys by a learned indexer
 training path over sequences of packed documents.
 
 Per layer, pre-norm, on a residual stream ``x [B, L, d]`` with ``seg`` the
-document of each position (positions count from the sequence's start, as
-:mod:`.laguna` numbers them):
+document of each position (positions count from the sequence's start):
 
-*Attention* is :mod:`.sdar_moe`'s own lines: ``h = RMSNorm(x)``;
+*Attention*, the Qwen3-MoE block's: ``h = RMSNorm(x)``;
 ``q, k, v = h Wq, h Wk, h Wv`` without bias; RMSNorm over the head dimension
 on ``q`` and on ``k``; RoPE (rotate-half, every dimension of a head) on both;
 grouped queries at scale ``head_dim ** -0.5``. The published
@@ -37,10 +36,8 @@ language-model loss alone (a selection has no gradient). One
 ``value_and_grad`` serves both owners.
 
 The vision tower is not built: the published language-model config sizes
-none, and a text-only batch never reaches it. Packed documents and the
-sequence input are :mod:`.olmo_hybrid`'s: the batch's numerical features are
-``L`` uniforms a sequence, position ``i > 0`` starts a document where
-``u_i < 1 / mean_document_length``; ``emb_acts`` is ``[rows [B, L, d]]``.
+none, and a text-only batch never reaches it. Packed documents are
+:mod:`..layers.decoder`'s; ``emb_acts`` is ``[rows [B, L, d]]``.
 
 The plain products are :func:`..layers.dense.mxu_dot` (on a TPU handed
 bfloat16 operands, float32 out of both passes), the indexer's projections
@@ -58,13 +55,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..layers.attention import rope, rope_frequencies
+from ..layers.decoder import document_segments, next_token_loss, rms_norm
 from ..layers.dense import mxu_dot
 from ..layers.moe import MoEShare, moe_share
 from ..layers.remat import checkpoint_layer
 from ..layers.sparse_index import sparse_attention
 from ..telemetry import scopes
-from .olmo_hybrid import document_segments, next_token_loss
-from .sdar_moe import rms_norm, rope, rope_frequencies
 
 
 @dataclasses.dataclass(frozen=True)

@@ -17,11 +17,10 @@ normalised input ``h``: ``q = rope_l(h Wq)``, ``k = rope_l(h Wk)``,
 ``Wg`` and ``Wo`` take their shape from the layer. ``mask_l`` is causal and
 inside a document; on a ``sliding_attention`` layer also
 ``i - j < sliding_window`` (a query sees itself and the ``sliding_window - 1``
-tokens before it). On a TPU that is JAX's splash-attention kernel, one
-multi-query call a key-value head under a causal or a local mask with the
-documents as segment ids: blocks the window empties are skipped, not masked.
-Without a TPU the model raises, and ``attention="xla"`` names the other path
-for tests on any backend.
+tokens before it). That is :mod:`..layers.attention`'s pair under ``Causal()``
+or ``Window(sliding_window)``, the documents as segment ids: the kernel skips
+the blocks the window empties, it does not mask them. ``attention="xla"``
+names the path that runs without a TPU (tests).
 
 *Two rotary tables* (:func:`rotary_table`, from ``rope_parameters`` by kind
 of layer): ``default`` rotates ``partial_rotary_factor * head_dim`` leading
@@ -39,12 +38,8 @@ expert a SwiGLU (:mod:`..layers.moe`: :func:`moe_share` computes the routed
 experts this chip holds, :func:`shared_expert` the one every chip computes
 for its own tokens).
 
-*Packed documents*, the loss (:func:`.olmo_hybrid.next_token_loss`) and the
-sequence input are :mod:`.olmo_hybrid`'s: the batch's numerical features are
-``L`` uniforms a sequence, position ``i > 0`` starts a document where
-``u_i < 1 / mean_document_length``; next-token cross-entropy over the
-positions whose next token belongs to the same document; ``emb_acts`` is
-``[rows [B, L, d]]``.
+*Packed documents* and the loss (:func:`..layers.decoder.next_token_loss`)
+are :mod:`..layers.decoder`'s; ``emb_acts`` is ``[rows [B, L, d]]``.
 
 The projections', the dense MLP's and the head's products are
 :func:`..layers.dense.mxu_dot`: on a TPU handed bfloat16 operands, float32
@@ -56,26 +51,27 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Mapping, Optional, Tuple
+from typing import Any, Dict, Mapping, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..layers.dense import mxu_dot
-from ..layers.moe import MoEShare, Router, moe_share, shared_expert
-from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
-from ..telemetry import scopes
-from .olmo_hybrid import document_segments
-from .sdar_moe import (
-    ATTENTION_BLOCK,
+from ..layers.attention import (
+    Causal,
+    Window,
     attention_path,
-    rms_norm,
+    attention_splash,
+    attention_xla,
     rope,
     rope_frequencies,
-    splash_block_sizes,
 )
+from ..layers.decoder import document_segments, rms_norm
+from ..layers.dense import mxu_dot
+from ..layers.moe import MoEShare, Router, moe_share, shared_expert
+from ..layers.remat import checkpoint_layer
+from ..telemetry import scopes
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -190,65 +186,6 @@ def rotary_table(cfg: LagunaConfig, kind: str):
   return inv_freq.astype(np.float32), float(scale)
 
 
-def attention_xla(q, k, v, seg, tile: int, window: Optional[int]):
-  """``q [B, L, Hkv, G, hd]`` (already scaled), ``k, v [B, L, Hkv, hd]``,
-  ``seg [B, L]`` -> ``[B, L, Hkv, G, hd]``: a tile of queries at a time
-  against the keys its mask can reach (up to the tile's end; from
-  ``window - 1`` before its start where there is a window), causal, inside
-  the query's document and, with a window, ``i - j < window``."""
-  length = q.shape[1]
-  tile = min(tile, length)
-  out = []
-  for a in range(0, length, tile):
-    e = min(a + tile, length)
-    first = 0 if window is None else max(0, a - window + 1)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", q[:, a:e], k[:, first:e])
-    s = s.astype(jnp.promote_types(s.dtype, jnp.float32))
-    back = np.arange(a, e)[:, None] - np.arange(first, e)[None, :]
-    near = (back >= 0) if window is None else (back >= 0) & (back < window)
-    allowed = near & (seg[:, a:e, None] == seg[:, None, first:e])
-    s = jnp.where(allowed[:, None, None], s, -jnp.inf)
-    prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    out.append(jnp.einsum("bkgqs,bskd->bqkgd", prob, v[:, first:e]))
-  return jnp.concatenate(out, axis=1)
-
-
-@functools.lru_cache(maxsize=None)
-def _splash_kernel(seq_len: int, group: int, window: Optional[int],
-                   block: int, interpret: bool):
-  from jax.experimental.pallas.ops.tpu import splash_attention as sa
-  shape = (seq_len, seq_len)
-  mask = sa.CausalMask(shape) if window is None \
-      else sa.LocalMask(shape, (window - 1, 0), 0)
-  # host arrays, constants of whatever program calls it (as in sdar_moe)
-  with jax.ensure_compile_time_eval():
-    kernel = sa.make_splash_mqa_single_device(
-        sa.MultiHeadMask([mask] * group),
-        block_sizes=splash_block_sizes(min(block, seq_len)),
-        residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
-  return jax.tree_util.tree_map(np.asarray, kernel)
-
-
-def attention_splash(q, k, v, seg, block: int, window: Optional[int],
-                     interpret: bool = False):
-  """Same contract as :func:`attention_xla`, through the splash-attention
-  kernel: one multi-query call per (sample, key-value head) under a causal
-  or a local mask, the documents as segment ids. Its operands are rounded to
-  bfloat16 (what the MXU's default precision makes of a float32 operand);
-  scores, softmax and accumulation are float32. Its output and log-sum-exp
-  are kept for the backward under the name ``SPLASH_RESIDUALS``, as in
-  ``sdar_moe``."""
-  from jax.experimental.pallas.ops.tpu import splash_attention as sa
-  kernel = _splash_kernel(q.shape[1], q.shape[3], window, block, interpret)
-  qh = jnp.transpose(q, (0, 2, 3, 1, 4)).astype(jnp.bfloat16)  # [B,Hkv,G,L,hd]
-  kh = jnp.transpose(k, (0, 2, 1, 3)).astype(jnp.bfloat16)     # [B,Hkv,L,hd]
-  vh = jnp.transpose(v, (0, 2, 1, 3)).astype(jnp.bfloat16)
-  one = lambda q, k, v, s: kernel(q, k, v,
-                                  segment_ids=sa.SegmentIds(q=s, kv=s))
-  out = jax.vmap(jax.vmap(one, in_axes=(0, 0, 0, None)))(qh, kh, vh, seg)
-  return jnp.transpose(out, (0, 3, 1, 2, 4)).astype(q.dtype)
-
-
 def attention_mixer(cfg: LagunaConfig, kind: str, p, h, seg):
   """One layer's attention on its normalised input ``h [B, L, d]`` ->
   ``[B, L, d]``; the head count is ``Wq``'s."""
@@ -269,11 +206,10 @@ def attention_mixer(cfg: LagunaConfig, kind: str, p, h, seg):
   with jax.named_scope(scopes.ATTN_QK):
     k = rope(k, positions, inv_freq, factor)
   v = proj(h, "wv").reshape(b, length, hkv, hd)
-  window = cfg.sliding_window if kind == SLIDING else None
+  mask = Window(cfg.sliding_window) if kind == SLIDING else Causal()
   attend = attention_path(cfg.attention, attention_xla, attention_splash)
   with jax.named_scope(scopes.ATTN_CORE):
-    a = attend(q.reshape(b, length, hkv, group, hd), k, v, seg,
-               ATTENTION_BLOCK, window)
+    a = attend(q.reshape(b, length, hkv, group, hd), k, v, mask, seg)
   gate = jax.nn.sigmoid(proj(h, "wg"))
   return proj(gate * a.reshape(b, length, hkv * group * hd), "wo")
 

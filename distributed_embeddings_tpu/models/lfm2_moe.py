@@ -20,10 +20,9 @@ bias; RMSNorm over the head dimension on ``q`` and on ``k``; RoPE
 (rotate-half over the whole head at ``rope_theta``, positions from the
 sequence's start) on both; ``softmax(q k^T / sqrt(head_dim))`` causal and
 inside a document with ``num_attention_heads / num_key_value_heads`` query
-heads a key-value head; ``Wo``. The attention proper is :mod:`.laguna`'s
-pair: on a TPU JAX's splash-attention kernel, one multi-query call a
-key-value head under a causal mask with the documents as segment ids;
-``attention="xla"`` names the other path for tests on any backend.
+heads a key-value head; ``Wo``. The attention proper is
+:mod:`..layers.attention`'s pair under ``Causal()``, the documents as segment
+ids; ``attention="xla"`` names the path that runs without a TPU (tests).
 
 *Dense MLP* (layers below ``num_dense_layers``):
 ``(SiLU(h W1) * (h W3)) W2`` at ``intermediate_size``.
@@ -41,10 +40,8 @@ Which of the published layers run here is ``layers_here`` (their numbers in
 the published model: a layer's kinds follow from its number); the leaves of
 the ``i``-th of them are named ``layer_<i>_*``.
 
-*Packed documents*, the loss (:func:`.olmo_hybrid.next_token_loss`) and the
-sequence input are :mod:`.olmo_hybrid`'s: the batch's numerical features are
-``L`` uniforms a sequence, position ``i > 0`` starts a document where
-``u_i < 1 / mean_document_length``; ``emb_acts`` is ``[rows [B, L, d]]``.
+*Packed documents* and the loss (:func:`..layers.decoder.next_token_loss`)
+are :mod:`..layers.decoder`'s; ``emb_acts`` is ``[rows [B, L, d]]``.
 
 The plain products are :func:`..layers.dense.mxu_dot`: on a TPU handed
 bfloat16 operands, float32 out of both passes; the router's is float32 at
@@ -61,20 +58,20 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..layers.attention import (
+    Causal,
+    attention_path,
+    attention_splash,
+    attention_xla,
+    rope,
+    rope_frequencies,
+)
+from ..layers.decoder import document_segments, rms_norm
 from ..layers.dense import mxu_dot
 from ..layers.moe import MoEShare, Router, moe_share
 from ..layers.remat import checkpoint_layer
 from ..layers.short_conv import short_conv_mixer
 from ..telemetry import scopes
-from .laguna import attention_splash, attention_xla
-from .olmo_hybrid import document_segments
-from .sdar_moe import (
-    ATTENTION_BLOCK,
-    attention_path,
-    rms_norm,
-    rope,
-    rope_frequencies,
-)
 
 CONV, FULL = "conv", "full_attention"
 DENSE, EXPERTS = "dense", "experts"
@@ -160,8 +157,7 @@ def attention_mixer(cfg: Lfm2MoeConfig, p, h, seg):
   v = proj(h, "wv").reshape(b, length, hkv, hd)
   attend = attention_path(cfg.attention, attention_xla, attention_splash)
   with jax.named_scope(scopes.ATTN_CORE):
-    a = attend(q.reshape(b, length, hkv, hq // hkv, hd), k, v, seg,
-               ATTENTION_BLOCK, None)
+    a = attend(q.reshape(b, length, hkv, hq // hkv, hd), k, v, Causal(), seg)
   return proj(a.reshape(b, length, hq * hd), "wo")
 
 

@@ -20,9 +20,9 @@ output goes through ``RMSNorm_{d_v}(o) * SiLU(z)`` and ``Wo``.
 ``q`` and on ``k`` across all the channels held, no rotary embedding (the
 published ``rope_theta`` is ``null``: position comes from the recurrent
 layers), causal attention at scale ``head_dim^-1/2`` inside a document, ``Wo``.
-On a TPU that is JAX's splash-attention kernel under a causal mask with the
-documents as segment ids; without a TPU the model raises, and
-``attention="xla"`` names the other path for tests on any backend.
+That is :mod:`..layers.attention`'s pair under ``Causal()`` in its
+``[B, L, H, hd]`` layout, the documents as segment ids; ``attention="xla"``
+names the path that runs without a TPU (tests).
 
 *MLP*: ``(SiLU(u Wgate) * (u Wup)) Wdown``.
 
@@ -31,17 +31,14 @@ published heads and compute ``Wo`` over those alone (one chip of a
 tensor-parallel group, without its all-reduce): the partial sum goes on. The
 q/k norm of the full-attention mixer is taken across the channels held.
 
-*Packed documents.* The batch's numerical features are ``L`` uniforms in
-[0, 1) a sequence: position 0 starts a document, and position ``i > 0`` starts
-one where ``u_i < 1 / mean_document_length``. A document's first token resets
-the rule's state and the convolution's window, and attention stays inside a
-document. The loss is next-token cross-entropy, mean over the positions whose
-next token belongs to the same document (:func:`next_token_loss`; the
-targets are the ids shifted by one).
+*Packed documents* (:mod:`..layers.decoder`: where they start is the batch's
+numerical features). A document's first token resets the rule's state and the
+convolution's window, and attention stays inside a document. The loss is
+:func:`..layers.decoder.next_token_loss`, imported here as this model's own.
 
 On the sparse train step the token table is a sequence input
 (``TableConfig(combiner=None)`` read at hotness ``L``): ``emb_acts`` is
-``[rows [B, L, d]]``, as in :mod:`.sdar_moe`.
+``[rows [B, L, d]]``.
 
 Both mixers' projections, the MLP's and the head's products are
 :func:`..layers.dense.mxu_dot`: on a TPU handed bfloat16 operands, float32
@@ -57,22 +54,20 @@ from typing import Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from ..layers.dense import mxu_dot
-from ..layers.gated_delta import (
-    causal_conv,
-    chunk_gated_delta_rule,
-    segment_ids,
-)
-from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
-from ..telemetry import scopes
-from .sdar_moe import (
-    ATTENTION_BLOCK,
+from ..layers.attention import (
+    Causal,
     attention_path,
-    rms_norm,
-    splash_block_sizes,
+    attention_splash,
+    attention_xla,
 )
+# `next_token_loss` is this model's loss: the benchmark's families make their
+# step with `models.olmo_hybrid.next_token_loss`
+from ..layers.decoder import document_segments, next_token_loss, rms_norm
+from ..layers.dense import mxu_dot
+from ..layers.gated_delta import causal_conv, chunk_gated_delta_rule
+from ..layers.remat import checkpoint_layer
+from ..telemetry import scopes
 
 LINEAR, FULL = "linear_attention", "full_attention"
 
@@ -109,12 +104,6 @@ class OlmoHybridConfig:
                        f"{LINEAR} or {FULL}")
 
 
-def document_segments(numerical, mean_document_length: int):
-  """The batch's numerical features ``[B, L]`` -> the document's number at
-  every position, ``[B, L]`` int32."""
-  return segment_ids(numerical < 1.0 / mean_document_length)
-
-
 def l2_norm(x, eps: float = 1e-6):
   return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
                            + eps)
@@ -149,53 +138,6 @@ def linear_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
   return proj(o.reshape(b, length, h * dv), "wo")
 
 
-def attention_xla(q, k, v, seg, tile: int):
-  """``q [B, L, H, hd]`` (already scaled), ``k, v`` alike, ``seg [B, L]`` ->
-  ``[B, L, H, hd]``: a tile of queries at a time against the keys up to its
-  end, causal and inside the query's document."""
-  length = q.shape[1]
-  tile = min(tile, length)
-  out = []
-  for a in range(0, length, tile):
-    e = min(a + tile, length)
-    s = jnp.einsum("bqhd,bshd->bhqs", q[:, a:e], k[:, :e])
-    s = s.astype(jnp.promote_types(s.dtype, jnp.float32))
-    allowed = (np.arange(e)[None, :] <= np.arange(a, e)[:, None]) \
-        & (seg[:, a:e, None] == seg[:, None, :e])
-    s = jnp.where(allowed[:, None], s, -jnp.inf)
-    prob = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    out.append(jnp.einsum("bhqs,bshd->bqhd", prob, v[:, :e]))
-  return jnp.concatenate(out, axis=1)
-
-
-@functools.lru_cache(maxsize=None)
-def _splash_kernel(seq_len: int, heads: int, block: int, interpret: bool):
-  from jax.experimental.pallas.ops.tpu import splash_attention as sa
-  # host arrays, constants of whatever program calls it (as in sdar_moe)
-  with jax.ensure_compile_time_eval():
-    kernel = sa.make_splash_mha_single_device(
-        sa.MultiHeadMask([sa.CausalMask((seq_len, seq_len))] * heads),
-        block_sizes=splash_block_sizes(min(block, seq_len)),
-        residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
-  return jax.tree_util.tree_map(np.asarray, kernel)
-
-
-def attention_splash(q, k, v, seg, block: int, interpret: bool = False):
-  """Same contract as :func:`attention_xla`, through the splash-attention
-  kernel: one multi-head call a sequence, the documents as segment ids. Its
-  operands are rounded to bfloat16 (what the MXU's default precision makes
-  of a float32 operand); scores, softmax and accumulation are float32. Its
-  output and log-sum-exp are kept for the backward under the name
-  ``SPLASH_RESIDUALS``, as in ``sdar_moe``."""
-  from jax.experimental.pallas.ops.tpu import splash_attention as sa
-  kernel = _splash_kernel(q.shape[1], q.shape[2], block, interpret)
-  heads_first = lambda x: jnp.swapaxes(x, 1, 2).astype(jnp.bfloat16)
-  out = jax.vmap(lambda q, k, v, s: kernel(
-      q, k, v, segment_ids=sa.SegmentIds(q=s, kv=s)))(
-          heads_first(q), heads_first(k), heads_first(v), seg)
-  return jnp.swapaxes(out, 1, 2).astype(q.dtype)
-
-
 def full_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
   """The heads held here of one full-attention mixer -> their part of
   ``o Wo``, ``[B, L, d]``."""
@@ -216,7 +158,7 @@ def full_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):
   attend = attention_path(cfg.attention, attention_xla, attention_splash)
   q, k, v = heads(q), heads(k), heads(proj(u, "wv"))
   with jax.named_scope(scopes.ATTN_CORE):
-    o = attend(q, k, v, seg, ATTENTION_BLOCK)
+    o = attend(q, k, v, Causal(), seg)
   return proj(o.reshape(b, length, h * hd), "wo")
 
 
@@ -304,15 +246,3 @@ class OlmoHybrid(nn.Module):
       logits = mxu_dot(rms_norm(x, final_norm, cfg.rms_norm_eps), head)
     same = jnp.pad(seg[:, 1:] == seg[:, :-1], ((0, 0), (0, 1)))
     return {"logits": logits, "weight": same.astype(logits.dtype)}
-
-
-def next_token_loss(outputs, labels):
-  """Mean over the positions that are not a document's last of
-  ``CE(logits_t, targets_t)``: ``outputs`` as :class:`OlmoHybrid` returns
-  them, ``labels["targets"] [B, L]`` the ids shifted by one."""
-  logits = outputs["logits"]
-  logits = logits.astype(jnp.promote_types(logits.dtype, jnp.float32))
-  logp = jax.nn.log_softmax(logits, axis=-1)
-  nll = -jnp.take_along_axis(logp, labels["targets"][..., None], -1)[..., 0]
-  weight = outputs["weight"]
-  return jnp.sum(weight * nll) / jnp.maximum(jnp.sum(weight), 1.0)

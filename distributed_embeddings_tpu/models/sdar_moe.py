@@ -15,12 +15,9 @@ blocks of ``block_length``. Each block draws a noise level ``t`` and each of
 its positions is masked with probability ``t``; the noisy copy ``xt`` holds the
 mask token's embedding at masked positions and ``x0``'s elsewhere. The model
 sees ``[xt ; x0]``, ``S = 2 L`` positions, both halves numbered ``0 .. L-1``
-for RoPE. With ``b(i) = i // block_length``: a noisy query sees the noisy
-keys of its own block and the clean keys of earlier blocks; a clean query sees
-the clean keys of its own and earlier blocks; nothing else
-(:func:`block_diffusion_mask`). Logits are taken at the noisy half and the
-loss is ``sum over masked positions of CE / t``, over ``B L``
-(:func:`block_diffusion_loss`).
+for RoPE; who sees whom is :class:`..layers.attention.BlockDiffusion`. Logits
+are taken at the noisy half and the loss is ``sum over masked positions of
+CE / t``, over ``B L`` (:func:`block_diffusion_loss`).
 
 On the sparse train step the token table is a sequence input
 (``TableConfig(combiner=None)`` read at hotness ``L``): ``emb_acts`` is
@@ -30,12 +27,11 @@ per block (``t = t_min + (1 - t_min) u``). The mask token's embedding is a
 dense leaf of its own (published: a row of ``embed_tokens``; the same
 mathematics), because the noisy copy is chosen, not looked up.
 
-Attention never holds ``[S, S]`` scores. It is JAX's splash-attention kernel
-under the static mask (blocks of keys that the mask empties, about 3/4 of
-them, are skipped by the kernel's own block map): a TPU kernel, and without a
-TPU the model raises rather than compute something else. ``attention="xla"``
-names the other path, for tests and counting tools on any backend: one tile
-of queries at a time against exactly the keys its blocks can see, in XLA.
+Attention never holds ``[S, S]`` scores: it is :mod:`..layers.attention`'s
+pair under that static mask, no segment ids (the kernel's block map skips the
+blocks of keys the mask empties, about 3/4 of them). Without a TPU the model
+raises rather than compute something else; ``attention="xla"`` names the
+other path, for tests and counting tools on any backend.
 
 The projections' and the head's products are :func:`..layers.dense.mxu_dot`:
 on a TPU handed bfloat16 operands, float32 out of both passes.
@@ -50,11 +46,19 @@ from typing import Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from ..layers.attention import (
+    BlockDiffusion,
+    attention_path,
+    attention_splash,
+    attention_xla,
+    rope,
+    rope_frequencies,
+)
+from ..layers.decoder import rms_norm
 from ..layers.dense import mxu_dot
 from ..layers.moe import MoEShare, moe_share
-from ..layers.remat import SPLASH_RESIDUALS, checkpoint_layer
+from ..layers.remat import checkpoint_layer
 from ..telemetry import scopes
 
 
@@ -90,41 +94,6 @@ class SDARMoEConfig:
                     tuple(self.experts_held))
 
 
-def rms_norm(x, gain, eps):
-  """In float32 at least (a float64 test stays float64)."""
-  dt = jnp.promote_types(x.dtype, jnp.float32)
-  wide = x.astype(dt)
-  var = jnp.mean(jnp.square(wide), axis=-1, keepdims=True)
-  return (wide * jax.lax.rsqrt(var + eps) * gain.astype(dt)).astype(x.dtype)
-
-
-def rope_frequencies(theta: float, rotary_dim: int) -> np.ndarray:
-  """``theta ** (-2 i / rotary_dim)``, ``i < rotary_dim / 2``: the inverse
-  frequencies of plain RoPE over ``rotary_dim`` dimensions, float32."""
-  half = rotary_dim // 2
-  return 1.0 / (theta ** (np.arange(half, dtype=np.float32) / half))
-
-
-def rope(x, positions, inv_freq, attention_factor: float = 1.0):
-  """``x [..., S, heads, head_dim]``, rotate-half over the first
-  ``2 len(inv_freq)`` dimensions of a head (the rotated width; the rest pass
-  as they are), angles in float32; cos and sin times ``attention_factor``
-  where a scaled table (YaRN) has one."""
-  half = len(inv_freq)
-  ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # [S, half]
-  cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
-  sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
-  if attention_factor != 1.0:
-    cos, sin = cos * attention_factor, sin * attention_factor
-  whole = 2 * half == x.shape[-1]
-  turned = x if whole else x[..., :2 * half]
-  rotated = jnp.concatenate([-turned[..., half:], turned[..., :half]],
-                            axis=-1)
-  turned = turned * cos + rotated * sin
-  return turned if whole \
-      else jnp.concatenate([turned, x[..., 2 * half:]], axis=-1)
-
-
 def noise_of(numerical, seq_len: int, block_length: int, t_min: float):
   """The batch's numerical features -> (masked ``[B, L]`` bool, the loss's
   weight ``[B, L]``: ``1 / t`` at masked positions, 0 elsewhere)."""
@@ -133,115 +102,6 @@ def noise_of(numerical, seq_len: int, block_length: int, t_min: float):
   t = jnp.repeat(t, block_length, axis=1)                # [B, L]
   masked = u < t
   return masked, jnp.where(masked, 1.0 / t, 0.0).astype(jnp.float32)
-
-
-def block_diffusion_mask(seq_len: int, block_length: int) -> np.ndarray:
-  """``[2 L, 2 L]`` bool, query x key, over ``[xt ; x0]``."""
-  blk = np.arange(seq_len) // block_length
-  same, earlier = blk[:, None] == blk[None, :], blk[None, :] < blk[:, None]
-  none = np.zeros_like(same)
-  return np.block([[same, earlier], [none, same | earlier]])
-
-
-def attention_xla(q, k, v, seq_len: int, block_length: int, tile: int):
-  """``q [B, S, Hkv, G, hd]`` (already scaled), ``k, v [B, S, Hkv, hd]`` ->
-  ``[B, S, Hkv, G, hd]``. One tile of queries at a time; a tile of the noisy
-  half sees its own noisy keys and the clean keys up to its end, a tile of
-  the clean half the clean keys up to its end: nothing beyond is computed."""
-  length = seq_len
-  tile = min(tile, length)
-  if length % tile or tile % block_length:
-    raise ValueError(f"seq_len {length}, tile {tile}, block {block_length}")
-  blk = np.arange(length) // block_length
-
-  def attend(qt, kt, vt, allowed):
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qt, kt).astype(jnp.float32)
-    s = jnp.where(jnp.asarray(allowed)[None, None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1).astype(vt.dtype)
-    return jnp.einsum("bkgqs,bskd->bqkgd", p, vt)
-
-  noisy, clean = [], []
-  for a in range(0, length, tile):
-    b = a + tile
-    k_clean, v_clean = k[:, length:length + b], v[:, length:length + b]
-    earlier = blk[None, :b] < blk[a:b, None]
-    same = blk[None, a:b] == blk[a:b, None]
-    noisy.append(attend(
-        q[:, a:b], jnp.concatenate([k[:, a:b], k_clean], axis=1),
-        jnp.concatenate([v[:, a:b], v_clean], axis=1),
-        np.concatenate([same, earlier], axis=1)))
-    clean.append(attend(q[:, length + a:length + b], k_clean, v_clean,
-                        blk[None, :b] <= blk[a:b, None]))
-  return jnp.concatenate(noisy + clean, axis=1)
-
-
-# Queries and keys a block of the splash kernel, and queries a tile of the XLA
-# path: 256 cost a quarter more time, 1024 no less (and its fused backward
-# does not fit VMEM); the kernel's fused backward (dq inside dkv) was no
-# faster (my chip runs, PR 29)
-ATTENTION_BLOCK = 512
-
-
-def splash_block_sizes(block: int):
-  """One block size for queries and keys, forward and both backward kernels
-  (no fused backward: see ``ATTENTION_BLOCK``)."""
-  from jax.experimental.pallas.ops.tpu import splash_attention as sa
-  return sa.BlockSizes(
-      block_q=block, block_kv=block, block_kv_compute=block,
-      block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-      block_q_dq=block, block_kv_dq=block)
-
-
-@functools.lru_cache(maxsize=None)
-def _splash_kernel(seq_len: int, block_length: int, group: int, block: int,
-                   interpret: bool):
-  from jax.experimental.pallas.ops.tpu import splash_attention as sa
-  mask = sa.NumpyMask(block_diffusion_mask(seq_len, block_length))
-  sizes = splash_block_sizes(min(block, 2 * seq_len))
-  # the kernel's block maps as host arrays, so that they are constants of
-  # whatever program calls it. The factory makes ``jnp`` arrays of them: in
-  # the middle of a trace (where this is first called) those would be that
-  # trace's tracers, kept here for the next one
-  with jax.ensure_compile_time_eval():
-    kernel = sa.make_splash_mqa_single_device(
-        sa.MultiHeadMask([mask] * group), block_sizes=sizes,
-        residual_checkpoint_name=SPLASH_RESIDUALS, interpret=interpret)
-  return jax.tree_util.tree_map(np.asarray, kernel)
-
-
-def attention_splash(q, k, v, seq_len: int, block_length: int, block: int,
-                     interpret: bool = False):
-  """Same contract as :func:`attention_xla`, through the splash-attention
-  kernel: one multi-query call per (sample, key head). Its operands are
-  rounded to bfloat16, which is what the MXU's default precision makes of a
-  float32 operand; scores, softmax and accumulation are float32. For its
-  backward the kernel keeps its output and the scores' log-sum-exp, under the
-  name ``SPLASH_RESIDUALS``: a layer rematerialised by ``checkpoint_layer``
-  runs the forward kernel once. ``interpret`` runs the kernel in Pallas's
-  interpreter (tests, any backend)."""
-  kernel = _splash_kernel(seq_len, block_length, q.shape[3], block,
-                          interpret)
-  qh = jnp.transpose(q, (0, 2, 3, 1, 4)).astype(jnp.bfloat16)  # [B,Hkv,G,S,hd]
-  kh = jnp.transpose(k, (0, 2, 1, 3)).astype(jnp.bfloat16)     # [B,Hkv,S,hd]
-  vh = jnp.transpose(v, (0, 2, 1, 3)).astype(jnp.bfloat16)
-  out = jax.vmap(jax.vmap(kernel))(qh, kh, vh)                 # [B,Hkv,G,S,hd]
-  return jnp.transpose(out, (0, 3, 1, 2, 4)).astype(q.dtype)
-
-
-def attention_path(name: str, xla, splash):
-  """The function a configuration's ``attention`` names: ``splash`` is the
-  TPU's kernel and raises on any other backend, ``xla`` is for tests and
-  counting tools."""
-  if name == "xla":
-    return xla
-  if name == "splash":
-    if jax.default_backend() != "tpu":
-      raise ValueError(
-          'attention="splash" is a TPU kernel and this backend is '
-          f'{jax.default_backend()!r}; a test or a counting tool on another '
-          'backend names attention="xla" itself')
-    return splash
-  raise ValueError(f"attention={name!r}: splash or xla")
 
 
 def decoder_layer(cfg: SDARMoEConfig, p, x):
@@ -264,7 +124,7 @@ def decoder_layer(cfg: SDARMoEConfig, p, x):
     q = q.reshape(b, s, hkv, hq // hkv, hd)
     attend = attention_path(cfg.attention, attention_xla, attention_splash)
     with jax.named_scope(scopes.ATTN_CORE):
-      o = attend(q, k, v, cfg.seq_len, cfg.block_length, ATTENTION_BLOCK)
+      o = attend(q, k, v, BlockDiffusion(cfg.block_length))
     with jax.named_scope(scopes.ATTN_PROJ):
       o = mxu_dot(o.reshape(b, s, hq * hd), p["wo"])
     x = x + o

@@ -435,7 +435,7 @@ def scatter_add_fused(layout: PackedLayout, buf: jax.Array, ids: jax.Array,
   # (disjoint windows add disjointly, same-window duplicates add like any
   # duplicate), and the kernel's cache is keyed by physical row. The
   # expansion stays outside the kernel by measurement: fused into either
-  # backend it costs ~1.7 ns/occ (docs/BENCHMARKS.md, profile_select).
+  # backend it costs ~1.7 ns/occ (docs/BENCHMARKS.md).
   # Mosaic rejects 1-row dynamic HBM slices of tiled memrefs wider than
   # one 128-lane tile ("slice along dim 0 must be aligned to (8)" at
   # phys_width 256 — w128 tables + interleaved aux), so the RMW kernel

@@ -3,8 +3,8 @@
 The apply phase is the single most expensive op of sparse embedding
 training on TPU: XLA's scatter-add runs a conservative serial update loop
 measured at ~75 ns/row on v5e regardless of uniqueness, sortedness, or
-buffer size (`tools/profile_scatter2.py`), while XLA's *gather* pipelines
-to ~10 ns/row. This kernel replaces the scatter's role of the reference's
+buffer size (controlled sweeps, docs/BENCHMARKS.md), while XLA's *gather*
+pipelines to ~10 ns/row. This kernel replaces the scatter's role of the reference's
 fused-backward + sparse-optimizer-apply pipeline
 (`/root/reference/distributed_embeddings/cc/kernels/embedding_lookup_kernels.cu:464-633`
 plus TF sparse applies) with a DMA read-modify-write pipeline:

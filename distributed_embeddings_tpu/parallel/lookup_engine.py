@@ -470,7 +470,7 @@ class SparseResiduals:
   # narrow fast path — exactly one sub-row window nonzero, so summing the
   # windows' aux halves extracts the occurrence's state). Slicing aux
   # lanes here per occurrence instead would cost a ~25 ns/row relayout
-  # right after the gather (measured, tools/profile_tiny_buckets).
+  # right after the gather (measured on v5e).
   aux_rows: Dict[tuple, jax.Array]
 
   def tree_flatten(self):
@@ -1287,8 +1287,8 @@ class DistributedLookup:
     slices the table half at bag granularity; the per-occurrence residual is
     the raw gather output, whose aux lanes the apply slices off inside the
     delta computation (where it fuses with the rule math). Per-occurrence
-    lane splits right after the gather measured ~25 ns/row on v5e
-    (`tools/profile_tiny_buckets.py`) — at bag granularity they are ~free."""
+    lane splits right after the gather measured ~25 ns/row on v5e — at bag
+    granularity they are ~free."""
     w = layout.width
     if isinstance(ids_all, DedupRouted):
       # dedup'd exchange: gather each unique id's fused row ONCE — the

@@ -25,7 +25,7 @@ APPLY = "de_apply"  # delta streams, XLA's scatter-add, the Pallas apply kernel
 ONEHOT = "de_onehot"  # a dense class's windowed one-hot MXU lookup; inside de_combine
 EXCHANGE = "de_exchange"  # the collectives of parallel/wire.py; inside de_route and de_combine
 INTERACT = "de_interact"  # models/dlrm.py::dot_interact; inside de_model
-ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/lfm2_moe.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE, gate, attention under the model's mask; inside de_model
+ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/lfm2_moe.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE (layers/attention.py::rope), gate, attention under the model's mask description (layers/attention.py: attention_splash on a TPU, attention_xla elsewhere); inside de_model
 WINDOW_ATTENTION = "de_window_attention"  # models/laguna.py: the mixer of a sliding_attention layer (causal, same document, i - j < sliding_window); inside de_attention
 FULL_ATTENTION = "de_full_attention"  # models/laguna.py: the mixer of a full_attention layer (causal, same document); inside de_attention
 MOE = "de_moe"  # layers/moe.py::moe_share and shared_expert, the whole expert layer; inside de_model
@@ -47,9 +47,9 @@ SHORT_CONV = "de_short_conv"  # models/lfm2_moe.py, layers/short_conv.py: a doub
 # layer a remainder (norms, gates, the residual add) that is read as the
 # layer less its parts and kernels. XLA fuses across a part's line, so a
 # part is exact to a fusion.
-ATTN_PROJ = "de_attn_proj"  # the matmuls with wq, wk, wv, wo (models/laguna.py: and wg); inside de_attention (Laguna: inside de_window_attention / de_full_attention)
+ATTN_PROJ = "de_attn_proj"  # the matmuls with wq, wk, wv, wo (models/laguna.py: and wg), each model's own lines round layers/attention.py; inside de_attention (Laguna: inside de_window_attention / de_full_attention)
 ATTN_QK = "de_attn_qk"  # q and k between projection and kernel: q/k norms, rope, the head_dim ** -0.5 scaling; inside de_attention (Laguna: as above)
-ATTN_CORE = "de_attn_core"  # the call of attend(...): transposes and casts to the kernel's layout, the kernel, the way back; inside de_attention (Laguna: as above). models/keye_sparse.py: the attention under the selection (layers/sparse_index.py): on a TPU the de_sparse_attn_fwd/dq/dkv kernels of ops/pallas_sparse_attn.py with the assembly of their int8 mask, its block counts and the casts to their layout; elsewhere XLA's scores, masked softmax and products with v a tile at a time, forward and the written-out backward
+ATTN_CORE = "de_attn_core"  # the call of attend(...), layers/attention.py::attention_splash (or attention_xla): transposes and casts to the kernel's layout, the kernel, the way back; inside de_attention (Laguna: as above). models/keye_sparse.py: the attention under the selection (layers/sparse_index.py): on a TPU the de_sparse_attn_fwd/dq/dkv kernels of ops/pallas_sparse_attn.py with the assembly of their int8 mask, its block counts and the casts to their layout; elsewhere XLA's scores, masked softmax and products with v a tile at a time, forward and the written-out backward
 INDEX_SCORES = "de_index_scores"  # the indexer's projections, its key's LayerNorm, its rotary pass, the score product, the ReLU and the weighted sum over index heads (backward: the score again and the three products of its gradient); inside de_sparse_index
 INDEX_SELECT = "de_index_select"  # layers/sparse_index.py::select_topk and the packing of the mask: forward only, the plan keeps the selection; inside de_sparse_index
 INDEX_LOSS = "de_index_loss"  # the KL's target (the heads' mean of the main attention's probabilities: on a TPU the de_sparse_attn_mean kernel, once a direction), the indexer's softmax over the selection and the KL (backward: its gradient into the score); inside de_sparse_index

@@ -13,11 +13,11 @@ import numpy as np
 import pytest
 
 import reference_olmo_hybrid as ref
+from distributed_embeddings_tpu.layers.decoder import segment_ids
 from distributed_embeddings_tpu.layers.gated_delta import (
     causal_conv,
     chunk_gated_delta_rule,
     linear_state_scan,
-    segment_ids,
 )
 
 B, H, DK, DV = 2, 3, 6, 10

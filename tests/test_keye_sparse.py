@@ -21,6 +21,10 @@ import pytest
 
 import reference_keye_sparse as ref
 from distributed_embeddings_tpu.layers import remat
+from distributed_embeddings_tpu.layers.decoder import (
+    document_segments,
+    next_token_loss,
+)
 from distributed_embeddings_tpu.layers.moe import moe_share
 from distributed_embeddings_tpu.models import keye_sparse
 from distributed_embeddings_tpu.models.keye_sparse import (
@@ -30,10 +34,6 @@ from distributed_embeddings_tpu.models.keye_sparse import (
     decoder_layer,
     layer_shapes,
     sparse_training_loss,
-)
-from distributed_embeddings_tpu.models.olmo_hybrid import (
-    document_segments,
-    next_token_loss,
 )
 from test_remat_plan import _count, _primitive
 

@@ -4,13 +4,11 @@ model has: layers of two head counts, a window shorter than a document, two
 rotary tables (one of them YaRN over half a head), a leading dense layer, a
 sigmoid router scaled by 2.5 beside a shared expert. On seeded weights:
 logits, loss, every gradient leaf and the gradient of the table's rows; the
-window's edge; the rotary tables against numbers worked out by hand from the
-published keys; the eight shares of the routed experts and the shared expert
+rotary tables against numbers worked out by hand from the published keys; the eight shares of the routed experts and the shared expert
 counted once, which add up to the uncut layer; one step through
 `make_sparse_train_step`; bfloat16 in the router's product or in the scores,
-which the model's tolerance refuses; the splash path in Pallas's interpreter
-under the local mask, and its lowering for the TPU at both published head
-counts."""
+which the model's tolerance refuses. The window's edge, the tile loop and the
+splash path at both published head counts: `tests/test_attention.py`."""
 
 import dataclasses
 import math
@@ -22,6 +20,11 @@ import optax
 import pytest
 
 import reference_laguna as ref
+from distributed_embeddings_tpu.layers.attention import rope
+from distributed_embeddings_tpu.layers.decoder import (
+    document_segments,
+    next_token_loss,
+)
 from distributed_embeddings_tpu.layers.embedding import TableConfig
 from distributed_embeddings_tpu.layers.moe import moe_share, shared_expert
 from distributed_embeddings_tpu.layers.planner import DistEmbeddingStrategy
@@ -32,17 +35,10 @@ from distributed_embeddings_tpu.models.laguna import (
     SPARSE,
     Laguna,
     LagunaConfig,
-    attention_splash,
-    attention_xla,
     freeze_rope_parameters,
     layer_shapes,
     rotary_table,
 )
-from distributed_embeddings_tpu.models.olmo_hybrid import (
-    document_segments,
-    next_token_loss,
-)
-from distributed_embeddings_tpu.models.sdar_moe import rope, rope_frequencies
 from distributed_embeddings_tpu.ops.packed_table import adam_rule
 from distributed_embeddings_tpu.parallel.lookup_engine import DistributedLookup
 from distributed_embeddings_tpu.training import (
@@ -202,64 +198,6 @@ def test_the_counters_of_every_expert_layer_come_out_with_the_model():
       and int(moe["assignments"].max()) < B * cfg.seq_len * 3
 
 
-# ---- the window ------------------------------------------------------------
-def _attention_case(length, hkv, group, hd, starts_at=(), seed=1, batch=1):
-  rng = np.random.default_rng(seed)
-  q = jnp.asarray(rng.normal(size=(batch, length, hkv, group, hd)) * 0.1,
-                  jnp.float32)
-  k, v = (jnp.asarray(rng.normal(size=(batch, length, hkv, hd)), jnp.float32)
-          for _ in range(2))
-  starts = np.zeros((batch, length), bool)
-  starts[:, 0] = True
-  starts[0, list(starts_at)] = True
-  return q, k, v, jnp.asarray(np.cumsum(starts, axis=1) - 1, jnp.int32)
-
-
-def _reach(attend, q, k, v, seg, query):
-  """The keys whose value moves the output at ``query``: ``[L]`` bool."""
-  g = jax.grad(lambda v: jnp.sum(attend(q, k, v, seg)[0, query]))(v)
-  return np.asarray(jnp.any(g[0] != 0, axis=(1, 2)))
-
-
-def test_the_windows_edge_at_the_published_512():
-  """A query sees itself and the 511 tokens before it: ``i - j`` 511 is
-  seen, 512 is not; a document that starts inside the window cuts it
-  short; a full layer sees the whole document."""
-  length, window = 1100, 512
-  q, k, v, seg = _attention_case(length, 1, 2, 8, starts_at=(700,))
-  sliding = lambda q, k, v, s: attention_xla(q, k, v, s, 256, window)
-  whole = lambda q, k, v, s: attention_xla(q, k, v, s, 256, None)
-  seen = _reach(sliding, q, k, v, seg, 650)
-  assert seen[650 - 511] and not seen[650 - 512]
-  assert seen[139:651].all() and not seen[:139].any() \
-      and not seen[651:].any()
-  seen = _reach(sliding, q, k, v, seg, 1000)      # its document starts at 700
-  assert seen[700:1001].all() and not seen[:700].any()
-  seen = _reach(sliding, q, k, v, seg, 1099)      # 1099 - 511 = 588 < 700
-  assert seen[700:1100].all() and not seen[:700].any()
-  seen = _reach(sliding, q, k, v, seg, 300)       # shorter than the window
-  assert seen[:301].all() and not seen[301:].any()
-  seen = _reach(whole, q, k, v, seg, 650)
-  assert seen[:651].all() and not seen[651:].any()
-  seen = _reach(whole, q, k, v, seg, 1099)
-  assert seen[700:].all() and not seen[:700].any()
-
-
-@pytest.mark.parametrize("window", [5, None])
-def test_the_tiled_path_is_attention_by_full_scores(window):
-  q, k, v, seg = _attention_case(24, 2, 3, 16, starts_at=(7, 15), batch=2)
-  starts = jnp.asarray(np.diff(np.asarray(seg), axis=1, prepend=-1) > 0)
-  kind = SLIDING if window else FULL
-  allowed = ref.allowed_pairs({"sliding_window": window}, kind, starts)
-  scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k)
-  prob = jax.nn.softmax(jnp.where(allowed[:, None, None], scores, -jnp.inf),
-                        axis=-1)
-  want = jnp.einsum("bkgqs,bskd->bqkgd", prob, v)
-  for tile in (4, 7, 24):
-    np.testing.assert_allclose(attention_xla(q, k, v, seg, tile, window),
-                               want, atol=2e-6)
-
-
 # ---- the rotary tables -----------------------------------------------------
 def test_both_rotary_tables_are_the_published_keys_worked_by_hand():
   """Laguna-XS.2's own ``rope_parameters`` at a head of 128. Sliding:
@@ -316,22 +254,6 @@ def test_both_rotary_tables_are_the_published_keys_worked_by_hand():
                                atol=2e-3)   # float32 frequencies at 5,000
     np.testing.assert_allclose(cos[:9, :len(inv)],
                                factor * np.cos(ang[:9]), atol=2e-6)
-
-
-def test_the_plain_table_of_a_whole_head_is_what_it_was():
-  """`sdar_moe`'s call: all of a head rotated at one theta, no factor."""
-  x = jnp.asarray(np.random.default_rng(1).normal(size=(5, 2, 8)),
-                  jnp.float32)
-  inv = rope_frequencies(1e6, 8)
-  assert np.array_equal(inv, 1.0 / (1e6 ** (np.arange(4, dtype=np.float32)
-                                            / 4)))
-  pos = jnp.arange(5)
-  ang = np.arange(5, dtype=np.float32)[:, None] * inv[None, :]
-  cos, sin = (np.concatenate([f(ang)] * 2, -1)[:, None] for f in
-              (np.cos, np.sin))
-  xs = np.asarray(x)
-  want = xs * cos + np.concatenate([-xs[..., 4:], xs[..., :4]], -1) * sin
-  np.testing.assert_allclose(rope(x, pos, inv), want, atol=1e-6)
 
 
 # ---- the expert layer ------------------------------------------------------
@@ -483,7 +405,7 @@ def test_the_whole_thing_trains_on_the_sparse_step():
   assert losses[-1] < 0.7 * losses[0]
 
 
-# ---- what the configuration refuses, and the TPU's kernel -------------------
+# ---- what the configuration refuses ----------------------------------------
 def test_without_a_tpu_the_splash_path_raises():
   assert LagunaConfig().attention == "splash"
   rows, numerical, _ = _batch(TOY, 1)
@@ -504,46 +426,3 @@ def test_without_a_tpu_the_splash_path_raises():
   with pytest.raises(ValueError, match="rope_type='linear'"):
     rotary_table(dataclasses.replace(TOY, rope_parameters=(
         (FULL, (("rope_type", "linear"), ("rope_theta", 1e4))),)), FULL)
-
-
-@pytest.mark.parametrize("window", [128, None])
-def test_the_splash_path_is_the_tiled_path_on_bfloat16_operands(window):
-  """The kernel the TPU runs, in Pallas's interpreter, under the local mask
-  (a window of 128 over blocks of 128: a query block reads its own block and
-  the one before) or the causal one, the documents as segment ids, three
-  query heads a key-value head: values and gradients are those of the XLA
-  path given the same operands rounded to bfloat16."""
-  length = 384
-  q, k, v, seg = _attention_case(length, 2, 3, 128, starts_at=(37, 290),
-                                 batch=1)
-  rounded = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
-  splash = lambda q, k, v: jnp.sum(jnp.sin(attention_splash(
-      q, k, v, seg, 128, window, interpret=True)))
-  tiled = lambda q, k, v: jnp.sum(jnp.sin(attention_xla(
-      rounded(q), rounded(k), rounded(v), seg, 64, window)))
-  got = jax.jit(jax.value_and_grad(splash, argnums=(0, 1, 2))).lower(
-      q, k, v).compile()(q, k, v)
-  with jax.default_matmul_precision("highest"):
-    want = jax.value_and_grad(tiled, argnums=(0, 1, 2))(q, k, v)
-  # the kernel also rounds the softmax's probabilities to bfloat16 before
-  # the product with V (2^-9 a value), which the tiled path does not
-  assert float(got[0]) == pytest.approx(float(want[0]), rel=3e-3)
-  for g, w in zip(got[1], want[1]):
-    assert float(jnp.max(jnp.abs(g - w))) < 0.02 * float(jnp.max(jnp.abs(w)))
-
-
-@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)])
-def test_the_splash_path_lowers_for_the_tpu_at_published_head_shapes(
-    heads, window):
-  """Pallas -> Mosaic lowering of forward and backward at 64 and 48 query
-  heads over 8 key-value heads of 128, blocks of 512, segment ids, with no
-  chip; the local mask keeps 3 of 10 blocks of a 2,048-token sequence."""
-  length = 2048
-  q, k, v, seg = _attention_case(length, 8, heads // 8, 128, starts_at=(700,))
-  f = jax.grad(lambda q, k, v: jnp.sum(attention_splash(
-      q, k, v, seg, 512, window)), argnums=(0, 1, 2))
-  text = jax.jit(f).trace(q, k, v).lower(
-      lowering_platforms=("tpu",)).as_text()
-  for kernel in ("splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv"):
-    assert kernel in text
-  assert text.count("tpu_custom_call") >= 3

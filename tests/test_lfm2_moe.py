@@ -8,9 +8,8 @@ sigmoid router whose choice is made under a selection bias. On seeded
 weights: logits, loss, every gradient leaf and the gradient of the table's
 rows; the eight shares of the experts, the dense parts counted once, which
 add up to the uncut layer; packed documents against the documents alone; one
-step through `make_sparse_train_step`, which leaves the bias where it was;
-heads of 64 through both attention paths (the kernel in Pallas's interpreter
-and its lowering for the TPU at the published 32 over 8)."""
+step through `make_sparse_train_step`, which leaves the bias where it was.
+Heads of 64 through both attention paths: `tests/test_attention.py`."""
 
 import dataclasses
 
@@ -21,14 +20,13 @@ import optax
 import pytest
 
 import reference_lfm2_moe as ref
-from test_laguna import _attention_case
+from distributed_embeddings_tpu.layers.decoder import (
+    document_segments,
+    next_token_loss,
+)
 from distributed_embeddings_tpu.layers.embedding import TableConfig
 from distributed_embeddings_tpu.layers.moe import moe_share
 from distributed_embeddings_tpu.layers.planner import DistEmbeddingStrategy
-from distributed_embeddings_tpu.models.laguna import (
-    attention_splash,
-    attention_xla,
-)
 from distributed_embeddings_tpu.models.lfm2_moe import (
     CONV,
     DENSE,
@@ -38,10 +36,6 @@ from distributed_embeddings_tpu.models.lfm2_moe import (
     Lfm2MoeConfig,
     decoder_layer,
     layer_shapes,
-)
-from distributed_embeddings_tpu.models.olmo_hybrid import (
-    document_segments,
-    next_token_loss,
 )
 from distributed_embeddings_tpu.ops.packed_table import adam_rule
 from distributed_embeddings_tpu.parallel.lookup_engine import DistributedLookup
@@ -375,7 +369,7 @@ def test_the_whole_thing_trains_on_the_sparse_step_and_adam_leaves_the_bias():
   assert not np.asarray(state["dense"]["layer_1_expert_bias"]).any()
 
 
-# ---- what the configuration refuses, and the TPU's kernel -------------------
+# ---- what the configuration refuses ----------------------------------------
 def test_without_a_tpu_the_splash_path_raises():
   assert Lfm2MoeConfig().attention == "splash"
   rows, numerical, _ = _batch(TOY, 1)
@@ -391,54 +385,3 @@ def test_without_a_tpu_the_splash_path_raises():
     dataclasses.replace(TOY, layers_here=(0, 40))
   with pytest.raises(ValueError, match="4 query heads over 3"):
     dataclasses.replace(TOY, num_key_value_heads=3)
-
-
-def test_heads_of_64_through_the_tiled_path_are_attention_by_full_scores():
-  """Half a lane tile a head, four query heads a key-value head."""
-  q, k, v, seg = _attention_case(48, 2, 4, 64, starts_at=(7, 30), batch=2)
-  i, j = np.arange(48)[:, None], np.arange(48)[None, :]
-  allowed = (j <= i)[None] & (np.asarray(seg)[:, :, None]
-                              == np.asarray(seg)[:, None, :])
-  with jax.default_matmul_precision("highest"):
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", q, k)
-    prob = jax.nn.softmax(jnp.where(allowed[:, None, None], scores, -jnp.inf),
-                          axis=-1)
-    want = jnp.einsum("bkgqs,bskd->bqkgd", prob, v)
-    for tile in (5, 16, 48):
-      np.testing.assert_allclose(attention_xla(q, k, v, seg, tile, None),
-                                 want, atol=2e-6)
-
-
-def test_heads_of_64_through_the_splash_path_are_the_tiled_paths():
-  """The kernel the TPU runs, in Pallas's interpreter, at a head of 64 and a
-  group of 4 under the causal mask with the documents as segment ids:
-  values and gradients are those of the XLA path given the same operands
-  rounded to bfloat16."""
-  q, k, v, seg = _attention_case(384, 2, 4, 64, starts_at=(37, 290))
-  rounded = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
-  splash = lambda q, k, v: jnp.sum(jnp.sin(attention_splash(
-      q, k, v, seg, 128, None, interpret=True)))
-  tiled = lambda q, k, v: jnp.sum(jnp.sin(attention_xla(
-      rounded(q), rounded(k), rounded(v), seg, 64, None)))
-  got = jax.jit(jax.value_and_grad(splash, argnums=(0, 1, 2))).lower(
-      q, k, v).compile()(q, k, v)
-  with jax.default_matmul_precision("highest"):
-    want = jax.value_and_grad(tiled, argnums=(0, 1, 2))(q, k, v)
-  # the kernel also rounds the softmax's probabilities to bfloat16 before
-  # the product with V (2^-9 a value), which the tiled path does not
-  assert float(got[0]) == pytest.approx(float(want[0]), rel=3e-3)
-  for g, w in zip(got[1], want[1]):
-    assert float(jnp.max(jnp.abs(g - w))) < 0.02 * float(jnp.max(jnp.abs(w)))
-
-
-def test_the_splash_path_lowers_for_the_tpu_at_the_published_head_shape():
-  """Pallas -> Mosaic lowering of forward and backward at 32 query heads
-  over 8 key-value heads of 64, blocks of 512, segment ids, with no chip."""
-  q, k, v, seg = _attention_case(2048, 8, 4, 64, starts_at=(700,))
-  f = jax.grad(lambda q, k, v: jnp.sum(attention_splash(
-      q, k, v, seg, 512, None)), argnums=(0, 1, 2))
-  text = jax.jit(f).trace(q, k, v).lower(
-      lowering_platforms=("tpu",)).as_text()
-  for kernel in ("splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv"):
-    assert kernel in text
-  assert text.count("tpu_custom_call") >= 3

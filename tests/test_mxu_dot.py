@@ -26,12 +26,10 @@ import test_lfm2_moe
 import test_olmo_hybrid
 import test_sdar_moe
 from distributed_embeddings_tpu.layers import dense
+from distributed_embeddings_tpu.layers.decoder import next_token_loss
 from distributed_embeddings_tpu.models.laguna import Laguna
 from distributed_embeddings_tpu.models.lfm2_moe import Lfm2Moe
-from distributed_embeddings_tpu.models.olmo_hybrid import (
-    OlmoHybrid,
-    next_token_loss,
-)
+from distributed_embeddings_tpu.models.olmo_hybrid import OlmoHybrid
 from distributed_embeddings_tpu.models.sdar_moe import (
     SDARMoE,
     block_diffusion_loss,

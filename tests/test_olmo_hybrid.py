@@ -5,8 +5,8 @@ gradient of the table's rows, over packed documents;
 the two head shares of both mixers, which add up to the whole layer; one
 step through `make_sparse_train_step` (loss, dense gradients, the token rows'
 summed-Adam update) against the reference's; bfloat16 inside the recurrence,
-which the model's tolerance refuses; the splash path in Pallas's
-interpreter, and its lowering for the TPU at the published head shapes."""
+which the model's tolerance refuses. The splash path at the published head
+shapes: `tests/test_attention.py`."""
 
 import dataclasses
 
@@ -17,6 +17,10 @@ import optax
 import pytest
 
 import reference_olmo_hybrid as ref
+from distributed_embeddings_tpu.layers.decoder import (
+    document_segments,
+    next_token_loss,
+)
 from distributed_embeddings_tpu.layers.embedding import TableConfig
 from distributed_embeddings_tpu.layers.planner import DistEmbeddingStrategy
 from distributed_embeddings_tpu.models.olmo_hybrid import (
@@ -24,13 +28,9 @@ from distributed_embeddings_tpu.models.olmo_hybrid import (
     LINEAR,
     OlmoHybrid,
     OlmoHybridConfig,
-    attention_splash,
-    attention_xla,
-    document_segments,
     full_attention_mixer,
     layer_shapes,
     linear_attention_mixer,
-    next_token_loss,
 )
 from distributed_embeddings_tpu.ops.packed_table import adam_rule
 from distributed_embeddings_tpu.parallel.lookup_engine import DistributedLookup
@@ -351,51 +351,3 @@ def test_without_a_tpu_the_splash_path_raises():
     dataclasses.replace(TOY, heads_held=(3, 2))
   with pytest.raises(ValueError, match="layer_types names"):
     dataclasses.replace(TOY, layer_types=("sliding_attention",))
-
-
-def _attention_case(length, heads, hd, seed=1):
-  rng = np.random.default_rng(seed)
-  q = jnp.asarray(rng.normal(size=(2, length, heads, hd)) * 0.1, jnp.float32)
-  k, v = (jnp.asarray(rng.normal(size=(2, length, heads, hd)), jnp.float32)
-          for _ in range(2))
-  starts = np.zeros((2, length), bool)
-  starts[:, 0] = True
-  starts[0, [37, 130]] = True
-  starts[1, 200] = True
-  return q, k, v, jnp.asarray(np.cumsum(starts, axis=1) - 1, jnp.int32)
-
-
-def test_the_splash_path_is_the_tiled_path_on_bfloat16_operands():
-  """The kernel the TPU runs, in Pallas's interpreter, causal with the
-  documents as segment ids: values and gradients are those of the XLA path
-  given the same operands rounded to bfloat16."""
-  length = 256
-  q, k, v, seg = _attention_case(length, 2, 128)
-  rounded = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
-  splash = lambda q, k, v: jnp.sum(jnp.sin(attention_splash(
-      q, k, v, seg, 128, interpret=True)))
-  tiled = lambda q, k, v: jnp.sum(jnp.sin(attention_xla(
-      rounded(q), rounded(k), rounded(v), seg, 64)))
-  got = jax.jit(jax.value_and_grad(splash, argnums=(0, 1, 2))).lower(
-      q, k, v).compile()(q, k, v)
-  with jax.default_matmul_precision("highest"):
-    want = jax.value_and_grad(tiled, argnums=(0, 1, 2))(q, k, v)
-  # the kernel also rounds the softmax's probabilities to bfloat16 before
-  # the product with V (2^-9 a value), which the tiled path does not
-  assert float(got[0]) == pytest.approx(float(want[0]), rel=3e-3)
-  for g, w in zip(got[1], want[1]):
-    assert float(jnp.max(jnp.abs(g - w))) < 0.02 * float(jnp.max(jnp.abs(w)))
-
-
-def test_the_splash_path_lowers_for_the_tpu_at_published_head_shapes():
-  """Pallas -> Mosaic lowering of forward and backward at 15 heads of 128,
-  blocks of 512, segment ids, with no chip."""
-  length = 1024
-  q, k, v, seg = _attention_case(length, 15, 128)
-  f = jax.grad(lambda q, k, v: jnp.sum(attention_splash(q, k, v, seg, 512)),
-               argnums=(0, 1, 2))
-  text = jax.jit(f).trace(q, k, v).lower(
-      lowering_platforms=("tpu",)).as_text()
-  for kernel in ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"):
-    assert kernel in text
-  assert text.count("tpu_custom_call") >= 3

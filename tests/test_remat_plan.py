@@ -17,7 +17,12 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
-from distributed_embeddings_tpu.layers import remat, short_conv, sparse_index
+from distributed_embeddings_tpu.layers import (
+    attention,
+    remat,
+    short_conv,
+    sparse_index,
+)
 from distributed_embeddings_tpu.layers.moe import MoEShare, Router, moe_share
 from distributed_embeddings_tpu.models import (
     keye_sparse,
@@ -193,17 +198,18 @@ def _splash_layer(name):
   seg = jnp.asarray(np.arange(length)[None, :] >= 70, jnp.int32)
 
   def attend(q, k, v):
+    grouped = q.reshape(1, length, hkv, group, hd)
     if name == "sdar_moe":     # [xt ; x0]: 2 L positions of L = 64
-      return sdar_moe.attention_splash(
-          q.reshape(1, length, hkv, group, hd), k, v, length // 2, 4, 128,
+      return attention.attention_splash(
+          grouped, k, v, attention.BlockDiffusion(4), None, 128,
           interpret=True)
     if name in ("laguna", "lfm2_moe"):   # a window of 40, and none
-      return laguna.attention_splash(
-          q.reshape(1, length, hkv, group, hd), k, v, seg, 128,
-          40 if name == "laguna" else None, interpret=True)
-    kv = lambda t: jnp.repeat(t, group, axis=2)
-    return olmo_hybrid.attention_splash(q, kv(k), kv(v), seg, 128,
-                                        interpret=True)
+      return attention.attention_splash(
+          grouped, k, v, attention.Window(40) if name == "laguna"
+          else attention.Causal(), seg, 128, interpret=True)
+    kv = lambda t: jnp.repeat(t, group, axis=2)   # heads with no group
+    return attention.attention_splash(q, kv(k), kv(v), attention.Causal(),
+                                      seg, 128, interpret=True)
 
   def layer(wl, x):
     qkv = (x @ wl).reshape(1, length, (group + 2) * hkv, hd)
@@ -527,13 +533,16 @@ def test_kept_is_what_the_code_names():
   source = inspect.getsource(sparse_index)
   assert source.count("SPARSE_SELECTION)") == 1
   assert source.count("SPARSE_ATTN_RESIDUALS)") == 2
-  for build, args in [
-      (sdar_moe._splash_kernel, (16, 4, 2, 128, True)),
-      (laguna._splash_kernel, (128, 2, None, 128, True)),
-      (laguna._splash_kernel, (128, 2, 40, 128, True)),
-      (olmo_hybrid._splash_kernel, (128, 2, 128, True))]:
-    assert build(*args).kwargs["residual_checkpoint_name"] \
-        == remat.SPLASH_RESIDUALS
+  # (description, positions, heads, grouped): the one builder's kernels
+  for args in [(attention.BlockDiffusion(4), 32, 2, True),
+               (attention.Causal(), 128, 2, True),
+               (attention.Window(40), 128, 2, True),
+               (attention.Causal(), 128, 2, False)]:
+    kernel = attention._splash_kernel(*args, 128, True)
+    assert kernel.kwargs["residual_checkpoint_name"] == remat.SPLASH_RESIDUALS
+    # its block maps are host arrays: constants of whatever program calls it
+    leaves = jax.tree_util.tree_leaves(kernel)
+    assert leaves and all(type(leaf) is np.ndarray for leaf in leaves)
   assert not [f.name for cfg in (sdar_moe.SDARMoEConfig, laguna.LagunaConfig,
                                  olmo_hybrid.OlmoHybridConfig,
                                  keye_sparse.KeyeSparseConfig,

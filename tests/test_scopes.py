@@ -23,6 +23,7 @@ import test_lfm2_moe
 import test_olmo_hybrid
 import test_sdar_moe
 from distributed_embeddings_tpu.layers import TableConfig, remat
+from distributed_embeddings_tpu.layers.decoder import next_token_loss
 from distributed_embeddings_tpu.layers.planner import DistEmbeddingStrategy
 from distributed_embeddings_tpu.models import (
     DLRM,
@@ -183,15 +184,14 @@ def test_the_vocabulary_is_one_flat_set_of_names():
 # the toy models of tests/test_sdar_moe.py, test_olmo_hybrid.py, test_laguna.py,
 # test_keye_sparse.py, test_lfm2_moe.py
 LM_TOYS = {
-    "lfm2_moe": (lfm2_moe.Lfm2Moe, olmo_hybrid.next_token_loss,
-                 test_lfm2_moe.TOY),
+    "lfm2_moe": (lfm2_moe.Lfm2Moe, next_token_loss, test_lfm2_moe.TOY),
     "sdar_moe": (sdar_moe.SDARMoE, sdar_moe.block_diffusion_loss,
                  dataclasses.replace(test_sdar_moe.TOY, num_experts=8,
                                      num_experts_per_tok=2,
                                      experts_held=(0, 4))),
-    "olmo_hybrid": (olmo_hybrid.OlmoHybrid, olmo_hybrid.next_token_loss,
+    "olmo_hybrid": (olmo_hybrid.OlmoHybrid, next_token_loss,
                     test_olmo_hybrid.TOY),
-    "laguna": (laguna.Laguna, olmo_hybrid.next_token_loss, test_laguna.TOY),
+    "laguna": (laguna.Laguna, next_token_loss, test_laguna.TOY),
     "keye_sparse": (keye_sparse.KeyeSparse, keye_sparse.sparse_training_loss,
                     dataclasses.replace(test_keye_sparse.TOY,
                                         num_hidden_layers=1,

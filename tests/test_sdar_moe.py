@@ -1,9 +1,9 @@
 """SDAR-MoE (`models/sdar_moe.py`) against the plain reference
 (`tests/reference_sdar_moe.py`) at toy widths on seeded weights, at
 ``highest`` matmul precision: logits, loss, every gradient leaf and the
-gradient of the table's rows; the block-diffusion mask against a
-position-by-position construction; and the whole model on the sparse train
-step, a sequence input under summed Adam, whose loss falls."""
+gradient of the table's rows; and the whole model on the sparse train step, a
+sequence input under summed Adam, whose loss falls. The attention proper and
+its block-diffusion mask: `tests/test_attention.py`."""
 
 import dataclasses
 
@@ -19,9 +19,7 @@ from distributed_embeddings_tpu.layers.planner import DistEmbeddingStrategy
 from distributed_embeddings_tpu.models.sdar_moe import (
     SDARMoE,
     SDARMoEConfig,
-    attention_xla,
     block_diffusion_loss,
-    block_diffusion_mask,
     noise_of,
 )
 from distributed_embeddings_tpu.ops.packed_table import adam_rule
@@ -57,30 +55,6 @@ def _params(cfg, rows, noise, seed=0):
   return jax.tree_util.tree_map(
       lambda x: x * 5 if x.ndim > 1 else x + 0.1 * jnp.asarray(
           rng.normal(size=x.shape), jnp.float32), params)
-
-
-@pytest.mark.parametrize("length,block", [(16, 4), (8, 2), (12, 3)])
-def test_the_mask_is_the_position_by_position_one(length, block):
-  mask = block_diffusion_mask(length, block)
-  assert np.array_equal(mask, ref.mask_by_hand(length, block))
-  assert mask.sum() == length * (length + block)   # benchmark/roofline_lm.py
-  assert mask.any(axis=1).all()                    # no query sees nothing
-
-
-@pytest.mark.parametrize("tile", [4, 8, 16])
-def test_tiled_attention_is_masked_attention(tile):
-  rng = np.random.default_rng(1)
-  length, block, hkv, g, hd = 16, 4, 2, 2, 8
-  q = jnp.asarray(rng.normal(size=(B, 2 * length, hkv, g, hd)), jnp.float32)
-  k, v = (jnp.asarray(rng.normal(size=(B, 2 * length, hkv, hd)), jnp.float32)
-          for _ in range(2))
-  with jax.default_matmul_precision("highest"):
-    got = attention_xla(q, k, v, length, block, tile)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", q, k)
-    s = jnp.where(ref.mask_by_hand(length, block)[None, None, None], s,
-                  -jnp.inf)
-    want = jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1), v)
-  np.testing.assert_allclose(got, want, atol=2e-6)
 
 
 def test_the_noise_masks_by_block():
@@ -192,51 +166,3 @@ def test_the_whole_thing_trains_on_the_sparse_step():
   # (E[masked / t] = 1; 64 positions are few, so only roughly)
   assert 0.4 * np.log(cfg.vocab_size) < losses[0] < 2 * np.log(cfg.vocab_size)
   assert losses[-1] < 0.7 * losses[0]
-
-
-def _attention_case(length, hkv, g, hd, seed=1):
-  rng = np.random.default_rng(seed)
-  q = jnp.asarray(rng.normal(size=(2, 2 * length, hkv, g, hd)) * 0.1,
-                  jnp.float32)
-  k, v = (jnp.asarray(rng.normal(size=(2, 2 * length, hkv, hd)), jnp.float32)
-          for _ in range(2))
-  return q, k, v
-
-
-def test_the_splash_path_is_the_tiled_path_on_bfloat16_operands():
-  """The kernel the TPU runs, in Pallas's interpreter: values and gradients
-  are those of the XLA path given the same operands rounded to bfloat16
-  (which is what the MXU's default precision makes of float32)."""
-  from distributed_embeddings_tpu.models.sdar_moe import attention_splash
-  length, block = 128, 4
-  q, k, v = _attention_case(length, 1, 2, 128)
-  rounded = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
-  splash = lambda q, k, v: jnp.sum(jnp.sin(attention_splash(
-      q, k, v, length, block, 128, interpret=True)))
-  tiled = lambda q, k, v: jnp.sum(jnp.sin(attention_xla(
-      rounded(q), rounded(k), rounded(v), length, block, 64)))
-  # compiled ahead of time, as the benchmark compiles its step: the kernel's
-  # block maps are constants of the program, not hidden arguments
-  got = jax.jit(jax.value_and_grad(splash, argnums=(0, 1, 2))).lower(
-      q, k, v).compile()(q, k, v)
-  with jax.default_matmul_precision("highest"):
-    want = jax.value_and_grad(tiled, argnums=(0, 1, 2))(q, k, v)
-  assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-3)
-  for g, w in zip(got[1], want[1]):
-    assert float(jnp.max(jnp.abs(g - w))) < 0.02 * float(jnp.max(jnp.abs(w)))
-
-
-def test_the_splash_path_lowers_for_the_tpu_at_published_head_shapes():
-  """Pallas -> Mosaic lowering of forward and backward at 8 query heads a
-  key head of 128, blocks of 512, with no chip (it does not run Mosaic's
-  own compile: `/root/scratch`-style rehearsals and the chip do)."""
-  from distributed_embeddings_tpu.models.sdar_moe import attention_splash
-  length = 1024
-  q, k, v = _attention_case(length, 1, 8, 128)
-  f = jax.grad(lambda q, k, v: jnp.sum(attention_splash(
-      q, k, v, length, 4, 512)), argnums=(0, 1, 2))
-  text = jax.jit(f).trace(q, k, v).lower(
-      lowering_platforms=("tpu",)).as_text()
-  for kernel in ("splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv"):
-    assert kernel in text
-  assert text.count("tpu_custom_call") >= 3

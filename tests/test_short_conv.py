@@ -12,10 +12,8 @@ from jax.ad_checkpoint import print_saved_residuals
 
 import reference_lfm2_moe as ref
 from distributed_embeddings_tpu.layers import remat
-from distributed_embeddings_tpu.layers.gated_delta import (
-    causal_conv,
-    segment_ids,
-)
+from distributed_embeddings_tpu.layers.decoder import segment_ids
+from distributed_embeddings_tpu.layers.gated_delta import causal_conv
 from distributed_embeddings_tpu.layers.short_conv import (
     gate_chain,
     short_conv_mixer,

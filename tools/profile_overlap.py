@@ -73,8 +73,8 @@ WORLD = 4
 WIDTH = 16
 ALPHA = 1.05
 
-# the serial host-pipeline stages (profile_tiering's split) vs the
-# device window, summed from trace span durations
+# the serial host-pipeline stages vs the device window, summed from trace
+# span durations
 HOST_SPANS = ("tiered/classify", "tiered/stage", "tiered/write_back",
               "tiered/rerank")
 
