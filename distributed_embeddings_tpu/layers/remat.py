@@ -46,6 +46,14 @@ mixer rebuilds the gate chain from it (two multiplies and a three-tap
 convolution an element, bound by memory) and runs ``W_out``'s product alone,
 whose output the layer's feed-forward reads.
 
+``MLA_LATENTS``: a latent-attention mixer's two down products
+(:func:`..layers.latent_attention.latent_attention`): ``h W_dq`` and
+``h W_dkv`` before their norms, float32 ``[T, q_lora_rank]`` and
+``[T, kv_lora_rank + rope]`` (25 MB and 19 MB a layer at 8,192 positions of
+768 and 576). What a product made is kept and the elementwise chain after it
+(the two latent norms, the split) is rebuilt: the rematerialised mixer runs
+the up products, the rotary pass and ``W_o`` again and neither down product.
+
 A layer that makes none of the named values is rematerialised whole: the
 other models' ``attention="xla"`` (a tile loop under JAX's own transpose,
 for tests and counting tools: nothing of it is kept, scores and
@@ -63,8 +71,9 @@ MOE_ROUTE = "moe_route"
 SPARSE_SELECTION = "sparse_selection"
 SPARSE_ATTN_RESIDUALS = "sparse_attn_residuals"
 SHORT_CONV_IN = "short_conv_in"
+MLA_LATENTS = "mla_latents"
 KEPT = (SPLASH_RESIDUALS, MOE_ROUTE, SPARSE_SELECTION, SPARSE_ATTN_RESIDUALS,
-        SHORT_CONV_IN)
+        SHORT_CONV_IN, MLA_LATENTS)
 
 
 def checkpoint_layer(layer):

@@ -25,7 +25,7 @@ APPLY = "de_apply"  # delta streams, XLA's scatter-add, the Pallas apply kernel
 ONEHOT = "de_onehot"  # a dense class's windowed one-hot MXU lookup; inside de_combine
 EXCHANGE = "de_exchange"  # the collectives of parallel/wire.py; inside de_route and de_combine
 INTERACT = "de_interact"  # models/dlrm.py::dot_interact; inside de_model
-ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/lfm2_moe.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE (layers/attention.py::rope), gate, attention under the model's mask description (layers/attention.py: attention_splash on a TPU, attention_xla elsewhere); inside de_model
+ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/lfm2_moe.py, models/glm_moe_lite.py: there layers/latent_attention.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE (layers/attention.py::rope), gate, attention under the model's mask description (layers/attention.py: attention_splash on a TPU, attention_xla elsewhere); inside de_model
 WINDOW_ATTENTION = "de_window_attention"  # models/laguna.py: the mixer of a sliding_attention layer (causal, same document, i - j < sliding_window); inside de_attention
 FULL_ATTENTION = "de_full_attention"  # models/laguna.py: the mixer of a full_attention layer (causal, same document); inside de_attention
 MOE = "de_moe"  # layers/moe.py::moe_share and shared_expert, the whole expert layer; inside de_model
@@ -37,6 +37,7 @@ LINEAR_ATTENTION = "de_linear_attention"  # models/olmo_hybrid.py: a gated-delta
 DELTA_RULE = "de_delta_rule"  # layers/gated_delta.py::chunk_gated_delta_rule, the chunked rule alone; inside de_linear_attention
 SPARSE_INDEX = "de_sparse_index"  # models/keye_sparse.py, layers/sparse_index.py: the learned indexer whole (its projections, scores, top-k and its own KL loss); inside de_attention
 MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_hybrid.py: every layer's; models/laguna.py and models/lfm2_moe.py: the leading dense layers'); inside de_model
+MTP = "de_mtp"  # models/glm_moe_lite.py::mtp_module, the multi-token-prediction module whole: the norms of its two inputs, W_eh, its decoder layer (with the scopes a layer has: its attention is ALSO under de_attention and its experts under de_moe), its final norm and the shared head (under de_lm_head); inside de_model
 SHORT_CONV = "de_short_conv"  # models/lfm2_moe.py, layers/short_conv.py: a double-gated short-convolution mixer whole (the input's norm, W_in, the gate B * u, the causal convolution with its reset, the gate C * c, W_out); inside de_model
 
 # Parts: always inside the child scope of their layer, one level finer: what an
@@ -53,6 +54,8 @@ ATTN_CORE = "de_attn_core"  # the call of attend(...), layers/attention.py::atte
 INDEX_SCORES = "de_index_scores"  # the indexer's projections, its key's LayerNorm, its rotary pass, the score product, the ReLU and the weighted sum over index heads (backward: the score again and the three products of its gradient); inside de_sparse_index
 INDEX_SELECT = "de_index_select"  # layers/sparse_index.py::select_topk and the packing of the mask: forward only, the plan keeps the selection; inside de_sparse_index
 INDEX_LOSS = "de_index_loss"  # the KL's target (the heads' mean of the main attention's probabilities: on a TPU the de_sparse_attn_mean kernel, once a direction), the indexer's softmax over the selection and the KL (backward: its gradient into the score); inside de_sparse_index
+MLA_DOWN = "de_mla_down"  # layers/latent_attention.py: the products with W_dq and W_dkv, the two latent norms and the split of the shared rotary key off the key-value latent; inside de_attention
+MLA_UP = "de_mla_up"  # layers/latent_attention.py: the products with W_uq and W_ukv, the split into a head's own and rotary parts, the broadcast of the shared rotary key to every head and the join; inside de_attention (W_o is de_attn_proj, the rotary pass and the scaling de_attn_qk, the kernel's call de_attn_core)
 MOE_ROUTER = "de_moe_router"  # layers/moe.py::route whole: the router's matmul, the scores, top_k, renormalisation; inside de_moe_route
 MOE_SORT = "de_moe_sort"  # the sort key, argsort, bincount, the cumulative sums, tok, p_sorted; inside de_moe_route
 MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h with its select (transposed: the scatter-add of the cotangent into h; on a TPU the head's is the kernel de_moe_combine, ops/pallas_moe_combine.py); inside de_moe_route
@@ -67,8 +70,8 @@ CHILDREN = (ONEHOT, EXCHANGE, INTERACT)
 # a language model's, all inside de_model (benchmark/scope_children*.py read them)
 LM_CHILDREN = (ATTENTION, MOE, MOE_ROUTE, MOE_EXPERTS, LM_HEAD,
                LINEAR_ATTENTION, DELTA_RULE, MLP, WINDOW_ATTENTION,
-               FULL_ATTENTION, MOE_SHARED, SPARSE_INDEX, SHORT_CONV)
+               FULL_ATTENTION, MOE_SHARED, SPARSE_INDEX, SHORT_CONV, MTP)
 # a language model's parts, each inside one of LM_CHILDREN
 PARTS = (ATTN_PROJ, ATTN_QK, ATTN_CORE, MOE_ROUTER, MOE_SORT, MOE_DISPATCH,
          MOE_RETURN, LINATTN_PROJ, LINATTN_CONV, INDEX_SCORES, INDEX_SELECT,
-         INDEX_LOSS, CONV_PROJ, CONV_GATE)
+         INDEX_LOSS, CONV_PROJ, CONV_GATE, MLA_DOWN, MLA_UP)

@@ -756,9 +756,10 @@ def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
     in-range by construction, so a nonzero counter means RAW ids leaked
     past the dynvocab translator — that batch must not train the clamp
     rows either.
-  - ``guard_metrics(ok, oov, overflow=None, head_counts=None)``: the
-    replicated ``{'bad_step', 'oov'}`` metrics dict (counters psum'd
-    across the mesh); with ``overflow`` (per-class dedup-capacity overflow
+  - ``guard_metrics(ok, oov, overflow=None, head_counts=None, terms=None)``:
+    the replicated ``{'bad_step', 'oov'}`` metrics dict (counters psum'd
+    across the mesh); with ``terms`` (a loss that names its terms:
+    ``forward_backward``) a ``'loss_terms'`` entry, their mesh mean; with ``overflow`` (per-class dedup-capacity overflow
     counts — plans with ``dedup_capacity`` set) a psum'd
     ``'dedup_overflow'`` entry joins it; with ``head_counts``
     (``engine.apply_head_counts``) an ``'apply_head_share'`` entry: per
@@ -788,8 +789,9 @@ def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
     return total == 0
 
   @jax.named_scope(scopes.DENSE_UPDATE)
-  def guard_metrics(ok, oov, overflow=None, head_counts=None):
+  def guard_metrics(ok, oov, overflow=None, head_counts=None, terms=None):
     if mesh is not None:
+      terms = jax.lax.pmean(terms, axis_name) if terms else terms
       oov = {n: jax.lax.psum(c, axis_name) for n, c in oov.items()}
       if overflow is not None:
         overflow = {n: jax.lax.psum(c, axis_name)
@@ -804,6 +806,8 @@ def _make_guard_helpers(plan: DistEmbeddingStrategy, mesh, axis_name: str):
       out["apply_head_share"] = {
           n: c[0].astype(jnp.float32) / jnp.maximum(c[1], 1)
           for n, c in head_counts.items()}
+    if terms:
+      out["loss_terms"] = terms
     return out
 
   return guard_gate, oov_ok, guard_metrics
@@ -832,8 +836,11 @@ def _make_train_step_pieces(engine: DistributedLookup, model,
   def forward_backward(state, fused, layouts, numerical, cats, labels,
                        keep_rows, rewrite_ids=None, local_grads=False):
     """``jax.value_and_grad`` of the loss w.r.t. (dense params, dense-class
-    tables, sparse activations). Returns ``(loss, (d_dense, d_emb_dense,
-    d_z), residuals, ids_all)``.
+    tables, sparse activations). Returns ``(loss, terms, (d_dense,
+    d_emb_dense, d_z), residuals, ids_all)``; ``terms`` is ``{}`` unless
+    ``loss_fn`` returned ``(loss, {name: scalar})``, the loss's terms by
+    name, which a guarded one-shot step reports as
+    ``metrics['loss_terms']`` (the micro-batch scan drops them).
 
     ``local_grads`` (the micro-batch scan): a varying zero, derived from
     the axis-varying labels, is added to the replicated param trees before
@@ -850,13 +857,15 @@ def _make_train_step_pieces(engine: DistributedLookup, model,
       logits = predict(dense_p, emb_dense, z_sp)
       with jax.named_scope(scopes.LOSS):
         loss = loss_fn(logits, labels)
+        # a loss of several terms may name them: (loss, {name: scalar})
+        loss, terms = loss if isinstance(loss, tuple) else (loss, {})
         if reg_fn is not None:
           # dense-kind tables' penalty (rank-local windows); scaled by world
           # to survive the uniform 1/world grad rescale of the dense update
           # — same convention as make_train_step
           scale = axis_size(axis_name) if mesh is not None else 1
           loss = loss + scale * reg_fn(emb_dense, rank)
-      return loss
+      return loss, terms
 
     dense, emb_dense = state["dense"], state["emb_dense"]
     if local_grads:
@@ -864,9 +873,10 @@ def _make_train_step_pieces(engine: DistributedLookup, model,
         vz = (jnp.sum(labels) * 0).astype(jnp.float32)
         dense, emb_dense = jax.tree_util.tree_map(
             lambda x: x + vz.astype(x.dtype), (dense, emb_dense))
-    loss, grads = jax.value_and_grad(loss_with, argnums=(0, 1, 2))(
-        dense, emb_dense, z_sparse)
-    return loss, grads, residuals, ids_all
+    (loss, terms), grads = jax.value_and_grad(
+        loss_with, argnums=(0, 1, 2), has_aux=True)(
+            dense, emb_dense, z_sparse)
+    return loss, terms, grads, residuals, ids_all
 
   @jax.named_scope(scopes.DENSE_UPDATE)
   def reduce_and_apply_dense(state, loss, d_dense, d_emb_dense, d_z=None,
@@ -917,7 +927,7 @@ def _make_train_step_pieces(engine: DistributedLookup, model,
 
   def commit(state, fused, layouts, loss, grads, dense_side, cats,
              ids_all=None, d_z=None, residuals=None, streams=None,
-             overflow=None):
+             overflow=None, terms=None):
     """Gate (guarded), apply the sparse update to ``fused``, assemble the
     new state. Returns ``(new_state, loss)``; guarded, ``(new_state, loss,
     metrics)``.
@@ -961,7 +971,7 @@ def _make_train_step_pieces(engine: DistributedLookup, model,
     new_state = {**dense_side, "fused": fused,
                  "step": step + (ok.astype(jnp.int32) if guard else 1)}
     if guard:
-      return new_state, loss, guard_metrics(ok, oov, overflow, heads)
+      return new_state, loss, guard_metrics(ok, oov, overflow, heads, terms)
     return new_state, loss
 
   return forward_backward, reduce_and_apply_dense, commit
@@ -1047,7 +1057,9 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
     model: flax module whose ``__call__(numerical, cats, emb_acts=None)``
       skips its ``DistributedEmbedding`` when ``emb_acts`` is given (DLRM
       and SyntheticModel do).
-    loss_fn: ``loss_fn(logits, labels) -> scalar`` (local-batch mean).
+    loss_fn: ``loss_fn(logits, labels) -> scalar`` (local-batch mean), or
+      ``-> (scalar, {name: scalar})`` where the loss names its terms (what
+      ``guard`` then reports).
     rule: :class:`SparseRule` (``sgd_rule`` / ``adagrad_rule``).
     exact: reproduce the reference's deduplicated backward exactly
       (sort-based; slower). Default False = per-occurrence semantics of
@@ -1077,7 +1089,10 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
       committed state is bit-identical to a run that never saw the
       batch. The step then returns ``(state, loss, metrics)`` with
       ``metrics = {'bad_step': int32 0/1, 'oov': {class: int32 count},
-      'apply_head_share': {sparse class: float32}}``
+      'apply_head_share': {sparse class: float32}}`` and, where
+      ``loss_fn`` returns ``(loss, {name: scalar})``, ``'loss_terms'``:
+      those terms, the mesh's mean (``models/glm_moe_lite.py``'s
+      ``next_token_loss`` and ``mtp_loss``)
       (OOV counters per the plan's ``oov`` policy, psum'd across
       devices; the share of the step's occurrences, over the whole mesh,
       that the apply kernel's VMEM-resident heads take; loss is the
@@ -1108,11 +1123,12 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
   has_dedup_cap = getattr(plan, "dedup_capacity", None) is not None
 
   def local_step(state, numerical, cats, labels):
-    loss, grads, residuals, ids_all = forward_backward(
+    loss, terms, grads, residuals, ids_all = forward_backward(
         state, state["fused"], layouts, numerical, cats, labels, keep_rows)
     loss, dense_side, d_z = reduce_and_apply_dense(state, loss, *grads)
     return commit(state, state["fused"], layouts, loss, grads[:2],
-                  dense_side, cats, ids_all, d_z=d_z, residuals=residuals)
+                  dense_side, cats, ids_all, d_z=d_z, residuals=residuals,
+                  terms=terms)
 
   def local_step_mb(state, numerical, cats, labels):
     n_mb = micro_batches
@@ -1124,7 +1140,7 @@ def make_sparse_train_step(model, plan: DistEmbeddingStrategy,
 
     def body(carry, mb):
       numerical_i, cats_i, labels_i = mb
-      loss_i, grads, residuals, ids_all = forward_backward(
+      loss_i, _, grads, residuals, ids_all = forward_backward(
           state, state["fused"], layouts, numerical_i, list(cats_i),
           labels_i, keep_rows, local_grads=True)
       with jax.named_scope(scopes.DENSE_UPDATE):
@@ -1261,13 +1277,13 @@ def make_tiered_train_step(model, tplan, loss_fn: Callable,
 
     fused_in = engine.install_staging(state["fused"], tier_specs,
                                       staged["rows"])
-    loss, grads, residuals, ids_all = forward_backward(
+    loss, terms, grads, residuals, ids_all = forward_backward(
         state, fused_in, layouts, numerical, cats, labels, keep_rows,
         rewrite_ids=to_slots)
     loss, dense_side, d_z = reduce_and_apply_dense(state, loss, *grads)
     new_state, loss, *guard_metrics = commit(
         state, fused_in, layouts, loss, grads[:2], dense_side, cats, ids_all,
-        d_z=d_z, residuals=residuals)
+        d_z=d_z, residuals=residuals, terms=terms)
     staged_out = engine.staged_regions(new_state["fused"], tier_specs,
                                        staged["grps"])
     new_state["fused"] = engine.trim_spill(new_state["fused"], tier_specs)

@@ -398,3 +398,59 @@ def test_the_bias_has_no_gradient_and_an_adam_step_leaves_it_bit_for_bit():
   assert np.array_equal(np.asarray(after["bias"]).view(np.uint32),
                         np.asarray(bias).view(np.uint32))
   assert not np.array_equal(after["router"], wr)
+
+
+# ---- all four fields at once, beside a shared expert -------------------------
+ALL_FOUR = Router("sigmoid", True, 1.8, selection_bias=True)
+
+
+def test_a_biased_scaled_sigmoid_router_beside_a_shared_expert():
+  """`Router("sigmoid", True, 1.8, selection_bias=True)` with
+  `shared_expert`, what `models/glm_moe_lite.py` sets: against the plain
+  equations written out (``s = sigmoid(h W_r)``; the top 2 of ``s + b``;
+  ``p_e = 1.8 s_e / sum of the chosen s``; every expert over every token;
+  the shared expert once), the eight shares and the shared expert counted
+  once adding up to them; the bias's gradient exactly zero, and one Adam step
+  leaves it bit for bit while the router and the shared expert move."""
+  h, wr, wg, wu, wd = _weights(7)
+  bias = jnp.asarray(np.random.default_rng(7).uniform(-0.2, 0.2, E),
+                     jnp.float32)
+  shared = (wg[0] * 0.5, wu[1] * 0.5, wd[2] * 0.5)
+  swiglu = lambda h, a, b, c: (jax.nn.silu(h @ a) * (h @ b)) @ c
+  with jax.default_matmul_precision("highest"):
+    s = jax.nn.sigmoid(h @ wr)
+    _, top_e = jax.lax.top_k(s + bias, K)
+    chosen = jnp.sum(jax.nn.one_hot(top_e, E), axis=1)
+    p = 1.8 * s * chosen / jnp.sum(s * chosen, axis=-1, keepdims=True)
+    whole = sum(p[:, e, None] * swiglu(h, wg[e], wu[e], wd[e])
+                for e in range(E)) + swiglu(h, *shared)
+    routed = [moe_share(h, wr, wg[f:f + 4], wu[f:f + 4], wd[f:f + 4],
+                        MoEShare(E, K, (f, 4), ALL_FOUR), bias)[0]
+              for f in range(0, E, 4)]
+    once = shared_expert(h, *shared)
+  np.testing.assert_allclose(jnp.sum(p, axis=-1), 1.8, rtol=1e-6)
+  assert np.any(np.asarray(top_e) != np.asarray(jax.lax.top_k(s, K)[1]))
+  np.testing.assert_allclose(sum(routed) + once, whole, atol=2e-5)
+  # the shared expert counted once a chip is another layer
+  assert float(jnp.max(jnp.abs(once))) > 0.1
+  # what route itself returns is that p, to rounding
+  top_p, got_e = route(h, wr, K, ALL_FOUR, bias)
+  assert np.array_equal(got_e, top_e)
+  np.testing.assert_allclose(
+      top_p, jnp.take_along_axis(p, top_e, axis=-1), rtol=1e-5)
+
+  share = MoEShare(E, K, (8, 8), ALL_FOUR)
+  params = {"router": wr, "bias": bias, "w_gate": wg[8:16], "w_up": wu[8:16],
+            "w_down": wd[8:16], "shared": shared}
+  loss = lambda q: jnp.sum(jnp.sin(
+      moe_share(h, q["router"], q["w_gate"], q["w_up"], q["w_down"], share,
+                q["bias"])[0] + shared_expert(h, *q["shared"])))
+  grads = jax.jit(jax.grad(loss))(params)
+  assert not np.asarray(grads["bias"]).any()     # exactly zero
+  tx = optax.adam(1e-2)
+  updates, _ = tx.update(grads, tx.init(params), params)
+  after = optax.apply_updates(params, updates)
+  assert np.array_equal(np.asarray(after["bias"]).view(np.uint32),
+                        np.asarray(bias).view(np.uint32))
+  assert not np.array_equal(after["router"], wr)
+  assert not np.array_equal(after["shared"][0], shared[0])

@@ -19,12 +19,14 @@ from jax.ad_checkpoint import print_saved_residuals
 
 from distributed_embeddings_tpu.layers import (
     attention,
+    latent_attention,
     remat,
     short_conv,
     sparse_index,
 )
 from distributed_embeddings_tpu.layers.moe import MoEShare, Router, moe_share
 from distributed_embeddings_tpu.models import (
+    glm_moe_lite,
     keye_sparse,
     laguna,
     lfm2_moe,
@@ -70,13 +72,24 @@ TOYS = {
         num_experts=16, num_experts_per_tok=4, layers_here=(1, 2, 3),
         vocab_size=50, experts_held=(4, 4), seq_len=24,
         mean_document_length=8, attention="xla")),
+    # published layers 0, 1 (dense, experts) and the prediction module
+    "glm_moe_lite": (glm_moe_lite, glm_moe_lite.GlmMoeLite,
+                     glm_moe_lite.GlmMoeLiteConfig(
+                         hidden_size=32, intermediate_size=48,
+                         moe_intermediate_size=12, num_attention_heads=4,
+                         q_lora_rank=10, kv_lora_rank=8, qk_nope_head_dim=6,
+                         qk_rope_head_dim=4, v_head_dim=14,
+                         n_routed_experts=16, num_experts_per_tok=4,
+                         layers_here=(0, 1), vocab_size=50,
+                         experts_held=(4, 4), seq_len=24,
+                         mean_document_length=8, attention="xla")),
 }
 
 
 def _layers(cfg):
-  """How many decoder layers the toy runs."""
+  """How many decoder layers the toy runs (a prediction module is one)."""
   if hasattr(cfg, "layers_here"):
-    return len(cfg.layers_here)
+    return len(cfg.layers_here) + getattr(cfg, "num_nextn_predict_layers", 0)
   return len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
 
 
@@ -96,8 +109,14 @@ def _case(model_cls, cfg, batch=2, seed=0):
 
 
 def _loss(model_cls, cfg, numerical):
-  return lambda params, rows: jnp.sum(jnp.sin(model_cls(cfg).apply(
-      {"params": params}, numerical, None, emb_acts=[rows])["logits"]))
+  def loss(params, rows):
+    out = model_cls(cfg).apply({"params": params}, numerical, None,
+                               emb_acts=[rows])
+    total = jnp.sum(jnp.sin(out["logits"]))
+    if "mtp_logits" in out:     # a prediction module's leaves are reached
+      total = total + jnp.sum(jnp.sin(out["mtp_logits"]))
+    return total
+  return loss
 
 
 def _count(jaxpr, wanted, skip=()):
@@ -141,9 +160,12 @@ def test_gradients_under_the_plan_are_those_with_no_checkpoint(
   op, outside `jit`). A checkpointed layer is still compiled as one program,
   whose fusions order the recurrent layers' sums otherwise: Olmo-Hybrid's toy
   is compared in float64 (in float32 it amplifies rounding a thousandfold:
-  `tests/test_olmo_hybrid.py`), to 1e-12 of a leaf's largest value."""
+  `tests/test_olmo_hybrid.py`), to 1e-12 of a leaf's largest value. So is
+  GLM's: under the plan its float32 gradients differ from the plain ones in
+  the last bits (1e-6 of a leaf) and in float64 agree to 1e-12, which is what
+  sums taken in another order read, not another formula."""
   module, model_cls, cfg = TOYS[name]
-  exact = name != "olmo_hybrid"
+  exact = name not in ("olmo_hybrid", "glm_moe_lite")
   with jax.enable_x64(not exact):
     params, numerical, rows = _case(model_cls, cfg)
     if not exact:
@@ -190,8 +212,10 @@ def _splash_layer(name):
   Pallas's interpreter, a projection before it (so that ``q``, ``k``, ``v``
   are rebuilt, not arguments) -> (loss(w, x), w, x, layers)."""
   rng = np.random.default_rng(0)
-  # LFM2's head is 64, half a lane tile; the others' 128
-  length, hkv, group, hd = 128, 1, 2, 64 if name == "lfm2_moe" else 128
+  # LFM2's head is 64, half a lane tile, GLM's 256 with no group; the
+  # others' 128
+  length, hkv, group = 128, 1, 2
+  hd = {"lfm2_moe": 64, "glm_moe_lite": 256}.get(name, 128)
   x = jnp.asarray(rng.normal(size=(1, length, 32)), jnp.float32)
   w = jnp.asarray(rng.normal(size=(2, 32, (group + 2) * hkv * hd)) * 0.2,
                   jnp.float32)
@@ -291,6 +315,43 @@ def test_a_convolution_layer_rebuilds_its_mixers_smaller_product(capsys):
   for g, w in zip(jax.tree_util.tree_leaves(got),
                   jax.tree_util.tree_leaves(want)):
     assert np.array_equal(g, w)
+
+
+def test_a_latent_layer_keeps_its_latents_and_rebuilds_the_rest(capsys):
+  """A layer of latent attention and a dense MLP (`models/glm_moe_lite.py`).
+  The plan keeps what the two down products made (`remat.MLA_LATENTS`:
+  ``h W_dq`` and ``h W_dkv`` before their norms), so the rebuilt forward
+  runs two products fewer than a bare checkpoint's, which runs both again;
+  the latent norms, the up products and the rotary pass are rebuilt either
+  way."""
+  _, _, cfg = TOYS["glm_moe_lite"]
+  rng = np.random.default_rng(0)
+  p = {n: jnp.asarray(rng.normal(size=shape) * 0.3, jnp.float32)
+       for n, (shape, _) in glm_moe_lite.layer_shapes(
+           cfg, glm_moe_lite.DENSE).items()}
+  x = jnp.asarray(rng.normal(size=(2, cfg.seq_len, cfg.hidden_size)),
+                  jnp.float32)
+  seg = jnp.asarray(np.arange(cfg.seq_len)[None, :] >= 9, jnp.int32) \
+      * jnp.ones((2, 1), jnp.int32)
+  layer = lambda p, x: glm_moe_lite.decoder_layer(
+      cfg, glm_moe_lite.DENSE, p, x, seg)[0]
+  loss = lambda wrap: lambda p, x: jnp.sum(jnp.sin(wrap(layer)(p, x)))
+  products = lambda wrap: _count(jax.make_jaxpr(jax.grad(
+      loss(wrap), argnums=(0, 1)))(p, x).jaxpr, _primitive("dot_general"))
+  assert products(jax.checkpoint) - products(remat.checkpoint_layer) == 2
+  assert products(remat.checkpoint_layer) > products(lambda f: f)
+  latents = [f"f32[2,{cfg.seq_len},{cfg.q_lora_rank}]",
+             f"f32[2,{cfg.seq_len},{cfg.kv_lora_rank + cfg.qk_rope_head_dim}]"]
+  kept = _residuals(capsys, loss(remat.checkpoint_layer), p, x)
+  bare = _residuals(capsys, loss(jax.checkpoint), p, x)
+  for latent in latents:
+    assert kept.count(latent) == 1 and latent not in bare
+  got = jax.grad(loss(remat.checkpoint_layer), argnums=(0, 1))(p, x)
+  want = jax.grad(loss(lambda f: f), argnums=(0, 1))(p, x)
+  # not to the bit (the test above holds the whole model in float64)
+  for g, w in zip(jax.tree_util.tree_leaves(got),
+                  jax.tree_util.tree_leaves(want)):
+    np.testing.assert_allclose(g, w, atol=1e-5 * float(jnp.max(jnp.abs(w))))
 
 
 def _moe_weights(seed):
@@ -526,9 +587,11 @@ def test_kept_is_what_the_code_names():
   assert set(remat.KEPT) == {remat.SPLASH_RESIDUALS, remat.MOE_ROUTE,
                              remat.SPARSE_SELECTION,
                              remat.SPARSE_ATTN_RESIDUALS,
-                             remat.SHORT_CONV_IN}
+                             remat.SHORT_CONV_IN, remat.MLA_LATENTS}
   # a short convolution's first product is named where it is made
   assert inspect.getsource(short_conv).count(", SHORT_CONV_IN)") == 1
+  # a latent-attention mixer's two down products likewise
+  assert inspect.getsource(latent_attention).count(", MLA_LATENTS)") == 2
   # the two a learned indexer's attention names are made in one place
   source = inspect.getsource(sparse_index)
   assert source.count("SPARSE_SELECTION)") == 1
@@ -546,6 +609,7 @@ def test_kept_is_what_the_code_names():
   assert not [f.name for cfg in (sdar_moe.SDARMoEConfig, laguna.LagunaConfig,
                                  olmo_hybrid.OlmoHybridConfig,
                                  keye_sparse.KeyeSparseConfig,
-                                 lfm2_moe.Lfm2MoeConfig)
+                                 lfm2_moe.Lfm2MoeConfig,
+                                 glm_moe_lite.GlmMoeLiteConfig)
               for f in dataclasses.fields(cfg)
               if "remat" in f.name or "checkpoint" in f.name]

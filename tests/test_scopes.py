@@ -3,7 +3,7 @@
 DLRM and the toy zoo step, at world 1 and on a mesh of four virtual devices.
 And the parts of a language-model step (``scopes.PARTS``): where each lies,
 in which passes, and what a rematerialised layer does not run again, on the
-LOWERED text of the five toy models' steps (the CPU's compiler merges a
+LOWERED text of the six toy models' steps (the CPU's compiler merges a
 rebuilt op with its forward twin; the TPU's barrier forbids that).
 """
 
@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import optax
 import pytest
 
+import test_glm_moe_lite
 import test_keye_sparse
 import test_laguna
 import test_lfm2_moe
@@ -29,6 +30,7 @@ from distributed_embeddings_tpu.models import (
     DLRM,
     SyntheticModel,
     bce_loss,
+    glm_moe_lite,
     keye_sparse,
     laguna,
     lfm2_moe,
@@ -172,7 +174,7 @@ def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
 def test_the_vocabulary_is_one_flat_set_of_names():
   names = scopes.TOP_LEVEL + scopes.CHILDREN + scopes.LM_CHILDREN \
       + scopes.PARTS
-  assert len(set(names)) == len(names) == 37
+  assert len(set(names)) == len(names) == 40
   for n in names:
     assert n.startswith("de_") and "/" not in n and "(" not in n
   declared = {v for k, v in vars(scopes).items()
@@ -182,8 +184,10 @@ def test_the_vocabulary_is_one_flat_set_of_names():
 
 # ---- the parts of a language-model step ---------------------------------------
 # the toy models of tests/test_sdar_moe.py, test_olmo_hybrid.py, test_laguna.py,
-# test_keye_sparse.py, test_lfm2_moe.py
+# test_keye_sparse.py, test_lfm2_moe.py, test_glm_moe_lite.py
 LM_TOYS = {
+    "glm_moe_lite": (glm_moe_lite.GlmMoeLite, glm_moe_lite.mtp_training_loss,
+                     test_glm_moe_lite.TOY),
     "lfm2_moe": (lfm2_moe.Lfm2Moe, next_token_loss, test_lfm2_moe.TOY),
     "sdar_moe": (sdar_moe.SDARMoE, sdar_moe.block_diffusion_loss,
                  dataclasses.replace(test_sdar_moe.TOY, num_experts=8,
@@ -208,11 +212,14 @@ INSIDE = {
     scopes.INDEX_SCORES: scopes.SPARSE_INDEX,
     scopes.INDEX_SELECT: scopes.SPARSE_INDEX,
     scopes.INDEX_LOSS: scopes.SPARSE_INDEX,
-    scopes.CONV_PROJ: scopes.SHORT_CONV, scopes.CONV_GATE: scopes.SHORT_CONV}
+    scopes.CONV_PROJ: scopes.SHORT_CONV, scopes.CONV_GATE: scopes.SHORT_CONV,
+    scopes.MLA_DOWN: scopes.ATTENTION, scopes.MLA_UP: scopes.ATTENTION}
 INDEX_PARTS = (scopes.INDEX_SCORES, scopes.INDEX_SELECT, scopes.INDEX_LOSS)
 ROUTE_PARTS = (scopes.MOE_ROUTER, scopes.MOE_SORT, scopes.MOE_DISPATCH,
                scopes.MOE_RETURN)
 PARTS_OF = {
+    "glm_moe_lite": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE,
+                     scopes.MLA_DOWN, scopes.MLA_UP) + ROUTE_PARTS,
     "sdar_moe": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
     + ROUTE_PARTS,
     "olmo_hybrid": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE,
@@ -243,6 +250,8 @@ def _lowered(name):
   numerical = jnp.full((batch, getattr(cfg, "n_numerical", cfg.seq_len)),
                        0.5, jnp.float32)
   labels = {"targets": cats}
+  if name == "glm_moe_lite":     # its prediction module's, two ahead
+    labels["targets_2"] = cats
   plan = DistEmbeddingStrategy(
       [TableConfig(cfg.vocab_size, cfg.hidden_size, combiner=None)], 1,
       "memory_balanced", input_table_map=[0], dense_row_threshold=0,
@@ -261,6 +270,13 @@ def _lowered(name):
 def _lowered_step(name) -> str:
   """:func:`_lowered` as text, with every op's name stack."""
   return _lowered(name).as_text(debug_info=True)
+
+
+def _logit_sum(out):
+  """A scalar of a model's outputs that reaches every leaf: the logits' sum
+  and, where there is a prediction module, its logits' too."""
+  total = jnp.sum(out["logits"])
+  return total + jnp.sum(out["mtp_logits"]) if "mtp_logits" in out else total
 
 
 def _components(name_stack: str):
@@ -353,6 +369,50 @@ def test_the_two_conv_parts_partition_the_mixer_but_for_its_norm(lm_stacks):
           if scopes.CONV_PROJ in _components(s)} >= {"dot_general"}
 
 
+def test_the_latent_parts_and_the_prediction_module_lie_where_the_table_says(
+    lm_stacks):
+  """Under ``de_attention`` of the latent mixer an op lies under at most one
+  of the five parts, the two latent parts hold the four latent products
+  between them, and the rebuilt forward runs no down product (the plan keeps
+  the latents). Everything under ``de_mtp`` lies inside ``de_model`` (the
+  module with a whole layer's scopes beneath it, its head under
+  ``de_lm_head``) or inside ``de_loss`` (its cross-entropy)."""
+  name, stacks = lm_stacks
+  module = [s for s in stacks if scopes.MTP in _components(s)]
+  assert bool(module) == (name == "glm_moe_lite")
+  if not module:
+    assert not any(p in _components(s) for s in stacks
+                   for p in (scopes.MLA_DOWN, scopes.MLA_UP))
+    return
+  for stack in module:
+    names = _components(stack)
+    before = names[:names.index(scopes.MTP)]
+    assert (scopes.MODEL in before) != (scopes.LOSS in before), stack
+  beneath = {n for s in module for n in _components(s)
+             if n.startswith("de_")}
+  assert {scopes.ATTENTION, scopes.MLA_DOWN, scopes.MLA_UP, scopes.ATTN_CORE,
+          scopes.MOE, scopes.MOE_ROUTER, scopes.MOE_SHARED,
+          scopes.LM_HEAD, scopes.LOSS} <= beneath
+  assert scopes.MLP not in beneath        # its layer is an expert layer
+  # the trunk's head lies under de_lm_head and not under de_mtp
+  assert any(scopes.LM_HEAD in _components(s)
+             and scopes.MTP not in _components(s) for s in stacks)
+  products = collections.Counter()
+  for stack in stacks:
+    names = _components(stack)
+    if scopes.ATTENTION not in names:
+      continue
+    assert sum(p in names for p in PARTS_OF[name][:5]) <= 1, stack
+    if names[-1] == "dot_general":
+      part = [p for p in (scopes.MLA_DOWN, scopes.MLA_UP) if p in names]
+      products[(part[0] if part else None, _pass_of(stack))] += 1
+  assert products[(scopes.MLA_DOWN, "forward")] > 0
+  assert products[(scopes.MLA_UP, "rebuilt")] > 0
+  assert products[(scopes.MLA_DOWN, "rebuilt")] == 0
+  assert any(_pass_of(s) == "rebuilt" and scopes.MLA_DOWN in _components(s)
+             for s in stacks)               # the latent norms are rebuilt
+
+
 def test_every_part_has_ops_in_all_three_passes(lm_stacks):
   """Every decoder layer runs under ``checkpoint_layer``, so what a part
   holds is traced forward, rebuilt and backward."""
@@ -383,6 +443,9 @@ def test_what_the_plan_keeps_is_not_rebuilt_under_its_part(lm_stacks):
 
 
 SPLASH_TOYS = {
+    # the published head: 192 + 64 for the scores, 256 for the values
+    "glm_moe_lite": dict(qk_nope_head_dim=192, qk_rope_head_dim=64,
+                         v_head_dim=256, seq_len=128),
     "sdar_moe": dict(head_dim=128, seq_len=64),
     "olmo_hybrid": dict(head_dim=128, seq_len=128, chunk=64),
     "laguna": dict(head_dim=128, seq_len=128),
@@ -407,8 +470,8 @@ def test_no_splash_forward_kernel_is_called_in_a_rebuilt_core(
                        jnp.float32)
   params = jax.eval_shape(lambda: model.init(
       jax.random.PRNGKey(0), numerical, None, emb_acts=[rows]))["params"]
-  grad = jax.value_and_grad(lambda p, r: jnp.sum(model.apply(
-      {"params": p}, numerical, None, emb_acts=[r])["logits"]))
+  grad = jax.value_and_grad(lambda p, r: _logit_sum(model.apply(
+      {"params": p}, numerical, None, emb_acts=[r])))
   text = jax.jit(grad).trace(params, rows).lower(
       lowering_platforms=("tpu",)).as_text(debug_info=True)
   locs = dict(_LOC.findall(text))
@@ -422,7 +485,9 @@ def test_no_splash_forward_kernel_is_called_in_a_rebuilt_core(
   assert forward
   sites = [locs[ref] for callee, ref in re.findall(
       r"call @([\w.]+)\(.*loc\((#loc\d+)\)", text) if callee in forward]
-  if name == "lfm2_moe":
+  if name == "glm_moe_lite":      # the trunk's layers and the module's
+    attention_layers = len(cfg.layers_here) + 1
+  elif name == "lfm2_moe":
     attention_layers = sum(mixer == lfm2_moe.FULL for mixer, _ in cfg.kinds)
   else:
     layers = len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
@@ -451,8 +516,8 @@ def test_the_parts_add_no_equation(name, monkeypatch):
 
   def jaxpr():
     # a function object each: `make_jaxpr` remembers what it traced
-    grad = jax.value_and_grad(lambda p, r: jnp.sum(model.apply(
-        {"params": p}, numerical, None, emb_acts=[r])["logits"]))
+    grad = jax.value_and_grad(lambda p, r: _logit_sum(model.apply(
+        {"params": p}, numerical, None, emb_acts=[r])))
     return re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(grad)(
         params, rows)))
 

@@ -15,6 +15,9 @@ real size the CPU takes a minute or two a batch; ``--layers 1`` shortens it):
   JAX_PLATFORMS=cpu python tools/moe_load.py sdar_moe_train_1chip [--seed N]
   JAX_PLATFORMS=cpu python tools/moe_load.py laguna_moe_train_1chip --seed N
   JAX_PLATFORMS=cpu python tools/moe_load.py lfm2_moe_train_1chip --seed N
+  JAX_PLATFORMS=cpu python tools/moe_load.py glm_mla_train_1chip --seed N
+
+(the last: the trunk's four expert layers, then the prediction module's).
 """
 
 import argparse
@@ -78,8 +81,8 @@ def main(argv=None):
   moe = jax.tree_util.tree_map(np.asarray, out["moe"])
   # a block-diffusion model runs the noisy copy beside the clean one
   positions = int(batch.cats.size) * (2 if "masked" in out else 1)
-  expected = positions * config.num_experts_per_tok \
-      * config.experts_held[1] / config.num_experts
+  share = config.share
+  expected = positions * share.top_k * share.held[1] / share.num_experts
   report = {
       "cell": args.cell, "seed": args.seed,
       "backend": jax.default_backend(),
@@ -96,7 +99,7 @@ def main(argv=None):
     report["masked_share"] = float(np.mean(np.asarray(out["masked"])))
   if "moved" in moe:
     report["moved_share"] = [
-        round(float(m) / (positions * config.num_experts_per_tok), 4)
+        round(float(m) / (positions * share.top_k), 4)
         for m in moe["moved"]]
   print(json.dumps(report))
   return report
