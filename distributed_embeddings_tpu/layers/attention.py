@@ -9,10 +9,11 @@ group: the layout is read off ``q``'s rank.
 
 :func:`attention_splash` is JAX's splash-attention kernel, what every cell
 runs: a TPU kernel that never holds ``[S, S]`` scores and skips the blocks of
-keys the mask empties. :func:`attention_xla` is the same attention in XLA, a
-tile of queries at a time: tests, counting tools on any backend, and the
-kernel's oracle. A configuration's ``attention`` names one
-(:func:`attention_path`).
+keys the mask empties and, over packed documents, those that hold another
+document's keys alone (:func:`documents_in_block_maps`).
+:func:`attention_xla` is the same attention in XLA, a tile of queries at a
+time: tests, counting tools on any backend, and the kernel's oracle. A
+configuration's ``attention`` names one (:func:`attention_path`).
 
 A mask description (:class:`Causal`, :class:`Window`, :class:`BlockDiffusion`:
 frozen, hashable) answers two questions and nothing else: ``splash(length)``,
@@ -164,29 +165,100 @@ def _splash_kernel(mask, length: int, heads: int, grouped: bool, block: int,
   return jax.tree_util.tree_map(np.asarray, kernel)
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def documents_in_block_maps(kernel, seg, block: int):
+  """``kernel`` (:func:`_splash_kernel`'s) with one sample's documents folded
+  into its block maps: of the grid steps the static mask keeps, those whose
+  block of queries and block of keys share no document are skipped too, so
+  the block is neither fetched nor multiplied. ``seg [S]`` does not decrease
+  along a packed sequence (``layers/decoder.py::document_segments``), so a
+  block holds every document from its first position's to its last's, and
+  two blocks share one where those ranges meet. The maps become arrays of
+  the device, which the kernels take by scalar prefetch as they take the
+  static ones; what a step computes inside a live block is untouched (the
+  segment ids still mask it by element)."""
+  ends = seg.reshape(-1, min(block, seg.shape[0]))
+  first, last = ends[:, 0], ends[:, -1]
+  shared = (last[:, None] >= first[None, :]) \
+      & (last[None, :] >= first[:, None])           # [blocks, blocks]
+
+  def fold(info, steps: int):
+    """``steps``: the axis a kernel walks, keys (2) in the forward and dq,
+    queries (1) in dkv; the other holds every block it keeps resident, a
+    row here. A step's partner block is the one ``data_next`` names, not the
+    step's place: a shrunk grid left-aligns a row's live steps. Written as
+    comparisons and reductions over ``[rows, steps, steps]``, which XLA
+    fuses (5 to 14 microseconds a layer on a v5e where gathers and
+    cumulative maxima took 10 to 50: PERF.md, PR 48)."""
+    by_row = (lambda x: jnp.swapaxes(x, 1, 2)) if steps == 1 else (lambda x: x)
+    static, fetch = by_row(info.block_mask), by_row(info.data_next)
+    partner = fetch[..., None] == jnp.arange(len(first))
+    together = jnp.any(partner & shared[:, None, :], axis=-1)
+    live = (static > 0) & together
+    # a dead step names the block of the nearest live step before it in its
+    # row (after it, for those a row starts with: the rule, and the reason,
+    # of ops/pallas_sparse_attn.py::block_plan), so it starts no copy
+    at = jnp.arange(static.shape[-1])
+    upto = at[:, None] >= at                       # [step, a step up to it]
+    before = jnp.max(jnp.where(live[..., None, :] & upto, at, -1), axis=-1)
+    after = jnp.min(jnp.where(live[..., None, :] & ~upto, at, len(at)),
+                    axis=-1)
+    near = jnp.where(before >= 0, before, jnp.where(after < len(at), after, at))
+    named = jnp.sum(jnp.where(near[..., None] == at, fetch[..., None, :], 0),
+                    axis=-1)
+    # a row that loses no step keeps the static map's names
+    lost = jnp.any((static > 0) & ~together, axis=-1, keepdims=True)
+    return info._replace(
+        block_mask=by_row(jnp.where(live, static, 0).astype(static.dtype)),
+        data_next=by_row(jnp.where(lost, named, fetch).astype(fetch.dtype)))
+
+  (fwd, dq, dkv), static = kernel.tree_flatten()
+  return type(kernel).tree_unflatten(
+      static, (fold(fwd, 2), fold(dq, 2), fold(dkv, 1)))
+
+
 def attention_splash(q, k, v, mask, seg=None, block: int = ATTENTION_BLOCK,
                      interpret: bool = False):
   """``q``, ``k``, ``v`` in either layout -> ``q``'s shape, through the
   splash-attention kernel under ``mask`` and, where ``seg`` is given, inside
-  the query's document (the kernel's segment ids). Its operands are rounded
-  to bfloat16, which is what the MXU's default precision makes of a float32
-  operand; scores, softmax and accumulation are float32. For its backward the
-  kernel keeps its output and the scores' log-sum-exp, under the name
-  ``SPLASH_RESIDUALS``: a layer rematerialised by ``checkpoint_layer`` runs
-  the forward kernel once. ``interpret``: Pallas's interpreter (tests)."""
+  the query's document: the kernel's segment ids, and its block maps with
+  the sample's documents folded in (:func:`documents_in_block_maps`), so
+  another document's blocks are not walked; ``seg`` does not decrease along
+  a sequence. Its operands are rounded to bfloat16, which is what the MXU's
+  default precision makes of a float32 operand; scores, softmax and
+  accumulation are float32. For its backward the kernel keeps its output and
+  the scores' log-sum-exp, under the name ``SPLASH_RESIDUALS``: a layer
+  rematerialised by ``checkpoint_layer`` runs the forward kernel once.
+  ``interpret``: Pallas's interpreter (tests)."""
   from jax.experimental.pallas.ops.tpu import splash_attention as sa
   grouped = q.ndim == 5
   kernel = _splash_kernel(mask, q.shape[1], q.shape[3 if grouped else 2],
                           grouped, block, interpret)
 
-  def call(q, k, v, s):
-    return kernel(q, k, v, segment_ids=None if s is None
-                  else sa.SegmentIds(q=s, kv=s))
+  def samples(q, k, v, s):
+    """The samples that share ``s``: one where it is given, all of them
+    where it is ``None``."""
+    if s is None:
+      call = lambda q, k, v: kernel(q, k, v, segment_ids=None)
+    else:
+      planned = documents_in_block_maps(kernel, s, block)
+      call = lambda q, k, v: planned(
+          q, k, v, segment_ids=sa.SegmentIds(q=s, kv=s))
+    # grouped: a key-value head at a time, all under their sample's ids
+    return jax.vmap(jax.vmap(call) if grouped else call)(q, k, v)
 
-  # grouped: a key-value head at a time, all under their sample's ids
-  sample = jax.vmap(call, in_axes=(0, 0, 0, None)) if grouped else call
   heads_first = lambda x: jnp.moveaxis(x, 1, -2).astype(jnp.bfloat16)
-  out = jax.vmap(sample)(heads_first(q), heads_first(k), heads_first(v), seg)
+  operands = heads_first(q), heads_first(k), heads_first(v)
+  if seg is None:
+    out = samples(*operands, None)
+  else:
+    # a sample at a time, each still under a ``vmap`` of its own: its maps
+    # are the kernels' scalar-prefetch operands, over which ``vmap`` is a
+    # loop inside the program, and without the leading axis XLA relays the
+    # kernel's log-sum-exp out whole (PERF.md, PR 48)
+    out = jnp.concatenate([
+        samples(*(x[b:b + 1] for x in operands), seg[b])
+        for b in range(q.shape[0])])
   return jnp.moveaxis(out, -2, 1).astype(q.dtype)
 
 

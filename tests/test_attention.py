@@ -3,11 +3,14 @@ faces against each other and against a dense mask written by hand; the XLA
 tile loop against attention by whole scores, mask by layout by tile; the
 splash kernel in Pallas's interpreter against the tile loop on the same
 operands rounded to bfloat16, and its lowering for the TPU at every published
-head shape; the rotary pass by hand; and that no decoder and no layer imports
-a model."""
+head shape; the documents folded into the kernel's block maps against a count
+of same-document pairs a block, and the kernel under those maps against the
+kernel under the static ones, bit for bit; the rotary pass by hand; and that
+no decoder and no layer imports a model."""
 
 import ast
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +18,7 @@ import numpy as np
 import pytest
 
 import reference_sdar_moe
+from distributed_embeddings_tpu.layers import attention
 from distributed_embeddings_tpu.layers.attention import (
     BlockDiffusion,
     Causal,
@@ -24,6 +28,7 @@ from distributed_embeddings_tpu.layers.attention import (
     rope,
     rope_frequencies,
 )
+from distributed_embeddings_tpu.layers.decoder import document_segments
 
 PACKAGE = pathlib.Path(__file__).parent.parent / "distributed_embeddings_tpu"
 
@@ -242,6 +247,187 @@ def test_the_splash_path_lowers_for_the_tpu_at_published_head_shapes(
   for part in ("fwd", "dq", "dkv"):
     assert f"splash_{kernel}_{part}" in text
   assert text.count("tpu_custom_call") >= 3
+
+
+# ---- the documents in the kernel's block maps -------------------------------
+PASSES = ("fwd", "dq", "dkv")
+
+
+def _segments(length, starts_by_sample):
+  """``[B, length]`` int32: the documents' numbers where sample ``b``'s start
+  at 0 and at ``starts_by_sample[b]``."""
+  starts = np.zeros((len(starts_by_sample), length), bool)
+  starts[:, 0] = True
+  for b, at in enumerate(starts_by_sample):
+    starts[b, list(at)] = True
+  return jnp.asarray(np.cumsum(starts, axis=1) - 1, jnp.int32)
+
+
+def _steps_by_row(info, which):
+  """A pass's ``block_mask`` and ``data_next`` as ``[rows, steps]``: a row is
+  the block a kernel holds (queries; keys in dkv) and walks its steps."""
+  turned = (lambda x: np.asarray(x)[0].T) if which == "dkv" \
+      else (lambda x: np.asarray(x)[0])
+  return turned(info.block_mask), turned(info.data_next)
+
+
+def test_the_documents_numbers_do_not_decrease_along_a_sequence():
+  """What `documents_in_block_maps` rests on: a block holds every document
+  from its first position's to its last's."""
+  u = np.random.default_rng(3).uniform(size=(4, 4096)).astype(np.float32)
+  seg = np.asarray(document_segments(jnp.asarray(u), 256))
+  steps = np.diff(seg, axis=1)
+  assert (steps >= 0).all() and (steps <= 1).all()
+  assert (seg[:, 0] == 0).all() and seg.max() > 8
+
+
+@pytest.mark.parametrize("which", PASSES)
+@pytest.mark.parametrize("mask,length,block", [
+    (Causal(), 4096, 512), (Window(512), 4096, 512),
+    (BlockDiffusion(4), 2048, 256)], ids=str)
+def test_the_planned_maps_against_a_count_of_pairs(mask, length, block, which):
+  """On seeded document starts (one on a block's first position each time):
+  a step the plan kills holds no same-document pair the mask allows, a step
+  it keeps joins two blocks that share a document (and under `Causal` and
+  `Window` then holds such a pair); a row that loses a step names, at every
+  dead step, the block of the nearest live step before it (after it for
+  those the row starts with), so consecutive dead steps name one block; a
+  row that loses none is as it was; dtypes and the other leaves are kept."""
+  grouped = not isinstance(mask, Causal)        # both factories
+  kernel = attention._splash_kernel(mask, length, 2, grouped, block, True)
+  dense = np.asarray(mask.splash(length)[:, :])
+  n, killed_in_all = length // block, 0
+  for seed in range(6):
+    rng = np.random.default_rng(seed)
+    starts = np.flatnonzero(rng.uniform(size=length) < 4.0 / length)
+    starts = np.union1d(starts, [block * int(rng.integers(1, n))])
+    seg = _segments(length, [starts])[0]
+    planned = attention.documents_in_block_maps(kernel, seg, block)
+    s = np.asarray(seg)
+    pairs = (dense & (s[:, None] == s[None, :])).reshape(
+        n, block, n, block).any(axis=(1, 3))
+    first, last = s[::block], s[block - 1::block]
+    share = (last[:, None] >= first[None, :]) \
+        & (last[None, :] >= first[:, None])
+    if which == "dkv":
+      pairs = pairs.T
+    info = getattr(kernel, f"{which}_mask_info")
+    got = getattr(planned, f"{which}_mask_info")
+    for name in info._fields:
+      before, after = getattr(info, name), getattr(got, name)
+      assert (before is None) == (after is None), name
+      if before is not None and name not in ("block_mask", "data_next"):
+        assert np.array_equal(before, after), name
+      if before is not None:
+        assert before.dtype == after.dtype and before.shape == after.shape
+    static, fetch = _steps_by_row(info, which)
+    live, named = _steps_by_row(got, which)
+    own = np.broadcast_to(np.arange(len(static))[:, None], fetch.shape)
+    assert np.array_equal(live[live > 0], static[live > 0])
+    assert not (live[static == 0] > 0).any()
+    dead = (static > 0) & (live == 0)
+    assert not pairs[own[dead], fetch[dead]].any()
+    kept = live > 0
+    assert share[own[kept], fetch[kept]].all()
+    if not isinstance(mask, BlockDiffusion):
+      assert pairs[own[kept], fetch[kept]].all()
+    assert kept.any(axis=1).all()                 # no row is left empty
+    killed_in_all += int(dead.sum())
+    for r in range(len(static)):
+      if not dead[r].any():
+        assert np.array_equal(named[r], fetch[r])
+        continue
+      at = np.flatnonzero(kept[r])
+      for step in range(static.shape[1]):
+        if kept[r, step]:
+          assert named[r, step] == fetch[r, step]
+        else:
+          near = at[at < step][-1] if (at < step).any() else at[0]
+          assert named[r, step] == fetch[r, near], (r, step)
+  assert killed_in_all > 0
+
+
+@pytest.mark.parametrize("mask,length,block", [
+    (Causal(), 2048, 512), (Window(512), 2048, 512),
+    (BlockDiffusion(4), 1024, 128)], ids=str)
+def test_one_document_leaves_the_static_maps_as_they_are(mask, length, block):
+  kernel = attention._splash_kernel(mask, length, 2, True, block, True)
+  planned = attention.documents_in_block_maps(
+      kernel, jnp.zeros((length,), jnp.int32), block)
+  before, after = (jax.tree_util.tree_flatten(x) for x in (kernel, planned))
+  assert before[1] == after[1] and len(before[0]) == len(after[0])
+  for a, b in zip(before[0], after[0]):
+    assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("mask,length,hkv,group,hd,starts", [
+    # LFM2's layout: a group of 4 over heads of 64; the second sequence's
+    # documents are others, one from a block's first position
+    pytest.param(Causal(), 768, 2, 4, 64, [(37, 290, 600), (256, 300)],
+                 id="lfm2"),
+    # GLM's: heads of 256 with no group
+    pytest.param(Causal(), 768, 2, None, 256, [(128, 300, 640), (5,)],
+                 id="glm"),
+    # Laguna's window layers: a document from a block's first position is
+    # what lets the plan skip one of a row's two blocks
+    pytest.param(Window(128), 768, 2, 3, 128, [(37, 256, 512), (384,)],
+                 id="laguna_window"),
+    # one document a sequence: nothing to skip
+    pytest.param(Causal(), 512, 1, 2, 128, [(), ()], id="one_document"),
+])
+def test_the_planned_kernel_is_the_unplanned_kernel_bit_for_bit(
+    monkeypatch, mask, length, hkv, group, hd, starts):
+  """A skipped block's products were discarded anyway (its scores are all
+  the mask's value): forward and the three gradients under the planned maps
+  EQUAL those under the static maps, in Pallas's interpreter; and both are
+  the XLA path's on the rounded operands, at the tolerance of
+  `test_the_splash_path_is_the_tiled_path_on_bfloat16_operands`."""
+  q, k, v, _ = _case(length, hkv, group, hd, batch=len(starts))
+  seg = _segments(length, starts)
+  splash = jax.value_and_grad(lambda q, k, v: jnp.sum(jnp.sin(attention_splash(
+      q, k, v, mask, seg, 128, interpret=True))), argnums=(0, 1, 2))
+  got = jax.jit(splash)(q, k, v)
+  with monkeypatch.context() as patch:
+    patch.setattr(attention, "documents_in_block_maps",
+                  lambda kernel, seg, block: kernel)
+    want = jax.jit(splash)(q, k, v)
+  for g, w in zip(jax.tree_util.tree_leaves(got),
+                  jax.tree_util.tree_leaves(want)):
+    assert np.array_equal(np.asarray(g), np.asarray(w))
+  rounded = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
+  with jax.default_matmul_precision("highest"):
+    tiled = jax.value_and_grad(lambda q, k, v: jnp.sum(jnp.sin(attention_xla(
+        rounded(q), rounded(k), rounded(v), mask, seg, 64))),
+                               argnums=(0, 1, 2))(q, k, v)
+  assert float(got[0]) == pytest.approx(float(tiled[0]), rel=3e-3)
+  for g, w in zip(got[1], tiled[1]):
+    assert float(jnp.max(jnp.abs(g - w))) < 0.02 * float(jnp.max(jnp.abs(w)))
+
+
+@pytest.mark.parametrize("mask,length,hkv,group,hd", [
+    (BlockDiffusion(4), 256, 1, 2, 128), (Causal(), 256, 2, None, 128)],
+                         ids=str)
+def test_without_segment_ids_the_call_traces_to_what_it_did(
+    mask, length, hkv, group, hd):
+  """`seg=None` (SDAR) goes round the plan: the jaxpr is that of the
+  statements `attention_splash` was before the documents entered the maps."""
+  q, k, v, _ = _case(length, hkv, group, hd, batch=2)
+
+  def before(q, k, v):
+    grouped = q.ndim == 5
+    kernel = attention._splash_kernel(
+        mask, q.shape[1], q.shape[3 if grouped else 2], grouped, 128, True)
+    call = lambda q, k, v, s: kernel(q, k, v, segment_ids=None)
+    sample = jax.vmap(call, in_axes=(0, 0, 0, None)) if grouped else call
+    heads_first = lambda x: jnp.moveaxis(x, 1, -2).astype(jnp.bfloat16)
+    out = jax.vmap(sample)(heads_first(q), heads_first(k), heads_first(v),
+                           None)
+    return jnp.moveaxis(out, -2, 1).astype(q.dtype)
+
+  text = lambda f: re.sub(r"0x[0-9a-f]+", "", str(jax.make_jaxpr(f)(q, k, v)))
+  assert text(before) == text(lambda q, k, v: attention_splash(
+      q, k, v, mask, None, 128, interpret=True))
+  assert "pallas_call" in text(before)
 
 
 # ---- the rotary pass -------------------------------------------------------
