@@ -1,0 +1,6 @@
+"""kda_gate_ms: what it reads is in ``kda_gate_ms.json``; the reduction is
+``benchmark/scope_parts.py``."""
+
+from benchmark import scope_parts
+
+read = scope_parts.reader(__file__)

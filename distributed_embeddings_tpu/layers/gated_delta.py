@@ -35,6 +35,14 @@ state only reaches the tokens before the chunk's first reset, and only the
 tokens after its last reset reach the outgoing state. ``g`` at a reset is
 never read.
 
+With ONE DECAY A KEY CHANNEL (:func:`chunk_kda_rule`, Kimi Delta Attention:
+``S'_t = Diag(exp(g_t)) S_{t-1}``, ``g_t [d_k]``) the same algebra holds with
+``gamma [C, d_k]``: the decay of a pair no longer factors out of its dot
+product, ``A_ij = beta_i sum_c k_ic k_jc e^{gamma_ic - gamma_jc}`` (``P`` the
+same with ``q_i``), ``e^gamma`` multiplies ``K`` and ``Q`` a channel and
+``M = Diag(e^{gamma_C}) - Kd^T Wk``. :func:`_decayed_products` says how the
+pairs are taken without an exponent above 0.
+
 Every product of the rule runs at ``highest`` matmul precision: the state
 carries rounding across thousands of tokens, and the rule is a hundredth of
 a layer's arithmetic (the projections around it are the caller's, at the
@@ -66,6 +74,13 @@ def causal_conv(x, w, seg):
                    )[:, :x.shape[1]] == seg
     out = out + jnp.where(same[..., None], shifted, 0) * w[taps - 1 - back]
   return out
+
+
+def l2_norm(x, eps: float = 1e-6):
+  """``x / sqrt(sum(x^2) + eps)`` over the last axis: what a model makes of
+  a head's ``q`` and ``k`` before the rule."""
+  return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                           + eps)
 
 
 @jax.custom_vjp
@@ -111,9 +126,23 @@ def chunk_gated_delta_rule(q, k, v, g, beta, seg, chunk: int = 64):
     return _chunked(q, k, v, g, beta, seg, chunk)
 
 
-def _chunked(q, k, v, g, beta, seg, chunk):
-  b, length, h, dk = q.shape
-  dv = v.shape[-1]
+def chunk_kda_rule(q, k, v, g, beta, seg, chunk: int = 64):
+  """:func:`chunk_gated_delta_rule` with ONE LOG-DECAY A KEY CHANNEL (Kimi
+  Delta Attention): ``g [B, L, H, dk]``, never above 0, and
+  ``S'_t = Diag(exp(g_t)) S_{t-1}``; everything else as there."""
+  with jax.named_scope(scopes.DELTA_RULE):
+    return _chunked_kda(q, k, v, g, beta, seg, chunk)
+
+
+_mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+
+
+def _to_chunks(q, k, v, g, beta, seg, chunk):
+  """The arguments in whole chunks, ``[B, N, H, C, ...]`` and never below
+  float32 -> (``q, k, v, g, beta``, ``seg [B, N, C]``, the document of the
+  token before each, whether a token is its document's first
+  ``[B, N, 1, C]``)."""
+  b, length = seg.shape
   n = -(-length // chunk)
   pad = n * chunk - length
   if pad:
@@ -122,26 +151,44 @@ def _chunked(q, k, v, g, beta, seg, chunk):
     tail = lambda x: jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
     q, k, v, g, beta = (tail(x) for x in (q, k, v, g, beta))
     seg = jnp.pad(seg, ((0, 0), (0, pad)), mode="edge")
-  mm = functools.partial(jnp.matmul, precision=_HIGHEST)
   dt = jnp.promote_types(q.dtype, jnp.float32)   # never below float32
   # [B, N, H, C, ...]
   heads = lambda x: jnp.moveaxis(
       x.reshape((b, n, chunk) + x.shape[2:]), 3, 2).astype(dt)
-  q, k, v = heads(q), heads(k), heads(v)
-  g, beta = heads(g), heads(beta)                          # [B, N, H, C]
+  q, k, v, g, beta = (heads(x) for x in (q, k, v, g, beta))
   seg = seg.reshape(b, n, chunk)
   before = jnp.pad(seg.reshape(b, -1), ((0, 0), (1, 0)),
                    constant_values=-1)[:, :-1].reshape(b, n, chunk)
   reset = (seg != before)[:, :, None, :]                   # [B, N, 1, C]
-  gamma = jnp.cumsum(jnp.where(reset, 0.0, g), axis=-1)    # [B, N, H, C]
-  # a pair counts inside one document; the incoming state reaches a token
-  # with no reset at or before it in the chunk; a token reaches the outgoing
-  # state with no reset after it
+  return (q, k, v, g, beta), seg, before, reset
+
+
+def _masks(seg, before):
+  """The resets as masks: a pair ``(i, j <= i)`` counts inside one document
+  ``[B, N, 1, C, C]``; the incoming state reaches a token with no reset at
+  or before it in the chunk; a token reaches the outgoing state with no
+  reset after it (both ``[B, N, 1, C]``)."""
+  chunk = seg.shape[-1]
   same = (seg[..., :, None] == seg[..., None, :])[:, :, None]
   incoming = (seg == before[..., :1])[:, :, None, :]
   outgoing = (seg == seg[..., -1:])[:, :, None, :]
   lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-  pair = same & lower
+  return same & lower, incoming, outgoing
+
+
+def _chunked(q, k, v, g, beta, seg, chunk):
+  # Op for op and in the order this function has had since PR 33, so that a
+  # model of the scalar rule compiles to the program it did
+  # (`tools/step_recompute.py`'s `program_sha`); the per-channel rule below
+  # shares what comes before the products and the scan
+  b, length, h, dk = q.shape
+  dv = v.shape[-1]
+  n = -(-length // chunk)
+  (q, k, v, g, beta), seg, before, reset = _to_chunks(
+      q, k, v, g, beta, seg, chunk)
+  mm, dt = _mm, q.dtype
+  gamma = jnp.cumsum(jnp.where(reset, 0.0, g), axis=-1)    # [B, N, H, C]
+  pair, incoming, outgoing = _masks(seg, before)
   # e^{gamma_i - gamma_j}, j <= i: the exponent is masked before exp, whose
   # other half would overflow
   decay = jnp.where(pair, jnp.exp(jnp.where(
@@ -167,5 +214,89 @@ def _chunked(q, k, v, g, beta, seg, chunk):
       jnp.zeros((b, h, dk, dv), dt))
   states = jnp.moveaxis(states, 0, 1)                      # [B, N, H, dk, dv]
   o = mm(p, u) + mm(into[..., None] * q - mm(p, wk), states)
+  o = jnp.moveaxis(o, 2, 3).reshape(b, n * chunk, h, dv)[:, :length]
+  return o, last
+
+
+# tokens of a sub-block of the per-channel rule's chunk: between two
+# sub-blocks the decay factors into a matmul, inside one it is taken pair by
+# pair, ``[SUB, SUB, dk]`` a sub-block
+SUB = 16
+
+
+@jax.checkpoint
+def _decayed_products(q, k, gamma):
+  """``sum_c x_ic k_jc e^{gamma_ic - gamma_jc}`` for ``x = k`` and ``x = q``
+  and every pair ``j <= i`` of a chunk (the others hold no meaning: the
+  caller masks them): ``q, k, gamma [.., C, dk]``, ``gamma`` never rising
+  along ``C`` -> two ``[.., C, C]``.
+
+  ``e^{-gamma}`` may not be formed: at a decay of a few nats a token it
+  overflows inside a chunk. Between sub-blocks of ``SUB`` tokens the LATER
+  block's first token is the reference of both factors (as ``fla``'s
+  ``chunk_kda``): ``e^{gamma_i - ref}`` on the later block's rows and
+  ``e^{ref - gamma_j}`` on every earlier token, neither exponent above 0,
+  and the sum over channels is a matmul. Inside a sub-block the exponent
+  ``gamma_i - gamma_j`` is formed pair by pair. Rematerialised: its
+  backward rebuilds the factors from ``q, k, gamma`` and keeps neither the
+  ``[nb, C, dk]`` factors nor the ``[SUB, SUB, dk]`` pairs."""
+  lead, (chunk, dk) = gamma.shape[:-2], gamma.shape[-2:]
+  sub = SUB if chunk % SUB == 0 else chunk
+  nb = chunk // sub
+  blocks = lambda x: x.reshape(lead + (nb, sub, dk))
+  gs = blocks(gamma)
+  ref = gs[..., :1, :]                                     # [.., nb, 1, dk]
+  rows = jnp.exp(gs - ref)
+  # a token before block ``i``'s first: e^{ref_i - gamma_j}
+  earlier = (jnp.arange(chunk)[None, :]
+             < sub * jnp.arange(nb)[:, None])[..., None]   # [nb, C, 1]
+  cols = jnp.where(earlier, jnp.exp(jnp.where(
+      earlier, ref - gamma[..., None, :, :], 0.0)), 0.0) * k[..., None, :, :]
+  between = _mm(jnp.concatenate([blocks(k) * rows, blocks(q) * rows],
+                                axis=-2), jnp.swapaxes(cols, -1, -2))
+  # inside a sub-block: [.., nb, sub, sub, dk] summed over the channels
+  lower = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+  inside = blocks(k)[..., None, :, :] * jnp.exp(jnp.where(
+      lower, gs[..., :, None, :] - gs[..., None, :, :], 0.0))
+  eye = jnp.eye(nb, dtype=gamma.dtype)[:, None, :, None]
+
+  def whole(between, x):
+    within = jnp.sum(blocks(x)[..., :, None, :] * inside, axis=-1)
+    return between.reshape(lead + (chunk, chunk)) + (
+        within[..., :, :, None, :] * eye).reshape(lead + (chunk, chunk))
+  return whole(between[..., :sub, :], k), whole(between[..., sub:, :], q)
+
+
+def _chunked_kda(q, k, v, g, beta, seg, chunk):
+  b, length, h, dk = q.shape
+  dv = v.shape[-1]
+  (q, k, v, g, beta), seg, before, reset = _to_chunks(
+      q, k, v, g, beta, seg, chunk)
+  n, dt = seg.shape[1], q.dtype
+  # [B, N, H, C, dk]; g at a reset is never read
+  gamma = jnp.cumsum(jnp.where(reset[..., None], 0.0, g), axis=-2)
+  pair, incoming, outgoing = _masks(seg, before)
+  kk, qk = _decayed_products(q, k, gamma)
+  strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+  a = beta[..., None] * jnp.where(pair & strict, kk, 0.0)
+  p = jnp.where(pair, qk, 0.0)
+  into = jnp.where(incoming[..., None], jnp.exp(gamma), 0.0)   # e^{gamma_i}
+  rhs = jnp.concatenate([beta[..., None] * v,
+                         beta[..., None] * into * k], axis=-1)
+  # (I + A) X = rhs: the diagonal is taken as 1 and never read
+  solved = jax.lax.linalg.triangular_solve(
+      a, rhs, left_side=True, lower=True, unit_diagonal=True)
+  u, wk = solved[..., :dv], solved[..., dv:]               # [.., C, dv|dk]
+  kd_t = jnp.swapaxes(k * jnp.where(
+      outgoing[..., None], jnp.exp(gamma[..., -1:, :] - gamma), 0.0), -1, -2)
+  # the incoming state outlives a chunk that holds no reset, a factor a
+  # key channel: Diag(e^{gamma_C})
+  carried = jnp.where(incoming[..., -1:], jnp.exp(gamma[..., -1, :]), 0.0)
+  m = carried[..., None] * jnp.eye(dk, dtype=dt) - _mm(kd_t, wk)
+  states, last = linear_state_scan(
+      jnp.moveaxis(m, 1, 0), jnp.moveaxis(_mm(kd_t, u), 1, 0),
+      jnp.zeros((b, h, dk, dv), dt))
+  states = jnp.moveaxis(states, 0, 1)                      # [B, N, H, dk, dv]
+  o = _mm(p, u) + _mm(into * q - _mm(p, wk), states)
   o = jnp.moveaxis(o, 2, 3).reshape(b, n * chunk, h, dv)[:, :length]
   return o, last

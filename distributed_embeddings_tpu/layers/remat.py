@@ -54,12 +54,28 @@ whose output the layer's feed-forward reads.
 (the two latent norms, the split) is rebuilt: the rematerialised mixer runs
 the up products, the rotary pass and ``W_o`` again and neither down product.
 
+``KDA_LATENTS``: the first halves of a per-channel delta-rule mixer's two
+low-rank chains (``models/solar_open2.py::kda_mixer``): ``u W_fa`` and
+``u W_ga``, float32 ``[T, 128]`` each (4 MB a layer each at 8,192
+positions). Each is a pass over ``u [T, hidden]`` that makes 128 columns,
+bound by memory: kept, the rebuilt mixer reads ``u`` for its three wide
+projections alone. Those (``u W_q``, ``u W_k``, ``u W_v``: 101 MB a layer
+together) are NOT kept: the step compiled for the chip stands at 16.3 of
+17.18 GB without them (PERF.md, PR 51). The convolutions, the chains' second
+halves and the rule itself, whose backward wants its own chunk matrices
+anyway, are rebuilt with the layer. The rule's scan keeps its per-chunk
+states as its forward's own output
+(``layers/gated_delta.py::linear_state_scan``), inside the layer's backward;
+its decayed pair products are one more ``jax.checkpoint``
+(``_decayed_products``: the per-channel factors are rebuilt, never kept).
+
 A layer that makes none of the named values is rematerialised whole: the
 other models' ``attention="xla"`` (a tile loop under JAX's own transpose,
 for tests and counting tools: nothing of it is kept, scores and
 probabilities are rebuilt with the layer) and a dense MLP. No other ``jax.checkpoint`` stands on a decoder
 layer's path but the two round the expert layer's tail, which no step walks
-unless a router overflows the head (``layers/moe.py``).
+unless a router overflows the head (``layers/moe.py``), and the one round
+the per-channel rule's pair products.
 ``tools/step_recompute.py <cell>`` counts, in a cell's compiled step, the calls
 this plan is meant to leave and the bytes it spends.
 """
@@ -72,8 +88,9 @@ SPARSE_SELECTION = "sparse_selection"
 SPARSE_ATTN_RESIDUALS = "sparse_attn_residuals"
 SHORT_CONV_IN = "short_conv_in"
 MLA_LATENTS = "mla_latents"
+KDA_LATENTS = "kda_latents"
 KEPT = (SPLASH_RESIDUALS, MOE_ROUTE, SPARSE_SELECTION, SPARSE_ATTN_RESIDUALS,
-        SHORT_CONV_IN, MLA_LATENTS)
+        SHORT_CONV_IN, MLA_LATENTS, KDA_LATENTS)
 
 
 def checkpoint_layer(layer):

@@ -65,7 +65,11 @@ from ..layers.attention import (
 # step with `models.olmo_hybrid.next_token_loss`
 from ..layers.decoder import document_segments, next_token_loss, rms_norm
 from ..layers.dense import mxu_dot
-from ..layers.gated_delta import causal_conv, chunk_gated_delta_rule
+from ..layers.gated_delta import (
+    causal_conv,
+    chunk_gated_delta_rule,
+    l2_norm,
+)
 from ..layers.remat import checkpoint_layer
 from ..telemetry import scopes
 
@@ -102,11 +106,6 @@ class OlmoHybridConfig:
     if stray:
       raise ValueError(f"layer_types names {sorted(stray)}: "
                        f"{LINEAR} or {FULL}")
-
-
-def l2_norm(x, eps: float = 1e-6):
-  return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
-                           + eps)
 
 
 def linear_attention_mixer(cfg: OlmoHybridConfig, p, u, seg):

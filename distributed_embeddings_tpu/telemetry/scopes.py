@@ -25,7 +25,7 @@ APPLY = "de_apply"  # delta streams, XLA's scatter-add, the Pallas apply kernel
 ONEHOT = "de_onehot"  # a dense class's windowed one-hot MXU lookup; inside de_combine
 EXCHANGE = "de_exchange"  # the collectives of parallel/wire.py; inside de_route and de_combine
 INTERACT = "de_interact"  # models/dlrm.py::dot_interact; inside de_model
-ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/lfm2_moe.py, models/glm_moe_lite.py: there layers/latent_attention.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE (layers/attention.py::rope), gate, attention under the model's mask description (layers/attention.py: attention_splash on a TPU, attention_xla elsewhere); inside de_model
+ATTENTION = "de_attention"  # a softmax-attention mixer whole, whatever the model (models/sdar_moe.py, models/olmo_hybrid.py, models/laguna.py, models/lfm2_moe.py, models/solar_open2.py, models/glm_moe_lite.py: there layers/latent_attention.py, models/keye_sparse.py: there with the indexer that chooses its keys): the input's norm where the block has one, q/k/v/o projections, q/k norms, RoPE (layers/attention.py::rope), gate, attention under the model's mask description (layers/attention.py: attention_splash on a TPU, attention_xla elsewhere); inside de_model
 WINDOW_ATTENTION = "de_window_attention"  # models/laguna.py: the mixer of a sliding_attention layer (causal, same document, i - j < sliding_window); inside de_attention
 FULL_ATTENTION = "de_full_attention"  # models/laguna.py: the mixer of a full_attention layer (causal, same document); inside de_attention
 MOE = "de_moe"  # layers/moe.py::moe_share and shared_expert, the whole expert layer; inside de_model
@@ -33,8 +33,8 @@ MOE_ROUTE = "de_moe_route"  # router, top-k, sort by expert, row gather, weighte
 MOE_EXPERTS = "de_moe_experts"  # the grouped matmuls over the held experts and the gate's silu; inside de_moe
 MOE_SHARED = "de_moe_shared"  # layers/moe.py::shared_expert: the expert every token passes, a plain dense SwiGLU; inside de_moe
 LM_HEAD = "de_lm_head"  # final norm and the vocabulary head; inside de_model
-LINEAR_ATTENTION = "de_linear_attention"  # models/olmo_hybrid.py: a gated-delta-rule mixer whole (projections, short convolutions, gates, the rule, gated output norm, W_o, the sublayer's norm); inside de_model
-DELTA_RULE = "de_delta_rule"  # layers/gated_delta.py::chunk_gated_delta_rule, the chunked rule alone; inside de_linear_attention
+LINEAR_ATTENTION = "de_linear_attention"  # models/olmo_hybrid.py, models/solar_open2.py (there the rule's decay is per key channel, and the input's norm is inside): a gated-delta-rule mixer whole (projections, short convolutions, gates, the rule, gated output norm, W_o, the sublayer's norm); inside de_model
+DELTA_RULE = "de_delta_rule"  # layers/gated_delta.py::chunk_gated_delta_rule and chunk_kda_rule, the chunked rule alone; inside de_linear_attention
 SPARSE_INDEX = "de_sparse_index"  # models/keye_sparse.py, layers/sparse_index.py: the learned indexer whole (its projections, scores, top-k and its own KL loss); inside de_attention
 MLP = "de_mlp"  # a dense SwiGLU MLP and the norm of its sublayer (models/olmo_hybrid.py: every layer's; models/laguna.py and models/lfm2_moe.py: the leading dense layers'); inside de_model
 MTP = "de_mtp"  # models/glm_moe_lite.py::mtp_module, the multi-token-prediction module whole: the norms of its two inputs, W_eh, its decoder layer (with the scopes a layer has: its attention is ALSO under de_attention and its experts under de_moe), its final norm and the shared head (under de_lm_head); inside de_model
@@ -60,8 +60,9 @@ MOE_ROUTER = "de_moe_router"  # layers/moe.py::route whole: the router's matmul,
 MOE_SORT = "de_moe_sort"  # the sort key, argsort, bincount, the cumulative sums, tok, p_sorted; inside de_moe_route
 MOE_DISPATCH = "de_moe_dispatch"  # the gather of the sorted stream's rows of h with its select (transposed: the scatter-add of the cotangent into h; on a TPU the head's is the kernel de_moe_combine, ops/pallas_moe_combine.py); inside de_moe_route
 MOE_RETURN = "de_moe_return"  # the weighting y * p with its select and the scatter-add into the output, head and tail (on a TPU the head's three are one call of the kernel de_moe_combine; transposed: a gather); inside de_moe_route
-LINATTN_PROJ = "de_linattn_proj"  # the matmuls with wq, wk, wv, wg, wb, wa, wo; inside de_linear_attention
+LINATTN_PROJ = "de_linattn_proj"  # the matmuls with wq, wk, wv, wg, wb, wa, wo (models/solar_open2.py: wq, wk, wv, wo; its gates' products are de_linattn_gate); inside de_linear_attention
 LINATTN_CONV = "de_linattn_conv"  # short(...): causal_conv with its reset and the silu, three times; inside de_linear_attention
+LINATTN_GATE = "de_linattn_gate"  # models/solar_open2.py::kda_mixer: what stands between the projections and the rule besides the convolutions, and behind it: the two low-rank chains (W_fa W_fb to the per-channel decay, W_ga W_gb to the output gate), softplus and the decay, beta with its product, the sigmoid-gated output norm; bound by memory; inside de_linear_attention (models/olmo_hybrid.py's mixer does not enter it)
 CONV_PROJ = "de_conv_proj"  # the matmuls with w_in and w_out; inside de_short_conv
 CONV_GATE = "de_conv_gate"  # the gate chain between them: B * u, causal_conv with its reset, C * c; bound by memory where the products are bound by the MXU; inside de_short_conv
 
@@ -74,4 +75,4 @@ LM_CHILDREN = (ATTENTION, MOE, MOE_ROUTE, MOE_EXPERTS, LM_HEAD,
 # a language model's parts, each inside one of LM_CHILDREN
 PARTS = (ATTN_PROJ, ATTN_QK, ATTN_CORE, MOE_ROUTER, MOE_SORT, MOE_DISPATCH,
          MOE_RETURN, LINATTN_PROJ, LINATTN_CONV, INDEX_SCORES, INDEX_SELECT,
-         INDEX_LOSS, CONV_PROJ, CONV_GATE, MLA_DOWN, MLA_UP)
+         INDEX_LOSS, CONV_PROJ, CONV_GATE, MLA_DOWN, MLA_UP, LINATTN_GATE)

@@ -32,6 +32,7 @@ from distributed_embeddings_tpu.models import (
     lfm2_moe,
     olmo_hybrid,
     sdar_moe,
+    solar_open2,
 )
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
@@ -83,6 +84,18 @@ TOYS = {
                          layers_here=(0, 1), vocab_size=50,
                          experts_held=(4, 4), seq_len=24,
                          mean_document_length=8, attention="xla")),
+    # published layers 0, 1: gated attention, then the per-channel rule (two
+    # chunks of 32, the second padded), both over experts
+    "solar_open2": (solar_open2, solar_open2.SolarOpen2,
+                    solar_open2.SolarOpen2Config(
+                        hidden_size=32, moe_intermediate_size=12,
+                        num_attention_heads=4, num_key_value_heads=2,
+                        head_dim=8, linear_num_heads=4, linear_head_dim=8,
+                        gqa_layers=(0, 4), n_routed_experts=16,
+                        num_experts_per_tok=4, num_hidden_layers=8,
+                        layers_here=(0, 1), vocab_size=50, heads_held=(0, 4),
+                        experts_held=(4, 4), seq_len=40,
+                        mean_document_length=10, chunk=32, attention="xla")),
 }
 
 
@@ -163,9 +176,10 @@ def test_gradients_under_the_plan_are_those_with_no_checkpoint(
   `tests/test_olmo_hybrid.py`), to 1e-12 of a leaf's largest value. So is
   GLM's: under the plan its float32 gradients differ from the plain ones in
   the last bits (1e-6 of a leaf) and in float64 agree to 1e-12, which is what
-  sums taken in another order read, not another formula."""
+  sums taken in another order read, not another formula; and Solar-Open2's,
+  whose mixers are recurrent as Olmo-Hybrid's."""
   module, model_cls, cfg = TOYS[name]
-  exact = name not in ("olmo_hybrid", "glm_moe_lite")
+  exact = name not in ("olmo_hybrid", "glm_moe_lite", "solar_open2")
   with jax.enable_x64(not exact):
     params, numerical, rows = _case(model_cls, cfg)
     if not exact:
@@ -194,17 +208,23 @@ def test_gradients_under_the_plan_are_those_with_no_checkpoint(
 def test_every_decoder_layer_runs_under_the_plan(name, monkeypatch):
   """One checkpoint a decoder layer, each with the plan's policy, and none
   beside them: with the helper taken out the backward holds no checkpoint at
-  all (the toys' shares have no tail)."""
+  all (the toys' shares have no tail) but the one round the per-channel
+  rule's pair products, one a KDA layer
+  (`layers/gated_delta.py::_decayed_products`)."""
   module, model_cls, cfg = TOYS[name]
   params, numerical, rows = _case(model_cls, cfg)
   # a function object each: `make_jaxpr` remembers what it traced
   grad = lambda: jax.grad(_loss(model_cls, cfg, numerical))
   tops = [eqn for eqn in jax.make_jaxpr(grad())(params, rows).jaxpr.eqns
           if _checkpoint(eqn)]
-  assert len(tops) == _layers(cfg)
-  assert all(eqn.params["policy"] is not None for eqn in tops)
+  own = sum(kind == solar_open2.KDA for kind in cfg.kinds) \
+      if name == "solar_open2" else 0
+  # the layers' carry the plan's policy, the rule's own carries none
+  planned = [eqn for eqn in tops if eqn.params["policy"] is not None]
+  assert len(planned) == _layers(cfg) and len(tops) - len(planned) == own
   monkeypatch.setattr(module, "checkpoint_layer", lambda layer: layer)
-  assert _count(jax.make_jaxpr(grad())(params, rows).jaxpr, _checkpoint) == 0
+  assert _count(jax.make_jaxpr(grad())(params, rows).jaxpr, _checkpoint) \
+      == own
 
 
 def _splash_layer(name):
@@ -227,7 +247,7 @@ def _splash_layer(name):
       return attention.attention_splash(
           grouped, k, v, attention.BlockDiffusion(4), None, 128,
           interpret=True)
-    if name in ("laguna", "lfm2_moe"):   # a window of 40, and none
+    if name in ("laguna", "lfm2_moe", "solar_open2"):   # a window of 40, and none
       return attention.attention_splash(
           grouped, k, v, attention.Window(40) if name == "laguna"
           else attention.Causal(), seg, 128, interpret=True)
@@ -352,6 +372,46 @@ def test_a_latent_layer_keeps_its_latents_and_rebuilds_the_rest(capsys):
   for g, w in zip(jax.tree_util.tree_leaves(got),
                   jax.tree_util.tree_leaves(want)):
     np.testing.assert_allclose(g, w, atol=1e-5 * float(jnp.max(jnp.abs(w))))
+
+
+def test_a_kda_layer_keeps_its_chains_latents_and_rebuilds_the_rest(capsys):
+  """A layer of the per-channel delta rule over experts
+  (`models/solar_open2.py`). The plan keeps what the first halves of the two
+  low-rank chains made (`remat.KDA_LATENTS`: ``u W_fa`` and ``u W_ga``), so
+  the rebuilt forward runs two products fewer than a bare checkpoint's,
+  which runs both again; the three wide projections, the convolutions, the
+  chains' second halves and the rule are rebuilt either way, and the rule's
+  scan keeps its per-chunk states for its own backward."""
+  _, _, cfg = TOYS["solar_open2"]
+  rng = np.random.default_rng(0)
+  p = {n: jnp.asarray(rng.normal(size=shape) * 0.3, jnp.float32)
+       for n, (shape, _) in solar_open2.layer_shapes(
+           cfg, solar_open2.KDA).items()}
+  x = jnp.asarray(rng.normal(size=(2, cfg.seq_len, cfg.hidden_size)),
+                  jnp.float32)
+  seg = jnp.asarray(np.arange(cfg.seq_len)[None, :] >= 9, jnp.int32) \
+      * jnp.ones((2, 1), jnp.int32)
+  layer = lambda p, x: solar_open2.decoder_layer(
+      cfg, solar_open2.KDA, p, x, seg)[0]
+  loss = lambda wrap: lambda p, x: jnp.sum(jnp.sin(wrap(layer)(p, x)))
+  products = lambda wrap: _count(jax.make_jaxpr(jax.grad(
+      loss(wrap), argnums=(0, 1)))(p, x).jaxpr, _primitive("dot_general"))
+  assert products(jax.checkpoint) - products(remat.checkpoint_layer) == 2
+  assert products(remat.checkpoint_layer) > products(lambda f: f)
+  latent = f"f32[2,{cfg.seq_len},{cfg.linear_head_dim}]"
+  kept = _residuals(capsys, loss(remat.checkpoint_layer), p, x)
+  bare = _residuals(capsys, loss(jax.checkpoint), p, x)
+  assert kept.count(latent) == 2 and latent not in bare
+  # no wide projection outlives the layer: [2, L, heads x 8] is the layer's
+  # input and nothing else
+  wide = f"f32[2,{cfg.seq_len},{cfg.heads_held[1] * cfg.linear_head_dim}]"
+  assert kept.count(wide) == bare.count(wide)
+  got = jax.grad(loss(remat.checkpoint_layer), argnums=(0, 1))(p, x)
+  want = jax.grad(loss(lambda f: f), argnums=(0, 1))(p, x)
+  # not to the bit (the test above holds the whole model in float64)
+  for g, w in zip(jax.tree_util.tree_leaves(got),
+                  jax.tree_util.tree_leaves(want)):
+    np.testing.assert_allclose(g, w, atol=1e-4 * float(jnp.max(jnp.abs(w))))
 
 
 def _moe_weights(seed):
@@ -587,11 +647,14 @@ def test_kept_is_what_the_code_names():
   assert set(remat.KEPT) == {remat.SPLASH_RESIDUALS, remat.MOE_ROUTE,
                              remat.SPARSE_SELECTION,
                              remat.SPARSE_ATTN_RESIDUALS,
-                             remat.SHORT_CONV_IN, remat.MLA_LATENTS}
+                             remat.SHORT_CONV_IN, remat.MLA_LATENTS,
+                             remat.KDA_LATENTS}
   # a short convolution's first product is named where it is made
   assert inspect.getsource(short_conv).count(", SHORT_CONV_IN)") == 1
   # a latent-attention mixer's two down products likewise
   assert inspect.getsource(latent_attention).count(", MLA_LATENTS)") == 2
+  # a KDA mixer's two low-rank chains pass one line that names their latent
+  assert inspect.getsource(solar_open2).count(", KDA_LATENTS)") == 1
   # the two a learned indexer's attention names are made in one place
   source = inspect.getsource(sparse_index)
   assert source.count("SPARSE_SELECTION)") == 1
@@ -610,6 +673,7 @@ def test_kept_is_what_the_code_names():
                                  olmo_hybrid.OlmoHybridConfig,
                                  keye_sparse.KeyeSparseConfig,
                                  lfm2_moe.Lfm2MoeConfig,
-                                 glm_moe_lite.GlmMoeLiteConfig)
+                                 glm_moe_lite.GlmMoeLiteConfig,
+                                 solar_open2.SolarOpen2Config)
               for f in dataclasses.fields(cfg)
               if "remat" in f.name or "checkpoint" in f.name]

@@ -3,7 +3,7 @@
 DLRM and the toy zoo step, at world 1 and on a mesh of four virtual devices.
 And the parts of a language-model step (``scopes.PARTS``): where each lies,
 in which passes, and what a rematerialised layer does not run again, on the
-LOWERED text of the six toy models' steps (the CPU's compiler merges a
+LOWERED text of the seven toy models' steps (the CPU's compiler merges a
 rebuilt op with its forward twin; the TPU's barrier forbids that).
 """
 
@@ -23,6 +23,7 @@ import test_laguna
 import test_lfm2_moe
 import test_olmo_hybrid
 import test_sdar_moe
+import test_solar_open2
 from distributed_embeddings_tpu.layers import TableConfig, remat
 from distributed_embeddings_tpu.layers.decoder import next_token_loss
 from distributed_embeddings_tpu.layers.planner import DistEmbeddingStrategy
@@ -36,6 +37,7 @@ from distributed_embeddings_tpu.models import (
     lfm2_moe,
     olmo_hybrid,
     sdar_moe,
+    solar_open2,
 )
 from distributed_embeddings_tpu.models.dlrm import dlrm_embedding_plan
 from distributed_embeddings_tpu.models.synthetic import (
@@ -131,11 +133,11 @@ COMPILER_MADE = re.compile(
 UNSCOPED_BY_DESIGN = re.compile(r"^jit\(\w+\)(/jit\(\w+\))*(/shard_map)?/add$")
 
 
-@pytest.mark.parametrize("family,world", [
-    (_dlrm, 1), (_zoo, 1), (_dlrm, 4), (_zoo, 4)],
-    ids=["dlrm-world1", "zoo-world1", "dlrm-world4", "zoo-world4"])
-def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
-  text = _compiled_step(family, world)
+def _account(text, made_too=lambda name: False):
+  """The compiled step's instructions that do work, by where they lie:
+  -> (``(top-level scope, backward) -> count``, the strays' lines, how many
+  the compiler made (or ``made_too`` says so of their ``op_name``), how many
+  in all)."""
   seen = collections.Counter()
   stray, total, made = [], 0, 0
   for line in text.splitlines():
@@ -148,13 +150,22 @@ def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
     scope, backward = _layer(name)
     if scope is not None:
       seen[(scope, backward)] += 1
-    elif COMPILER_MADE.match(name):
+    elif COMPILER_MADE.match(name) or made_too(name):
       made += 1
     elif UNSCOPED_BY_DESIGN.match(name):
       if m.group(1) not in ("add", "fusion"):
         stray.append(line.strip()[:200])
     else:
       stray.append(line.strip()[:200])
+  return seen, stray, made, total
+
+
+@pytest.mark.parametrize("family,world", [
+    (_dlrm, 1), (_zoo, 1), (_dlrm, 4), (_zoo, 4)],
+    ids=["dlrm-world1", "zoo-world1", "dlrm-world4", "zoo-world4"])
+def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
+  text = _compiled_step(family, world)
+  seen, stray, made, total = _account(text)
   assert not stray, "\n".join(stray[:20])
   assert made < 0.3 * total, (made, total)
   for scope in scopes.TOP_LEVEL:
@@ -174,7 +185,7 @@ def test_every_instruction_of_the_step_has_a_top_level_scope(family, world):
 def test_the_vocabulary_is_one_flat_set_of_names():
   names = scopes.TOP_LEVEL + scopes.CHILDREN + scopes.LM_CHILDREN \
       + scopes.PARTS
-  assert len(set(names)) == len(names) == 40
+  assert len(set(names)) == len(names) == 41
   for n in names:
     assert n.startswith("de_") and "/" not in n and "(" not in n
   declared = {v for k, v in vars(scopes).items()
@@ -184,11 +195,14 @@ def test_the_vocabulary_is_one_flat_set_of_names():
 
 # ---- the parts of a language-model step ---------------------------------------
 # the toy models of tests/test_sdar_moe.py, test_olmo_hybrid.py, test_laguna.py,
-# test_keye_sparse.py, test_lfm2_moe.py, test_glm_moe_lite.py
+# test_keye_sparse.py, test_lfm2_moe.py, test_glm_moe_lite.py,
+# test_solar_open2.py
 LM_TOYS = {
     "glm_moe_lite": (glm_moe_lite.GlmMoeLite, glm_moe_lite.mtp_training_loss,
                      test_glm_moe_lite.TOY),
     "lfm2_moe": (lfm2_moe.Lfm2Moe, next_token_loss, test_lfm2_moe.TOY),
+    "solar_open2": (solar_open2.SolarOpen2, next_token_loss,
+                    test_solar_open2.TOY),
     "sdar_moe": (sdar_moe.SDARMoE, sdar_moe.block_diffusion_loss,
                  dataclasses.replace(test_sdar_moe.TOY, num_experts=8,
                                      num_experts_per_tok=2,
@@ -209,6 +223,7 @@ INSIDE = {
     scopes.MOE_RETURN: scopes.MOE_ROUTE,
     scopes.LINATTN_PROJ: scopes.LINEAR_ATTENTION,
     scopes.LINATTN_CONV: scopes.LINEAR_ATTENTION,
+    scopes.LINATTN_GATE: scopes.LINEAR_ATTENTION,
     scopes.INDEX_SCORES: scopes.SPARSE_INDEX,
     scopes.INDEX_SELECT: scopes.SPARSE_INDEX,
     scopes.INDEX_LOSS: scopes.SPARSE_INDEX,
@@ -229,7 +244,10 @@ PARTS_OF = {
     "keye_sparse": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE)
     + INDEX_PARTS + ROUTE_PARTS,
     "lfm2_moe": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE,
-                 scopes.CONV_PROJ, scopes.CONV_GATE) + ROUTE_PARTS}
+                 scopes.CONV_PROJ, scopes.CONV_GATE) + ROUTE_PARTS,
+    "solar_open2": (scopes.ATTN_PROJ, scopes.ATTN_QK, scopes.ATTN_CORE,
+                    scopes.LINATTN_PROJ, scopes.LINATTN_CONV,
+                    scopes.LINATTN_GATE) + ROUTE_PARTS}
 # the passes a part has ops in where not all three: what `remat.KEPT` names is
 # made in the forward and never rebuilt (the selection; the attention's output
 # and log-sum-exp, so the rebuilt layer computes no score)
@@ -369,6 +387,65 @@ def test_the_two_conv_parts_partition_the_mixer_but_for_its_norm(lm_stacks):
           if scopes.CONV_PROJ in _components(s)} >= {"dot_general"}
 
 
+def test_every_instruction_of_the_kda_models_step_has_a_top_level_scope():
+  """The Solar-Open2 toy's compiled sparse train step, as the DLRM and zoo
+  steps above: every instruction that does work lies under exactly one
+  top-level scope (an ``op_name`` holds one outermost), none strays, and
+  the model's own lie under ``de_model`` in both directions. What XLA's CPU
+  compiler makes of a sort and of a running sum are computations of its own
+  whose instructions carry a bare name and no stack (``lt_to``,
+  ``reduce_window_sum``; a label's parameter likewise): counted as the
+  compiler's."""
+  seen, stray, made, total = _account(
+      _lowered("solar_open2").compile().as_text(),
+      made_too=lambda name: "/" not in name)
+  assert not stray, "\n".join(stray[:20])
+  assert made < 0.3 * total, (made, total)
+  # one sequence input on one device: what de_combine holds is a reshape of
+  # the gathered rows, which the compiler folds away in both directions
+  for scope in set(scopes.TOP_LEVEL) - {scopes.COMBINE}:
+    assert seen[(scope, False)], f"no forward op under {scope}"
+  for scope in (scopes.MODEL, scopes.LOSS):
+    assert seen[(scope, True)], f"no backward op under {scope}"
+  for scope in (scopes.ROUTE, scopes.GATHER, scopes.DENSE_UPDATE,
+                scopes.APPLY):
+    assert not seen[(scope, True)], f"{scope} has a backward op"
+  assert set(scope for scope, _ in seen) <= set(scopes.TOP_LEVEL)
+  # the model is most of the step
+  assert seen[(scopes.MODEL, False)] + seen[(scopes.MODEL, True)] > total / 2
+
+
+def test_the_kda_mixers_parts_lie_where_the_table_says(lm_stacks):
+  """Under ``de_linear_attention`` an op lies under at most one of the three
+  parts and the rule; ``de_linattn_gate`` holds the low-rank chains'
+  products, softplus's and the sigmoids' ops and nothing of the rule or of
+  the convolutions; Olmo-Hybrid's mixer does not enter it; the rule's pair
+  products are rebuilt inside the backward (the one ``jax.checkpoint`` on
+  the layer's path besides the layer's own)."""
+  name, stacks = lm_stacks
+  gate = [s for s in stacks if scopes.LINATTN_GATE in _components(s)]
+  assert bool(gate) == (name == "solar_open2")
+  if not gate:
+    return
+  inside = (scopes.LINATTN_PROJ, scopes.LINATTN_CONV, scopes.LINATTN_GATE,
+            scopes.DELTA_RULE)
+  for stack in stacks:
+    names = _components(stack)
+    if scopes.LINEAR_ATTENTION in names:
+      assert sum(p in names for p in inside) <= 1, stack
+      assert scopes.ATTENTION not in names and scopes.MOE not in names, stack
+  last = {_components(s)[-1] for s in gate}
+  assert {"dot_general", "logistic", "exp", "mul"} <= last, last
+  assert "triangular_solve" not in last and "pad" not in last
+  rule = [s for s in stacks if scopes.DELTA_RULE in _components(s)]
+  assert {"triangular_solve", "while", "cumsum"} <= {
+      _components(s)[-1] for s in rule}
+  # the pair products' own checkpoint: rebuilt twice over inside the
+  # layer's backward (the layer's rebuilt forward, then the rule's)
+  assert any(_components(s).count(REMAT) == 1 and "transpose(" in s
+             for s in rule)
+
+
 def test_the_latent_parts_and_the_prediction_module_lie_where_the_table_says(
     lm_stacks):
   """Under ``de_attention`` of the latent mixer an op lies under at most one
@@ -448,6 +525,8 @@ SPLASH_TOYS = {
                          v_head_dim=256, seq_len=128),
     "sdar_moe": dict(head_dim=128, seq_len=64),
     "olmo_hybrid": dict(head_dim=128, seq_len=128, chunk=64),
+    # one multi-query call a key-value head: 2 query heads on each of 2
+    "solar_open2": dict(head_dim=128, seq_len=128, chunk=64),
     "laguna": dict(head_dim=128, seq_len=128),
     # the published head: half a lane tile
     "lfm2_moe": dict(head_dim=64, seq_len=128)}
@@ -489,6 +568,8 @@ def test_no_splash_forward_kernel_is_called_in_a_rebuilt_core(
     attention_layers = len(cfg.layers_here) + 1
   elif name == "lfm2_moe":
     attention_layers = sum(mixer == lfm2_moe.FULL for mixer, _ in cfg.kinds)
+  elif name == "solar_open2":
+    attention_layers = sum(kind == solar_open2.GQA for kind in cfg.kinds)
   else:
     layers = len(getattr(cfg, "layer_types", ())) or cfg.num_hidden_layers
     attention_layers = layers if name != "olmo_hybrid" else sum(

@@ -16,8 +16,12 @@ real size the CPU takes a minute or two a batch; ``--layers 1`` shortens it):
   JAX_PLATFORMS=cpu python tools/moe_load.py laguna_moe_train_1chip --seed N
   JAX_PLATFORMS=cpu python tools/moe_load.py lfm2_moe_train_1chip --seed N
   JAX_PLATFORMS=cpu python tools/moe_load.py glm_mla_train_1chip --seed N
+  JAX_PLATFORMS=cpu python tools/moe_load.py solar_kda_train_1chip --seed N
 
-(the last: the trunk's four expert layers, then the prediction module's).
+(the last but one: the trunk's four expert layers, then the prediction
+module's; the last: 320 router outputs of which 8 are held, so the expected
+load is 1,638.4 assignments a layer, about 205 an expert, and the head
+holds four of them, 6,560 rows).
 """
 
 import argparse
