@@ -23,14 +23,15 @@ it to ``moe_share``'s sum (counted once when shares are added up).
 
 Dropless and without a capacity. The assignments are sorted by expert, held
 ones first, so the rows an expert computes are contiguous, and the grouped
-matmuls (``jax.lax.ragged_dot``; on a TPU XLA's own Mosaic grouped-matmul
-kernel, which skips rows that belong to no group) go over them. On a TPU at
-default precision they are handed what the MXU multiplies anyway
-(``layers/dense.py::grouped_mxu_dots``): the rows, ``silu(gate) * up`` and the
-cotangents each rounded to bfloat16 once, where they are made, and read by
-every product that wants them; float32 sums, float32 ``y``, ``dx`` and ``dw``
-from a written-out backward. Everywhere else the three ``lax.ragged_dot``
-stand as they were. How many
+matmuls (``layers/dense.py::grouped_mxu_dots``) go over them. On a TPU at
+default precision they are handed what the MXU multiplies anyway: the rows,
+``silu(gate) * up`` and the cotangents each rounded to bfloat16 once, where
+they are made, and read by every product that wants them; float32 sums,
+float32 ``y``, ``dx`` and ``dw`` from a written-out backward; and they run in
+the kernels of ``ops/pallas_grouped_matmul.py``, tiled to each product's
+shape, which neither multiply a row that belongs to no group nor leave it unwritten (XLA's
+own ``lax.ragged_dot`` kernel walks every shape under one tiling: PERF.md, PR
+53). Everywhere else the three ``lax.ragged_dot`` stand as they were. How many
 assignments land here is data, not a shape: the expected count is
 ``tokens * top_k * held / num_experts``, the worst case ``num_experts / held``
 times that. The sorted stream's head, ``HEAD_LOADS`` times the expected

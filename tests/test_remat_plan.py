@@ -509,6 +509,7 @@ def test_the_tool_counts_a_steps_calls():
 %branch_walk.3 (p: f32[8,4]) -> f32[8,4] {
   %p = f32[8,4]{1,0} parameter(0)
   %ragged-dot-metadata.9 = (s32[17]{0}, s32[1]{0}) custom-call(%p), custom_call_target="tpu_custom_call"
+  %de_grouped_dot.9 = f32[8,4]{1,0:T(8,128)} custom-call(%p, %p), custom_call_target="tpu_custom_call"
   ROOT %ragged-dot-none.7 = f32[8,4]{1,0:T(8,128)} custom-call(%p, %p), custom_call_target="tpu_custom_call"
 }
 
@@ -551,6 +552,9 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
   %x16 = bf16[8,4]{1,0:T(8,128)(2,1)} convert(%x)
   %ragged-dot-none.13 = f32[8,4]{1,0:T(8,128)} custom-call(%k, %ragged-dot-metadata.5, %k, /*index=3*/%x16, %x16), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[8]{0}, s32[17]{0}, s32[8]{0}, bf16[8,4]{1,0}, bf16[8,4]{1,0}}
   %ragged-dot-none.14 = f32[8,4]{1,0:T(8,128)} custom-call(%k, %x16, %x), custom_call_target="tpu_custom_call"
+  %de_grouped_dot.1 = f32[8,4]{1,0:T(8,128)} custom-call(%k, %k, %k, %k, %x16, %x16), custom_call_target="tpu_custom_call"
+  %de_grouped_dot = f32[8,4]{1,0:T(8,128)} custom-call(%k, %k, %k, %k, %x16, %x16), custom_call_target="tpu_custom_call"
+  %de_grouped_dw.3 = f32[2,4,4]{2,1,0:T(8,128)} custom-call(%k, %k, %k, %k, %x16, %x16), custom_call_target="tpu_custom_call"
   %pred = pred[] constant(true)
   ROOT %conditional.1 = f32[8,4]{1,0} conditional(%pred, %ragged-dot-none.12, %x), true_computation=%branch_walk.3, false_computation=%branch_rest.4
 }
@@ -570,7 +574,10 @@ ENTRY %main.5 (x: f32[8,4], k: s32[8]) -> f32[8,4] {
       "sparse_attn_fwd": 1, "sparse_attn_mean": 2, "sparse_attn_dq": 0,
       "sparse_attn_dkv": 1,
       # the expert layer's combine (ops/pallas_moe_combine.py)
-      "moe_combine": 2}
+      "moe_combine": 2,
+      # the grouped-matmul kernels (ops/pallas_grouped_matmul.py), the tail's apart
+      "grouped_dot": 2, "grouped_dot_tail": 1, "grouped_dw": 1,
+      "grouped_dw_tail": 0}
   # a toy step as this backend compiles it: the route's argsort once a layer
   # under the plan, twice under a bare checkpoint
   counts = {name: step_recompute.count_ops(_toy_step(wrap).compile().as_text())

@@ -27,7 +27,13 @@ installed beside JAX) and prints, for one step, from the compiled HLO:
                router overflows the head); ragged_dot_bf16,
                ragged_dot_tail_bf16: those of each that are handed bfloat16
                rows and weights (all of them on a TPU at default precision
-               since PR 45, `layers/dense.py::grouped_mxu_dots`; 0 before)
+               since PR 45, `layers/dense.py::grouped_mxu_dots`; 0 before).
+               Since PR 53 all four are 0 wherever the kernels below apply
+  grouped_dot, grouped_dw   calls of the grouped-matmul kernels of
+               `ops/pallas_grouped_matmul.py` (`de_grouped_dot`: a layer's 3
+               forward, 3 rematerialised and 3 `dx` products; `de_grouped_dw`:
+               its 3 `dw`); grouped_dot_tail, grouped_dw_tail: those inside a
+               conditional (the tail's)
   route_sort   the expert layers' stable argsort of the assignments: once a
                layer where the sorted order is kept; route_top_k: the sorts
                the router's `top_k` compiles to (forward and rematerialised);
@@ -42,7 +48,10 @@ installed beside JAX) and prints, for one step, from the compiled HLO:
                `layers/dense.py` does not serve)
   state_gb, temporaries_gb, total_gb   the compiled step's arguments (the
                train state and a batch) and its temporaries, in GB of the
-               chip's 17.18
+               chip's 17.18; code_gb: the executable itself, which lies in
+               HBM too and which `hbm_peak_gib` sees (a Mosaic kernel's body
+               is kept once a CALL: PR 53's first kernels, their products
+               written out whole, added 0.2 GB to GLM's step)
   program_sha  sha256 of the compiled step's HLO text with every
                `metadata={...}`, `frontend_attributes={...}` and source
                location taken out: what the chip runs, less what a profiler
@@ -158,7 +167,9 @@ def count_ops(hlo_text: str):
   ``ragged_dot_tail_bf16``: those of each whose two matrices, its last
   operands, are bfloat16), a kernel of
   ``ops/pallas_sparse_attn.py`` one named ``de_sparse_attn_<which>``, the
-  expert layer's combine ``de_moe_combine``. A sort is told by the
+  expert layer's combine ``de_moe_combine``, a grouped-matmul kernel of
+  ``ops/pallas_grouped_matmul.py`` ``de_grouped_dot`` or ``de_grouped_dw``
+  (``*_tail``: inside a conditional). A sort is told by the
   ``op_name`` the program gave it: ``route_sort`` is the expert layer's stable
   argsort, ``route_top_k`` the sort the router's ``top_k`` compiles to. A
   plain product is a ``dot`` or a ``convolution`` instruction, counted by its
@@ -169,7 +180,8 @@ def count_ops(hlo_text: str):
   counts = dict.fromkeys(("splash_fwd", "ragged_dot", "ragged_dot_tail",
                           "ragged_dot_bf16", "ragged_dot_tail_bf16", "sort",
                           "route_sort", "route_top_k", "dense_dot_f32",
-                          "dense_dot_bf16", "moe_combine",
+                          "dense_dot_bf16", "moe_combine", "grouped_dot",
+                          "grouped_dot_tail", "grouped_dw", "grouped_dw_tail",
                           *(f"sparse_attn_{which}" for which in
                             ("fwd", "mean", "dq", "dkv"))), 0)
   for comp, lines in comps.items():
@@ -214,6 +226,9 @@ def count_ops(hlo_text: str):
         counts[kernel.group(1)] += 1
       elif opcode == "custom-call" and re.match(r"de_moe_combine\b", name):
         counts["moe_combine"] += 1
+      elif opcode == "custom-call" and (
+          kernel := re.match(r"de_(grouped_(?:dot|dw))\b", name)):
+        counts[kernel.group(1) + ("_tail" if comp in tail else "")] += 1
   return counts
 
 
@@ -312,6 +327,7 @@ def main(argv=None):
             "temporaries_gb": gb(mem.temp_size_in_bytes),
             "total_gb": gb(mem.argument_size_in_bytes
                            + mem.temp_size_in_bytes),
+            "code_gb": gb(mem.generated_code_size_in_bytes),
             "program_sha": program_sha(text)}
   print(json.dumps(report))
   return report
